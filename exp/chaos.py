@@ -72,9 +72,7 @@ def _service_env(fault: Optional[str]) -> Dict[str, str]:
     env = dict(os.environ)
     env.pop("LGBM_TPU_FAULT", None)
     env.update({"JAX_PLATFORMS": "cpu",
-                "PYTHONPATH": REPO + os.pathsep + env.get("PYTHONPATH", ""),
-                "JAX_COMPILATION_CACHE_DIR": "/tmp/lgbtpu_jax_cache",
-                "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "1"})
+                "PYTHONPATH": REPO + os.pathsep + env.get("PYTHONPATH", "")})
     if fault:
         env["LGBM_TPU_FAULT"] = fault
     return env

@@ -81,9 +81,7 @@ def _run_trainer(workdir: str, cycles: int, fault: Optional[str],
     env.pop("LGBM_TPU_FAULT", None)
     env.update({"JAX_PLATFORMS": "cpu",
                 "PYTHONPATH": REPO + os.pathsep + env.get("PYTHONPATH", ""),
-                "LGBM_TPU_METRICS_FILE": metrics_file,
-                "JAX_COMPILATION_CACHE_DIR": "/tmp/lgbtpu_jax_cache",
-                "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "1"})
+                "LGBM_TPU_METRICS_FILE": metrics_file})
     if fault:
         env["LGBM_TPU_FAULT"] = fault
     args = ([sys.executable, "-m", "lightgbm_tpu", "task=train_online",
@@ -324,9 +322,7 @@ def run_phase2(workdir: str, seed: int = 11, canary_fraction: float = 0.25,
     env.update({"JAX_PLATFORMS": "cpu",
                 "PYTHONPATH": REPO + os.pathsep + env.get("PYTHONPATH", ""),
                 "LGBM_TPU_METRICS_FILE": mfile,
-                "LGBM_TPU_FAULT": "regress_model:%d" % bad_cycle,
-                "JAX_COMPILATION_CACHE_DIR": "/tmp/lgbtpu_jax_cache",
-                "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "1"})
+                "LGBM_TPU_FAULT": "regress_model:%d" % bad_cycle})
     interval = 1.5
     cycles = 5
     trainer_args = ([sys.executable, "-m", "lightgbm_tpu",

@@ -63,7 +63,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 from lightgbm_tpu.runtime import publish, resilience, telemetry, \
-    tracing, warmup  # noqa: E402
+    tracing  # noqa: E402
 
 SCHEMA_VERSION = 1
 
@@ -517,10 +517,9 @@ def run_scenario(name: str, workdir: str, replicas: int = 2,
     env = dict(os.environ)
     env.pop("LGBM_TPU_FAULT", None)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    # one persistent compile cache for the whole fleet (ISSUE 15): the
-    # trainer and every replica share compiled programs instead of each
-    # paying the cold compile (the fingerprinted subdir keeps it safe)
-    env.setdefault(warmup.CACHE_ENV, os.path.join(workdir, "compile_cache"))
+    # the trainer and every replica share ONE persistent compile cache
+    # (warmup.enable_compile_cache: the same fixed directory in every
+    # process) instead of each paying the cold compile
     # every process of the fleet self-collects its trace ring here
     # (ISSUE 14): the trainer's cycles + publishes, each replica's
     # requests/batches/swaps — merged below into ONE timeline
@@ -894,11 +893,9 @@ def run_fleet_scenario(name: str, workdir: str, duration_s: float = 40.0,
     text = _train_fleet_model(workdir, spec, seed)
     models = _publish_zoo(sdir, text)
 
-    # one persistent compile cache for the whole fleet (ISSUE 15): the
-    # first replica pays the compile, every later spawn starts warm —
-    # the seam that makes spawn-to-ready ~2 s
-    os.environ.setdefault(warmup.CACHE_ENV,
-                          os.path.join(workdir, "compile_cache"))
+    # the whole fleet shares ONE persistent compile cache (the seam's
+    # fixed directory): the first replica pays the compile, every later
+    # spawn starts warm
     replica_spec = {
         "models": models,
         "params": {"verbose": -1},

@@ -1,6 +1,30 @@
-"""Smoke: run the Pallas segment kernels on the REAL TPU vs the portable path."""
-import sys, os, time
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+"""Kernel-level check: every Pallas segment kernel, compiled by Mosaic on
+the chip and compared with the portable lax engine (or with an already
+checked sibling kernel).
+
+One verdict per kernel.  The kernels on the default path (histogram with
+both one-hot expansions, RMW / accumulator / roll partition, precision)
+come first; the six staged ones behind ``pallas_segment.STAGED_FLAGS``
+follow with a fetch-forced race each, printed as information.  A section
+that raises records its error and the run carries on to the next kernel,
+so one call to the chip answers for all of them; the exit code is non-zero
+if any section failed.  The last stdout line is one JSON object, also
+written to ``chiprun_out/smoke_tpu_kernels.json``.
+
+On the chip:   python exp/smoke_tpu_kernels.py
+CPU rehearsal: JAX_PLATFORMS=cpu python exp/smoke_tpu_kernels.py --interpret
+(the Pallas interpreter at a reduced row count; proves the script, says
+nothing about Mosaic).
+"""
+import json
+import os
+import sys
+import time
+import traceback
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -8,416 +32,352 @@ import jax.numpy as jnp
 from lightgbm_tpu.ops import segment as seg
 from lightgbm_tpu.ops import pallas_segment as pseg
 
-print("backend:", jax.default_backend(), flush=True)
+INTERPRET = "--interpret" in sys.argv[1:]
+if not INTERPRET and jax.default_backend() != "tpu":
+    sys.exit("smoke_tpu_kernels: platform is %r, not tpu (pass --interpret "
+             "to rehearse the script on the CPU)" % jax.default_backend())
+
+N = 2048 if INTERPRET else 8192
+IK = dict(interpret=INTERPRET)
 rng = np.random.default_rng(0)
-N, F = 4096, 6
-B = 64
-P = 128  # lane-aligned payload width, as the fast path provides on TPU
-GRAD, HESS, CNT, VAL = F, F + 1, F + 2, F + 3
-
-payload = np.zeros((N + seg.GUARD, P), np.float32)
-payload[:N, :F] = rng.integers(0, B, (N, F))
-payload[:N, GRAD] = rng.standard_normal(N)
-payload[:N, HESS] = rng.random(N) + 0.1
-payload[:N, CNT] = 1.0
-payload = jnp.asarray(payload)
-aux = jnp.zeros_like(payload)
-
-start, count = jnp.int32(128), jnp.int32(3000)
-
-t0 = time.time()
-h_pl = pseg.segment_histogram(payload, start, count, num_features=F,
-                              num_bins=B, grad_col=GRAD, hess_col=HESS,
-                              cnt_col=CNT)
-jax.block_until_ready(h_pl)
-print("pallas hist compile+run %.1fs" % (time.time() - t0), flush=True)
-h_ref = seg.segment_histogram(payload, start, count, num_features=F,
-                              num_bins=B, grad_col=GRAD, hess_col=HESS,
-                              cnt_col=CNT)
-err = float(jnp.abs(h_pl - h_ref).max())
-print("hist max abs err:", err, flush=True)
-assert err < 1e-3, err
-
-pred = seg.SplitPredicate(
-    col=jnp.int32(2), threshold=jnp.int32(30),
-    default_left=jnp.bool_(True), is_cat=jnp.bool_(False),
-    missing_type=jnp.int32(0), num_bin=jnp.int32(B),
-    default_bin=jnp.int32(0), offset=jnp.int32(0),
-    identity=jnp.bool_(True), bitset=jnp.zeros(B, jnp.int32))
-
-t0 = time.time()
-p_pl, a_pl, nl_pl = pseg.partition_segment(
-    payload, aux, start, count, pred, jnp.float32(1.5), jnp.float32(-2.5),
-    VAL, B)
-jax.block_until_ready(p_pl)
-print("pallas partition compile+run %.1fs" % (time.time() - t0), flush=True)
-p_ref, a_ref, nl_ref = seg.partition_segment(
-    payload, aux, start, count, pred, jnp.float32(1.5), jnp.float32(-2.5),
-    VAL)
-print("num_left pallas=%d ref=%d" % (int(nl_pl), int(nl_ref)), flush=True)
-assert int(nl_pl) == int(nl_ref)
-perr = float(jnp.abs(p_pl - p_ref).max())
-print("partition payload max abs err:", perr, flush=True)
-assert perr < 1e-5, perr
-print("SMOKE OK", flush=True)
 
 
-# --- round-4 additions: feature-TILED histogram at wide-benchmark shapes
-# (MS-LTR 137x256, Expo 700x256) with the double-buffered chunk DMA ---
-for (Fw, Bw) in ((137, 256), (700, 256), (968, 64), (2000, 64)):
-    assert pseg.fits_vmem(Fw, Bw), (Fw, Bw)
-    Pw = -(-(Fw + 12) // 128) * 128
-    gcol, hcol, ccol = Fw, Fw + 1, Fw + 2
-    pay_w = np.zeros((2048 + seg.GUARD, Pw), np.float32)
-    pay_w[:2048, :Fw] = rng.integers(0, Bw, (2048, Fw))
-    pay_w[:2048, gcol] = rng.standard_normal(2048)
-    pay_w[:2048, hcol] = rng.random(2048) + 0.1
-    pay_w[:2048, ccol] = 1.0
-    pay_w = jnp.asarray(pay_w)
-    s_w, c_w = jnp.int32(256), jnp.int32(1500)
-    t0 = time.time()
-    h_w = pseg.segment_histogram(pay_w, s_w, c_w, num_features=Fw,
-                                 num_bins=Bw, grad_col=gcol, hess_col=hcol,
-                                 cnt_col=ccol)
-    jax.block_until_ready(h_w)
-    print("tiled hist %dx%d compile+run %.1fs" % (Fw, Bw, time.time() - t0),
-          flush=True)
-    h_wref = seg.segment_histogram(pay_w, s_w, c_w, num_features=Fw,
-                                   num_bins=Bw, grad_col=gcol, hess_col=hcol,
-                                   cnt_col=ccol)
-    err = float(jnp.abs(h_w - h_wref).max())
-    print("tiled hist %dx%d max abs err: %s" % (Fw, Bw, err), flush=True)
-    assert err < 1e-2, err
-print("tiled + double-buffered histogram kernels OK on", jax.default_backend(),
-      flush=True)
-
-
-# --- precision: the MXU's default f32 matmul is ONE bf16 pass, which (before
-# the HIGHEST/part-decomposition fixes) rounded every permuted payload value
-# to 8 mantissa bits and collapsed the radix-4096 idx columns.  These checks
-# only bite on real hardware — interpret mode is plain f32.  ---
-IDX = F + 4
-payx = np.zeros((8192 + seg.GUARD, P), np.float32)
-payx[:8192, :F] = rng.integers(0, B, (8192, F))
-gvals = (1.0 + rng.random(8192) * 2.0**-18).astype(np.float32)  # >8 mantissa bits
-payx[:8192, GRAD] = gvals
-payx[:8192, HESS] = 1.0
-payx[:8192, CNT] = 1.0
-payx[:8192, IDX] = np.arange(8192, dtype=np.float32) % 4096
-p_x, _, _ = pseg.partition_segment(
-    jnp.asarray(payx), jnp.zeros_like(jnp.asarray(payx)), jnp.int32(0),
-    jnp.int32(8192), pred, jnp.float32(1.0), jnp.float32(-1.0), VAL, B)
-p_x = np.asarray(p_x)
-assert np.array_equal(np.sort(p_x[:8192, IDX]), np.sort(payx[:8192, IDX])), \
-    "idx columns corrupted by the partition matmul"
-assert np.array_equal(np.sort(p_x[:8192, GRAD]), np.sort(gvals)), \
-    "payload values bf16-rounded by the partition matmul"
-h_x = pseg.segment_histogram(jnp.asarray(payx), jnp.int32(0), jnp.int32(8192),
-                             num_features=F, num_bins=B, grad_col=GRAD,
-                             hess_col=HESS, cnt_col=CNT)
-h64 = np.zeros((F, B), np.float64)
-for f in range(F):
-    np.add.at(h64[f], payx[:8192, f].astype(np.int64), gvals.astype(np.float64))
-gerr = float(np.abs(np.asarray(h_x)[:, :, 0] - h64).max())
-print("hist grad-sum err vs float64: %.3g" % gerr, flush=True)
-assert gerr < 1e-3, gerr   # f32-accumulation class, NOT bf16-input class (~0.5)
-print("PRECISION OK: exact permutation + f32-class histograms on",
-      jax.default_backend(), flush=True)
-
-
-# --- accumulator-window partition kernel: Mosaic-compile + exactness +
-# speed vs the RMW kernel.  Flip pseg.PARTITION_ACC_VALIDATED once this
-# section is green on real hardware. ---
-import time as _t
-for (s_a, c_a) in ((128, 3000), (7, 8000), (513, 256), (0, 8192)):
-    p_a, a_a, nl_a = pseg.partition_segment_acc(
-        jnp.asarray(payx), jnp.zeros_like(jnp.asarray(payx)),
-        jnp.int32(s_a), jnp.int32(c_a), pred, jnp.float32(1.5),
-        jnp.float32(-2.5), VAL, B)
-    p_r, a_r, nl_r = seg.partition_segment(
-        jnp.asarray(payx), jnp.zeros_like(jnp.asarray(payx)),
-        jnp.int32(s_a), jnp.int32(c_a), pred, jnp.float32(1.5),
-        jnp.float32(-2.5), VAL)
-    assert int(nl_a) == int(nl_r), (s_a, c_a, int(nl_a), int(nl_r))
-    err_a = float(jnp.abs(p_a - p_r).max())
-    print("acc partition (%d,%d): nl=%d err=%s" % (s_a, c_a, int(nl_a), err_a),
-          flush=True)
-    assert err_a == 0.0, err_a
-p_roll, _, nl_roll = pseg.partition_segment_acc(
-    jnp.asarray(payx), jnp.zeros_like(jnp.asarray(payx)), jnp.int32(7),
-    jnp.int32(8000), pred, jnp.float32(1.5), jnp.float32(-2.5), VAL, B,
-    roll_place=True)
-p_rollref, _, nl_rollref = seg.partition_segment(
-    jnp.asarray(payx), jnp.zeros_like(jnp.asarray(payx)), jnp.int32(7),
-    jnp.int32(8000), pred, jnp.float32(1.5), jnp.float32(-2.5), VAL)
-assert int(nl_roll) == int(nl_rollref)
-err_roll = float(jnp.abs(p_roll - p_rollref).max())
-print("acc+roll partition err:", err_roll, flush=True)
-assert err_roll == 0.0, err_roll
-for name, fn in (("rmw", lambda p_, a_: pseg.partition_segment(
-                     p_, a_, jnp.int32(0), jnp.int32(8192), pred,
-                     jnp.float32(1.), jnp.float32(-1.), VAL, B)),
-                 ("acc", lambda p_, a_: pseg.partition_segment_acc(
-                     p_, a_, jnp.int32(0), jnp.int32(8192), pred,
-                     jnp.float32(1.), jnp.float32(-1.), VAL, B,
-                     roll_place=False)),
-                 ("acc+roll", lambda p_, a_: pseg.partition_segment_acc(
-                     p_, a_, jnp.int32(0), jnp.int32(8192), pred,
-                     jnp.float32(1.), jnp.float32(-1.), VAL, B,
-                     roll_place=True))):
+def median_ms(fn, reps=5):
+    """Median wall time of fn(), which must fetch its result to the host."""
+    if INTERPRET:
+        return None
+    fn()
     ts = []
-    for _ in range(5):
-        p_, a_ = jnp.asarray(payx), jnp.zeros_like(jnp.asarray(payx))
-        _ = np.asarray(p_)[0, 0]
-        t0 = _t.perf_counter()
-        nl_ = int(fn(p_, a_)[2])
-        ts.append(_t.perf_counter() - t0)
-    print("partition[%s] 8192 rows: median %.2f ms (fetch-forced)"
-          % (name, sorted(ts)[2] * 1e3), flush=True)
-print("ACC PARTITION OK on", jax.default_backend(), flush=True)
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        fn()
+        ts.append(time.perf_counter() - t0)
+    return round(sorted(ts)[reps // 2] * 1e3, 3)
 
 
-# --- repeat-based one-hot expansion: Mosaic-compile + exactness + speed
-# vs the expand-matmul histogram.  Flip pseg.HIST_REPEAT_VALIDATED once
-# green here. ---
-for (Fr, Br) in ((28, 256), (137, 256), (700, 256)):
-    Pr = -(-(Fr + 12) // 128) * 128
-    gc, hc, cc = Fr, Fr + 1, Fr + 2
-    pay_r = np.zeros((8192 + seg.GUARD, Pr), np.float32)
-    pay_r[:8192, :Fr] = rng.integers(0, Br, (8192, Fr))
-    pay_r[:8192, gc] = rng.standard_normal(8192)
-    pay_r[:8192, hc] = rng.random(8192) + 0.1
-    pay_r[:8192, cc] = 1.0
-    pay_r = jnp.asarray(pay_r)
-    kw = dict(num_features=Fr, num_bins=Br, grad_col=gc, hess_col=hc,
-              cnt_col=cc)
-    h_m = pseg.segment_histogram(pay_r, jnp.int32(128), jnp.int32(7000),
-                                 expand_impl="matmul", **kw)
-    h_r = pseg.segment_histogram(pay_r, jnp.int32(128), jnp.int32(7000),
-                                 expand_impl="repeat", **kw)
-    err_r = float(jnp.abs(np.asarray(h_m) - np.asarray(h_r)).max())
-    print("repeat hist %dx%d max abs err vs matmul: %s" % (Fr, Br, err_r),
-          flush=True)
-    assert err_r < 1e-4, err_r
-    for label in ("matmul", "repeat"):
-        ts = []
-        for i in range(5):
-            t0 = _t.perf_counter()
-            h_ = np.asarray(pseg.segment_histogram(
-                pay_r, jnp.int32(0), jnp.int32(8192 - i),
-                expand_impl=label, **kw))[0, 0, 2]
-            ts.append(_t.perf_counter() - t0)
-        print("hist[%s] %dx%d 8192 rows: median %.2f ms (fetch-forced)"
-              % (label, Fr, Br, sorted(ts)[2] * 1e3), flush=True)
-print("REPEAT HIST OK on", jax.default_backend(), flush=True)
+def make_payload(n, F, B, width=None, grads=None):
+    """[n + GUARD, P] payload: F bin columns then grad, hess, count."""
+    P = width or -(-(F + 12) // 128) * 128
+    pay = np.zeros((n + seg.GUARD, P), np.float32)
+    pay[:n, :F] = rng.integers(0, B, (n, F))
+    pay[:n, F] = rng.standard_normal(n) if grads is None else grads
+    pay[:n, F + 1] = rng.random(n) + 0.1
+    pay[:n, F + 2] = 1.0
+    return jnp.asarray(pay)
 
 
-# --- merged partition+hist kernel: Mosaic-compile + exactness + speed vs
-# the split acc-partition + hist pair.  Flip pseg.PARTITION_HIST_VALIDATED
-# once this section is green on real hardware. ---
-MF, MB = 28, 256
-MP = 128
-mg, mh, mc, MVAL = MF, MF + 1, MF + 2, MF + 3
-pay_m = np.zeros((8192 + seg.GUARD, MP), np.float32)
-pay_m[:8192, :MF] = rng.integers(0, MB, (8192, MF))
-pay_m[:8192, mg] = rng.standard_normal(8192)
-pay_m[:8192, mh] = rng.random(8192) + 0.1
-pay_m[:8192, mc] = 1.0
-pay_m = jnp.asarray(pay_m)
-pred_m = seg.SplitPredicate(
-    col=jnp.int32(2), threshold=jnp.int32(100),
-    default_left=jnp.bool_(True), is_cat=jnp.bool_(False),
-    missing_type=jnp.int32(0), num_bin=jnp.int32(MB),
-    default_bin=jnp.int32(0), offset=jnp.int32(0),
-    identity=jnp.bool_(True), bitset=jnp.zeros(MB, jnp.int32))
-mkw = dict(num_features=MF, grad_col=mg, hess_col=mh, cnt_col=mc)
-for (s_m, c_m) in ((128, 3000), (7, 8000), (513, 256)):
-    p_m, a_m, nl_m, hl_m, hr_m = pseg.partition_segment_hist(
-        pay_m, jnp.zeros_like(pay_m), jnp.int32(s_m), jnp.int32(c_m),
-        pred_m, jnp.float32(1.5), jnp.float32(-2.5), MVAL, MB, **mkw)
-    p_mr, _, nl_mr = seg.partition_segment(
-        pay_m, jnp.zeros_like(pay_m), jnp.int32(s_m), jnp.int32(c_m),
-        pred_m, jnp.float32(1.5), jnp.float32(-2.5), MVAL)
-    assert int(nl_m) == int(nl_mr), (s_m, c_m, int(nl_m), int(nl_mr))
-    perr_m = float(jnp.abs(p_m - p_mr).max())
-    hl_ref = seg.segment_histogram(p_mr, jnp.int32(s_m), nl_mr,
-                                   num_bins=MB, **mkw)
-    hr_ref = seg.segment_histogram(p_mr, jnp.int32(s_m) + nl_mr,
-                                   jnp.int32(c_m) - nl_mr,
-                                   num_bins=MB, **mkw)
-    herr = max(float(jnp.abs(hl_m - hl_ref).max()),
-               float(jnp.abs(hr_m - hr_ref).max()))
-    print("merged part+hist (%d,%d): nl=%d perr=%s herr=%.3g"
-          % (s_m, c_m, int(nl_m), perr_m, herr), flush=True)
-    assert perr_m == 0.0, perr_m
-    assert herr < 1e-3, herr
-# race: merged kernel vs (acc partition + one smaller-child hist) — the
-# product's per-split device work in each mode
+def make_pred(col, threshold, B):
+    return seg.SplitPredicate(
+        col=jnp.int32(col), threshold=jnp.int32(threshold),
+        default_left=jnp.bool_(True), is_cat=jnp.bool_(False),
+        missing_type=jnp.int32(0), num_bin=jnp.int32(B),
+        default_bin=jnp.int32(0), offset=jnp.int32(0),
+        identity=jnp.bool_(True), bitset=jnp.zeros(B, jnp.int32))
 
 
-def _split_mode(p_, a_):
-    h_ = pseg.segment_histogram(p_, jnp.int32(0), jnp.int32(4096),
-                                num_bins=MB, **mkw)
-    out_ = pseg.partition_segment_acc(
-        p_, a_, jnp.int32(0), jnp.int32(8192), pred_m,
-        jnp.float32(1.), jnp.float32(-1.), MVAL, MB)
-    jax.block_until_ready(h_)
-    return out_
+def hist_kw(F, B):
+    return dict(num_features=F, num_bins=B, grad_col=F, hess_col=F + 1,
+                cnt_col=F + 2)
 
 
-def _merged_mode(p_, a_):
-    return pseg.partition_segment_hist(
-        p_, a_, jnp.int32(0), jnp.int32(8192), pred_m,
-        jnp.float32(1.), jnp.float32(-1.), MVAL, MB, **mkw)
+def segs(*pairs):
+    """Clip (start, count) pairs to the rehearsal row count."""
+    return [(s, min(c, N - s)) for s, c in pairs if s < N]
 
 
-for name, fn in (("split: acc+hist", _split_mode), ("merged", _merged_mode)):
-    ts = []
-    for _ in range(5):
-        p_, a_ = jnp.asarray(pay_m), jnp.zeros_like(pay_m)
-        _ = np.asarray(p_)[0, 0]
-        t0 = _t.perf_counter()
-        nl_ = int(fn(p_, a_)[2])
-        ts.append(_t.perf_counter() - t0)
-    print("per-split device work[%s] 8192 rows: median %.2f ms (fetch-forced)"
-          % (name, sorted(ts)[2] * 1e3), flush=True)
-print("MERGED PART+HIST OK on", jax.default_backend(), flush=True)
+def check_partition(fn, pay, pred, value_col, pairs):
+    """fn(payload, aux, start, count) against the portable partition."""
+    for s, c in pairs:
+        p, _, nl = fn(pay, jnp.zeros_like(pay), jnp.int32(s), jnp.int32(c))
+        pr, _, nlr = seg.partition_segment(
+            pay, jnp.zeros_like(pay), jnp.int32(s), jnp.int32(c), pred,
+            jnp.float32(1.5), jnp.float32(-2.5), value_col)
+        assert int(nl) == int(nlr), (s, c, int(nl), int(nlr))
+        err = float(jnp.abs(p - pr).max())
+        assert err == 0.0, (s, c, err)
 
 
-# --- column-block histogram engine: Mosaic-compile + exactness at an
-# ultra-wide payload (the raw-Allstate / Epsilon class that overflows the
-# single-pass plan), including the two-window DMA the single-pass kernel
-# never issues.  Flip pseg.HIST_COLBLOCK_VALIDATED once this section is
-# green on real hardware. ---
-CBF, CBB = 1500, 64            # spans 3 column blocks + ragged tail
-CBP = -(-(CBF + 8) // 128) * 128
-pay_cb = np.zeros((8192 + seg.GUARD, CBP), np.float32)
-pay_cb[:8192, :CBF] = rng.integers(0, CBB, (8192, CBF))
-pay_cb[:8192, CBF] = rng.standard_normal(8192)
-pay_cb[:8192, CBF + 1] = rng.random(8192) + 0.1
-pay_cb[:8192, CBF + 2] = 1.0
-pay_cb = jnp.asarray(pay_cb)
-cbkw = dict(num_features=CBF, num_bins=CBB, grad_col=CBF,
-            hess_col=CBF + 1, cnt_col=CBF + 2)
-assert pseg.fits_vmem_colblock(CBF, CBB, CBP, CBF, CBF + 1, CBF + 2)
-for (s_cb, c_cb) in ((0, 8000), (7, 4097), (513, 256)):
-    h_cb = pseg.segment_histogram_colblock(
-        pay_cb, jnp.int32(s_cb), jnp.int32(c_cb), **cbkw)
-    h_ref = seg.segment_histogram(pay_cb, jnp.int32(s_cb),
-                                  jnp.int32(c_cb), **cbkw)
-    err_cb = float(jnp.abs(h_cb - h_ref).max())
-    print("colblock hist (%d,%d): err=%.3g" % (s_cb, c_cb, err_cb),
-          flush=True)
-    assert err_cb < 1e-3, err_cb
-ts = []
-for i in range(5):
-    t0 = _t.perf_counter()
-    _ = np.asarray(pseg.segment_histogram_colblock(
-        pay_cb, jnp.int32(0), jnp.int32(8192 - i), **cbkw))[0, 0, 2]
-    ts.append(_t.perf_counter() - t0)
-print("colblock hist %dx%d 8192 rows: median %.2f ms (fetch-forced)"
-      % (CBF, CBB, sorted(ts)[2] * 1e3), flush=True)
-print("COLBLOCK HIST OK on", jax.default_backend(), flush=True)
+# Higgs-shaped payload shared by most sections
+F, B = 28, 256
+VAL = F + 3
+PAY = make_payload(N, F, B, width=128)
+PRED = make_pred(2, 100, B)
+KW = hist_kw(F, B)
+LV, RV = jnp.float32(1.5), jnp.float32(-2.5)
 
 
-# --- 4-deep read ring for the acc partition: Mosaic-compile + exactness
-# + race vs the validated 2-deep ring (the per-chunk DMA wait is the
-# measured bottleneck; depth 4 issues three chunks ahead).  Flip
-# pseg.PARTITION_RING4_VALIDATED once green AND the race favors (or
-# ties) depth 4. ---
-for rd in (2, 4):
-    p_r4, _, nl_r4 = pseg.partition_segment_acc(
-        jnp.asarray(pay_m), jnp.zeros_like(pay_m), jnp.int32(128),
-        jnp.int32(7000), pred_m, jnp.float32(1.5), jnp.float32(-2.5),
-        MVAL, MB, ring_depth=rd)
-    if rd == 2:
-        p_ref_r, nl_ref_r = np.asarray(p_r4), int(nl_r4)
-    else:
-        assert int(nl_r4) == nl_ref_r
-        err_r4 = float(np.abs(np.asarray(p_r4) - p_ref_r).max())
-        print("ring4 vs ring2 exactness: err=%.3g" % err_r4, flush=True)
-        assert err_r4 == 0.0, err_r4
-for rd in (2, 4):
-    ts = []
-    for _ in range(5):
-        p_, a_ = jnp.asarray(pay_m), jnp.zeros_like(pay_m)
-        _ = np.asarray(p_)[0, 0]
-        t0 = _t.perf_counter()
-        nl_ = int(pseg.partition_segment_acc(
-            p_, a_, jnp.int32(0), jnp.int32(8192), pred_m,
-            jnp.float32(1.), jnp.float32(-1.), MVAL, MB,
-            ring_depth=rd)[2])
-        ts.append(_t.perf_counter() - t0)
-    print("acc partition ring=%d 8192 rows: median %.2f ms (fetch-forced)"
-          % (rd, sorted(ts)[2] * 1e3), flush=True)
-print("RING OK on", jax.default_backend(), flush=True)
-# the flip also switches the MERGED kernel's ring: validate it at depth 4
-p_m4, _, nl_m4, hl_m4, hr_m4 = pseg.partition_segment_hist(
-    jnp.asarray(pay_m), jnp.zeros_like(pay_m), jnp.int32(128),
-    jnp.int32(7000), pred_m, jnp.float32(1.5), jnp.float32(-2.5),
-    MVAL, MB, ring_depth=4, **mkw)
-p_m2, _, nl_m2, hl_m2, hr_m2 = pseg.partition_segment_hist(
-    jnp.asarray(pay_m), jnp.zeros_like(pay_m), jnp.int32(128),
-    jnp.int32(7000), pred_m, jnp.float32(1.5), jnp.float32(-2.5),
-    MVAL, MB, ring_depth=2, **mkw)
-assert int(nl_m4) == int(nl_m2)
-err_m4 = max(float(jnp.abs(p_m4 - p_m2).max()),
-             float(jnp.abs(hl_m4 - hl_m2).max()),
-             float(jnp.abs(hr_m4 - hr_m2).max()))
-print("merged kernel ring4 vs ring2: err=%.3g" % err_m4, flush=True)
-assert err_m4 == 0.0, err_m4
-print("RING(MERGED) OK on", jax.default_backend(), flush=True)
+# ---- default path ---------------------------------------------------------
+
+def hist_expand():
+    """segment_histogram, both one-hot expansions, against the lax engine
+    at the Higgs / MS-LTR / Expo widths, plus feature-tiled wide shapes.
+    The repeat expansion assumes pltpu.repeat concatenates tiles (column
+    b*fw + f); a layout change shows here as a large error."""
+    info = {}
+    shapes = ((28, 256), (137, 256), (700, 256), (968, 64), (2000, 64))
+    for Fw, Bw in shapes[:2] if INTERPRET else shapes:
+        assert pseg.fits_vmem(Fw, Bw), (Fw, Bw)
+        pay = make_payload(N, Fw, Bw)
+        kw = hist_kw(Fw, Bw)
+        ref = seg.segment_histogram(pay, jnp.int32(128), jnp.int32(N - 1000),
+                                    **kw)
+        for impl in ("matmul", "repeat"):
+            h = pseg.segment_histogram(pay, jnp.int32(128),
+                                       jnp.int32(N - 1000),
+                                       expand_impl=impl, **kw, **IK)
+            err = float(jnp.abs(h - ref).max())
+            assert err < 1e-2, (Fw, Bw, impl, err)
+            info["%dx%d_%s_ms" % (Fw, Bw, impl)] = median_ms(
+                lambda: np.asarray(pseg.segment_histogram(
+                    pay, jnp.int32(0), jnp.int32(N), expand_impl=impl,
+                    **kw, **IK))[0, 0, 2])
+    info["default_at_higgs"] = pseg._default_expand_impl(28, 256)
+    return info
 
 
-# --- column-block PARTITION: Mosaic-compile + exactness at an ultra-wide
-# payload (Epsilon/raw-Allstate class; the full-width partition kernels
-# cannot plan VMEM there).  Includes the one new Mosaic pattern of the
-# family: the snapshot kernel's traced-but-128-aligned lane base.  Flip
-# pseg.PARTITION_BLOCKS_VALIDATED once green and the race beats the
-# portable partition. ---
-PBF, PBB = 1200, 64
-PBP = -(-(PBF + 8) // 128) * 128
-pay_pb = np.zeros((8192 + seg.GUARD, PBP), np.float32)
-pay_pb[:8192, :PBF] = rng.integers(0, PBB, (8192, PBF))
-pay_pb[:8192, PBF] = rng.standard_normal(8192)
-pay_pb[:8192, PBF + 1] = rng.random(8192) + 0.1
-pay_pb[:8192, PBF + 2] = 1.0
-pay_pb = jnp.asarray(pay_pb)
-PBVAL = PBF + 3
-pred_pb = seg.SplitPredicate(
-    col=jnp.int32(700), threshold=jnp.int32(30),
-    default_left=jnp.bool_(True), is_cat=jnp.bool_(False),
-    missing_type=jnp.int32(0), num_bin=jnp.int32(PBB),
-    default_bin=jnp.int32(0), offset=jnp.int32(0),
-    identity=jnp.bool_(True), bitset=jnp.zeros(PBB, jnp.int32))
-for (s_pb, c_pb) in ((128, 3000), (7, 8000), (513, 256)):
-    p_pb, _, nl_pb = pseg.partition_segment_acc_blocks(
-        pay_pb, jnp.zeros_like(pay_pb), jnp.int32(s_pb), jnp.int32(c_pb),
-        pred_pb, jnp.float32(1.5), jnp.float32(-2.5), PBVAL, PBB)
-    p_pr, _, nl_pr = seg.partition_segment(
-        pay_pb, jnp.zeros_like(pay_pb), jnp.int32(s_pb), jnp.int32(c_pb),
-        pred_pb, jnp.float32(1.5), jnp.float32(-2.5), PBVAL)
-    assert int(nl_pb) == int(nl_pr), (s_pb, c_pb, int(nl_pb), int(nl_pr))
-    err_pb = float(jnp.abs(p_pb - p_pr).max())
-    print("blocks partition (%d,%d): nl=%d err=%.3g"
-          % (s_pb, c_pb, int(nl_pb), err_pb), flush=True)
-    assert err_pb == 0.0, err_pb
-for name, fn in (
-    ("portable", lambda p_, a_: seg.partition_segment(
-        p_, a_, jnp.int32(0), jnp.int32(8192), pred_pb,
-        jnp.float32(1.), jnp.float32(-1.), PBVAL)),
-    ("blocks", lambda p_, a_: pseg.partition_segment_acc_blocks(
-        p_, a_, jnp.int32(0), jnp.int32(8192), pred_pb,
-        jnp.float32(1.), jnp.float32(-1.), PBVAL, PBB)),
-):
-    ts = []
-    for _ in range(5):
-        p_, a_ = jnp.asarray(pay_pb), jnp.zeros_like(pay_pb)
-        _ = np.asarray(p_)[0, 0]
-        t0 = _t.perf_counter()
-        out_ = fn(p_, a_)
-        _ = np.asarray(out_[0])[0, 0]
-        ts.append(_t.perf_counter() - t0)
-    print("ultra-wide partition[%s] 8192x%d rows: median %.2f ms "
-          "(fetch-forced)" % (name, PBP, sorted(ts)[2] * 1e3), flush=True)
-print("BLOCKS PARTITION OK on", jax.default_backend(), flush=True)
+def partition_rmw():
+    """partition_segment (read-modify-write windows)."""
+    check_partition(
+        lambda p, a, s, c: pseg.partition_segment(
+            p, a, s, c, PRED, LV, RV, VAL, B, **IK),
+        PAY, PRED, VAL, segs((128, 3000), (7, 8000), (513, 256)))
+    return {"ms": median_ms(lambda: int(pseg.partition_segment(
+        PAY, jnp.zeros_like(PAY), jnp.int32(0), jnp.int32(N), PRED, LV, RV,
+        VAL, B, **IK)[2]))}
+
+
+def partition_acc():
+    """partition_segment_acc, matmul placement and roll placement (a
+    traced sublane pltpu.roll of a [2C, P] concatenate)."""
+    info = {}
+    for roll in (False, True):
+        check_partition(
+            lambda p, a, s, c: pseg.partition_segment_acc(
+                p, a, s, c, PRED, LV, RV, VAL, B, roll_place=roll, **IK),
+            PAY, PRED, VAL,
+            segs((128, 3000), (7, 8000), (513, 256), (0, 8192)))
+        info["roll" if roll else "matmul"] = median_ms(
+            lambda: int(pseg.partition_segment_acc(
+                PAY, jnp.zeros_like(PAY), jnp.int32(0), jnp.int32(N), PRED,
+                LV, RV, VAL, B, roll_place=roll, **IK)[2]))
+    return info
+
+
+def precision():
+    """The MXU's default f32 matmul is one bf16 pass: the partition must
+    still permute payload values and radix-4096 index columns exactly, and
+    the histogram must keep f32-class sums (bf16-class would be ~0.5).
+    Only bites on hardware; the interpreter is plain f32."""
+    IDX = F + 4
+    gvals = (1.0 + rng.random(N) * 2.0 ** -18).astype(np.float32)
+    pay = np.array(make_payload(N, F, B, width=128, grads=gvals))
+    pay[:N, F + 1] = 1.0
+    pay[:N, IDX] = np.arange(N, dtype=np.float32) % 4096
+    out = np.asarray(pseg.partition_segment_acc(
+        jnp.asarray(pay), jnp.zeros_like(jnp.asarray(pay)), jnp.int32(0),
+        jnp.int32(N), PRED, LV, RV, VAL, B, **IK)[0])
+    assert np.array_equal(np.sort(out[:N, IDX]), np.sort(pay[:N, IDX])), \
+        "idx column corrupted by the partition matmul"
+    assert np.array_equal(np.sort(out[:N, F]), np.sort(gvals)), \
+        "payload values bf16-rounded by the partition matmul"
+    h = pseg.segment_histogram(jnp.asarray(pay), jnp.int32(0), jnp.int32(N),
+                               **KW, **IK)
+    h64 = np.zeros((F, B), np.float64)
+    for f in range(F):
+        np.add.at(h64[f], pay[:N, f].astype(np.int64),
+                  gvals.astype(np.float64))
+    gerr = float(np.abs(np.asarray(h)[:, :, 0] - h64).max())
+    assert gerr < 1e-3, gerr
+    return {"hist_grad_err_vs_f64": gerr}
+
+
+# ---- staged (flags in pallas_segment.STAGED_FLAGS; deciding them is a
+# later PR — the races are information) ------------------------------------
+
+def merged():
+    """partition_segment_hist: partition + both children's histograms."""
+    for s, c in segs((128, 3000), (7, 8000), (513, 256)):
+        pm, _, nlm, hl, hr = pseg.partition_segment_hist(
+            PAY, jnp.zeros_like(PAY), jnp.int32(s), jnp.int32(c), PRED,
+            LV, RV, VAL, B, num_features=F, grad_col=F, hess_col=F + 1,
+            cnt_col=F + 2, **IK)
+        pr, _, nlr = seg.partition_segment(
+            PAY, jnp.zeros_like(PAY), jnp.int32(s), jnp.int32(c), PRED,
+            LV, RV, VAL)
+        assert int(nlm) == int(nlr), (s, c)
+        assert float(jnp.abs(pm - pr).max()) == 0.0, (s, c)
+        hlr = seg.segment_histogram(pr, jnp.int32(s), nlr, **KW)
+        hrr = seg.segment_histogram(pr, jnp.int32(s) + nlr,
+                                    jnp.int32(c) - nlr, **KW)
+        herr = max(float(jnp.abs(hl - hlr).max()),
+                   float(jnp.abs(hr - hrr).max()))
+        assert herr < 1e-3, (s, c, herr)
+
+    def split_mode():
+        h_ = pseg.segment_histogram(PAY, jnp.int32(0), jnp.int32(N // 2),
+                                    **KW, **IK)
+        out = pseg.partition_segment_acc(
+            PAY, jnp.zeros_like(PAY), jnp.int32(0), jnp.int32(N), PRED,
+            LV, RV, VAL, B, **IK)
+        np.asarray(h_)[0, 0, 2]
+        return int(out[2])
+
+    def merged_mode():
+        return int(pseg.partition_segment_hist(
+            PAY, jnp.zeros_like(PAY), jnp.int32(0), jnp.int32(N), PRED,
+            LV, RV, VAL, B, num_features=F, grad_col=F, hess_col=F + 1,
+            cnt_col=F + 2, **IK)[2])
+
+    return {"split_ms": median_ms(split_mode),
+            "merged_ms": median_ms(merged_mode)}
+
+
+def frontier():
+    """segment_histogram_batched: K segments in one grid-(K,) dispatch."""
+    starts = jnp.asarray([0, 512, 1024, 7, 1536, 0], jnp.int32)
+    counts = jnp.asarray([500, 512, 250, 505, 500, 0], jnp.int32)
+    hb = pseg.segment_histogram_batched(PAY, starts, counts, **KW, **IK)
+    for k in range(6):
+        h1 = pseg.segment_histogram(PAY, starts[k], counts[k], **KW, **IK)
+        assert float(jnp.abs(hb[k] - h1).max()) == 0.0, k
+
+    def seq_mode():
+        for k in range(6):
+            np.asarray(pseg.segment_histogram(
+                PAY, starts[k], counts[k], **KW, **IK))[0, 0, 2]
+
+    return {"sequential6_ms": median_ms(seq_mode),
+            "batched6_ms": median_ms(
+                lambda: np.asarray(pseg.segment_histogram_batched(
+                    PAY, starts, counts, **KW, **IK))[0, 0, 0, 2])}
+
+
+def quant():
+    """segment_histogram_quant: int8 values x int8 one-hot -> int32; bit
+    equality with the portable integer engine."""
+    payq = np.array(PAY)
+    payq[:N, F] = rng.integers(-127, 128, N)
+    payq[:N, F + 1] = rng.integers(0, 128, N)
+    payq = jnp.asarray(payq)
+    for s, c in segs((0, 8000), (7, 4097), (1024, 1), (0, 0)):
+        hq = pseg.segment_histogram_quant(payq, jnp.int32(s), jnp.int32(c),
+                                          **KW, **IK)
+        hr = seg.segment_histogram(payq, jnp.int32(s), jnp.int32(c),
+                                   quantized=True, **KW)
+        assert int(jnp.abs(hq - hr).max()) == 0, (s, c)
+    return {"quant_int8_ms": median_ms(
+                lambda: np.asarray(pseg.segment_histogram_quant(
+                    payq, jnp.int32(0), jnp.int32(N), **KW, **IK))[0, 0, 2]),
+            "f32_kernel_ms": median_ms(
+                lambda: np.asarray(pseg.segment_histogram(
+                    payq, jnp.int32(0), jnp.int32(N), **KW, **IK))[0, 0, 2])}
+
+
+def colblock():
+    """segment_histogram_colblock at an ultra-wide payload (3 column
+    blocks + ragged tail; the two-window DMA)."""
+    Fw, Bw = 1500, 64
+    Pw = -(-(Fw + 8) // 128) * 128
+    pay = make_payload(N, Fw, Bw, width=Pw)
+    kw = hist_kw(Fw, Bw)
+    assert pseg.fits_vmem_colblock(Fw, Bw, Pw, Fw, Fw + 1, Fw + 2)
+    for s, c in segs((0, 8000), (7, 4097), (513, 256)):
+        h = pseg.segment_histogram_colblock(pay, jnp.int32(s), jnp.int32(c),
+                                            **kw, **IK)
+        ref = seg.segment_histogram(pay, jnp.int32(s), jnp.int32(c), **kw)
+        err = float(jnp.abs(h - ref).max())
+        assert err < 1e-3, (s, c, err)
+    return {"colblock_ms": median_ms(
+                lambda: np.asarray(pseg.segment_histogram_colblock(
+                    pay, jnp.int32(0), jnp.int32(N), **kw, **IK))[0, 0, 2]),
+            "portable_ms": median_ms(
+                lambda: np.asarray(seg.segment_histogram(
+                    pay, jnp.int32(0), jnp.int32(N), **kw))[0, 0, 2])}
+
+
+def blocks():
+    """partition_segment_acc_blocks at an ultra-wide payload (the snapshot
+    kernel's traced, 128-aligned lane base)."""
+    Fw, Bw = 1200, 64
+    Pw = -(-(Fw + 8) // 128) * 128
+    pay = make_payload(N, Fw, Bw, width=Pw)
+    pred = make_pred(700, 30, Bw)
+    check_partition(
+        lambda p, a, s, c: pseg.partition_segment_acc_blocks(
+            p, a, s, c, pred, LV, RV, Fw + 3, Bw, **IK),
+        pay, pred, Fw + 3, segs((128, 3000), (7, 8000), (513, 256)))
+    return {"blocks_ms": median_ms(
+                lambda: np.asarray(pseg.partition_segment_acc_blocks(
+                    pay, jnp.zeros_like(pay), jnp.int32(0), jnp.int32(N),
+                    pred, LV, RV, Fw + 3, Bw, **IK)[0])[0, 0]),
+            "portable_ms": median_ms(
+                lambda: np.asarray(seg.partition_segment(
+                    pay, jnp.zeros_like(pay), jnp.int32(0), jnp.int32(N),
+                    pred, LV, RV, Fw + 3)[0])[0, 0])}
+
+
+def ring4():
+    """4-deep read ring of the accumulator partition and of the merged
+    kernel, exact against depth 2."""
+    info = {}
+    fns = {
+        "acc": lambda rd, s, c: pseg.partition_segment_acc(
+            PAY, jnp.zeros_like(PAY), jnp.int32(s), jnp.int32(c), PRED,
+            LV, RV, VAL, B, ring_depth=rd, **IK),
+        "merged": lambda rd, s, c: pseg.partition_segment_hist(
+            PAY, jnp.zeros_like(PAY), jnp.int32(s), jnp.int32(c), PRED,
+            LV, RV, VAL, B, ring_depth=rd, num_features=F, grad_col=F,
+            hess_col=F + 1, cnt_col=F + 2, **IK),
+    }
+    for name, fn in fns.items():
+        o2, o4 = fn(2, 128, N - 1000), fn(4, 128, N - 1000)
+        assert int(o2[2]) == int(o4[2]), name
+        # output 1 is the aux scratch, whose leftovers may differ by depth
+        for i in (0,) + tuple(range(3, len(o2))):
+            assert float(jnp.abs(o2[i] - o4[i]).max()) == 0.0, (name, i)
+        for rd in (2, 4):
+            info["%s_ring%d_ms" % (name, rd)] = median_ms(
+                lambda: np.asarray(fn(rd, 0, N)[0])[0, 0])
+    return info
+
+
+DEFAULT_PATH = (hist_expand, partition_rmw, partition_acc, precision)
+STAGED = (merged, colblock, ring4, blocks, frontier, quant)
+
+
+def main():
+    from lightgbm_tpu.runtime.doctor import device_report
+    device = device_report()
+    print("platform=%s kind=%s jax=%s interpret=%s rows=%d"
+          % (device["platform"], device["kind"], jax.__version__, INTERPRET,
+             N), flush=True)
+    assert {f.__name__ for f in STAGED} == set(pseg.STAGED_FLAGS)
+    verdicts = {}
+    for fn in DEFAULT_PATH + STAGED:
+        name = fn.__name__
+        t0 = time.perf_counter()
+        try:
+            info = fn()
+            verdicts[name] = {"ok": True, **info}
+        except Exception as e:  # a verdict, not a crash: next kernel runs
+            traceback.print_exc()
+            verdicts[name] = {"ok": False, "error": "%s: %s" % (
+                type(e).__name__, str(e)[:2000])}
+        verdicts[name]["staged"] = fn in STAGED
+        verdicts[name]["seconds"] = round(time.perf_counter() - t0, 1)
+        print("%-14s %s" % (name, json.dumps(verdicts[name])), flush=True)
+    out = {"device": device, "interpret": INTERPRET, "rows": N,
+           "flags": {k: getattr(pseg, v)
+                     for k, v in pseg.STAGED_FLAGS.items()},
+           "verdicts": verdicts}
+    line = json.dumps(out)
+    os.makedirs(os.path.join(REPO, "chiprun_out"), exist_ok=True)
+    with open(os.path.join(REPO, "chiprun_out", "smoke_tpu_kernels.json"),
+              "w") as f:
+        f.write(line + "\n")
+    print(line, flush=True)
+    return 0 if all(v["ok"] for v in verdicts.values()) else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1,10 +1,9 @@
 #!/usr/bin/env python
 """Bench + sim trajectory collator (ISSUE 10 / ISSUE 11 satellites).
 
-Five ``BENCH_r*.json`` driver artifacts sit at the repo root, yet the
-round reports kept describing an "empty bench trajectory" — nothing
-collated them.  This tool turns the committed artifacts into one
-trajectory table (iters/sec, vs_baseline, per-section rows/sec) and
+This tool turns ``BENCH_r*.json`` driver artifacts (none are committed
+any more: the old platform's records went in PR 21, and the ledger takes
+over with ROADMAP A1) into one trajectory table (iters/sec, vs_baseline, per-section rows/sec) and
 flags any round that regressed more than ``REGRESSION_THRESHOLD``
 against the best PRIOR round measured at the same shape — cross-scale
 comparisons (a 2M-row CPU round vs a 200k-row fallback round) are
@@ -972,7 +971,9 @@ def run(repo: str = REPO,
 
 
 def main(argv=None) -> int:
-    rep = run()
+    """Collate the artifacts in argv[0] (default: the repo root)."""
+    argv = sys.argv[1:] if argv is None else argv
+    rep = run(argv[0] if argv else REPO)
     cols = ["round", "n_rows", "platform", "iters_per_sec", "vs_baseline",
             "sec_per_iter"]
     print("bench_history: %d round(s) collated" % rep["rounds"])

@@ -74,16 +74,10 @@ class Application:
             tracing.set_enabled(False)
         tracing.set_context(self.task)
         tracing.maybe_autostart()
-        # persistent-compile-cache seam (ISSUE 15): compile_cache_dir=
-        # (same as $LGBM_TPU_COMPILE_CACHE) wires jax's persistent
-        # compilation cache to a fingerprinted subdirectory before any
-        # task compiles; zero-cost (no jax import) when neither is set
+        # persistent compilation cache, before any task compiles: where
+        # $JAX_COMPILATION_CACHE_DIR says, else the fixed in-checkout path
         from .runtime import warmup
-        cache_dir = self.raw_params.pop("compile_cache_dir", None)
-        if cache_dir:
-            warmup.enable_compile_cache(cache_dir)
-        else:
-            warmup.maybe_enable_from_env()
+        warmup.enable_compile_cache()
 
     def run(self) -> None:
         if self.task in ("train", "refit"):
@@ -405,7 +399,7 @@ class Application:
             predict_deadline_s=float(params.pop("predict_deadline", 30.0)),
             poll_interval_s=float(params.pop("serve_poll_interval", 0.2)),
             breaker_cooldown_s=float(params.pop("breaker_cooldown", 2.0)),
-            probe_platform_on_start=True, log=Log)
+            log=Log)
         runtime.start()
         server = ServingServer(runtime, host=host, port=port)
         wire_servers = []
@@ -515,11 +509,11 @@ class Application:
         Log.info("Finished converting model, saved to %s", out_path)
 
     def doctor(self) -> None:
-        """One-command debug bundle (runtime/doctor.py): platform probe,
-        env/config fingerprint, stage trails, metrics snapshot, compile
-        ledger and the newest BENCH/CHAOS/MULTICHIP artifacts in one
-        atomic checksummed tar.  Params: `output_dir=` (default .),
-        `probe=false` skips the platform probe, `probe_deadline=S`,
+        """One-command debug bundle (runtime/doctor.py): the platform JAX
+        binds in this process, env/config fingerprint, stage trails,
+        metrics snapshot, compile ledger and the newest BENCH/CHAOS
+        artifacts in one atomic checksummed tar.  Params: `output_dir=`
+        (default .), `probe=false` leaves the platform untouched,
         `artifact_dir=` overrides where artifacts are collected from.
         See docs/OBSERVABILITY.md for the runbook."""
         from .runtime.doctor import collect_debug_bundle
@@ -527,11 +521,9 @@ class Application:
         out_dir = params.pop("output_dir", params.pop("out_dir", "."))
         probe = str(params.pop("probe", "true")).lower() not in ("false",
                                                                  "0")
-        deadline = float(params.pop("probe_deadline", 10.0))
         artifact_dir = params.pop("artifact_dir", None)
         rec = collect_debug_bundle(out_dir=out_dir, tag=None,
                                    config=params, probe=probe,
-                                   probe_deadline=deadline,
                                    artifact_dir=artifact_dir)
         # the path on stdout is the machine contract (exp scripts commit
         # the manifest next to the round's artifacts)

@@ -32,7 +32,6 @@ from ..ops.split import (FeatureMeta, K_MIN_SCORE, MISSING_NAN, MISSING_ZERO,
                          find_best_split_batched, leaf_output,
                          pad_feature_meta, per_feature_best_gains)
 from ..runtime import xla_obs
-from ..utils import compat
 
 
 class GrowerConfig(NamedTuple):
@@ -336,7 +335,7 @@ def make_tree_grower(meta: FeatureMeta, cfg: GrowerConfig, num_bins_max: int,
             # mark the per-row carry device-varying so shard_map's replication
             # checker tracks it correctly through the fori_loop (rows are
             # sharded; in feature mode rows are replicated instead)
-            leaf_id0 = compat.pvary(leaf_id0, axis_name)
+            leaf_id0 = lax.pcast(leaf_id0, axis_name, to="varying")
         state = {
             "hist": jnp.zeros((L, Fh, B, 3), jnp.float32).at[0].set(hist_root),
             "leaf_id": leaf_id0,

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..runtime import xla_obs
+from ..utils.log import Log
 
 from ..ops.bundle import BundleMap, expand_histogram, identity_bundle_map
 from ..ops.split import (FeatureMeta, K_MIN_SCORE, SplitResult,
@@ -40,6 +41,29 @@ from ..ops import segment as seg
 from ..ops.segment import SplitPredicate
 from .forced import PRIORITY_UNIT, ForcedSchedule
 from .grower import GrowerConfig, make_winner_sync
+
+
+def partition_engine(hist_impl: str, payload_width: int,
+                     num_bins: int) -> str:
+    """The partition implementation for a [N, payload_width] payload,
+    chosen from the platform and the shape: the accumulator kernel where
+    its VMEM plan fits, then the read-modify-write kernel, then the
+    column-block kernel (ultra-wide payloads, staged), else the portable
+    lax partition.  Gated separately from the histogram: the partition is
+    exact at any bin count but spans the full payload width, so a wide
+    payload can overflow it while the histogram kernel still fits."""
+    if hist_impl == "lax" or jax.default_backend() != "tpu":
+        return "lax"
+    from ..ops import pallas_segment as pseg
+    if (pseg.PARTITION_ACC_VALIDATED
+            and pseg.partition_acc_fits_vmem(payload_width, num_bins)):
+        return "pallas-acc"
+    if pseg.partition_fits_vmem(payload_width, num_bins):
+        return "pallas-rmw"
+    if (pseg.PARTITION_BLOCKS_VALIDATED and payload_width % 128 == 0
+            and pseg.partition_blocks_fits_vmem(payload_width, num_bins)):
+        return "pallas-blocks"
+    return "lax"
 
 
 class PayloadCols(NamedTuple):
@@ -225,34 +249,31 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
             hist_fn = functools.partial(seg.segment_histogram, **hist_kwargs)
             hist_engine = "lax"
 
-    # the partition kernel is gated separately from the histogram: it is
-    # exact at any bin count (HIGHEST-precision permutation) but spans the
-    # full payload width, so Epsilon-wide P overflows its un-tiled VMEM
-    # plan while e.g. a >256-bin config only falls off the HISTOGRAM kernel
+    def part_fn(payload, aux, start, count, pred, lv, rv):
+        # the width is static at trace time; a caller that did not pass
+        # payload_width gets its engine resolved (and reported) here
+        part = partition_engine(cfg.hist_impl, payload.shape[1], B)
+        engines["partition"] = part
+        if part == "lax":
+            return seg.partition_segment(payload, aux, start, count, pred,
+                                         lv, rv, cols.value)
+        from ..ops import pallas_segment as pseg
+        kernel = {"pallas-acc": pseg.partition_segment_acc,
+                  "pallas-rmw": pseg.partition_segment,
+                  "pallas-blocks": pseg.partition_segment_acc_blocks}[part]
+        return kernel(payload, aux, start, count, pred, lv, rv, cols.value, B)
+
     pallas_part = (cfg.hist_impl != "lax"
                    and jax.default_backend() == "tpu")
-
-    def part_fn(payload, aux, start, count, pred, lv, rv):
-        if pallas_part:
-            from ..ops import pallas_segment as pseg
-            if (pseg.PARTITION_ACC_VALIDATED
-                    and pseg.partition_acc_fits_vmem(payload.shape[1], B)):
-                return pseg.partition_segment_acc(payload, aux, start, count,
-                                                  pred, lv, rv, cols.value, B)
-            if pseg.partition_fits_vmem(payload.shape[1], B):
-                return pseg.partition_segment(payload, aux, start, count,
-                                              pred, lv, rv, cols.value, B)
-            if (pseg.PARTITION_BLOCKS_VALIDATED
-                    and payload.shape[1] % 128 == 0
-                    and pseg.partition_blocks_fits_vmem(
-                        payload.shape[1], B)):
-                # ultra-wide payloads: per-lane-window passes with a
-                # shared routing read (Epsilon/raw-Allstate class)
-                return pseg.partition_segment_acc_blocks(
-                    payload, aux, start, count, pred, lv, rv,
-                    cols.value, B)
-        return seg.partition_segment(payload, aux, start, count, pred,
-                                     lv, rv, cols.value)
+    #: what this build resolved, readable as ``grower.engines`` — the
+    #: choice is made from platform and shape, never invisibly
+    engines = {"histogram": hist_engine,
+               "partition": (partition_engine(cfg.hist_impl, payload_width, B)
+                             if payload_width is not None else None)}
+    Log.info("partitioned grower engines: histogram=%s partition=%s "
+             "(%d columns x %d bins, payload width %s)",
+             engines["histogram"], engines["partition"] or "at first trace",
+             Ghist, B, payload_width)
 
     # ---- merged partition+hist mode (serial only): one kernel per split
     # computes the partition AND both children's histograms from the same
@@ -1078,5 +1099,7 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
     # payload/aux are donated: the training state is updated in place across
     # trees, never copied (HistogramPool-style buffer discipline without the
     # pointer juggling of feature_histogram.hpp:655-826)
-    return xla_obs.jit(grow, site="grower2.partitioned",
-                       donate_argnums=(0, 1)) if jit else grow
+    grower = xla_obs.jit(grow, site="grower2.partitioned",
+                         donate_argnums=(0, 1)) if jit else grow
+    grower.engines = engines
+    return grower

@@ -38,8 +38,11 @@ def ensure_built() -> str:
     """Build (or freshen) the shared library; returns its path.  make is
     a no-op when the .so is newer than the sources, so running it
     unconditionally keeps stale pre-built libraries from being loaded."""
-    subprocess.run(["make", "-C", _CPP_DIR], check=True,
-                   capture_output=True)
+    r = subprocess.run(["make", "-C", _CPP_DIR], capture_output=True,
+                       text=True)
+    if r.returncode != 0:
+        raise LightGBMError("make -C %s failed (rc=%d): %s"
+                            % (_CPP_DIR, r.returncode, r.stderr[-500:]))
     return _LIB_PATH
 
 

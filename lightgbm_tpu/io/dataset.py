@@ -13,6 +13,7 @@ arrives with M3 and only changes how columns are packed.
 """
 from __future__ import annotations
 
+import time
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
@@ -83,6 +84,10 @@ class BinnedDataset:
         self.feature_names: List[str] = []
         self.monotone_constraints: Optional[np.ndarray] = None
         self.feature_penalty: Optional[np.ndarray] = None
+        #: which bin-encode path built `bins` and how long it took
+        #: ({"path": "native"|"python", "seconds": s}); None when the
+        #: matrix was not binned here (binary cache, streaming loader)
+        self.binning: Optional[dict] = None
 
     # -- construction --------------------------------------------------------
     @classmethod
@@ -116,11 +121,17 @@ class BinnedDataset:
         # when every non-trivial feature is numerical; otherwise (or with
         # no native library) the per-feature Python path
         from .native import encode_bins
-        if not encode_bins(X, bin_mappers, bins):
+        t0 = time.perf_counter()
+        native = encode_bins(X, bin_mappers, bins)
+        if not native:
             for j, mapper in enumerate(bin_mappers):
                 if mapper.is_trivial:
                     continue
                 bins[j, :n] = mapper.values_to_bins(X[:, j].astype(np.float64))
+        ds.binning = {"path": "native" if native else "python",
+                      "seconds": round(time.perf_counter() - t0, 3)}
+        Log.info("binned %d x %d values through the %s path in %.2fs",
+                 n, f, ds.binning["path"], ds.binning["seconds"])
 
         # Exclusive Feature Bundling (reference dataset.cpp:66-210): pack
         # mutually-exclusive sparse features into shared storage columns.

@@ -2,8 +2,11 @@
 
 The reference's loader is native code end to end (dataset_loader.cpp +
 parser.cpp + bin.h ValueToBin); these wrappers give the Python loader the
-same native parse and bin-encode stages.  Every entry returns None on any
-problem so callers fall back to the tolerant Python implementations.
+same native parse and bin-encode stages.  Every entry returns None (or
+False) on any problem so callers fall back to the tolerant Python
+implementations; a library that cannot be built or loaded is reported
+once, with the reason, because at Higgs scale the Python bin loop costs
+minutes.
 """
 from __future__ import annotations
 
@@ -11,6 +14,8 @@ import ctypes
 from typing import List, Optional, Tuple
 
 import numpy as np
+
+from ..utils.log import Log
 
 _lib = None
 _lib_failed = False
@@ -42,8 +47,11 @@ def _load():
                 ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_int),
                 ctypes.POINTER(ctypes.c_ubyte), ctypes.c_longlong]
             _lib = lib
-        except Exception:
+        except Exception as e:      # noqa: BLE001 — no compiler, no make, ...
             _lib_failed = True
+            Log.warning("native ingest library unavailable (%s: %s); "
+                        "parsing and binning take the Python paths",
+                        type(e).__name__, e)
     return _lib
 
 

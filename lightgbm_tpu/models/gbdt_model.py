@@ -261,3 +261,136 @@ class GBDTModel:
                 else:
                     imp[f] += max(tree.split_gain[node], 0.0)
         return imp
+
+
+# model-file fields that must match EXACTLY (tree structure + routing);
+# float statistics may differ in the last ulps because two engines (or a
+# distributed psum and the serial scan) accumulate partial sums in a
+# different order
+_EXACT_FIELDS = ("split_feature=", "threshold=", "decision_type=",
+                 "left_child=", "right_child=", "leaf_count=",
+                 "internal_count=", "num_leaves=", "num_cat=",
+                 "cat_threshold=", "cat_boundaries=", "shrinkage=")
+_CLOSE_FIELDS = ("leaf_value=", "internal_value=", "split_gain=",
+                 "leaf_weight=", "internal_weight=")
+
+
+def assert_models_equivalent(a: str, b: str, rtol: float = 1e-4,
+                             atol: float = 1e-6) -> None:
+    """Two model strings describe the same trees: structure and routing
+    exact, float statistics to tolerance.  Raises AssertionError naming
+    the first line that differs.  The rule the test suite compares
+    learners and engines by, and chip_smoke.py compares the Pallas and
+    lax engines by on the chip."""
+    la, lb = a.splitlines(), b.splitlines()
+    assert len(la) == len(lb), (len(la), len(lb))
+    for xa, xb in zip(la, lb):
+        if xa == xb:
+            continue
+        key = xa.split("=")[0] + "="
+        if key == "tree_sizes=":   # byte lengths shift with value digits
+            continue
+        assert key == xb.split("=")[0] + "=", (xa, xb)
+        assert key not in _EXACT_FIELDS, \
+            "structural mismatch: %s vs %s" % (xa, xb)
+        assert key in _CLOSE_FIELDS, \
+            "unexpected diff line: %s vs %s" % (xa, xb)
+        va = np.asarray([float(v) for v in xa.split("=")[1].split()])
+        vb = np.asarray([float(v) for v in xb.split("=")[1].split()])
+        if key == "split_gain=":
+            # gains are differences of large sums: f32 cancellation makes
+            # them the noisiest field when accumulation order differs
+            np.testing.assert_allclose(va, vb, rtol=max(rtol, 5e-3),
+                                       atol=max(atol, 1e-3))
+        else:
+            np.testing.assert_allclose(va, vb, rtol=rtol, atol=atol)
+
+
+def _leaf_regions(tree: Tree) -> Dict[frozenset, tuple]:
+    """A tree as a function: each leaf keyed by the SET of decisions on its
+    path (feature, threshold or category bitset, decision type, side), so
+    neither the numbering of nodes nor the order of splits enters."""
+    regions: Dict[frozenset, tuple] = {}
+    if tree.num_leaves <= 1:
+        return {frozenset(): (int(tree.leaf_count[0]),
+                              float(tree.leaf_value[0]))}
+    stack = [(0, frozenset())]
+    while stack:
+        node, path = stack.pop()
+        if node < 0:
+            regions[path] = (int(tree.leaf_count[~node]),
+                             float(tree.leaf_value[~node]))
+            continue
+        dt = int(tree.decision_type[node])
+        if dt & 1:      # categorical: the bitset, not its position
+            c = int(tree.threshold[node])
+            cut = tuple(tree.cat_threshold[tree.cat_boundaries[c]:
+                                           tree.cat_boundaries[c + 1]])
+        else:
+            cut = float(tree.threshold[node])
+        step = (int(tree.split_feature[node]), cut, dt)
+        stack.append((int(tree.left_child[node]), path | {step + ("L",)}))
+        stack.append((int(tree.right_child[node]), path | {step + ("R",)}))
+    return regions
+
+
+def _first_divergence(ta: Tree, tb: Tree) -> Optional[str]:
+    """Walk both trees from the root along identical decisions and
+    describe the shallowest node where they stop agreeing."""
+    if ta.num_leaves <= 1 or tb.num_leaves <= 1:
+        return None if ta.num_leaves == tb.num_leaves else "one tree is a stump"
+    level = [(0, 0)]
+    depth = 0
+    while level:
+        nxt = []
+        for na, nb in level:
+            if na < 0 and nb < 0:
+                continue
+            if na < 0 or nb < 0:
+                t, n = (ta, na) if nb < 0 else (tb, nb)
+                return ("depth %d: split (feature %d, gain %.6g) in one tree, "
+                        "a leaf in the other"
+                        % (depth, t.split_feature[n], t.split_gain[n]))
+            if (ta.split_feature[na] != tb.split_feature[nb]
+                    or ta.threshold[na] != tb.threshold[nb]):
+                return ("depth %d: feature %d <= %.6g (gain %.6g) vs "
+                        "feature %d <= %.6g (gain %.6g)"
+                        % (depth, ta.split_feature[na], ta.threshold[na],
+                           ta.split_gain[na], tb.split_feature[nb],
+                           tb.threshold[nb], tb.split_gain[nb]))
+            nxt.append((int(ta.left_child[na]), int(tb.left_child[nb])))
+            nxt.append((int(ta.right_child[na]), int(tb.right_child[nb])))
+        level = nxt
+        depth += 1
+    return None
+
+
+def compare_tree_functions(a: str, b: str) -> List[Dict]:
+    """Tree by tree, how far two model strings describe the same FUNCTIONS:
+    leaf regions in common (order of splits and numbering of nodes do not
+    enter), whether the common regions hold the same row counts, the
+    largest leaf-value difference on them, the shallowest divergence, and
+    where the split order first differs.  The caller sets the bounds."""
+    ma = GBDTModel.load_model_from_string(a)
+    mb = GBDTModel.load_model_from_string(b)
+    assert len(ma.trees) == len(mb.trees), (len(ma.trees), len(mb.trees))
+    report = []
+    for ta, tb in zip(ma.trees, mb.trees):
+        ra, rb = _leaf_regions(ta), _leaf_regions(tb)
+        common = set(ra) & set(rb)
+        n = min(ta.num_leaves, tb.num_leaves) - 1
+        first = next((j for j in range(n)
+                      if ta.split_feature[j] != tb.split_feature[j]
+                      or ta.threshold[j] != tb.threshold[j]), None)
+        report.append({
+            "leaves": [int(ta.num_leaves), int(tb.num_leaves)],
+            "common_regions": len(common),
+            "counts_equal": all(ra[k][0] == rb[k][0] for k in common),
+            "max_value_diff": max((abs(ra[k][1] - rb[k][1])
+                                   for k in common), default=0.0),
+            "divergence": _first_divergence(ta, tb),
+            "split_order": None if first is None else
+            "differs from split %d (gains %.6g vs %.6g)"
+            % (first, ta.split_gain[first], tb.split_gain[first]),
+        })
+    return report

@@ -34,19 +34,9 @@ from ..runtime import xla_obs
 from .segment import CHUNK, GUARD
 from .split import MISSING_NAN, MISSING_ZERO
 
-def _side_effect_params():
-    """compiler_params marking a kernel side-effecting (its in-place HBM
-    writes through aliased outputs must never be DCE'd or reordered).
-    jax renamed TPUCompilerParams -> CompilerParams and moved
-    has_side_effects between versions; resolve whatever this jax ships —
-    on versions without the flag the input_output_aliases still order the
-    writes, so default params are the best (and only) available."""
-    import dataclasses
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    if any(f.name == "has_side_effects" for f in dataclasses.fields(cls)):
-        return cls(has_side_effects=True)
-    return cls()
+#: the partition kernels write HBM in place through aliased outputs; those
+#: writes must never be DCE'd or reordered
+_SIDE_EFFECTS = pltpu.CompilerParams(has_side_effects=True)
 
 # per-tile one-hot budget: the expand and one-hot intermediates over one
 # FEATURE TILE are each [CHUNK, ~TILE_FB] f32 (2 MB).  Features are tiled
@@ -159,9 +149,8 @@ FRONTIER_BATCH_VALIDATED = False
 HIST_QUANT_VALIDATED = False
 
 #: staged-flag registry: verdict/flip name -> module flag.  Shared by
-#: exp/flip_validated.py (human flips), exp/smoke_staged.py (verdict
-#: names) and bench.py (in-process enablement) so the three can never
-#: disagree on names.
+#: exp/flip_validated.py (human flips) and exp/smoke_tpu_kernels.py
+#: (verdict names) so the two can never disagree on names.
 STAGED_FLAGS = {
     "merged": "PARTITION_HIST_VALIDATED",
     "colblock": "HIST_COLBLOCK_VALIDATED",
@@ -1245,7 +1234,7 @@ def partition_segment(payload, aux, start, count, pred, left_value,
                    jax.ShapeDtypeStruct(aux.shape, aux.dtype),
                    jax.ShapeDtypeStruct((1,), jnp.int32)),
         input_output_aliases={3: 0, 4: 1},
-        compiler_params=_side_effect_params(),
+        compiler_params=_SIDE_EFFECTS,
         interpret=interpret,
     )(scalars, fvals, bitset, payload, aux)
     return payload_new, aux_new, nl[0]
@@ -1673,7 +1662,7 @@ def _partition_segment_acc(payload, aux, start, count, pred, left_value,
                    jax.ShapeDtypeStruct(aux.shape, aux.dtype),
                    jax.ShapeDtypeStruct((1,), jnp.int32)),
         input_output_aliases={3: 0, 4: 1},
-        compiler_params=_side_effect_params(),
+        compiler_params=_SIDE_EFFECTS,
         interpret=interpret,
     )(scalars, fvals, bitset, payload, aux)
     return payload_new, aux_new, nl[0]
@@ -1758,7 +1747,7 @@ def _partition_segment_hist(payload, aux, start, count, pred, left_value,
                    jax.ShapeDtypeStruct((8 * n_tiles, W), jnp.float32),
                    jax.ShapeDtypeStruct((8 * n_tiles, W), jnp.float32)),
         input_output_aliases={3: 0, 4: 1},
-        compiler_params=_side_effect_params(),
+        compiler_params=_SIDE_EFFECTS,
         interpret=interpret,
     )(scalars, fvals, bitset, payload, aux)
     hist_l = _untile_hist(hl, F, B, Ft, n_tiles, W, expand_impl)
@@ -2114,7 +2103,7 @@ def _partition_segment_acc_blocks(payload, aux, start, count, pred,
         ),
         out_shape=jax.ShapeDtypeStruct((payload.shape[0], 128),
                                        jnp.float32),
-        compiler_params=_side_effect_params(),
+        compiler_params=_SIDE_EFFECTS,
         interpret=interpret,
     )(scalars, payload)
     nl = None
@@ -2153,7 +2142,7 @@ def _partition_segment_acc_blocks(payload, aux, start, count, pred,
                        jax.ShapeDtypeStruct(aux.shape, aux.dtype),
                        jax.ShapeDtypeStruct((1,), jnp.int32)),
             input_output_aliases={3: 0, 4: 1},
-            compiler_params=_side_effect_params(),
+            compiler_params=_SIDE_EFFECTS,
             interpret=interpret,
         )(scalars, fvals, bitset, payload, aux, snap)
         nl = nl_k if nl is None else nl

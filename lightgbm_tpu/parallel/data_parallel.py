@@ -24,7 +24,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..boosting.grower import GrowerConfig, make_tree_grower
 from ..runtime import xla_obs
 from ..ops.split import FeatureMeta
-from ..utils import compat
 from ._common import make_step, resolve_objective
 
 DATA_AXIS = "data"
@@ -52,7 +51,7 @@ def make_data_parallel_train_step(meta: FeatureMeta, cfg: GrowerConfig,
     # the SyncUpGlobalBestSplit psum, so the carried split state is
     # replicated in value, but the varying-axes tracker cannot prove it
     # through the fori_loop carry
-    sharded = compat.shard_map(
+    sharded = jax.shard_map(
         step, mesh=mesh,
         in_specs=(P(None, DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS),
                   P(DATA_AXIS), P(DATA_AXIS), P(None)),

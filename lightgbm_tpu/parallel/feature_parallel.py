@@ -19,7 +19,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..boosting.grower import GrowerConfig, make_tree_grower
 from ..ops.split import FeatureMeta, pad_feature_meta  # noqa: F401  (re-export)
 from ..runtime import xla_obs
-from ..utils import compat
 from ._common import make_step, resolve_objective
 
 FEATURE_AXIS = "feature"
@@ -49,7 +48,7 @@ def make_feature_parallel_train_step(meta: FeatureMeta, cfg: GrowerConfig,
     grow = make_tree_grower(meta, cfg, num_bins_max, axis_name=FEATURE_AXIS,
                             jit=False, mode="feature")
     step = make_step(grow, objective, learning_rate)
-    sharded = compat.shard_map(
+    sharded = jax.shard_map(
         step, mesh=mesh,
         in_specs=(P(FEATURE_AXIS, None), P(), P(), P(), P(), P(FEATURE_AXIS)),
         out_specs=(P(), P()))

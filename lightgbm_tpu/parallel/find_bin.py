@@ -93,13 +93,12 @@ def make_distributed_find_bin(mesh: Mesh, max_bin: int,
         return jnp.concatenate(
             [bounds, jnp.full((F, 1), jnp.inf, bounds.dtype)], axis=1)
 
-    from jax.experimental.shard_map import shard_map
     # the post-all_gather computation is device-identical, but the static
     # replication checker cannot see through vmap(searchsorted); the
     # replication tests assert it dynamically instead
-    fn = shard_map(per_shard, mesh=mesh,
-                   in_specs=P(DATA_AXIS, None),
-                   out_specs=P(), check_rep=False)
+    fn = jax.shard_map(per_shard, mesh=mesh,
+                       in_specs=P(DATA_AXIS, None),
+                       out_specs=P(), check_vma=False)
     return xla_obs.jit(fn, site="parallel.find_bin")
 
 

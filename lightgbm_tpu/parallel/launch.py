@@ -127,11 +127,7 @@ def resolve_rank(machine_list: List[Tuple[str, int]],
 
 def _already_initialized() -> bool:
     import jax
-    try:
-        return bool(jax.distributed.is_initialized())
-    except AttributeError:   # older jax: probe the client directly
-        from jax._src import distributed as _dist
-        return _dist.global_state.client is not None
+    return bool(jax.distributed.is_initialized())
 
 
 #: bounded bring-up (reference parity: linkers_socket.cpp retries its
@@ -203,9 +199,7 @@ def init_distributed(machines: str = None,
         # — and skip the DNS walk of the machine list entirely
         Log.info("jax.distributed already initialized; keeping the "
                  "existing cluster")
-        from jax._src import distributed as _dist
-        pid = getattr(_dist.global_state, "process_id", 0)
-        return int(pid or 0)
+        return int(jax.process_index())
     mlist = parse_machine_list(machines, machine_list_filename,
                                default_port=local_listen_port)
     if len(mlist) == 1:

@@ -18,7 +18,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..boosting.grower import GrowerConfig, make_tree_grower
 from ..runtime import xla_obs
 from ..ops.split import FeatureMeta
-from ..utils import compat
 from ._common import make_step, resolve_objective
 
 DATA_AXIS = "data"
@@ -40,7 +39,7 @@ def make_voting_parallel_train_step(meta: FeatureMeta, cfg: GrowerConfig,
     # check_vma off: the vote (all_gather -> identical top-2k set on every
     # shard) and the psum'ed subset histograms are replicated in value, but
     # the varying-axes tracker cannot prove it through the scan carry
-    sharded = compat.shard_map(
+    sharded = jax.shard_map(
         step, mesh=mesh,
         in_specs=(P(None, DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS),
                   P(DATA_AXIS), P(DATA_AXIS), P(None)),

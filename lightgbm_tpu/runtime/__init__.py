@@ -3,8 +3,8 @@ atomic model publish/subscribe, and the continuous-training service loop.
 
 This package holds the machinery that keeps long runs alive on flaky
 platforms — it deliberately imports neither jax nor any other heavy
-dependency at module scope, so the hermetic dryrun bootstrap and the CLI
-entry can use it before (or instead of) binding an accelerator platform.
+dependency at module scope, so the CLI entry and the fleet controller
+can use it before (or instead of) binding an accelerator platform.
 (`continuous` and `serving` are not imported here: they pull numpy and,
 lazily, the model stack; import them explicitly where a service loop or
 a serving runtime is actually being run.)

@@ -642,9 +642,8 @@ class ContinuousTrainer:
     # -- the loop ------------------------------------------------------------
     def run(self) -> int:
         cfg = self.cfg
-        # persistent-compile-cache seam (ISSUE 15): honor
-        # $LGBM_TPU_COMPILE_CACHE before the first cycle compiles
-        warmup.maybe_enable_from_env()
+        # persistent compilation cache on before the first cycle compiles
+        warmup.enable_compile_cache()
         guard = resilience.PreemptionGuard(cfg.output_model,
                                            retention=cfg.snapshot_retention,
                                            log=self.log)

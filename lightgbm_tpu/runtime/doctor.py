@@ -1,12 +1,14 @@
 """One-command debug bundle (``task=doctor`` / `collect_debug_bundle`).
 
-The artifact a failed hardware window ships home (ISSUE 10).  Five
-rounds of red MULTICHIP artifacts proved that ad-hoc evidence gathering
-loses exactly the file that mattered; this module packages EVERYTHING a
-post-mortem needs into one atomic tar with a checksummed manifest:
+The artifact a failed run ships home (ISSUE 10).  Ad-hoc evidence
+gathering loses exactly the file that mattered; this module packages
+EVERYTHING a post-mortem needs into one atomic tar with a checksummed
+manifest:
 
-* **platform probe** — `resilience.probe_platform` in a short-deadline
-  subprocess (a dead tunnel is recorded, never waited on);
+* **platform** — the platform, device kind and device count JAX binds
+  IN THIS PROCESS (a chip belongs to one process: no probe child, and
+  nothing is degraded — a platform that cannot initialize becomes an
+  ``errors`` entry);
 * **environment / config fingerprint** — python/jax/numpy versions,
   platform, argv, and every ``LGBM_* / JAX_* / XLA_* / BENCH_*`` env
   var, plus the CLI's resolved parameters when available;
@@ -46,7 +48,8 @@ from typing import Any, Dict, List, Optional
 
 from . import resilience, telemetry, tracing, warmup, xla_obs
 
-__all__ = ["collect_debug_bundle", "verify_bundle", "env_fingerprint"]
+__all__ = ["collect_debug_bundle", "verify_bundle", "env_fingerprint",
+           "device_report"]
 
 #: newest-first artifact globs bundled from the artifact directory
 ARTIFACT_GLOBS = ("BENCH_r*.json", "BENCH_local*.json", "CHAOS*.json",
@@ -119,6 +122,23 @@ def _artifact_members(artifact_dir: str) -> Dict[str, bytes]:
     return out
 
 
+def device_report() -> Dict[str, Any]:
+    """The device as JAX reports it IN THIS PROCESS — platform, kind and
+    count.  Initializes the backend (and so takes the chip); every entry
+    point that states or checks its platform goes through here."""
+    import jax
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+
+
+def _platform_member() -> Dict[str, Any]:
+    import jax
+    import jaxlib
+    return dict(device_report(), jax=jax.__version__,
+                jaxlib=jaxlib.__version__)
+
+
 def _metrics_member() -> bytes:
     snap: Dict[str, Any]
     try:
@@ -135,7 +155,6 @@ def collect_debug_bundle(out_dir: str = ".",
                          tag: Optional[str] = None,
                          config: Optional[Dict[str, Any]] = None,
                          probe: bool = True,
-                         probe_deadline: float = 10.0,
                          stage_reports: Optional[List[str]] = None,
                          artifact_dir: Optional[str] = None,
                          note: Optional[str] = None) -> Dict[str, Any]:
@@ -162,8 +181,7 @@ def collect_debug_bundle(out_dir: str = ".",
 
     gather("env.json", lambda: env_fingerprint(config))
     if probe:
-        gather("probe.json",
-               lambda: resilience.probe_platform(deadline=probe_deadline))
+        gather("platform.json", _platform_member)
     gather("metrics.json", _metrics_member)
     gather("xla_ledger.json", lambda: xla_obs.LEDGER.to_json())
     # warm-start state (ISSUE 15): persistent compile-cache dir /

@@ -29,7 +29,7 @@ protocol:
 * **the `--replica` entrypoint** — one serving replica as a process:
   builds a `ServingRuntime` from a JSON spec (model zoo + quotas +
   bounded residency + shed policy), rides the PR 15 warm-start seam
-  ($LGBM_TPU_COMPILE_CACHE + published shape manifests +
+  (the shared persistent compile cache + published shape manifests +
   prewarm-before-admit), fronts it with a binary `WireTCPServer`,
   publishes its ports atomically to an endpoint file, and polls
   ``fleet_state.json`` for the shed grant.  SIGTERM drains gracefully

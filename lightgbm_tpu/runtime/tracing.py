@@ -42,8 +42,8 @@ global read + an early return (the BENCH ``telemetry`` section asserts
 the combined disabled path stays under 1% of an iteration —
 ``LGBM_TPU_TRACE=0`` is the kill switch).
 
-No jax / numpy at module scope — the hermetic dryrun bootstrap and
-platform-free subscribers must be able to import this.
+No jax / numpy at module scope — platform-free subscribers must be
+able to import this.
 """
 from __future__ import annotations
 

@@ -1,32 +1,28 @@
 """Warm-start subsystem: persistent program cache + shape manifests.
 
-Every process in the fleet used to pay cold XLA compilation on startup:
-serving replicas compiled before their first real batch, `train_online`
-relaunches recompiled the whole fused-step family after SIGTERM, and
-each step of `exp/on_tpu_return.sh` re-lowered the same ~45 `xla_obs`
-sites inside a scarce hardware window.  This module makes startup a
-measured, optimized quantity — LightGBM's own "bin once, reuse the
-binary cache" design (PAPER.md §L2) applied to compiled programs:
+Every process used to pay cold XLA compilation on startup: serving
+replicas compiled before their first real batch and `train_online`
+relaunches recompiled the whole fused-step family after SIGTERM.  This
+module makes startup a measured, optimized quantity — LightGBM's own "bin
+once, reuse the binary cache" design (PAPER.md §L2) applied to compiled
+programs:
 
-* **Persistent compilation cache seam** — `enable_compile_cache(base)`
-  (CLI ``compile_cache_dir=`` / ``$LGBM_TPU_COMPILE_CACHE``) wires
-  ``jax_compilation_cache_dir`` to a FINGERPRINTED subdirectory of the
-  requested base: the fingerprint keys the requested backend, the jax
-  version, the staged-kernel flag set, and the host CPU feature flags
-  (XLA:CPU entries embed AOT machine code; loading one compiled on a
-  different host can die of SIGILL — the same argument
-  ``__graft_entry__._hermetic_cpu_env`` makes for the dryrun cache,
-  which stays self-contained because it runs before this package is
-  importable).  A stale or cross-version cache can therefore never
-  poison results: a different stack simply lands in a different
-  subdirectory and runs cold.  The cache is size-budgeted
-  (``$LGBM_TPU_COMPILE_CACHE_MB``, default 512): an LRU sweep by mtime
-  evicts the oldest entries past the budget.  Per-compile hit/miss
-  classification (did this compile load from disk or write a fresh
-  entry?) rides the `xla_obs` compile observer into
+* **Persistent compilation cache seam** — `enable_compile_cache()` is
+  the one place the program decides where jax's persistent compilation
+  cache lives.  When ``JAX_COMPILATION_CACHE_DIR`` is set, jax already
+  reads it: the program sets no directory, creates nothing under it and
+  never sweeps it — whoever placed the cache owns it.  When it is not
+  set, the cache is one fixed path inside the checkout
+  (`DEFAULT_CACHE_DIR`, git-ignored) with nothing of the host, process
+  or time in its name: the directory is part of the cache's key, so a
+  path that moves never hits, and jax's own entry key already covers
+  backend, jax version and compile options.  Only that owned directory
+  is size-budgeted (`OWNED_BUDGET_MB`, oldest-mtime eviction).
+  Per-compile hit/miss classification (did this compile load from disk
+  or write a fresh entry?) rides the `xla_obs` compile observer into
   ``lgbm_compile_cache_events_total{event}`` AND the compile ledger
-  (site ``warmup.persistent_cache``), so doctor bundles and BENCH
-  records carry the cache's behavior.
+  (site ``warmup.persistent_cache``), against whichever directory is
+  active.
 
 * **Shape manifests** — serving and the continuous trainer export the
   shape buckets and jit sites they actually compiled (straight from the
@@ -48,7 +44,7 @@ binary cache" design (PAPER.md §L2) applied to compiled programs:
   ``lgbm_warmup_total{kind,outcome}`` + ``lgbm_warmup_seconds{kind}``.
 
 No jax at module scope — the CLI entry and platform-free subscribers
-import this; jax loads only when a cache dir is actually being enabled.
+import this; jax loads when the cache seam is enabled.
 """
 from __future__ import annotations
 
@@ -56,31 +52,34 @@ import hashlib
 import json
 import os
 import threading
-import time
 from typing import Any, Dict, List, Optional, Tuple
 
 from . import telemetry, xla_obs
 from .resilience import atomic_write, wallclock
 
 __all__ = [
-    "CACHE_ENV", "CACHE_BUDGET_ENV", "MANIFEST_NAME",
+    "JAX_CACHE_ENV", "DEFAULT_CACHE_DIR", "MANIFEST_NAME",
     "MANIFEST_SCHEMA_VERSION",
-    "cache_fingerprint", "enable_compile_cache", "maybe_enable_from_env",
-    "sweep_cache", "cache_status",
+    "enable_compile_cache", "sweep_cache", "cache_status",
     "write_manifest", "read_manifest", "manifest_path",
     "build_serving_section", "build_train_section", "params_sig",
     "classify_serving_section", "classify_train_section",
     "serving_row_buckets", "record_prewarm",
 ]
 
-#: base directory of the persistent compilation cache (the fingerprinted
-#: subdir is created under it); CLI spelling: ``compile_cache_dir=``
-CACHE_ENV = "LGBM_TPU_COMPILE_CACHE"
+#: jax's own variable.  Set: jax reads it and the directory belongs to
+#: whoever set it.  Unset: `DEFAULT_CACHE_DIR`.
+JAX_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
-#: size budget of ONE fingerprinted subdirectory, in MB (LRU sweep by
-#: mtime past it; 0 disables the sweep)
-CACHE_BUDGET_ENV = "LGBM_TPU_COMPILE_CACHE_MB"
-DEFAULT_BUDGET_MB = 512
+#: the cache directory when `JAX_CACHE_ENV` is unset: fixed, inside the
+#: checkout, listed in .gitignore
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+#: size budget of the OWNED directory, in MB (oldest-mtime sweep past
+#: it); a directory placed from outside is never swept
+OWNED_BUDGET_MB = 512
 
 #: the shape manifest published alongside model generations.  Not a
 #: ``gen_`` file: `publish.generation_paths` never lists it and
@@ -98,102 +97,40 @@ MAX_BUCKET_ROWS = 1 << 22
 
 _lock = threading.Lock()
 _STATE: Dict[str, Any] = {
-    "enabled": False, "dir": None, "fingerprint": None,
-    "hits": 0, "misses": 0, "evictions": 0, "budget_mb": None,
-    "dir_sig": None,
+    "enabled": False, "dir": None, "owned": False,
+    "hits": 0, "misses": 0, "evictions": 0, "dir_sig": None,
 }
-
-
-# ---------------------------------------------------------------------------
-# fingerprint
-# ---------------------------------------------------------------------------
-
-def _host_fingerprint() -> str:
-    """Short stable hash of this host's CPU feature flags (XLA:CPU cache
-    entries embed AOT machine code — a different host gets a cold cache
-    instead of a SIGILL)."""
-    try:
-        with open("/proc/cpuinfo") as fh:
-            flags = next((ln for ln in fh if ln.startswith("flags")), "")
-    except OSError:
-        flags = ""
-    import platform
-    blob = (flags + "|" + platform.machine()).encode()
-    return hashlib.sha256(blob).hexdigest()[:8]
-
-
-def _staged_flags_sig() -> str:
-    """Hash of the staged-kernel flag set AND its current values: a flag
-    flip (exp/flip_validated.py) compiles different programs, so it gets
-    its own cache subdirectory instead of poisoning the old one."""
-    try:
-        from ..ops import pallas_segment as pseg
-        pairs = sorted((name, bool(getattr(pseg, flag, False)))
-                       for name, flag in pseg.STAGED_FLAGS.items())
-    except Exception:    # noqa: BLE001 — a broken kernel import stays cold
-        pairs = [("nostaged", False)]
-    return hashlib.sha256(repr(pairs).encode()).hexdigest()[:8]
-
-
-def _requested_backend() -> str:
-    """The REQUESTED platform string, without initializing a backend:
-    jax.config's jax_platforms when jax is already imported, else the
-    JAX_PLATFORMS env var, else "default"."""
-    import sys
-    jax = sys.modules.get("jax")
-    if jax is not None:
-        try:
-            p = jax.config.jax_platforms
-            if p:
-                return str(p)
-        except Exception:    # noqa: BLE001 — config attr moved
-            pass
-    return os.environ.get("JAX_PLATFORMS") or "default"
-
-
-def cache_fingerprint() -> str:
-    """Identity of the compiled-program universe this process inhabits:
-    ``<backend>-jax<version>-<staged8>-<host8>``.  Two processes share a
-    cache subdirectory iff every component matches."""
-    import jax
-    backend = _requested_backend().replace(os.sep, "_").replace(",", "+")
-    return "%s-jax%s-%s-%s" % (backend, jax.__version__,
-                               _staged_flags_sig(), _host_fingerprint())
 
 
 # ---------------------------------------------------------------------------
 # persistent compilation cache
 # ---------------------------------------------------------------------------
 
-def enable_compile_cache(base_dir: Optional[str] = None,
-                         budget_mb: Optional[int] = None,
-                         min_compile_s: float = 0.0) -> Optional[str]:
-    """Wire jax's persistent compilation cache to the fingerprinted
-    subdirectory of `base_dir` (default: ``$LGBM_TPU_COMPILE_CACHE``;
-    returns None — and touches nothing — when neither is set).
+def enable_compile_cache(min_compile_s: float = 0.0) -> str:
+    """Turn on jax's persistent compilation cache and return the
+    directory in use.  Every entry point calls this once before its
+    first compile (`Application`, `ServingRuntime.start`,
+    `ContinuousTrainer.run`, bench.py, chip_smoke.py, tests/conftest.py).
+
+    ``JAX_COMPILATION_CACHE_DIR`` set: that directory, untouched by this
+    program beyond the entries jax itself writes.  Unset:
+    `DEFAULT_CACHE_DIR`, created if missing and swept to its budget.
 
     Threshold 0 persists even sub-second programs so a warm start
-    recompiles NOTHING; the size budget keeps the subdirectory bounded
-    (oldest-mtime eviction).  Idempotent per (process, dir).  Returns
-    the fingerprinted cache directory."""
-    base = base_dir if base_dir else os.environ.get(CACHE_ENV)
-    if not base:
-        return None
-    fp = cache_fingerprint()
-    cdir = os.path.join(os.path.expanduser(base), fp)
+    recompiles NOTHING.  Idempotent per process."""
+    import jax
+    external = os.environ.get(JAX_CACHE_ENV)
+    cdir = external or DEFAULT_CACHE_DIR
     with _lock:
         if _STATE["enabled"] and _STATE["dir"] == cdir:
             return cdir
-    os.makedirs(cdir, exist_ok=True)
-    import jax
-    jax.config.update("jax_compilation_cache_dir", cdir)
+    if not external:
+        os.makedirs(cdir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cdir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       float(min_compile_s))
-    if budget_mb is None:
-        budget_mb = int(os.environ.get(CACHE_BUDGET_ENV, DEFAULT_BUDGET_MB))
     with _lock:
-        _STATE.update(enabled=True, dir=cdir, fingerprint=fp,
-                      budget_mb=int(budget_mb),
+        _STATE.update(enabled=True, dir=cdir, owned=not external,
                       dir_sig=_dir_sig(cdir))
     # per-compile hit/miss classification rides the compile ledger's
     # observer seam (xla_obs must not import warmup — the observer is
@@ -201,16 +138,6 @@ def enable_compile_cache(base_dir: Optional[str] = None,
     xla_obs.set_compile_observer(_compile_observer)
     sweep_cache()
     return cdir
-
-
-def maybe_enable_from_env() -> Optional[str]:
-    """`enable_compile_cache()` iff ``$LGBM_TPU_COMPILE_CACHE`` is set —
-    zero-cost (no jax import) when it is not.  Every service entry point
-    (CLI tasks, ServingRuntime.start, ContinuousTrainer.run, bench)
-    calls this once."""
-    if not os.environ.get(CACHE_ENV):
-        return None
-    return enable_compile_cache()
 
 
 def _dir_sig(cdir: str) -> Optional[Tuple[int, int]]:
@@ -246,14 +173,14 @@ def _compile_observer(site: str, wall_s: float) -> None:
     xla_obs.cache_event("warmup.persistent_cache", event)
 
 
-def sweep_cache(budget_mb: Optional[int] = None) -> int:
-    """LRU sweep of the active cache directory: evict oldest-mtime
-    entries until the directory fits the budget.  Returns the number of
-    entries evicted (0 when disabled or under budget)."""
+def sweep_cache(budget_mb: int = OWNED_BUDGET_MB) -> int:
+    """LRU sweep of the OWNED cache directory: evict oldest-mtime
+    entries until it fits the budget.  Returns the number of entries
+    evicted — always 0 for a directory placed from outside through
+    ``JAX_COMPILATION_CACHE_DIR``, which is never touched."""
     with _lock:
-        cdir = _STATE["dir"] if _STATE["enabled"] else None
-        if budget_mb is None:
-            budget_mb = _STATE["budget_mb"] or DEFAULT_BUDGET_MB
+        cdir = (_STATE["dir"]
+                if _STATE["enabled"] and _STATE["owned"] else None)
     if cdir is None or budget_mb <= 0:
         return 0
     entries: List[Tuple[float, int, str]] = []
@@ -291,9 +218,8 @@ def sweep_cache(budget_mb: Optional[int] = None) -> int:
 def cache_status() -> Dict[str, Any]:
     """Machine-readable cache state (the doctor-bundle member)."""
     with _lock:
-        st = {k: _STATE[k] for k in ("enabled", "dir", "fingerprint",
-                                     "hits", "misses", "evictions",
-                                     "budget_mb")}
+        st = {k: _STATE[k] for k in ("enabled", "dir", "owned",
+                                     "hits", "misses", "evictions")}
     files, total = 0, 0
     if st["dir"]:
         try:
@@ -313,9 +239,8 @@ def cache_status() -> Dict[str, Any]:
 def _reset_for_tests() -> None:
     """Test seam: forget the enable state (jax config is left as-is)."""
     with _lock:
-        _STATE.update(enabled=False, dir=None, fingerprint=None,
-                      hits=0, misses=0, evictions=0, budget_mb=None,
-                      dir_sig=None)
+        _STATE.update(enabled=False, dir=None, owned=False,
+                      hits=0, misses=0, evictions=0, dir_sig=None)
 
 
 # ---------------------------------------------------------------------------
@@ -400,7 +325,6 @@ def build_serving_section(num_features: int, row_buckets: List[int],
         "num_features": int(num_features),
         "row_buckets": sorted({int(b) for b in row_buckets}),
         "generation": int(generation) if generation is not None else None,
-        "fingerprint": _safe_fingerprint(),
         "created": wallclock(),
         "sites": _ledger_sites(),
     }
@@ -428,17 +352,9 @@ def build_train_section(params: Dict[str, Any], n_features: int,
         "kind": "train_online",
         "params_sig": params_sig(params, n_features),
         "generation": int(generation) if generation is not None else None,
-        "fingerprint": _safe_fingerprint(),
         "created": wallclock(),
         "sites": _ledger_sites(),
     }
-
-
-def _safe_fingerprint() -> Optional[str]:
-    try:
-        return cache_fingerprint()
-    except Exception:    # noqa: BLE001 — provenance only, never a blocker
-        return None
 
 
 def classify_serving_section(sec: Dict[str, Any],

@@ -1,9 +1,9 @@
 """Bench-trajectory collator (ISSUE 10 satellite, helper/bench_history.py).
 
-The committed BENCH_r01–r05 fixtures must collate into a non-empty
-trajectory with NO latest-round regression (the acceptance gate), and
-the regression detector must actually fire on a synthetic >10% drop —
-with cross-shape rounds never compared.
+Synthetic BENCH_r* / BENCH_WINDOW_r* fixtures must collate into a
+non-empty trajectory with NO latest-round regression (the acceptance
+gate), and the regression detector must actually fire on a synthetic
+>10% drop — with cross-shape rounds never compared.
 """
 import json
 import os
@@ -25,39 +25,56 @@ def _write_round(d, n, parsed=None, tail=""):
     (d / ("BENCH_r%02d.json" % n)).write_text(json.dumps(rec))
 
 
-def test_committed_fixtures_collate_clean():
-    # r01–r05 plus the BENCH_WINDOW_r13 window A/B (ISSUE 14: the
-    # attrib decomposition collates across BOTH artifact families)
-    rep = bench_history.run(REPO)
-    assert rep["rounds"] == 6
-    assert len(rep["trajectory"]) == 6
+def _attrib(dispatch_s, device_s, drain_s, dispatches):
+    return {"attrib": {"per_iter": {
+        "dispatch_s": dispatch_s, "device_wait_s": device_s,
+        "drain_s": drain_s, "dispatches_per_iter": dispatches}}}
+
+
+def _write_fixture_rounds(d):
+    """Two bench rounds and a window A/B round at one shape, improving."""
+    shape = {"n_rows": 60_000, "platform": "tpu"}
+    _write_round(d, 4, dict(shape, value=0.25, vs_baseline=0.12))
+    _write_round(d, 5, dict(shape, value=0.26, vs_baseline=0.125))
+    window = dict(shape, value=0.27, vs_baseline=0.13,
+                  **_attrib(0.0123, 0.0456, 0.0078, 0.5))
+    (d / "BENCH_WINDOW_r13.json").write_text(json.dumps(
+        {"n": 13, "rc": 0, "tail": "", "parsed": window}))
+    return window
+
+
+def test_fixture_rounds_collate_clean(tmp_path):
+    # both artifact families collate into one trajectory (ISSUE 14: the
+    # attrib decomposition rides BENCH_r* and BENCH_WINDOW_r* alike)
+    fix = _write_fixture_rounds(tmp_path)
+    rep = bench_history.run(str(tmp_path))
+    assert rep["rounds"] == 3
+    assert len(rep["trajectory"]) == 3
     latest = rep["trajectory"][-1]
     assert latest["round"] == 13
     assert latest["file"] == "BENCH_WINDOW_r13.json"
     # values come from the fixtures, not thin air
-    fix = json.load(open(os.path.join(REPO,
-                                      "BENCH_WINDOW_r13.json")))["parsed"]
     assert latest["iters_per_sec"] == fix["value"]
-    # the attrib series landed, in ms, from the committed artifact
+    # the attrib series landed, in ms
     attr = fix["attrib"]["per_iter"]
     assert latest["dispatches_per_iter"] == attr["dispatches_per_iter"]
     assert latest["attrib_dispatch_ms"] == \
         round(attr["dispatch_s"] * 1000, 3)
     assert latest["attrib_drain_ms"] == round(attr["drain_s"] * 1000, 3)
     r5 = [r for r in rep["trajectory"] if r["round"] == 5][0]
-    fix5 = json.load(open(os.path.join(REPO, "BENCH_r05.json")))["parsed"]
-    assert r5["iters_per_sec"] == fix5["value"]
-    assert r5["vs_baseline"] == fix5["vs_baseline"]
-    # the acceptance gate: the regression check runs clean as committed
+    assert r5["iters_per_sec"] == 0.26 and r5["vs_baseline"] == 0.125
+    # the acceptance gate: the regression check runs clean
     assert rep["latest_regressions"] == [], rep["latest_regressions"]
 
 
-def test_cli_exits_zero_on_committed_fixtures():
+def test_cli_exits_zero_on_clean_fixtures(tmp_path):
+    _write_fixture_rounds(tmp_path)
     r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "helper", "bench_history.py")],
+        [sys.executable, os.path.join(REPO, "helper", "bench_history.py"),
+         str(tmp_path)],
         capture_output=True, text=True)
     assert r.returncode == 0, r.stdout + r.stderr
-    assert "6 round(s) collated" in r.stdout
+    assert "3 round(s) collated" in r.stdout
 
 
 def test_synthetic_regression_is_flagged(tmp_path):

@@ -130,3 +130,24 @@ def test_collection_failure_degrades_to_manifest_error(tmp_path,
                                       artifact_dir=str(tmp_path))
     assert "metrics.json" in rec["manifest"]["errors"]
     assert doctor.verify_bundle(rec["path"])["ok"]
+
+
+def test_platform_member_reports_what_this_process_binds(tmp_path):
+    """task=doctor's platform report is IN-PROCESS (a chip belongs to one
+    process: no probe child) and degrades nothing — it names the platform,
+    device kind and count jax binds here."""
+    import jax
+    rec = doctor.collect_debug_bundle(out_dir=str(tmp_path), probe=True,
+                                      artifact_dir=str(tmp_path))
+    assert "errors" not in rec["manifest"]
+    with tarfile.open(rec["path"]) as tar:
+        by = {i.name.split("/", 1)[1]: tar.extractfile(i).read()
+              for i in tar.getmembers()}
+    plat = json.loads(by["platform.json"])
+    assert plat["platform"] == jax.devices()[0].platform == "cpu"
+    assert plat["count"] == len(jax.devices())
+    assert plat["jax"] == jax.__version__
+    skipped = doctor.collect_debug_bundle(out_dir=str(tmp_path), probe=False,
+                                          artifact_dir=str(tmp_path))
+    assert "platform.json" not in {m["name"] for m
+                                   in skipped["manifest"]["members"]}

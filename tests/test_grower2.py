@@ -315,3 +315,26 @@ def test_merged_hist_mode_near_tie_splits(seed):
                                   outs[1]["seg_start"][:nl])
     np.testing.assert_array_equal(outs[0]["seg_cnt"][:nl],
                                   outs[1]["seg_cnt"][:nl])
+
+
+def test_grower_reports_the_engines_it_resolved():
+    """The grower names the histogram and partition implementations it
+    chose (from platform and shape) as `.engines`, and the booster passes
+    them on — on the CPU both are the portable lax engine.  A TPU must read
+    "pallas" / "pallas-acc" at the Higgs shape; chip_smoke.py asserts that
+    on the chip."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.boosting.grower2 import partition_engine
+
+    assert partition_engine("auto", 128, 256) == "lax"
+    assert partition_engine("lax", 128, 256) == "lax"
+
+    rng = np.random.default_rng(0)
+    X = rng.standard_normal((600, 5)).astype(np.float32)
+    y = (X[:, 0] > 0).astype(np.float32)
+    bst = lgb.Booster({"objective": "binary", "num_leaves": 7,
+                       "verbose": -1}, lgb.Dataset(X, label=y))
+    assert bst._engine.engines is None      # fast path not built yet
+    bst.update()
+    assert bst._engine.engines == {"histogram": "lax", "partition": "lax"}
+    assert bst._engine._fast.grower.engines is bst._engine.engines

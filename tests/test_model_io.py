@@ -83,3 +83,51 @@ def test_config_check_fails():
     from lightgbm_tpu.utils.log import LightGBMError
     with pytest.raises(LightGBMError):
         Config({"num_leaves": 1})
+
+
+def _two_orders_of_one_tree():
+    """The same three splits made in two orders (leaf-wise growth picks
+    the frontier leaf by gain; a near-tie in gain swaps the order)."""
+    from lightgbm_tpu.models.tree import Tree
+    trees = []
+    for second, third in ((0, 1), (1, 0)):
+        t = Tree(4)
+        t.split(0, 0, 0, 0.5, -1.0, 1.0, 60, 40, 9.0, 0, True)
+        sides = {0: (1, 0.25, -2.0, -0.5, 30, 30, 4.0),
+                 1: (2, 0.75, 0.5, 2.0, 25, 15, 4.0)}
+        for leaf in (second, third):
+            f, thr, lv, rv, lc, rc, gain = sides[leaf]
+            t.split(leaf, f, 0, thr, lv, rv, lc, rc, gain, 0, True)
+        trees.append(t)
+    return trees
+
+
+def _model_text(tree):
+    m = GBDTModel()
+    m.max_feature_idx = 2
+    m.objective_str = "regression"
+    m.trees.append(tree)
+    return m.save_model_to_string()
+
+
+def test_compare_tree_functions_ignores_split_order_and_names_divergence():
+    """What chip_smoke.py compares engines by: two trees that made the same
+    splits in another order are the same function (same leaf regions, row
+    counts and values), while a moved threshold is reported as a
+    divergence with the gains on both sides."""
+    from lightgbm_tpu.models.gbdt_model import (assert_models_equivalent,
+                                                compare_tree_functions)
+    a, b = _two_orders_of_one_tree()
+    text_a, text_b = _model_text(a), _model_text(b)
+    with pytest.raises(AssertionError):     # the strict, ordered rule
+        assert_models_equivalent(text_a, text_b)
+    (rep,) = compare_tree_functions(text_a, text_b)
+    assert rep["leaves"] == [4, 4] and rep["common_regions"] == 4
+    assert rep["counts_equal"] and rep["max_value_diff"] == 0.0
+    assert rep["divergence"] is None
+    assert rep["split_order"].startswith("differs from split 1")
+
+    b.threshold[0] = 0.6                    # the root now cuts elsewhere
+    (rep,) = compare_tree_functions(text_a, _model_text(b))
+    assert rep["common_regions"] == 0
+    assert rep["divergence"].startswith("depth 0: feature 0 <= 0.5")

@@ -149,3 +149,35 @@ def test_parse_dense_declines_text_tokens_and_keeps_sep_only_rows():
         p3 = _write(td, "1," + "1" + "0" * 400 + ",2\n", "o.csv")
         X, _ = native.parse_dense(p3, ",", 0, False, 3)
         assert np.isposinf(X[0, 0])
+
+
+def test_python_binning_fallback_is_visible_not_silent(monkeypatch):
+    """When the native library cannot be built or loaded, binning takes the
+    Python loop — minutes at Higgs scale — so the fallback must say so:
+    one warning carrying the reason, and the path and its time recorded on
+    the dataset (`BinnedDataset.binning`, which chip_smoke.py prints)."""
+    from lightgbm_tpu import capi
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.utils.log import Log
+
+    rng = np.random.default_rng(1)
+    X = rng.standard_normal((500, 4))
+    config = Config({"max_bin": 31})
+    reference = BinnedDataset.from_matrix(X, config)
+
+    def no_compiler():
+        raise OSError("make: command not found")
+
+    warnings = []
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_lib_failed", False)
+    monkeypatch.setattr(capi, "_lib", None)
+    monkeypatch.setattr(capi, "ensure_built", no_compiler)
+    monkeypatch.setattr(Log, "warning", staticmethod(
+        lambda msg, *a: warnings.append(msg % a)))
+    ds = BinnedDataset.from_matrix(X, config)
+    again = BinnedDataset.from_matrix(X, config)
+    assert ds.binning["path"] == again.binning["path"] == "python"
+    assert ds.binning["seconds"] >= 0.0
+    assert len(warnings) == 1 and "make: command not found" in warnings[0]
+    np.testing.assert_array_equal(ds.bins, reference.bins)

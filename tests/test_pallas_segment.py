@@ -546,13 +546,10 @@ def test_frontier_flag_staged_off():
     assert pseg.STAGED_FLAGS["frontier"] == "FRONTIER_BATCH_VALIDATED"
 
 
-@pytest.mark.parametrize("expand", ["matmul"])
+@pytest.mark.parametrize("expand", ["matmul", "repeat"])
 def test_hist_batched_matches_portable(expand):
     """Grid-(K,) batched kernel vs the portable batched engine, including
-    unaligned starts, a 1-row segment and a zero-count padding slot.
-    (repeat mode is excluded the same way the single-segment grid is on
-    this jax: interpret-mode pltpu.repeat emulation disagrees with the
-    hardware-validated layout — see on_tpu_return.sh.)"""
+    unaligned starts, a 1-row segment and a zero-count padding slot."""
     pay = _payload(1024, seed=5)
     starts = jnp.asarray([0, 256, 100, 513, 7, 0], jnp.int32)
     counts = jnp.asarray([1000, 700, 37, 256, 1, 0], jnp.int32)

@@ -391,7 +391,7 @@ def test_fault_table_is_the_single_registry():
             assert resilience.fault_active(name)
         os.environ["LGBM_TPU_FAULT"] = "definitely_not_a_fault"
         with pytest.raises(ValueError):
-            resilience.fault_active("hang_import")
+            resilience.fault_active("die_at_iter")
     finally:
         if old is None:
             os.environ.pop("LGBM_TPU_FAULT", None)
@@ -849,3 +849,21 @@ def test_wire_uds_refuses_to_unlink_live_server_path(tmp_path):
         finally:
             usrv.shutdown()
             usrv.server_close()
+
+
+def test_start_reports_the_platform_and_never_rewrites_it(monkeypatch):
+    """start() binds the platform jax is configured for, records it in
+    stats(), and leaves JAX_PLATFORMS alone — no probe child, no
+    degradation to another platform behind the caller's back."""
+    import jax
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    before = dict(os.environ)
+    with ServingRuntime(model_str=_synth_model(),
+                        params={"verbose": -1}) as rt:
+        platform = rt.stats()["platform"]
+        rec = rt.predict(np.zeros((3, 6)))
+    assert platform == {"platform": "cpu", "kind": jax.devices()[0]
+                        .device_kind, "count": len(jax.devices())}
+    assert rec.served_by == "device"
+    assert os.environ["JAX_PLATFORMS"] == before["JAX_PLATFORMS"]
+    assert not hasattr(rt, "probe_platform_on_start")

@@ -2,9 +2,10 @@
 
 Layers under test:
 
-* runtime/warmup.py — the fingerprinted persistent-compile-cache seam
-  (enable / hit-miss classification / LRU sweep) and the checksummed
-  shape manifest (merge semantics, torn/stale/mismatch classification);
+* runtime/warmup.py — the persistent-compile-cache seam (a directory
+  placed from outside through $JAX_COMPILATION_CACHE_DIR is left alone;
+  otherwise one fixed in-checkout path, swept to a budget) and the
+  checksummed shape manifest (merge semantics, torn/stale/mismatch classification);
 * runtime/serving.py — prewarm-before-admit: a fresh runtime
   precompiles the manifest's row buckets BEFORE readiness opens, every
   failure mode degrades to the legacy smallest-bucket prewarm with a
@@ -146,44 +147,98 @@ def test_concurrent_readers_never_observe_torn_manifest(tmp_path):
 # persistent compile cache
 # ---------------------------------------------------------------------------
 
-def test_cache_fingerprint_stable_and_staged_sensitive():
-    fp1 = warmup.cache_fingerprint()
-    assert fp1 == warmup.cache_fingerprint()
-    from lightgbm_tpu.ops import pallas_segment as pseg
-    name, flag = sorted(pseg.STAGED_FLAGS.items())[0]
-    old = getattr(pseg, flag)
-    try:
-        setattr(pseg, flag, not old)
-        assert warmup.cache_fingerprint() != fp1, (
-            "flipping staged flag %s did not change the cache "
-            "fingerprint — a flip could poison the old cache" % name)
-    finally:
-        setattr(pseg, flag, old)
-
-
-def test_cache_sweep_evicts_oldest_past_budget(tmp_path, monkeypatch):
-    # enable on a scratch base; conftest already enabled the shared
-    # cache, so force a re-enable onto this directory
+def _restore_suite_cache():
+    """Put the seam back the way conftest left it."""
     warmup._reset_for_tests()
-    cdir = warmup.enable_compile_cache(str(tmp_path), budget_mb=1)
-    assert cdir and cdir.startswith(str(tmp_path))
-    assert os.path.basename(cdir) == warmup.cache_fingerprint()
-    # 3 fake entries of ~0.6 MB: budget 1 MB keeps the newest one
+    warmup.enable_compile_cache(min_compile_s=1.0)
+
+
+def _record_config_updates(monkeypatch):
+    """Swap jax.config.update for a recorder, so a test can see what the
+    seam WOULD set without moving the suite's real cache."""
+    import jax
+    updates = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda key, value: updates.__setitem__(key, value))
+    return updates
+
+
+def test_external_cache_dir_is_neither_set_nor_swept(tmp_path, monkeypatch):
+    """$JAX_COMPILATION_CACHE_DIR set: jax already reads it, so the seam
+    sets no directory, creates nothing under it and never sweeps it."""
+    ext = tmp_path / "placed_from_outside"
+    ext.mkdir()
     for i, name in enumerate(("a", "b", "c")):
-        p = os.path.join(cdir, name)
-        with open(p, "wb") as fh:
-            fh.write(b"\0" * (600 * 1024))
+        p = ext / name
+        p.write_bytes(b"\0" * (600 * 1024))
         os.utime(p, (1000 + i, 1000 + i))
-    evicted = warmup.sweep_cache(budget_mb=1)
-    assert evicted == 2
-    assert sorted(os.listdir(cdir)) == ["c"]
-    st = warmup.cache_status()
-    assert st["evictions"] >= 2 and st["files"] == 1
-    # restore the suite-wide cache (conftest settings) for later tests
+    monkeypatch.setenv(warmup.JAX_CACHE_ENV, str(ext))
+    updates = _record_config_updates(monkeypatch)
     warmup._reset_for_tests()
-    warmup.enable_compile_cache(
-        os.environ.get(warmup.CACHE_ENV, "/tmp/lgbtpu_jax_cache"),
-        min_compile_s=1.0)
+    try:
+        assert warmup.enable_compile_cache() == str(ext)
+        assert "jax_compilation_cache_dir" not in updates
+        assert warmup.sweep_cache(budget_mb=1) == 0
+        assert sorted(os.listdir(ext)) == ["a", "b", "c"]
+        st = warmup.cache_status()
+        assert st["dir"] == str(ext) and st["owned"] is False
+        assert st["files"] == 3 and st["evictions"] == 0
+    finally:
+        monkeypatch.undo()
+        _restore_suite_cache()
+
+
+_CACHE_DIR_CHILD = (
+    "import sys; sys.path.insert(0, %r)\n"
+    "from lightgbm_tpu.runtime import warmup\n"
+    "import jax\n"
+    "d = warmup.enable_compile_cache()\n"
+    "assert jax.config.jax_compilation_cache_dir == d\n"
+    "print(d)\n")
+
+
+def test_default_cache_dir_is_fixed_inside_the_checkout():
+    """Unset: one fixed path inside the checkout, the same in every
+    process — nothing of the host, the pid or the time in its name (the
+    path is part of the cache key; a directory that moves never hits)."""
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert warmup.DEFAULT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    env = {k: v for k, v in os.environ.items() if k != warmup.JAX_CACHE_ENV}
+    env["JAX_PLATFORMS"] = "cpu"
+    seen = [subprocess.run([sys.executable, "-c", _CACHE_DIR_CHILD % repo],
+                           env=env, capture_output=True, text=True,
+                           timeout=120) for _ in range(2)]
+    for r in seen:
+        assert r.returncode == 0, r.stderr[-1000:]
+    assert [r.stdout.strip() for r in seen] == [warmup.DEFAULT_CACHE_DIR] * 2
+
+
+def test_owned_cache_sweep_evicts_oldest_past_budget(tmp_path, monkeypatch):
+    # a scratch stand-in for the in-checkout directory; conftest already
+    # enabled the shared cache, so force a re-enable
+    monkeypatch.delenv(warmup.JAX_CACHE_ENV, raising=False)
+    monkeypatch.setattr(warmup, "DEFAULT_CACHE_DIR", str(tmp_path / "own"))
+    updates = _record_config_updates(monkeypatch)
+    warmup._reset_for_tests()
+    try:
+        cdir = warmup.enable_compile_cache()
+        assert cdir == str(tmp_path / "own") and os.path.isdir(cdir)
+        assert updates["jax_compilation_cache_dir"] == cdir
+        # 3 fake entries of ~0.6 MB: budget 1 MB keeps the newest one
+        for i, name in enumerate(("a", "b", "c")):
+            p = os.path.join(cdir, name)
+            with open(p, "wb") as fh:
+                fh.write(b"\0" * (600 * 1024))
+            os.utime(p, (1000 + i, 1000 + i))
+        assert warmup.sweep_cache(budget_mb=1) == 2
+        assert sorted(os.listdir(cdir)) == ["c"]
+        st = warmup.cache_status()
+        assert st["owned"] and st["evictions"] >= 2 and st["files"] == 1
+    finally:
+        monkeypatch.undo()
+        _restore_suite_cache()
 
 
 # ---------------------------------------------------------------------------
