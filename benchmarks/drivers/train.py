@@ -1,0 +1,131 @@
+"""Closed-loop training: one trainer calling `Booster.update()`.
+
+Set-up: the task and its held-out rows from the seed, `lgb.Dataset`
+constructed, the `Booster` (the configuration's `params`, with the mix's
+own `params` over them: bagging, GOSS or quantized gradients are mixes),
+`warmup_iters` iterations and a drain (every shape the window uses is
+compiled or loaded by then).  Window:
+`update()` until the seconds are spent, then the drain that hands every
+tree to the host; the time is divided by the iterations that ran.  A
+traced window runs `trace_iters` iterations instead.
+
+Correct means: the partition-ordered fast path with the engines the
+configuration names; on a mesh, the payload on as many distinct devices
+as the cell has chips; every tree of the window has more than one leaf;
+the first tree is the function the plain reference computes from the
+raw data (lib/reference.py: row counts equal -- past 2^24 rows, within
+what float32 counting loses -- and leaf values within
+LEAF_VALUE_ATOL); and the held-out metric of the model cut at
+`quality_at_iter` lies in the band recorded when the cell was defined.
+"""
+import time
+
+import numpy as np
+
+from benchmarks.lib import quality, reference, synth
+
+#: the first tree's leaf values against float64 sums: the subtraction
+#: trick hands a small leaf the absolute rounding of its largest
+#: ancestor's f32 gradient sum (chip_smoke.LEAF_VALUE_ATOL, PR 21:
+#: largest seen 1.4e-4)
+LEAF_VALUE_ATOL = 5e-4
+
+
+def setup(run):
+    import lightgbm_tpu as lgb
+    cfg, mix = run.config, run.traffic
+    params = dict(cfg["params"], **mix.get("params", {}))
+    make = getattr(synth, cfg["generator"])
+    with run.timed("data_s"):
+        X, y = make(cfg["rows"], cfg["features"], (run.seed, 0))
+        Xh, yh = make(cfg["heldout_rows"], cfg["features"], (run.seed, 1))
+    with run.timed("dataset_s"):
+        train_set = lgb.Dataset(X, label=y, params=params).construct()
+    with run.timed("booster_s"):
+        bst = lgb.Booster(params, train_set)
+    with run.timed("warmup_s"):
+        for _ in range(mix["warmup_iters"]):
+            bst.update()
+        bst.current_iteration()             # drains the dispatch pipeline
+    run.state.update(bst=bst, X=X, y=y, Xh=Xh, yh=yh,
+                     binning=train_set.binned.binning)
+    run.say("train", rows=len(X), features=X.shape[1],
+            binning=run.state["binning"], **run.setup)
+
+
+def window(run, seconds):
+    bst, mix = run.state["bst"], run.traffic
+    returned = []                           # when each update() came back
+    t0 = time.perf_counter()
+    while (len(returned) < mix["trace_iters"] if run.trace
+           else time.perf_counter() - t0 < seconds):
+        with run.span("bench/update"):
+            finished = bst.update()
+        returned.append(time.perf_counter() - t0)
+        if finished:
+            break
+    with run.span("bench/drain"):
+        bst.current_iteration()
+    wall = time.perf_counter() - t0
+    iters = len(returned)
+    if not run.trace and iters < mix["min_iters"]:
+        raise RuntimeError("%d iterations in %.1f s: fewer than min_iters=%d"
+                           % (iters, seconds, mix["min_iters"]))
+    run.window.update(iters=iters, seconds=wall,
+                      metrics={"train_s_per_iter": wall / iters})
+    steps = np.diff([0.0] + returned)
+    run.say("window", iters=iters, seconds=wall, s_per_iter=wall / iters,
+            drain_s=wall - returned[-1],
+            update_return_s=[round(float(s), 4) for s in steps])
+
+
+def _on_distinct_devices(payload):
+    return len({s.device.id for s in payload.addressable_shards})
+
+
+def verify(run):
+    cfg, mix = run.config, run.traffic
+    bst = run.state["bst"]
+    eng = bst._engine
+    trees = eng.model.trees
+    warm, iters = mix["warmup_iters"], run.window["iters"]
+    run.trees = trees[warm:warm + iters]
+    fast = eng._fast
+    run.state.update(lanes=int(fast.P), storage_columns=int(fast.G),
+                     payload_rows=int(fast.n_rows),
+                     wide_index=bool(fast.wide_idx))
+    leaves = [int(t.num_leaves) for t in run.trees]
+    failed = sum(n < 2 for n in leaves) + (warm + iters - len(trees))
+    checks = {
+        "fast_path": bool(eng._fast_active),
+        "engines": eng.engines,
+        "payload": {"rows": int(fast.n_rows), "lanes": int(fast.P),
+                    "wide_index": bool(fast.wide_idx),
+                    "devices": _on_distinct_devices(fast.payload)},
+        "trees_on_host": len(trees), "leaves_min": min(leaves),
+    }
+    ok = (checks["fast_path"] and eng.engines == cfg["engines"]
+          and checks["payload"]["devices"] == run.cell["chips"]
+          and failed == 0)
+
+    t0 = time.perf_counter()
+    p = cfg["params"]
+    checks["tree0"] = reference.tree0_check(
+        trees[0], run.state["X"], run.state["y"], p["learning_rate"],
+        p.get("lambda_l2", 0.0))
+    ok = ok and checks["tree0"]["counts_ok"] \
+        and checks["tree0"]["max_value_diff"] <= LEAF_VALUE_ATOL
+
+    cut = cfg["quality_at_iter"]
+    if len(trees) >= cut:
+        raw = reference.predict_raw(trees[:cut], run.state["Xh"])
+        value = float(quality.METRICS[cfg["quality_metric"]](
+            run.state["yh"], raw))
+        lo, hi = cfg["quality_band"]
+        checks["heldout"] = {"metric": cfg["quality_metric"], "at_iter": cut,
+                             "value": value, "band": [lo, hi]}
+        ok = ok and lo <= value <= hi
+        run.window["metrics"]["heldout_quality"] = value
+    checks["verify_s"] = time.perf_counter() - t0
+    return {"correct": bool(ok), "attempted": iters, "failed": int(failed),
+            "checks": checks}
