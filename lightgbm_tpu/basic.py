@@ -20,6 +20,7 @@ from .io.dataset import BinnedDataset, Metadata
 from .metric import create_metrics
 from .models.gbdt_model import GBDTModel
 from .objective import create_objective, create_objective_from_model_string
+from .runtime import tracing
 from .utils.log import LightGBMError, Log
 
 
@@ -159,6 +160,12 @@ class Dataset:
     def construct(self, config: Optional[Config] = None) -> "Dataset":
         if self._binned is not None:
             return self
+        # the whole of ingest as one span; `BinnedDataset.from_matrix`
+        # names its parts under it, what is left is this span's self time
+        with tracing.span("dataset/construct"):
+            return self._construct(config)
+
+    def _construct(self, config: Optional[Config]) -> "Dataset":
         if config is None:
             config = Config(self.params)
         from .io.stream import StreamingDatasetBuilder

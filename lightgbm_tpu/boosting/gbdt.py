@@ -36,7 +36,7 @@ from ..ops import segment as seg
 from ..ops.bundle import (BundleMap, bundle_map_from_info, decode_bin,
                           identity_bundle_map)
 from .grower import GrowerConfig, make_tree_grower
-from .grower2 import (PayloadCols, TREE_DEVICE_FIELDS,
+from .grower2 import (PayloadCols, TREE_DEVICE_FIELDS, phase,
                       make_partitioned_grower)
 from .pipeline import TreeAssembler
 
@@ -484,9 +484,10 @@ class _FastState:
         def _fill_body(payload, k):
             """Write class k's gradients into the grad/hess columns —
             shared by the piecewise (profiled) and fused paths."""
-            gk, hk = _class_grads(payload, k)
-            payload = seg.payload_col_write(payload, grad_col, gk)
-            return seg.payload_col_write(payload, hess_col, hk)
+            with phase("grad"):
+                gk, hk = _class_grads(payload, k)
+                payload = seg.payload_col_write(payload, grad_col, gk)
+                return seg.payload_col_write(payload, hess_col, hk)
 
         @functools.partial(xla_obs.jit, site="gbdt.fill_class",
                            donate_argnums=(0,), static_argnames=("k",))
@@ -503,11 +504,12 @@ class _FastState:
                 integer-valued columns feed the int32 histogram engine
                 and the [2] scale pair rides to the grower's dequantize
                 boundary."""
-                gk, hk = _class_grads(payload, k)
-                qg, qh, qscale = quantize_pair(gk, hk, qseed, qmax_f)
-                payload = seg.payload_col_write(payload, grad_col, qg)
-                payload = seg.payload_col_write(payload, hess_col, qh)
-                return payload, qscale
+                with phase("grad"):
+                    gk, hk = _class_grads(payload, k)
+                    qg, qh, qscale = quantize_pair(gk, hk, qseed, qmax_f)
+                    payload = seg.payload_col_write(payload, grad_col, qg)
+                    payload = seg.payload_col_write(payload, hess_col, qh)
+                    return payload, qscale
 
             @functools.partial(xla_obs.jit,
                                site="gbdt.fill_class_quant",
@@ -533,9 +535,11 @@ class _FastState:
             out, payload, aux = grower.__wrapped__(*args) \
                 if hasattr(grower, "__wrapped__") else grower(*args)
             # stumps must not move the scores (gbdt.cpp stops instead)
-            upd = jnp.where(out["num_leaves"] > 1,
-                            payload[:, value_col] * lr, 0.0)
-            payload = seg.payload_col_write(payload, score0 + k, upd, "add")
+            with phase("score"):
+                upd = jnp.where(out["num_leaves"] > 1,
+                                payload[:, value_col] * lr, 0.0)
+                payload = seg.payload_col_write(payload, score0 + k, upd,
+                                                "add")
             return out, payload, aux
 
         @functools.partial(xla_obs.jit, site="gbdt.step",
@@ -578,10 +582,12 @@ class _FastState:
             derives (gradient-weight, count-mask) from them off the
             pristine valid column, and class k's weighted gradients plus
             the selection mask land in the working columns."""
-            g, h = _all_grads(payload)
-            valid = payload[:, bvalid_col]
-            gw, cm = sample_hook(g * valid, h * valid, valid, key, enabled)
-            payload = _write_sampled(payload, g, h, k, gw, cm)
+            with phase("grad"):
+                g, h = _all_grads(payload)
+                valid = payload[:, bvalid_col]
+                gw, cm = sample_hook(g * valid, h * valid, valid, key,
+                                     enabled)
+                payload = _write_sampled(payload, g, h, k, gw, cm)
             return _grow_and_score(payload, aux, fmask, lr, k)
 
         gweight_col = self.gweight_col
@@ -603,10 +609,11 @@ class _FastState:
         @functools.partial(xla_obs.jit, site="gbdt.step_masked",
                            donate_argnums=(0, 1))
         def step_masked(payload, aux, fmask, lr, k):
-            g, h = _all_grads(payload)
-            payload = _write_sampled(payload, g, h, k,
-                                     payload[:, gweight_col],
-                                     payload[:, cnt_col])
+            with phase("grad"):
+                g, h = _all_grads(payload)
+                payload = _write_sampled(payload, g, h, k,
+                                         payload[:, gweight_col],
+                                         payload[:, cnt_col])
             return _grow_and_score(payload, aux, fmask, lr, k)
 
         bmap_fs = gbdt.bundle_map
@@ -776,10 +783,12 @@ class _FastState:
         """(Re)build the payload from the legacy-order state — used on first
         entry and when re-entering the fast path after a sync back (the
         jitted closures and the grower survive, so no retracing)."""
-        self.payload = self._build(gbdt.bins_dev, gbdt.label_dev,
-                                   gbdt.weight_dev, gbdt.valid_mask,
-                                   gbdt.score)
-        self.aux = jnp.zeros_like(self.payload)
+        with tracing.span("booster/payload", rows=self.n_rows,
+                          lanes=self.P, devices=self.ndev):
+            self.payload = self._build(gbdt.bins_dev, gbdt.label_dev,
+                                       gbdt.weight_dev, gbdt.valid_mask,
+                                       gbdt.score)
+            self.aux = jnp.zeros_like(self.payload)
         self._bag_dirty = True  # cnt col holds the plain valid mask
 
     def host_idx(self) -> np.ndarray:
@@ -1703,9 +1712,6 @@ class GBDT:
             self._assembler = TreeAssembler(self._pipeline_depth)
         it = self.iter
         t_dispatch = time.monotonic()
-        # dispatch mark on the causal timeline: the matching drain span
-        # lands on the assembler thread under the same iteration context
-        tracing.instant("tree dispatch", it=it, k=k)
 
         def host_half():
             host = _fetch_packed(out, label="pipeline_drain")

@@ -43,6 +43,27 @@ from .forced import PRIORITY_UNIT, ForcedSchedule
 from .grower import GrowerConfig, make_winner_sync
 
 
+#: the phases of the fused step (`gbdt.step`: gradients -> grow -> score
+#: add), by which its device time is told apart in a profiler trace
+PHASES = ("grad", "root_hist", "partition", "hist", "subtract",
+          "split_search", "tree_update", "score", "allreduce")
+
+
+def phase(name: str):
+    """`jax.named_scope("lgbm.<name>")` round one phase of the fused
+    step.  Trace-time only: it goes into the `op_name` metadata of the
+    operations traced inside (what a profiler trace calls `tf_op`),
+    changes no instruction's name and costs nothing at run time.
+    Scopes nest and the innermost names the phase.  They go ROUND a
+    Pallas call, never into its `name=`: the trace names the kernel's
+    custom call after its jitted wrapper and the benchmark matches
+    that."""
+    if name not in PHASES:
+        raise ValueError("no phase %r of the fused step (has: %s)"
+                         % (name, ", ".join(PHASES)))
+    return jax.named_scope("lgbm." + name)
+
+
 def partition_engine(hist_impl: str, payload_width: int,
                      num_bins: int) -> str:
     """The partition implementation for a [N, payload_width] payload,
@@ -389,6 +410,12 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
 
     def grow(payload: jax.Array, aux: jax.Array,
              feature_mask: jax.Array, qscale: jax.Array = None):
+        # what no narrower phase below names is the tree's own
+        # book-keeping: selection, the state writes, the records
+        with phase("tree_update"):
+            return grow_tree(payload, aux, feature_mask, qscale)
+
+    def grow_tree(payload, aux, feature_mask, qscale):
         n_rows = jnp.int32(payload.shape[0] - seg.GUARD)
 
         # dequantize-at-the-boundary: int32 histograms become f32 views
@@ -428,10 +455,12 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
 
             if scatter_mode:
                 def reduce_hist(h):
-                    if padg:
-                        h = jnp.pad(h, ((0, padg), (0, 0), (0, 0)))
-                    return lax.psum_scatter(h, axis_name,
-                                            scatter_dimension=0, tiled=True)
+                    with phase("allreduce"):
+                        if padg:
+                            h = jnp.pad(h, ((0, padg), (0, 0), (0, 0)))
+                        return lax.psum_scatter(h, axis_name,
+                                                scatter_dimension=0,
+                                                tiled=True)
             else:
                 # feature mode: hist_fn already produced the owned slice
                 # over the full rows — nothing crosses the wire
@@ -448,9 +477,10 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
                         jnp.where(g < f_offset + Gloc, g - f_offset, g))
 
             def find_split(hist_loc, sg, sh, cnt, **constraints):
-                return bcast_from_winner(
-                    find_local(deq(hist_loc), sg, sh, cnt, fmask_loc,
-                               **constraints))
+                res = find_local(deq(hist_loc), sg, sh, cnt, fmask_loc,
+                                 **constraints)
+                with phase("allreduce"):
+                    return bcast_from_winner(res)
 
         elif voting_mode:
             k_vote = min(top_k, F)
@@ -474,14 +504,17 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
                     feature_mask, meta=meta, **vote_kwargs)
                 top_vals, top_idx = lax.top_k(local_gains, k_vote)
                 valid_vote = (top_vals > K_MIN_SCORE).astype(jnp.int32)
-                all_top = lax.all_gather(top_idx, axis_name)
-                all_valid = lax.all_gather(valid_vote, axis_name)
+                with phase("allreduce"):
+                    all_top = lax.all_gather(top_idx, axis_name)
+                    all_valid = lax.all_gather(valid_vote, axis_name)
                 votes = jnp.zeros(F, jnp.int32).at[all_top.reshape(-1)].add(
                     all_valid.reshape(-1))
                 _, sel = lax.top_k(votes, S)
                 # the vote winners' histograms cross the wire as integers
                 # in quantized mode (exact psum, 0 ulp shard-order drift)
-                hsel = deq(lax.psum(hist_local[sel], axis_name))
+                with phase("allreduce"):
+                    hsel = lax.psum(hist_local[sel], axis_name)
+                hsel = deq(hsel)
                 meta_sel = FeatureMeta(*[a[sel] for a in meta])
                 res = find_best_split(hsel, sg, sh, cnt, feature_mask[sel],
                                       meta=meta_sel, **find_kwargs,
@@ -490,7 +523,10 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
 
         else:
             def reduce_hist(h):
-                return lax.psum(h, axis_name) if replicated else h
+                if not replicated:
+                    return h
+                with phase("allreduce"):
+                    return lax.psum(h, axis_name)
 
             def find_split(h, sg, sh, cnt, **constraints):
                 return find(hist_view(deq(h)), sg, sh, cnt, feature_mask,
@@ -506,12 +542,15 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
                                                feature_mask, meta=meta,
                                                **find_kwargs)
 
-        hist_root_local = hist_fn(payload, jnp.int32(0), n_rows)
-        # every row lands in exactly one bin of storage column 0, so the
-        # root totals fall out of the histogram — no separate full-data pass
-        totals = jnp.sum(hist_root_local[0], axis=0)
+        with phase("root_hist"):
+            hist_root_local = hist_fn(payload, jnp.int32(0), n_rows)
+            # every row lands in exactly one bin of storage column 0, so
+            # the root totals fall out of the histogram — no separate
+            # full-data pass
+            totals = jnp.sum(hist_root_local[0], axis=0)
         if meshed and not feature_mode:
-            totals = lax.psum(totals, axis_name)
+            with phase("allreduce"):
+                totals = lax.psum(totals, axis_name)
         elif feature_mode:
             # every shard sees FULL rows, so its local column-0 totals are
             # already global IN VALUE — but fp summation order differs per
@@ -520,31 +559,33 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
             # Pin global column 0's totals (shard 0's, the exact sums the
             # serial engine uses) onto every shard so all shards — and the
             # serial learner — agree bit-for-bit.
-            totals = lax.psum(jnp.where(my == 0, totals,
-                                        jnp.zeros_like(totals)), axis_name)
+            with phase("allreduce"):
+                totals = lax.psum(jnp.where(my == 0, totals,
+                                            jnp.zeros_like(totals)),
+                                  axis_name)
         hist_root = reduce_hist(hist_root_local)
         # quantized mode: totals crossed the wire as exact integers; the
         # f32 leaf aggregates exist only from this boundary on
         totals = deq(totals)
         root_g, root_h, root_c = totals[0], totals[1], totals[2]
-        if cfg.with_monotone:
-            res0 = find_split(hist_root, root_g, root_h, root_c,
-                              min_constraint=jnp.float32(-jnp.inf),
-                              max_constraint=jnp.float32(jnp.inf))
-        else:
-            res0 = find_split(hist_root, root_g, root_h, root_c)
+        with phase("split_search"):
+            if cfg.with_monotone:
+                res0 = find_split(hist_root, root_g, root_h, root_c,
+                                  min_constraint=jnp.float32(-jnp.inf),
+                                  max_constraint=jnp.float32(jnp.inf))
+            else:
+                res0 = find_split(hist_root, root_g, root_h, root_c)
+            real0 = res0.gain
+            root_rank = jnp.int32(-1)
+            if forced is not None:
+                res0, real0, root_rank = forced_override(
+                    jnp.int32(0), hist_view(hist_root), root_g, root_h,
+                    root_c, res0)
 
         # rows start as one root segment with the root Newton step as the
         # per-row output (covers the unsplittable-stump case)
         root_out = out_fn(root_g, root_h)
         payload = seg.payload_col_write(payload, cols.value, root_out)
-
-        real0 = res0.gain
-        root_rank = jnp.int32(-1)
-        if forced is not None:
-            res0, real0, root_rank = forced_override(
-                jnp.int32(0), hist_view(hist_root), root_g, root_h, root_c,
-                res0)
 
         ni = max(L - 1, 1)
         state = {
@@ -645,30 +686,38 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
                 # one kernel: partition + BOTH children's histograms from
                 # the same row pass (no parent hist, no subtraction, no
                 # pool).  Serial-only, so reduce_hist is identity.
-                payload, aux, nl_raw, new_left, new_right = part_hist_fn(
-                    st["payload"], st["aux"], start, count, pred,
-                    st["blo"][best_leaf], st["bro"][best_leaf])
+                with phase("partition"):
+                    payload, aux, nl_raw, new_left, new_right = \
+                        part_hist_fn(
+                            st["payload"], st["aux"], start, count, pred,
+                            st["blo"][best_leaf], st["bro"][best_leaf])
                 nr_raw = count - nl_raw
             else:
                 # parent histogram: read the pool slot, or rebuild it from
                 # the (still contiguous) parent segment if it was evicted
-                if pooled:
-                    # NOTE: the rebuild branch runs a collective in mesh
-                    # modes; the pool bookkeeping is replicated-in-value,
-                    # so every shard takes the same branch and the psum
-                    # pairs up
-                    pslot = st["slot_of_leaf"][best_leaf]
-                    hist_parent = lax.cond(
-                        pslot >= 0,
-                        lambda: st["hist"][jnp.maximum(pslot, 0)],
-                        lambda: reduce_hist(hist_fn(st["payload"], start,
-                                                    count)))
-                else:
-                    hist_parent = st["hist"][best_leaf]
+                def rebuild_parent():
+                    with phase("hist"):
+                        h = hist_fn(st["payload"], start, count)
+                    return reduce_hist(h)
 
-                payload, aux, nl_raw = part_fn(
-                    st["payload"], st["aux"], start, count, pred,
-                    st["blo"][best_leaf], st["bro"][best_leaf])
+                with phase("subtract"):
+                    if pooled:
+                        # NOTE: the rebuild branch runs a collective in
+                        # mesh modes; the pool bookkeeping is
+                        # replicated-in-value, so every shard takes the
+                        # same branch and the psum pairs up
+                        pslot = st["slot_of_leaf"][best_leaf]
+                        hist_parent = lax.cond(
+                            pslot >= 0,
+                            lambda: st["hist"][jnp.maximum(pslot, 0)],
+                            rebuild_parent)
+                    else:
+                        hist_parent = st["hist"][best_leaf]
+
+                with phase("partition"):
+                    payload, aux, nl_raw = part_fn(
+                        st["payload"], st["aux"], start, count, pred,
+                        st["blo"][best_leaf], st["bro"][best_leaf])
                 nr_raw = count - nl_raw
 
                 # histograms: build only the smaller child, derive the
@@ -679,93 +728,102 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
                 left_smaller = lcnt <= rcnt
                 h_start = jnp.where(left_smaller, start, start + nl_raw)
                 h_count = jnp.where(left_smaller, nl_raw, nr_raw)
-                hist_small = reduce_hist(hist_fn(payload, h_start, h_count))
-                hist_big = hist_parent - hist_small
-                new_left = jnp.where(left_smaller, hist_small, hist_big)
-                new_right = jnp.where(left_smaller, hist_big, hist_small)
-            if pooled:
-                slot_of_leaf = st["slot_of_leaf"]
-                leaf_of_slot = st["leaf_of_slot"]
-                use = st["slot_use"]
-                iota_pool = jnp.arange(POOL, dtype=jnp.int32)
+                with phase("hist"):
+                    hist_small = hist_fn(payload, h_start, h_count)
+                hist_small = reduce_hist(hist_small)
+                with phase("subtract"):
+                    hist_big = hist_parent - hist_small
+                    new_left = jnp.where(left_smaller, hist_small, hist_big)
+                    new_right = jnp.where(left_smaller, hist_big, hist_small)
+            # the histogram pool's book-keeping and the two slot writes
+            with phase("subtract"):
+                if pooled:
+                    slot_of_leaf = st["slot_of_leaf"]
+                    leaf_of_slot = st["leaf_of_slot"]
+                    use = st["slot_use"]
+                    iota_pool = jnp.arange(POOL, dtype=jnp.int32)
 
-                def evict(slot_of_leaf, leaf_of_slot, victim):
-                    old = leaf_of_slot[victim]
-                    oldc = jnp.maximum(old, 0)
-                    slot_of_leaf = slot_of_leaf.at[oldc].set(
-                        jnp.where(old >= 0, -1, slot_of_leaf[oldc]))
-                    return slot_of_leaf
+                    def evict(slot_of_leaf, leaf_of_slot, victim):
+                        old = leaf_of_slot[victim]
+                        oldc = jnp.maximum(old, 0)
+                        slot_of_leaf = slot_of_leaf.at[oldc].set(
+                            jnp.where(old >= 0, -1, slot_of_leaf[oldc]))
+                        return slot_of_leaf
 
-                # left child: reuse the parent's slot, else evict the LRU
-                victim_l = jnp.argmin(use).astype(jnp.int32)
-                lslot = jnp.where(pslot >= 0, pslot, victim_l)
-                slot_of_leaf = jnp.where(
-                    pslot >= 0, slot_of_leaf,
-                    evict(slot_of_leaf, leaf_of_slot, victim_l))
-                leaf_of_slot = leaf_of_slot.at[lslot].set(best_leaf)
-                use = use.at[lslot].set(s)
-                # right child: evict the LRU among the remaining slots
-                prio = jnp.where(iota_pool == lslot, jnp.int32(1 << 30), use)
-                rslot = jnp.argmin(prio).astype(jnp.int32)
-                slot_of_leaf = evict(slot_of_leaf, leaf_of_slot, rslot)
-                leaf_of_slot = leaf_of_slot.at[rslot].set(s)
-                use = use.at[rslot].set(s)
-                slot_of_leaf = slot_of_leaf.at[best_leaf].set(lslot)
-                slot_of_leaf = slot_of_leaf.at[s].set(rslot)
-                hist = st["hist"].at[lslot].set(new_left)
-                hist = hist.at[rslot].set(new_right)
-            elif not merged_hist:
-                hist = st["hist"].at[best_leaf].set(new_left)
-                hist = hist.at[s].set(new_right)
+                    # left child: reuse the parent's slot, else evict the LRU
+                    victim_l = jnp.argmin(use).astype(jnp.int32)
+                    lslot = jnp.where(pslot >= 0, pslot, victim_l)
+                    slot_of_leaf = jnp.where(
+                        pslot >= 0, slot_of_leaf,
+                        evict(slot_of_leaf, leaf_of_slot, victim_l))
+                    leaf_of_slot = leaf_of_slot.at[lslot].set(best_leaf)
+                    use = use.at[lslot].set(s)
+                    # right child: evict the LRU among the remaining slots
+                    prio = jnp.where(iota_pool == lslot,
+                                     jnp.int32(1 << 30), use)
+                    rslot = jnp.argmin(prio).astype(jnp.int32)
+                    slot_of_leaf = evict(slot_of_leaf, leaf_of_slot, rslot)
+                    leaf_of_slot = leaf_of_slot.at[rslot].set(s)
+                    use = use.at[rslot].set(s)
+                    slot_of_leaf = slot_of_leaf.at[best_leaf].set(lslot)
+                    slot_of_leaf = slot_of_leaf.at[s].set(rslot)
+                    hist = st["hist"].at[lslot].set(new_left)
+                    hist = hist.at[rslot].set(new_right)
+                elif not merged_hist:
+                    hist = st["hist"].at[best_leaf].set(new_left)
+                    hist = hist.at[s].set(new_right)
 
             child_depth = st["leaf_depth"][best_leaf] + 1
-            if cfg.with_monotone:
-                from .grower import propagate_monotone_bounds
-                lmin, lmax, rmin, rmax = propagate_monotone_bounds(
-                    st["blo"][best_leaf], st["bro"][best_leaf],
-                    ~st["bcat"][best_leaf], meta.monotone[f],
-                    st["mincon"][best_leaf], st["maxcon"][best_leaf])
-                res_l = find_split(new_left, lg, lh, lcnt,
-                                   min_constraint=lmin, max_constraint=lmax)
-                res_r = find_split(new_right, rg, rh, rcnt,
-                                   min_constraint=rmin, max_constraint=rmax)
-            elif stacked_find:
-                # the sequential loop must stay bit-comparable with the
-                # frontier-batched grower: evaluate the two children
-                # through the SAME stacked-fori search the batched rounds
-                # use (see find_best_split_batched's exactness note),
-                # then split the [2] rows back out
-                lmin = lmax = rmin = rmax = None
-                res2_ = find_split_batched(
-                    jnp.stack([new_left, new_right]),
-                    jnp.stack([lg, rg]), jnp.stack([lh, rh]),
-                    jnp.stack([lcnt, rcnt]))
-                res_l = jax.tree_util.tree_map(lambda a: a[0], res2_)
-                res_r = jax.tree_util.tree_map(lambda a: a[1], res2_)
-            else:
-                lmin = lmax = rmin = rmax = None
-                res_l = find_split(new_left, lg, lh, lcnt)
-                res_r = find_split(new_right, rg, rh, rcnt)
-            real_l, real_r = res_l.gain, res_r.gain
-            if forced is not None:
-                jp = st["fleaf"][best_leaf]
-                applied = (jp >= 0) & \
-                    (st["bgain"][best_leaf] >= 0.5 * PRIORITY_UNIT)
-                jp0 = jnp.maximum(jp, 0)
-                jl = jnp.where(applied, fc_lnext[jp0], -1)
-                jr = jnp.where(applied, fc_rnext[jp0], -1)
-                res_l, real_l, jl = forced_override(
-                    jl, hist_view(new_left), lg, lh, lcnt, res_l,
-                    min_constraint=lmin, max_constraint=lmax)
-                res_r, real_r, jr = forced_override(
-                    jr, hist_view(new_right), rg, rh, rcnt, res_r,
-                    min_constraint=rmin, max_constraint=rmax)
-            if cfg.max_depth > 0:
-                depth_ok = child_depth < cfg.max_depth
-            else:
-                depth_ok = jnp.bool_(True)
-            gain_l = jnp.where(depth_ok, res_l.gain, K_MIN_SCORE)
-            gain_r = jnp.where(depth_ok, res_r.gain, K_MIN_SCORE)
+            with phase("split_search"):
+                if cfg.with_monotone:
+                    from .grower import propagate_monotone_bounds
+                    lmin, lmax, rmin, rmax = propagate_monotone_bounds(
+                        st["blo"][best_leaf], st["bro"][best_leaf],
+                        ~st["bcat"][best_leaf], meta.monotone[f],
+                        st["mincon"][best_leaf], st["maxcon"][best_leaf])
+                    res_l = find_split(new_left, lg, lh, lcnt,
+                                       min_constraint=lmin,
+                                       max_constraint=lmax)
+                    res_r = find_split(new_right, rg, rh, rcnt,
+                                       min_constraint=rmin,
+                                       max_constraint=rmax)
+                elif stacked_find:
+                    # the sequential loop must stay bit-comparable with the
+                    # frontier-batched grower: evaluate the two children
+                    # through the SAME stacked-fori search the batched rounds
+                    # use (see find_best_split_batched's exactness note),
+                    # then split the [2] rows back out
+                    lmin = lmax = rmin = rmax = None
+                    res2_ = find_split_batched(
+                        jnp.stack([new_left, new_right]),
+                        jnp.stack([lg, rg]), jnp.stack([lh, rh]),
+                        jnp.stack([lcnt, rcnt]))
+                    res_l = jax.tree_util.tree_map(lambda a: a[0], res2_)
+                    res_r = jax.tree_util.tree_map(lambda a: a[1], res2_)
+                else:
+                    lmin = lmax = rmin = rmax = None
+                    res_l = find_split(new_left, lg, lh, lcnt)
+                    res_r = find_split(new_right, rg, rh, rcnt)
+                real_l, real_r = res_l.gain, res_r.gain
+                if forced is not None:
+                    jp = st["fleaf"][best_leaf]
+                    applied = (jp >= 0) & \
+                        (st["bgain"][best_leaf] >= 0.5 * PRIORITY_UNIT)
+                    jp0 = jnp.maximum(jp, 0)
+                    jl = jnp.where(applied, fc_lnext[jp0], -1)
+                    jr = jnp.where(applied, fc_rnext[jp0], -1)
+                    res_l, real_l, jl = forced_override(
+                        jl, hist_view(new_left), lg, lh, lcnt, res_l,
+                        min_constraint=lmin, max_constraint=lmax)
+                    res_r, real_r, jr = forced_override(
+                        jr, hist_view(new_right), rg, rh, rcnt, res_r,
+                        min_constraint=rmin, max_constraint=rmax)
+                if cfg.max_depth > 0:
+                    depth_ok = child_depth < cfg.max_depth
+                else:
+                    depth_ok = jnp.bool_(True)
+                gain_l = jnp.where(depth_ok, res_l.gain, K_MIN_SCORE)
+                gain_r = jnp.where(depth_ok, res_r.gain, K_MIN_SCORE)
 
             def set2(arr, vl, vr):
                 return arr.at[best_leaf].set(vl).at[s].set(vr)
@@ -903,8 +961,9 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
                     st["payload"], aux, start_c[k], cnt_c[k], pred)
                 return aux, nls.at[k].set(nl)
 
-            aux, nl_c = lax.fori_loop(
-                0, KB, eval_part, (st["aux"], jnp.zeros(KB, jnp.int32)))
+            with phase("partition"):
+                aux, nl_c = lax.fori_loop(
+                    0, KB, eval_part, (st["aux"], jnp.zeros(KB, jnp.int32)))
             payload = st["payload"]
 
             # eval phase B: ONE batched histogram dispatch over the K
@@ -922,18 +981,21 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
             left_smaller = lc_c <= rc_c
             h_start = jnp.where(left_smaller, start_c, start_c + nl_c)
             h_count = jnp.where(left_smaller, nl_c, cnt_c - nl_c)
-            hist_small = hist_batched_fn(aux, h_start, h_count)
-            hist_big = st["hist"][cand] - hist_small
-            ls4 = left_smaller[:, None, None, None]
-            new_left = jnp.where(ls4, hist_small, hist_big)
-            new_right = jnp.where(ls4, hist_big, hist_small)
+            with phase("hist"):
+                hist_small = hist_batched_fn(aux, h_start, h_count)
+            with phase("subtract"):
+                hist_big = st["hist"][cand] - hist_small
+                ls4 = left_smaller[:, None, None, None]
+                new_left = jnp.where(ls4, hist_small, hist_big)
+                new_right = jnp.where(ls4, hist_big, hist_small)
 
             # eval phase C: ONE fused split search over the 2K children
-            res2 = find_split_batched(
-                jnp.concatenate([new_left, new_right]),
-                jnp.concatenate([lg_c, rg_c]),
-                jnp.concatenate([lh_c, rh_c]),
-                jnp.concatenate([lc_c, rc_c]))
+            with phase("split_search"):
+                res2 = find_split_batched(
+                    jnp.concatenate([new_left, new_right]),
+                    jnp.concatenate([lg_c, rg_c]),
+                    jnp.concatenate([lh_c, rh_c]),
+                    jnp.concatenate([lc_c, rc_c]))
             child_depth = st["leaf_depth"][cand] + 1
             if cfg.max_depth > 0:
                 depth_ok = child_depth < cfg.max_depth
@@ -1051,7 +1113,8 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
                     pay, aux, start_c[j], cnt, nl_c[j], blo_c[j], bro_c[j],
                     cols.value)
 
-            payload = lax.fori_loop(0, KB, commit_part, payload)
+            with phase("partition"):
+                payload = lax.fori_loop(0, KB, commit_part, payload)
 
             st2["rounds"] = st2["rounds"] + 1
             st2["payload"] = payload

@@ -77,11 +77,15 @@ class TreeAssembler:
             if self._error is not None:
                 err, self._error = self._error, None
                 raise err
-            while len(self._fifo) >= self.depth:
-                self._cv.wait()
-                if self._error is not None:
-                    err, self._error = self._error, None
-                    raise err
+            if len(self._fifo) >= self.depth:
+                # back-pressure: the seconds the dispatch thread waits
+                # for the device (and the worker) to catch up
+                with tracing.span("assembler/wait", pending=len(self._fifo)):
+                    while len(self._fifo) >= self.depth:
+                        self._cv.wait()
+                        if self._error is not None:
+                            err, self._error = self._error, None
+                            raise err
             self._fifo.append((fn, max(1, int(trees))))
             # live queue depth (ISSUE 9): how far the device is running
             # ahead of the host model right now
@@ -119,8 +123,10 @@ class TreeAssembler:
         """Drain every pending half, stop the worker, and re-raise the
         first deferred error.  Idempotent; cheap when already empty."""
         with self._cv:
-            while self._fifo:
-                self._cv.wait()
+            if self._fifo:
+                with tracing.span("assembler/wait", pending=len(self._fifo)):
+                    while self._fifo:
+                        self._cv.wait()
             self._stopping = True
             self._cv.notify_all()
             thread, self._thread = self._thread, None

@@ -18,6 +18,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from ..runtime import tracing
 from ..utils.log import Log
 from ..utils.random import Random
 from .binning import (BIN_TYPE_CATEGORICAL, BIN_TYPE_NUMERICAL, BinMapper)
@@ -110,7 +111,9 @@ class BinnedDataset:
             else ["Column_%d" % i for i in range(f)]
 
         if bin_mappers is None:
-            bin_mappers = cls._find_bin_mappers(X, config, categorical_feature)
+            with tracing.span("dataset/find_bins", features=f):
+                bin_mappers = cls._find_bin_mappers(X, config,
+                                                    categorical_feature)
         ds.bin_mappers = bin_mappers
         ds.max_num_bin = max((m.num_bin for m in bin_mappers), default=1)
 
@@ -122,12 +125,15 @@ class BinnedDataset:
         # no native library) the per-feature Python path
         from .native import encode_bins
         t0 = time.perf_counter()
-        native = encode_bins(X, bin_mappers, bins)
+        with tracing.span("dataset/encode", path="native"):
+            native = encode_bins(X, bin_mappers, bins)
         if not native:
-            for j, mapper in enumerate(bin_mappers):
-                if mapper.is_trivial:
-                    continue
-                bins[j, :n] = mapper.values_to_bins(X[:, j].astype(np.float64))
+            with tracing.span("dataset/encode", path="python"):
+                for j, mapper in enumerate(bin_mappers):
+                    if mapper.is_trivial:
+                        continue
+                    bins[j, :n] = mapper.values_to_bins(
+                        X[:, j].astype(np.float64))
         ds.binning = {"path": "native" if native else "python",
                       "seconds": round(time.perf_counter() - t0, 3)}
         Log.info("binned %d x %d values through the %s path in %.2fs",
@@ -139,35 +145,36 @@ class BinnedDataset:
         # learners (data/voting) train bundled on the mesh fast path;
         # feature-parallel keeps unbundled storage (its feature sharding
         # predates bundles).
-        num_bins_arr = [m.num_bin for m in bin_mappers]
-        default_bins_arr = [m.default_bin for m in bin_mappers]
-        if reference_bundle is not None:
-            from .bundling import apply_bundles
-            ds.bundle_info = reference_bundle
-            bins = apply_bundles(bins, reference_bundle, num_bins_arr,
-                                 default_bins_arr)
-        elif (bool(getattr(config, "enable_bundle", True))
-              and str(getattr(config, "tree_learner", "serial"))
-              in ("serial", "data", "voting")
-              and f >= 2):
-            # features mostly at their zero bin are bundling candidates;
-            # denser ones isolate themselves anyway via the conflict budget
-            # but would make conflict counting quadratic-expensive
-            bundleable = [
-                (not m.is_trivial) and m.sparse_rate >= 0.5
-                and m.num_bin >= 2 for m in bin_mappers]
-            if sum(bundleable) >= 2:
-                out = bundle_features(
-                    bins, num_bins_arr, default_bins_arr, bundleable, n,
-                    max_conflict_rate=float(
-                        getattr(config, "max_conflict_rate", 0.0) or 0.0),
-                    max_bundle_bins=max(ds.max_num_bin, 255),
-                    sample_cnt=int(getattr(config,
-                                           "bin_construct_sample_cnt",
-                                           200000)),
-                    seed=int(getattr(config, "data_random_seed", 1)))
-                if out is not None:
-                    bins, ds.bundle_info = out
+        with tracing.span("dataset/bundle"):
+            num_bins_arr = [m.num_bin for m in bin_mappers]
+            default_bins_arr = [m.default_bin for m in bin_mappers]
+            if reference_bundle is not None:
+                from .bundling import apply_bundles
+                ds.bundle_info = reference_bundle
+                bins = apply_bundles(bins, reference_bundle, num_bins_arr,
+                                     default_bins_arr)
+            elif (bool(getattr(config, "enable_bundle", True))
+                  and str(getattr(config, "tree_learner", "serial"))
+                  in ("serial", "data", "voting")
+                  and f >= 2):
+                # features mostly at their zero bin are bundling candidates;
+                # denser ones isolate themselves anyway via the conflict budget
+                # but would make conflict counting quadratic-expensive
+                bundleable = [
+                    (not m.is_trivial) and m.sparse_rate >= 0.5
+                    and m.num_bin >= 2 for m in bin_mappers]
+                if sum(bundleable) >= 2:
+                    out = bundle_features(
+                        bins, num_bins_arr, default_bins_arr, bundleable, n,
+                        max_conflict_rate=float(
+                            getattr(config, "max_conflict_rate", 0.0) or 0.0),
+                        max_bundle_bins=max(ds.max_num_bin, 255),
+                        sample_cnt=int(getattr(config,
+                                               "bin_construct_sample_cnt",
+                                               200000)),
+                        seed=int(getattr(config, "data_random_seed", 1)))
+                    if out is not None:
+                        bins, ds.bundle_info = out
         if ds.bundle_info is not None:
             ds.max_num_bin = max(ds.max_num_bin,
                                  ds.bundle_info.max_group_bin)

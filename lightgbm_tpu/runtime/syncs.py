@@ -19,7 +19,9 @@ Two orthogonal dimensions are recorded per event:
 
 The counters are process-global and monotonically increasing; consumers
 take a :func:`snapshot` before a region and diff with :func:`delta`
-after it (bench reports ``host_syncs_per_iter`` this way).
+after it (bench reports ``host_syncs_per_iter`` this way).  Each blocking
+call is also timed, as a ``fetch/<label>`` span of the flight recorder
+(`runtime/tracing.py`): the seconds a thread was blocked on the device.
 
 Implicit syncs (``np.asarray`` on a live jax array, printing a device
 array) are outside the seam by construction; the training/boosting code
@@ -31,7 +33,7 @@ from __future__ import annotations
 import threading
 from typing import Any, Dict, Optional
 
-from . import telemetry
+from . import telemetry, tracing
 
 _lock = threading.Lock()
 _counts: Dict[str, int] = {}
@@ -82,14 +84,16 @@ def device_get(x: Any, label: str = "host_fetch") -> Any:
     before blocking, so a pytree is one round of transfers)."""
     import jax
     record(label)
-    return jax.device_get(x)
+    with tracing.span("fetch/" + label):
+        return jax.device_get(x)
 
 
 def block_until_ready(x: Any, label: str = "barrier") -> Any:
     """Audited `jax.block_until_ready`."""
     import jax
     record(label)
-    return jax.block_until_ready(x)
+    with tracing.span("fetch/" + label):
+        return jax.block_until_ready(x)
 
 
 def snapshot() -> Dict[str, Any]:

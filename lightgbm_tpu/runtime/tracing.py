@@ -35,6 +35,14 @@ publish/subscribe, subprocess launches):
   track names and no per-file clock fixups.  ``$LGBM_TPU_TRACE_DIR``
   arms an atexit dump (``trace_<host>_<pid>.json``) in every process
   that imports the runtime, so a fleet run collects itself.
+* **The device profiler's trace** is the second sink of every LIVE span
+  (`span`, and `bind` on the other thread): while one is open it holds
+  ``jax.profiler.TraceAnnotation("lgbm/" + name)``, so under any
+  profiler session (``LGBM_TPU_PROFILE=<dir>``, the benchmark's
+  ``--trace 1``) the program's spans sit in the trace's host plane on
+  the device's clock, on the thread that did the work.  Retro-recorded
+  events (`record`: watchdog stage closes, compiles) have no live
+  interval and stay ring-only.
 
 The hot-loop contract matches PR 9's: every recording call checks the
 module enable flag first, so with tracing disabled each site costs one
@@ -54,6 +62,7 @@ import json
 import os
 import socket
 import struct
+import sys
 import threading
 import time
 import zlib
@@ -65,7 +74,7 @@ __all__ = [
     "TRACE_RING_EVENTS", "TRACE_DIR_ENV", "TRACEPARENT_ENV",
     "TRACE_ENABLED_ENV",
     "set_enabled", "enabled", "reset", "set_context",
-    "span", "instant", "record", "counter_event",
+    "span", "instant", "record",
     "current", "current_traceparent", "context", "attach", "bind",
     "make_traceparent", "parse_traceparent", "process_root",
     "flow_id", "flow_start", "flow_end",
@@ -385,11 +394,31 @@ def record(name: str, t0_ns: int, dur_ns: int, *,
     _RING.append(ev)
 
 
+#: every live span's name in the device profiler's trace, so a reader
+#: of the trace tells the program's spans from everything else there
+ANNOTATION_PREFIX = "lgbm/"
+
+
+def _annotation(name: str):
+    """The second sink of a live span: a `jax.profiler.TraceAnnotation`
+    that puts it, as ``lgbm/<name>``, on the recording thread's line of
+    the profiler's host plane, on the clock the device trace uses.  It
+    costs next to nothing while no profiler session is on.  None when
+    jax is not loaded: this module never imports it."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return None
+    return profiler.TraceAnnotation(ANNOTATION_PREFIX + name)
+
+
 @contextlib.contextmanager
 def span(name: str, **labels):
     """Open a live span: a child of the current context (or a fresh
     trace root when there is none), ambient for everything recorded in
-    the scope, one 'X' event at close carrying ok/error status."""
+    the scope, one 'X' event at close carrying ok/error status.  While
+    it is open it also holds the profiler annotation of the same name
+    (`_annotation`): the ring and the device trace are two sinks of ONE
+    span source, and the enable flag turns off both."""
     if not _enabled:
         yield None
         return
@@ -397,6 +426,9 @@ def span(name: str, **labels):
     trace = ctx[0] if ctx is not None else new_trace_id()
     parent = ctx[1] if ctx is not None else None
     sid = new_span_id()
+    ann = _annotation(name)
+    if ann is not None:
+        ann.__enter__()
     st = _stack()
     st.append((trace, sid))
     t0 = time.monotonic_ns()
@@ -407,8 +439,11 @@ def span(name: str, **labels):
         status = "error"
         raise
     finally:
+        dur = time.monotonic_ns() - t0
         st.pop()
-        record(name, t0, time.monotonic_ns() - t0, trace=trace,
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        record(name, t0, dur, trace=trace,
                span_id=sid, parent=parent, status=status, **labels)
 
 
@@ -427,15 +462,6 @@ def instant(name: str, track: Optional[str] = None, **labels) -> None:
     if labels:
         ev["args"] = _clean_labels(labels)
     _RING.append(ev)
-
-
-def counter_event(name: str, value: float, track: str = "counters") -> None:
-    """One Perfetto counter sample (renders as a little graph row)."""
-    if not _enabled:
-        return
-    _RING.append({"ph": "C", "name": str(name)[:_LABEL_MAX_CHARS],
-                  "t_ns": time.monotonic_ns(),
-                  "tid": _track_tid(track), "value": float(value)})
 
 
 # -- flow links (publish -> subscriber arrows) ------------------------------
@@ -538,15 +564,12 @@ def export_chrome(path: Optional[str] = None,
             out["cat"] = "link"
             if e["ph"] == "f":
                 out["bp"] = "e"
-        if e["ph"] == "C":
-            out["args"] = {"value": e["value"]}
-        else:
-            args = dict(e.get("args", {}))
-            for key in ("trace", "span", "parent", "status"):
-                if key in e:
-                    args[key] = e[key]
-            if args:
-                out["args"] = args
+        args = dict(e.get("args", {}))
+        for key in ("trace", "span", "parent", "status"):
+            if key in e:
+                args[key] = e[key]
+        if args:
+            out["args"] = args
         events.append(out)
     doc = {
         "traceEvents": events,

@@ -37,7 +37,9 @@ through:
 Metrics ride the PR 9 registry (`lgbm_xla_compiles_total{site}`,
 ``lgbm_xla_compile_seconds{site}``, the cache/retrace families above);
 the ledger itself is pure host bookkeeping — with telemetry disabled
-the per-call cost is two clock reads and a list check.
+the per-call cost is two clock reads and a list check.  Every call is
+also one ``launch/<site>`` span of the flight recorder
+(`runtime/tracing.py`; off with ``LGBM_TPU_TRACE=0``).
 
 No jax / numpy at module scope — jax loads lazily inside `jit()`.
 """
@@ -351,6 +353,7 @@ class LedgeredJit:
     def __init__(self, fn: Callable, site: str, jit_kwargs: Dict[str, Any]):
         import jax
         self.site = site
+        self._span = "launch/" + site
         self._rec = LEDGER.register(site)
         rec = self._rec
 
@@ -373,8 +376,12 @@ class LedgeredJit:
         n0 = len(notes)
         if LEDGER._cost_capture:
             self._maybe_capture_cost(args, kwargs)
+        # the launch as a span: the host's time to enqueue one program
+        # (or, when the call compiles, to trace, lower and build or load
+        # it -- the retro-recorded "xla compile <site>" beside it says so)
         t0 = time.perf_counter()
-        out = self._jitted(*args, **kwargs)
+        with tracing.span(self._span):
+            out = self._jitted(*args, **kwargs)
         dt = time.perf_counter() - t0
         if len(notes) > n0:
             mine = [sig for r, sig in notes[n0:] if r is rec]

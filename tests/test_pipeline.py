@@ -3,6 +3,8 @@ vs off across every boosting family, the tier-1 sync-audit pin (0
 blocking host fetches on the tree->tree critical path at
 pipeline_depth=1), flush barriers at model reads, deferred no-split
 stop, and the bounded pack caches."""
+import time
+
 import numpy as np
 import pytest
 
@@ -193,3 +195,98 @@ def test_sentinel_disables_pipeline_but_trains():
     snap = syncs.snapshot()
     assert snap["critical_by_label"].get("tree_fetch") == 3, snap
     assert bst.num_trees() == 4
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 24: the seconds update() waits for the device are a span
+# ---------------------------------------------------------------------------
+
+def _ring_spans():
+    from lightgbm_tpu.runtime import tracing
+    return [e for e in tracing.export_chrome()["traceEvents"]
+            if e["ph"] == "X"]
+
+
+@pytest.mark.parametrize("where", ["submit", "flush", "no_wait"])
+def test_assembler_wait_span_only_when_it_waits(where):
+    import threading
+
+    from lightgbm_tpu.boosting.pipeline import TreeAssembler
+    from lightgbm_tpu.runtime import tracing
+
+    def settle(asm):
+        while asm.pending:
+            time.sleep(0.001)
+
+    tracing.reset()
+    release = threading.Event()
+    asm = TreeAssembler(depth=1)
+    with tracing.span("train/iteration") as ctx:
+        if where == "no_wait":
+            asm.submit(lambda: None)
+            settle(asm)                 # nothing pending: nothing to wait for
+            asm.submit(lambda: None)
+            settle(asm)
+            asm.flush()
+        else:
+            asm.submit(release.wait)
+            threading.Timer(0.05, release.set).start()
+            if where == "flush":
+                asm.flush()
+            else:
+                asm.submit(lambda: None)    # depth 1: the first unit counts
+                settle(asm)
+    asm.flush()
+    evs = _ring_spans()
+    waits = [e for e in evs if e["name"] == "assembler/wait"]
+    assert len([e for e in evs if e["name"] == "assembler/drain"]) \
+        == (1 if where == "flush" else 2)
+    if where == "no_wait":
+        assert waits == []
+    else:
+        assert len(waits) == 1
+        assert waits[0]["args"]["parent"] == ctx[1]
+        assert waits[0]["args"]["pending"] == 1
+        assert waits[0]["dur"] >= 20e3      # us: it did wait for the timer
+    tracing.reset()
+
+
+@pytest.mark.parametrize("name, parent", [
+    ("fetch/pipeline_drain", "assembler/drain"),
+    ("launch/gbdt.pack_fetch", "assembler/drain"),
+    ("assembler/drain", "train/iteration"),
+    ("launch/gbdt.step", "train/iteration"),
+])
+def test_drain_spans_hang_under_the_dispatching_iteration(name, parent):
+    from lightgbm_tpu.runtime import tracing
+    tracing.reset()
+    bst = _train({}, depth=1, rounds=2)
+    assert bst.num_trees() == 2
+    evs = _ring_spans()
+    by_id = {e["args"]["span"]: e for e in evs}
+    found = [e for e in evs if e["name"] == name]
+    assert len(found) >= 2
+    main = {e["tid"] for e in evs if e["name"] == "train/iteration"}
+    for e in found:
+        up = by_id[e["args"]["parent"]]
+        assert up["name"] == parent
+        assert e["args"]["trace"] == up["args"]["trace"]
+        # the host half runs on the assembler's thread, the launch of
+        # the step on the dispatching one
+        assert (e["tid"] in main) == (name == "launch/gbdt.step")
+    tracing.reset()
+
+
+def test_a_blocking_fetch_is_timed_not_only_counted():
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.runtime import tracing
+    tracing.reset()
+    before = syncs.snapshot()
+    syncs.device_get(jnp.ones(3), label="t_fetch")
+    syncs.block_until_ready(jnp.ones(3), label="t_barrier")
+    assert syncs.delta(before)["by_label"] == {"t_fetch": 1, "t_barrier": 1}
+    assert [e["name"] for e in _ring_spans()] \
+        == ["fetch/t_fetch", "fetch/t_barrier"]
+    tracing.reset()
+

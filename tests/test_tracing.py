@@ -122,7 +122,6 @@ def test_disabled_path_records_nothing_and_bind_is_identity():
         tracing.instant("x")
         tracing.record("x", 0, 0)
         tracing.flow_start("x", 1)
-        tracing.counter_event("x", 1.0)
         with tracing.span("x") as ctx:
             assert ctx is None
         fn = lambda: 1                          # noqa: E731
@@ -369,3 +368,146 @@ def test_metric_coverage_lint_green_and_drift_negative():
     # appears in telemetry.py as a dict key, yet it is still reported
     hits = lint.coverage(table=table)
     assert hits["lgbm_totally_unarmed_metric"] == []
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 24: one span source, two sinks -- the ring and the device
+# profiler's trace -- and the catalogue of spans at the seams
+# ---------------------------------------------------------------------------
+
+#: span -> its parent after a few iterations of a small booster (None: a
+#: root).  docs/OBSERVABILITY.md, "Spans", is the same list in prose.
+SPAN_CATALOGUE = {
+    "dataset/construct": None,
+    "dataset/find_bins": "dataset/construct",
+    "dataset/encode": "dataset/construct",
+    "dataset/bundle": "dataset/construct",
+    "train/iteration": None,
+    "booster/payload": "train/iteration",
+    "launch/gbdt.payload_build": "booster/payload",
+    "launch/gbdt.step": "train/iteration",
+    "assembler/wait": "train/iteration",
+    "assembler/drain": "train/iteration",
+    "launch/gbdt.pack_fetch": "assembler/drain",
+    "fetch/pipeline_drain": "assembler/drain",
+}
+#: the spans of the drain's host half: on the assembler's thread
+ON_WORKER = ("assembler/drain", "launch/gbdt.pack_fetch",
+             "fetch/pipeline_drain")
+
+
+def _profiled_iterations(trace_dir, iters=3):
+    """A small booster from raw matrix to `iters` drained trees under a
+    CPU `jax.profiler` session: (ring events, host-plane events as
+    (name, line index))."""
+    import glob
+
+    import jax
+
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.boosting import gbdt
+
+    rng = np.random.default_rng(5)
+    X = rng.standard_normal((2000, 8)).astype(np.float32)
+    y = (X[:, 0] + X[:, 1] * X[:, 2] > 0).astype(np.float64)
+    params = {"objective": "binary", "num_leaves": 7, "verbose": -1,
+              "pipeline_depth": 1}
+    # a drain slower than a launch: the next submit() HAS to wait
+    real_fetch = gbdt._fetch_packed
+
+    def slow_fetch(out, label="tree_fetch"):
+        time.sleep(0.05)
+        return real_fetch(out, label=label)
+
+    tracing.reset()
+    gbdt._fetch_packed = slow_fetch
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        bst = lgb.Booster(params, lgb.Dataset(X, label=y, params=params))
+        for _ in range(iters):
+            bst.update()
+        assert bst.current_iteration() == iters
+    finally:
+        jax.profiler.stop_trace()
+        gbdt._fetch_packed = real_fetch
+    ring = [e for e in tracing.export_chrome()["traceEvents"]
+            if e["ph"] == "X"]
+    [path] = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                       recursive=True)
+    host = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for i, line in enumerate(plane.lines):
+                host.extend((e.name, i) for e in line.events)
+    return ring, host
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    return _profiled_iterations(tmp_path_factory.mktemp("profile"))
+
+
+@pytest.mark.parametrize("name", sorted(SPAN_CATALOGUE))
+def test_span_catalogue_in_the_ring_with_its_parent(profiled, name):
+    ring, _ = profiled
+    by_id = {e["args"]["span"]: e for e in ring}
+    found = [e for e in ring if e["name"] == name]
+    assert found, sorted({e["name"] for e in ring})
+    parents = {by_id[e["args"]["parent"]]["name"]
+               if e["args"].get("parent") in by_id else None for e in found}
+    assert SPAN_CATALOGUE[name] in parents, parents
+    main_tid = next(e["tid"] for e in ring if e["name"] == "train/iteration")
+    for e in found:
+        assert (e["tid"] != main_tid) == (name in ON_WORKER)
+        parent = by_id.get(e["args"].get("parent"))
+        if parent is not None:
+            # the hand-off keeps the causal chain: same trace id
+            assert e["args"]["trace"] == parent["args"]["trace"]
+    # the instant this PR retired: the launch span is the same mark
+    assert not [e for e in ring if e["name"] == "tree dispatch"]
+
+
+@pytest.mark.parametrize("name", sorted(SPAN_CATALOGUE))
+def test_span_catalogue_in_the_profilers_host_plane(profiled, name):
+    _, host = profiled
+    lines = {i for n, i in host if n == "lgbm/" + name}
+    assert lines, sorted({n for n, _ in host if n.startswith("lgbm/")})
+    main = {i for n, i in host if n == "lgbm/train/iteration"}
+    # on the thread that did the work: the drain's spans on another line
+    assert lines.isdisjoint(main) == (name in ON_WORKER)
+
+
+def test_disabled_recorder_writes_no_annotation_either(tmp_path):
+    prev = tracing.set_enabled(False)
+    try:
+        ring, host = _profiled_iterations(tmp_path, iters=2)
+    finally:
+        tracing.set_enabled(prev)
+    assert ring == []
+    assert not [n for n, _ in host if n.startswith("lgbm/")]
+    assert host                     # the session itself recorded
+
+
+def test_runtime_package_and_tracing_load_no_jax():
+    """`tracing` takes the annotation class from `sys.modules` and does
+    without it when jax is not loaded.  The top-level package imports
+    jax, so the check stubs it and imports the runtime alone."""
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = (
+        "import sys, types\n"
+        "pkg = types.ModuleType('lightgbm_tpu')\n"
+        "pkg.__path__ = [%r]\n"
+        "sys.modules['lightgbm_tpu'] = pkg\n"
+        "import lightgbm_tpu.runtime.tracing as tracing\n"
+        "assert 'jax' not in sys.modules, 'import loaded jax'\n"
+        "with tracing.span('x'):\n"
+        "    pass\n"
+        "assert 'jax' not in sys.modules, 'span loaded jax'\n"
+        "assert tracing.ring_summary()['events'] == 1\n"
+        % os.path.join(root, "lightgbm_tpu"))
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+

@@ -237,3 +237,98 @@ def test_serving_compiled_flag_and_prewarm_tag(tmp_path):
         assert r1.compiled is True
         r2 = rt.predict(rng.standard_normal((40, 6)))   # warm bucket
         assert r2.compiled is False
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 24: every launch is a span, and the fused step's phases are scopes
+# ---------------------------------------------------------------------------
+
+def test_every_call_is_a_launch_span_beside_its_compile_event():
+    import jax.numpy as jnp
+    from lightgbm_tpu.runtime import tracing
+
+    f = xla_obs.jit(lambda x: x + 1, site="t.launch_span")
+    tracing.reset()
+    with tracing.span("caller") as ctx:
+        f(jnp.ones(4))                          # compiles
+        f(jnp.ones(4))                          # hit
+    prev = tracing.set_enabled(False)
+    try:
+        f(jnp.ones(4))                          # recorder off: no span
+    finally:
+        tracing.set_enabled(prev)
+    evs = [e for e in tracing.export_chrome()["traceEvents"]
+           if e["ph"] == "X"]
+    launches = [e for e in evs if e["name"] == "launch/t.launch_span"]
+    compiles = [e for e in evs if e["name"] == "xla compile t.launch_span"]
+    assert len(launches) == 2 and len(compiles) == 1
+    assert all(e["args"]["parent"] == ctx[1] for e in launches + compiles)
+    # the compiling launch is the long one: the compile event says why
+    assert launches[0]["dur"] >= compiles[0]["dur"] * 0.5
+    tracing.reset()
+
+
+def _step_op_names(extra):
+    """`op_name` metadata of `gbdt.step` lowered for a small booster."""
+    import re
+
+    import jax.numpy as jnp
+    from jax._src.lib import xla_client
+
+    X, y = _synth(n=1024, f=6)
+    params = dict({"objective": "binary", "num_leaves": 7, "verbose": -1},
+                  **extra)
+    bst = lgb.Booster(params, lgb.Dataset(X, label=y, params=params))
+    bst.update()
+    eng = bst._engine
+    fs = eng._fast
+    assert eng._fast_active
+    lowered = fs._step.lower(fs.payload, fs.aux, eng._feature_sample(),
+                             jnp.float32(0.1), jnp.int32(0))
+    opts = xla_client._xla.HloPrintOptions.short_parsable()
+    opts.print_metadata = True
+    text = lowered.compiler_ir(dialect="hlo").get_hlo_module().to_string(opts)
+    # [(instruction's opcode, its op_name)]
+    return re.findall(r'= \S+ ([\w-]+)\(.*op_name="([^"]*)"', text)
+
+
+@pytest.fixture(scope="module")
+def serial_step_op_names():
+    return _step_op_names({})
+
+
+@pytest.fixture(scope="module")
+def mesh_step_op_names():
+    return _step_op_names({"tree_learner": "data", "num_machines": 4})
+
+
+def test_phase_helper_knows_its_names():
+    from lightgbm_tpu.boosting import grower2
+    assert len(set(grower2.PHASES)) == 9
+    with pytest.raises(ValueError):
+        grower2.phase("probe")
+
+
+@pytest.mark.parametrize("name", ["grad", "root_hist", "partition", "hist",
+                                  "subtract", "split_search", "tree_update",
+                                  "score"])
+def test_fused_step_carries_a_scope_for_every_phase(serial_step_op_names,
+                                                    name):
+    assert [n for _, n in serial_step_op_names if "lgbm.%s" % name in n]
+    # the serial step reduces nothing across devices
+    assert not [n for _, n in serial_step_op_names if "lgbm.allreduce" in n]
+
+
+@pytest.mark.parametrize("name", ["allreduce", "root_hist", "hist",
+                                  "partition", "split_search"])
+def test_mesh_step_uses_the_same_names_and_scopes_its_collectives(
+        mesh_step_op_names, name):
+    assert [n for _, n in mesh_step_op_names if "lgbm.%s" % name in n], name
+    if name == "allreduce":
+        # every collective of the grower sits under the scope, the
+        # histogram's reduce-scatter among them
+        reduced = {op: n for op, n in mesh_step_op_names
+                   if op in ("all-reduce", "reduce-scatter", "all-gather")}
+        assert "reduce-scatter" in reduced or "all-reduce" in reduced
+        assert all("lgbm.allreduce" in n for n in reduced.values()), reduced
+
