@@ -11,10 +11,11 @@ so one call to the chip answers for all of them; the exit code is non-zero
 if any section failed.  The last stdout line is one JSON object, also
 written to ``chiprun_out/smoke_tpu_kernels.json``.
 
-On the chip:   python exp/smoke_tpu_kernels.py
+On the chip:   python exp/smoke_tpu_kernels.py [section ...]
 CPU rehearsal: JAX_PLATFORMS=cpu python exp/smoke_tpu_kernels.py --interpret
 (the Pallas interpreter at a reduced row count; proves the script, says
-nothing about Mosaic).
+nothing about Mosaic).  Section names (`partition_acc precision merged
+ring4` are the four that run `_acc_kernel`) keep the run to those.
 """
 import json
 import os
@@ -352,8 +353,13 @@ def main():
           % (device["platform"], device["kind"], jax.__version__, INTERPRET,
              N), flush=True)
     assert {f.__name__ for f in STAGED} == set(pseg.STAGED_FLAGS)
+    sections = {f.__name__: f for f in DEFAULT_PATH + STAGED}
+    wanted = [a for a in sys.argv[1:] if not a.startswith("--")]
+    if set(wanted) - set(sections):
+        sys.exit("smoke_tpu_kernels: no section %s (has: %s)"
+                 % (sorted(set(wanted) - set(sections)), sorted(sections)))
     verdicts = {}
-    for fn in DEFAULT_PATH + STAGED:
+    for fn in [sections[a] for a in wanted] or DEFAULT_PATH + STAGED:
         name = fn.__name__
         t0 = time.perf_counter()
         try:

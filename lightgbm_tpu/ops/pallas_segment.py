@@ -195,46 +195,78 @@ COLBLOCK_WIDTH = 512
 PARTITION_HIST_VALIDATED = False
 
 
+def _acc_plan_bytes(payload_width: int, num_bins: int, ring_depth: int,
+                    group: int) -> int:
+    """VMEM plan of the accumulator-window partition kernel with pass A
+    taking `group` chunks a loop trip: the read ring (`ring_depth` groups
+    of chunks), two [2C, P] accumulators, stage/blend buffers, the P-wide
+    placement intermediates of each chunk in flight (budgeted for the
+    LARGER of the two placement modes — roll mode keeps parts + permuted +
+    doubled + rolled buffers live, ~10C rows), the [C, C] machinery (`tri`
+    and the row iota once, a one-hot and its relayouts a chunk in flight;
+    the matmul mode's [2C, C] pair fits the same 8C*C at group 1) and the
+    categorical bitset one-hot of each chunk's routing."""
+    P, C = payload_width, CHUNK
+    return (4 * P * C * (ring_depth * group    # ring
+                         + 6                   # accs(4C) + stage/rbuf(2C)
+                         + 10 * group)         # placement intermediates
+            + 4 * C * C * (6 + 2 * group)
+            + 4 * C * num_bins * group)
+
+
+#: chunks pass A of the accumulator partition takes a loop trip where VMEM
+#: allows.  The body of one chunk is a dependent chain (routing -> rank ->
+#: one-hot -> matmuls) the scheduler cannot shorten; the chains of several
+#: chunks in one basic block interleave (PERF.md §6, PR 25: 1591 / 1350 /
+#: 1241 ns a chunk at 1 / 2 / 4 on the chip).  Not 4: every chunk of a
+#: trip is a copy of the body for Mosaic to compile, and at 4 the fused
+#: step compiled 4.6 s longer than the parent's on the chip's host (0.5 s
+#: at 2), which a training job pays for every new data set (`gbdt.step`
+#: is keyed on the data).
+_PASS_A_GROUP = 2
+
+
+def _pass_a_group(payload_width: int, num_bins: int, ring_depth: int,
+                  extra_bytes: int = 0) -> int:
+    """Chunks a trip of pass A: `_PASS_A_GROUP` where its VMEM plan fits
+    beside `extra_bytes` (the merged kernel's histogram machinery), else
+    1, the plan the fits_vmem gates admit or refuse.  More chunks in
+    flight need more VMEM, so the width of the payload decides."""
+    fits = (_acc_plan_bytes(payload_width, num_bins, ring_depth,
+                            _PASS_A_GROUP) + extra_bytes <= _VMEM_BUDGET)
+    return _PASS_A_GROUP if fits else 1
+
+
+def _hist_plan_bytes(num_features: int, num_bins: int) -> int:
+    """What the merged kernel plans beside the partition: the histogram
+    tile machinery and TWO [8T, W] part-accumulators (left + right)."""
+    ft, n_tiles, w = _tiling(num_features, num_bins)
+    return (2 * 4 * CHUNK * w              # expand/rep + one-hot tile
+            + 2 * 4 * 8 * n_tiles * w      # two child accumulators
+            + 4 * ft * w)                  # window expander
+
+
 def partition_hist_fits_vmem(payload_width: int, num_features: int,
                              num_bins: int) -> bool:
     """VMEM plan of the merged partition+histogram kernel: the acc
-    partition's plan plus the histogram tile machinery and TWO [8T, W]
-    part-accumulators (left + right child).  Higgs/MS-LTR shapes fit;
+    partition's plan plus the histogram's.  Higgs/MS-LTR shapes fit;
     Expo-wide accumulators (88 tiles) overflow and fall back to the
     split kernels."""
     if num_bins > 256:
         return False
-    ft, n_tiles, w = _tiling(num_features, num_bins)
-    P, C = payload_width, CHUNK
-    ring_depth = _ring_depth_default()
-    est_acc = ((ring_depth - 2) * 4 * P * C
-               + 4 * P * 18 * C + 4 * 8 * C * C + 4 * C * num_bins)
-    est_hist = (2 * 4 * CHUNK * w              # expand/rep + one-hot tile
-                + 2 * 4 * 8 * n_tiles * w      # two child accumulators
-                + 4 * ft * w)                  # window expander
-    return est_acc + est_hist <= _VMEM_BUDGET
+    return (_acc_plan_bytes(payload_width, num_bins, _ring_depth_default(), 1)
+            + _hist_plan_bytes(num_features, num_bins)) <= _VMEM_BUDGET
 
 
 def partition_acc_fits_vmem(payload_width: int, num_bins: int,
                             ring_depth: int = None) -> bool:
-    """VMEM plan of the accumulator-window partition kernel: read ring,
-    two [2C, P] accumulators, stage/blend buffers, the P-wide placement
-    intermediates (budgeted for the LARGER of the two placement modes —
-    roll mode keeps parts + compacted + doubled + rolled buffers live per
-    side, ~8C rows vs the matmul mode's shared ~5C), the placement
-    one-hot machinery and the categorical bitset one-hot."""
+    """True when the accumulator-window partition kernel's VMEM plan fits
+    with pass A one chunk a trip (narrower payloads take more,
+    `_pass_a_group`)."""
     if ring_depth is None:
         ring_depth = _ring_depth_default()
-    P, C = payload_width, CHUNK
-    est = ((ring_depth - 2) * 4 * P * C   # ring slots past the baseline 2
-           + 4 * P * 18 * C   # ring(2C) + accs(4C) + stage/rbuf(2C) + placement intermediates(~10C, roll mode worst case)
-           + 4 * 8 * C * C         # worst mode's [*, C] one-hot machinery:
-                                   #   matmul: mat[2C,C] + iota_2i[2C,C] +
-                                   #           rank's ri/rj/tri [C,C] x3 (7C*C)
-                                   #   roll:   matc + fresh iota + ri/rj/tri,
-                                   #           [C,C] x5 (5C*C); 8C*C covers both
-           + 4 * C * num_bins)     # categorical bitset one-hot in go_left
-    return est <= _VMEM_BUDGET
+    return _acc_plan_bytes(payload_width, num_bins, ring_depth,
+                           1) <= _VMEM_BUDGET
 
 
 def partition_fits_vmem(payload_width: int, num_bins: int) -> bool:
@@ -1249,18 +1281,39 @@ C2 = 2 * CHUNK
 
 def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm,
                 payload_out, aux_out, nl_out, *rest,
-                P, B, value_col, roll_place=False, hist_cfg=None):
+                P, B, value_col, roll_place=False, hist_cfg=None, group=1):
     """Accumulator-window partition: same contract as `_partition_kernel`,
     restructured around the measured bottleneck (per-chunk latency, not
     bandwidth).  Lefts and rights accumulate in VMEM windows [2C, P] that
     flush ALIGNED, FULL chunks to HBM only when a window fills — so the
     per-chunk read-modify-write round trips of the RMW kernel collapse to
-    one amortized direct write per side, the destination offset is folded
-    into the placement one-hot (no separate shift matmul), reads prefetch
-    on a double-buffered ring, and exactness costs three ONE-pass matmuls
-    on a bf16-exact hi/mid/lo decomposition instead of a 6-pass HIGHEST.
+    one amortized direct write per side, reads prefetch on a
+    double-buffered ring, and exactness costs three ONE-pass matmuls on a
+    bf16-exact hi/mid/lo decomposition instead of a 6-pass HIGHEST.
     Only the LAST window of a segment needs a blend read (its tail crosses
     into the next leaf's rows).
+
+    Pass A places a chunk's rows with ONE permutation, not one compaction
+    per side: lefts in order, then rights in order, is a stable partition
+    of the chunk's valid rows, so one destination vector (one tri mat-vec
+    for the lefts' ranks; the rights' ranks are iota arithmetic, the valid
+    rows of a chunk being one contiguous range) and one [C, C] one-hot
+    applied to the three parts give a [C, P] block with the lefts at rows
+    [0, nl_k) and the rights at [nl_k, nl_k + nr_k).  Each side's
+    placement is then a rotate of the SAME doubled block to its
+    accumulator's cursor (exact data movement).  Per chunk: 4 MXU
+    contractions (1 rank + 3 parts), one [C, C] matrix built on the VPU,
+    two rotates, two blends; what does not depend on the chunk (`tri`, the
+    [C, C] row iota) is built once before the loop.
+
+    That body is one dependent chain (routing -> rank -> one-hot ->
+    matmuls -> rotate -> blend) and the chip runs it at the chain's
+    latency, not at any unit's rate (PERF.md §6, PR 25), so pass A takes
+    `group` chunks a loop trip: every wait and load first, then each
+    chunk's permuted block, which depends on no cursor, then the blocks
+    placed in order.  The chains of a trip share a basic block and
+    interleave.  The ring holds its depth in groups; a trip's chunks past
+    the segment's last are not read and count as empty.
 
     With `hist_cfg` set (the merged partition+hist kernel), pass A also
     accumulates BOTH children's histograms from the resident ring chunks:
@@ -1297,20 +1350,19 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm,
         return ((iota_rows >= shift - k * CHUNK) &
                 (iota_rows < shift + count - k * CHUNK)).astype(jnp.int32)
 
-    def go_left(data, k):
-        return _go_left_rows(scalars, bitset_ref, data, B, iota_p) \
-            * valid_mask(k)                                  # [C] i32 0/1
+    # chunk-independent [C, C] machinery, built once before the chunk loop
+    # (as _hist_kernel does for its one-hot machinery).  The iotas are
+    # built at [C, C] directly: slicing the [2C, C] ones (e.g.
+    # iota_2i[:CHUNK]) crashes Mosaic's ApplyVectorLayout — a broadcasted
+    # iota is stored replicated along its constant dim, and
+    # vector.extract_strided_slice asks that dim for more vregs than the
+    # replicated layout holds (hardware-bisected, round 4).
+    iota_ci = lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 0)
+    tri = (lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 1) <
+           iota_ci).astype(jnp.float32)
 
     def rank_of(keep_i):
-        """Exclusive prefix count of kept rows (tri matvec; <= C, exact).
-        The iotas are built at [C, C] directly: slicing the [2C, C] ones
-        (e.g. iota_2j[:CHUNK]) crashes Mosaic's ApplyVectorLayout — a
-        broadcasted iota is stored replicated along its constant dim, and
-        vector.extract_strided_slice asks that dim for more vregs than the
-        replicated layout holds (hardware-bisected, round 4)."""
-        ri = lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 0)
-        rj = lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 1)
-        tri = (rj < ri).astype(jnp.float32)
+        """Exclusive prefix count of kept rows (tri matvec; <= C, exact)."""
         return jnp.dot(tri, keep_i.astype(jnp.float32)[:, None],
                        preferred_element_type=jnp.float32)[:, 0].astype(jnp.int32)
 
@@ -1333,20 +1385,18 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm,
                 jnp.dot(mat, mid, preferred_element_type=jnp.float32) +
                 jnp.dot(mat, lo, preferred_element_type=jnp.float32))
 
-    def place_compact_roll(parts, rank, member, off):
-        """[2C, P]: compact kept rows to the top with a [C, C] one-hot
-        (half the placement matmul), then rotate the doubled buffer so
-        they land at [off, off+cnt) — the rotate is exact data movement."""
-        matc = ((lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 0) ==
-                 rank[None, :]) &
-                (member[None, :] > 0)).astype(jnp.float32)       # [C, C]
-        # fresh [C, C] iota, NOT iota_2i[:CHUNK] — see rank_of
+    def permute_doubled(parts, dest, member):
+        """[2C, P], twice the [C, P] block in which source row j
+        (member[j]=1) sits at row dest[j]: one 0/1 one-hot applied to the
+        exact parts (three one-pass matmuls).  Doubled so that a rotate by
+        any cursor difference places a run of it without wrap-around."""
+        mat = ((iota_ci == dest[None, :]) &
+               (member[None, :] > 0)).astype(jnp.float32)        # [C, C]
         hi, mid, lo = parts
-        compacted = (jnp.dot(matc, hi, preferred_element_type=jnp.float32) +
-                     jnp.dot(matc, mid, preferred_element_type=jnp.float32) +
-                     jnp.dot(matc, lo, preferred_element_type=jnp.float32))
-        return pltpu.roll(jnp.concatenate([compacted, compacted], axis=0),
-                          off, axis=0)
+        perm = (jnp.dot(mat, hi, preferred_element_type=jnp.float32) +
+                jnp.dot(mat, mid, preferred_element_type=jnp.float32) +
+                jnp.dot(mat, lo, preferred_element_type=jnp.float32))
+        return jnp.concatenate([perm, perm], axis=0)
 
     def drain(dst_ref, stage_buf, sem, pend):
         """Wait a still-flying flush before its staging buffer/semaphore
@@ -1453,50 +1503,55 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm,
                         dimension_numbers=(((1,), (0,)), ((), ())),
                         preferred_element_type=jnp.float32)
 
-    R = ring.shape[0]   # ring depth: 2 validated, 4 staged (RING4 flag)
+    # the ring holds its depth (2 validated, 4 staged: RING4 flag) in
+    # GROUPS of chunks for pass A, in chunks for pass B
+    G = group
+    R = ring.shape[0] // G
 
     @pl.when(nch > 0)
     def _prefetch_first():
-        # fill the ring: R-1 chunks in flight before the loop starts
-        for i in range(R - 1):
+        # fill the ring: R-1 groups in flight before the loop starts
+        for i in range((R - 1) * G):
             @pl.when(i < nch)
             def _start(i=i):
                 ring_dma(payload_out, i, i).start()
 
     # ---- pass A: one read of the segment; lefts accumulate toward payload
     # windows, rights accumulate toward aux staging windows -------------
-    def body_a(k, carry):
-        nl, nr, lo_, ro_, lfl, rfl, pl_, pr_ = carry
-        slot = lax.rem(k, R)
-
-        @pl.when(k + R - 1 < nch)
-        def _prefetch_next():
-            ring_dma(payload_out, k + R - 1, lax.rem(k + R - 1, R)).start()
-
-        ring_dma(payload_out, k, slot).wait()
-        data = ring[slot]
-
-        @pl.when(k == 0)
-        def _seed():
-            # the first window's prologue rows belong to the previous
-            # leaf; seeding from chunk 0 makes every later flush a plain
-            # full-window write
-            lacc[0:CHUNK] = data
-
-        gl = go_left(data, k)
-        keep_r = valid_mask(k) - gl
+    def permuted(k, data):
+        """(nlk, nrk, block) of chunk k: what of its placement depends on
+        no cursor and no accumulator, so that the chunks of a trip are
+        independent up to here."""
+        valid = valid_mask(k)
+        gl = _go_left_rows(scalars, bitset_ref, data, B, iota_p) * valid
+        keep_r = valid - gl
         if hist_cfg is not None:
             hist_accumulate(data, gl, keep_r)
         nlk = jnp.sum(gl)
         nrk = jnp.sum(keep_r)
         rank_l = rank_of(gl)
-        rank_r = rank_of(keep_r)
-
+        # the chunk's valid rows are one contiguous range, so the valid
+        # rows before row i are iota arithmetic and the rights among them
+        # are those that are not lefts: no second prefix count
+        rank_r = jnp.maximum(
+            iota_rows - jnp.maximum(shift - k * CHUNK, 0), 0) - rank_l
         parts = _bf16_parts(data)
         if roll_place:
-            placed_l = place_compact_roll(parts, rank_l, gl, lo_)
-            placed_r = place_compact_roll(parts, rank_r, keep_r, ro_)
+            # ONE stable partition of the chunk: lefts to [0, nlk), rights
+            # to [nlk, nlk + nrk), both in original order
+            return nlk, nrk, permute_doubled(
+                parts, jnp.where(gl > 0, rank_l, nlk + rank_r), valid)
+        return nlk, nrk, (parts, rank_l, gl, rank_r, keep_r)
+
+    def place(nlk, nrk, block, carry):
+        nl, nr, lo_, ro_, lfl, rfl, pl_, pr_ = carry
+        if roll_place:
+            # each side is a rotate of the same doubled block to its
+            # cursor (the rotate-by-a-difference of pass B)
+            placed_l = pltpu.roll(block, lo_, axis=0)
+            placed_r = pltpu.roll(block, ro_ - nlk + C2, axis=0)
         else:
+            parts, rank_l, gl, rank_r, keep_r = block
             placed_l = place_matmul(parts, lo_ + rank_l, gl)
             placed_r = place_matmul(parts, ro_ + rank_r, keep_r)
         blend(lacc, placed_l, nlk, lo_, left_value)
@@ -1517,8 +1572,42 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm,
                 ro_ + nrk - fr * CHUNK, lfl + fl, rfl + fr,
                 jnp.maximum(pl_, fl), jnp.maximum(pr_, fr))
 
+    def body_a(t, carry):
+        k0 = t * G
+        slots = [lax.rem(k0 + i, R * G) for i in range(G)]
+        for k in [k0 + (R - 1) * G + i for i in range(G)]:
+            @pl.when(k < nch)
+            def _prefetch_next(k=k):
+                ring_dma(payload_out, k, lax.rem(k, R * G)).start()
+
+        # every wait and load before any chunk's arithmetic: a DMA wait
+        # is a barrier the scheduler moves nothing across
+        ring_dma(payload_out, k0, slots[0]).wait()
+        for i in range(1, G):
+            @pl.when(k0 + i < nch)
+            def _wait(i=i):
+                ring_dma(payload_out, k0 + i, slots[i]).wait()
+
+        # a chunk past the segment's last was not read: its slot holds an
+        # older chunk or nothing yet, and 0 x NaN would poison the matmuls
+        datas = [ring[slots[0]]] + [
+            jnp.where(k0 + i < nch, ring[slots[i]], 0.0)
+            for i in range(1, G)]
+
+        @pl.when(t == 0)
+        def _seed():
+            # the first window's prologue rows belong to the previous
+            # leaf; seeding from chunk 0 makes every later flush a plain
+            # full-window write
+            lacc[0:CHUNK] = datas[0]
+
+        blocks = [permuted(k0 + i, datas[i]) for i in range(G)]
+        for block in blocks:
+            carry = place(*block, carry)
+        return carry
+
     (num_left, num_right, lo_, ro_, lfl, rfl, pl_, pr_) = lax.fori_loop(
-        0, nch, body_a,
+        0, (nch + G - 1) // G, body_a,
         (jnp.int32(0), jnp.int32(0), shift, shift,
          jnp.int32(0), jnp.int32(0), jnp.int32(0), jnp.int32(0)))
     nl_out[0] = num_left
@@ -1634,8 +1723,9 @@ def _partition_segment_acc(payload, aux, start, count, pred, left_value,
     ]).astype(jnp.int32)
     fvals = jnp.stack([left_value, right_value]).astype(jnp.float32)
     bitset = pred.bitset.astype(jnp.int32).reshape(1, B)
+    group = _pass_a_group(P, B, ring_depth)
     kern = functools.partial(_acc_kernel, P=P, B=B, value_col=value_col,
-                             roll_place=roll_place)
+                             roll_place=roll_place, group=group)
     payload_new, aux_new, nl = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1648,12 +1738,13 @@ def _partition_segment_acc(payload, aux, start, count, pred, left_value,
                        pl.BlockSpec(memory_space=pl.ANY),
                        pl.BlockSpec(memory_space=pltpu.SMEM)),
             scratch_shapes=[
-                pltpu.VMEM((ring_depth, CHUNK, P), jnp.float32),  # read ring
+                pltpu.VMEM((ring_depth * group, CHUNK, P),
+                           jnp.float32),                  # read ring
                 pltpu.VMEM((C2, P), jnp.float32),         # left accumulator
                 pltpu.VMEM((C2, P), jnp.float32),         # right accumulator
                 pltpu.VMEM((CHUNK, P), jnp.float32),      # flush stage
                 pltpu.VMEM((CHUNK, P), jnp.float32),      # final blend read
-                pltpu.SemaphoreType.DMA((ring_depth,)),
+                pltpu.SemaphoreType.DMA((ring_depth * group,)),
                 pltpu.SemaphoreType.DMA(()),
                 pltpu.SemaphoreType.DMA(()),
             ],
@@ -1715,8 +1806,10 @@ def _partition_segment_hist(payload, aux, start, count, pred, left_value,
     hist_cfg = dict(F=F, B=B, Ft=Ft, W=W, grad_col=grad_col,
                     hess_col=hess_col, cnt_col=cnt_col,
                     expand_impl=expand_impl)
+    group = _pass_a_group(P, B, ring_depth, _hist_plan_bytes(F, B))
     kern = functools.partial(_acc_kernel, P=P, B=B, value_col=value_col,
-                             roll_place=roll_place, hist_cfg=hist_cfg)
+                             roll_place=roll_place, hist_cfg=hist_cfg,
+                             group=group)
     payload_new, aux_new, nl, hl, hr = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1731,12 +1824,13 @@ def _partition_segment_hist(payload, aux, start, count, pred, left_value,
                        pl.BlockSpec(memory_space=pltpu.VMEM),
                        pl.BlockSpec(memory_space=pltpu.VMEM)),
             scratch_shapes=[
-                pltpu.VMEM((ring_depth, CHUNK, P), jnp.float32),  # read ring
+                pltpu.VMEM((ring_depth * group, CHUNK, P),
+                           jnp.float32),                  # read ring
                 pltpu.VMEM((C2, P), jnp.float32),         # left accumulator
                 pltpu.VMEM((C2, P), jnp.float32),         # right accumulator
                 pltpu.VMEM((CHUNK, P), jnp.float32),      # flush stage
                 pltpu.VMEM((CHUNK, P), jnp.float32),      # final blend read
-                pltpu.SemaphoreType.DMA((ring_depth,)),
+                pltpu.SemaphoreType.DMA((ring_depth * group,)),
                 pltpu.SemaphoreType.DMA(()),
                 pltpu.SemaphoreType.DMA(()),
             ],

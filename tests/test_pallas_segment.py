@@ -121,6 +121,11 @@ def _pred(feature=1, threshold=B // 2, default_left=False, is_cat=False,
     (7, 1, {}),
     (9, 1015, dict(feature=2, threshold=B // 3)),
     (255, 513, dict(feature=4, threshold=1)),
+    # count < CHUNK with shift + count crossing a chunk edge
+    (100, 254, dict(feature=3, threshold=7)),
+    # a segment that ends exactly on a chunk edge, from a shifted start
+    (7, 505, dict(feature=0, threshold=5)),
+    (3, 1000, dict(feature=4, threshold=11)),
     # EFB bundle decode: storage col 2 holds an offset-encoded member
     (64, 500, dict(feature=2, threshold=3, offset=5, identity=False,
                    num_bin=9, default_bin=0)),
@@ -152,26 +157,115 @@ def test_partition_matches(start, count, predkw, impl):
                                rtol=1e-6, atol=0)
 
 
+def _routed_payload(skew, start, count):
+    """The split column (feature 1, threshold B // 2) set so that whole
+    chunks of the kernel's aligned read stream route one way: the cases in
+    which one side of the chunk's single permutation is empty."""
+    pay = np.array(_payload(1024, seed=count))
+    base = start - start % 8
+    chunk_of = (np.arange(pay.shape[0]) - base) // seg.CHUNK
+    left, right = 0.0, float(B - 1)
+    if skew == "all_left":
+        pay[:, 1] = left
+    elif skew == "all_right":
+        pay[:, 1] = right
+    elif skew == "left_chunk":       # chunk 1 all left, its neighbours mixed
+        pay[chunk_of == 1, 1] = left
+    elif skew == "right_chunk":
+        pay[chunk_of == 1, 1] = right
+    elif skew == "no_left_first":    # nl_k = 0 in the (shifted) first chunk
+        pay[chunk_of == 0, 1] = right
+    elif skew == "no_right_first":
+        pay[chunk_of == 0, 1] = left
+    return jnp.asarray(pay)
+
+
 @pytest.mark.parametrize("start,count", [(0, 1024), (7, 777), (100, 1),
-                                         (256, 512), (513, 511)])
-@pytest.mark.parametrize("skew", ["all_left", "all_right"])
+                                         (256, 512), (513, 511),
+                                         (100, 254), (7, 505), (3, 1000)])
+@pytest.mark.parametrize("skew", ["all_left", "all_right", "left_chunk",
+                                  "right_chunk", "no_left_first",
+                                  "no_right_first"])
 def test_partition_acc_skewed(start, count, skew):
-    """One-sided splits exercise the accumulator kernel's empty-side and
-    rare-flush paths (all rows route one way; the other accumulator never
-    fills)."""
-    pay = _payload(1024, seed=count)
+    """One-sided chunks exercise the accumulator kernel's empty-side and
+    rare-flush paths, and the ends of the one permutation that places a
+    chunk (lefts to [0, nl_k), rights behind them): all rows of the
+    segment, of a middle chunk or of the first, shifted chunk route one
+    way.  Payload, the rights staged in aux and num_left, bit for bit."""
+    pay = _routed_payload(skew, start, count)
     aux = jnp.zeros_like(pay)
-    pred = _pred(threshold=(B if skew == "all_left" else -1))
+    pred = _pred()
     lv, rv = jnp.float32(1.5), jnp.float32(-2.5)
     ref_pay, _, ref_nl = seg.partition_segment(
         pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv, VALUE_COL)
+    nl = int(ref_nl)
     for roll in (False, True):
-        got_pay, _, got_nl = pseg.partition_segment_acc(
+        got_pay, got_aux, got_nl = pseg.partition_segment_acc(
             pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv,
             VALUE_COL, B, interpret=True, roll_place=roll)
-        assert int(got_nl) == int(ref_nl)
-        np.testing.assert_allclose(np.asarray(got_pay), np.asarray(ref_pay),
-                                   rtol=1e-6, atol=0)
+        assert int(got_nl) == nl
+        np.testing.assert_array_equal(np.asarray(got_pay),
+                                      np.asarray(ref_pay))
+        np.testing.assert_array_equal(
+            np.asarray(got_aux)[start:start + count - nl],
+            np.asarray(ref_pay)[start + nl:start + count])
+
+
+def _while_dots(jaxpr):
+    """dot_generals inside each `while` of a jaxpr, in program order."""
+    def dots(jp):
+        n = 0
+        for eqn in jp.eqns:
+            n += eqn.primitive.name == "dot_general"
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                n += dots(sub)
+        return n
+
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "while":
+            out.append(dots(eqn.params["body_jaxpr"].jaxpr))
+        else:
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                out.extend(_while_dots(sub))
+    return out
+
+
+def test_pass_a_is_one_permutation():
+    """Pass A of `_acc_kernel` places a chunk with ONE permutation: its
+    loop body holds 4 MXU contractions a chunk (1 rank mat-vec + the
+    one-hot against the 3 exact parts) for each chunk of a trip, and pass
+    B none.  A second compaction per side (8 a chunk, the body before PR
+    25) must not come back quietly; traced only, nothing runs."""
+    pay = _payload(1024)
+    closed = jax.make_jaxpr(
+        lambda p, a: pseg._partition_segment_acc(
+            p, a, jnp.int32(7), jnp.int32(777), _pred(), jnp.float32(1.0),
+            jnp.float32(-1.0), VALUE_COL, B, False, True, 2))(
+        pay, jnp.zeros_like(pay))
+    assert _while_dots(closed.jaxpr) == [4 * pseg._pass_a_group(P, B, 2), 0]
+
+
+@pytest.mark.parametrize("width,group", [(P, 2), (256, 2), (384, 1)])
+@pytest.mark.parametrize("start,count", [(0, 1024), (7, 777), (100, 254),
+                                         (513, 37)])
+def test_partition_acc_groups(width, group, start, count):
+    """Pass A takes as many chunks a loop trip as the payload's width
+    leaves VMEM for; every group size gives the portable partition bit
+    for bit, whole trips and trips whose last chunks lie past the
+    segment (1 to 5 chunks here) alike."""
+    assert pseg._pass_a_group(width, B, 2) == group
+    pay = jnp.pad(_payload(1024, seed=count), ((0, 0), (0, width - P)))
+    aux = jnp.zeros_like(pay)
+    pred = _pred(feature=2, threshold=B // 3)
+    lv, rv = jnp.float32(-0.25), jnp.float32(0.75)
+    ref_pay, _, ref_nl = seg.partition_segment(
+        pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv, VALUE_COL)
+    got_pay, _, got_nl = pseg.partition_segment_acc(
+        pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv,
+        VALUE_COL, B, interpret=True)
+    assert int(got_nl) == int(ref_nl)
+    np.testing.assert_array_equal(np.asarray(got_pay), np.asarray(ref_pay))
 
 
 def test_validated_flags_gate_product_paths():
