@@ -214,6 +214,220 @@ int num_threads() {
 #endif
 }
 
+
+// ---- find-bin (io/binning.py greedy_find_bin, find_bin_with_zero_as_one_bin
+// and the numerical branch of BinMapper.find_bin, statement for statement:
+// the Python routines are the oracle and the bounds are byte-equal) --------
+
+constexpr double kZeroThreshold = 1e-35;
+
+inline void push_bound(std::vector<double>* bounds, double mid) {
+  double val = std::nextafter(mid, INFINITY);
+  if (bounds->empty() || val > bounds->back()) bounds->push_back(val);
+}
+
+// Equal-frequency boundaries over n (distinct value, count) pairs.
+// false: a case the Python routine raises on (left to it).
+bool greedy_find_bin(const double* d, const long long* c, long long n,
+                     long long max_bin, long long total_cnt,
+                     long long min_data_in_bin, std::vector<char>* big_buf,
+                     std::vector<double>* bounds) {
+  bounds->clear();
+  if (n == 0) {
+    bounds->push_back(INFINITY);
+    return true;
+  }
+  if (n <= max_bin) {
+    long long cur = 0;
+    for (long long i = 0; i + 1 < n; ++i) {
+      cur += c[i];
+      if (cur >= min_data_in_bin) {
+        size_t before = bounds->size();
+        push_bound(bounds, (d[i] + d[i + 1]) / 2.0);
+        if (bounds->size() != before) cur = 0;
+      }
+    }
+    bounds->push_back(INFINITY);
+    return true;
+  }
+  if (min_data_in_bin > 0)
+    max_bin = std::max(1LL, std::min(max_bin, total_cnt / min_data_in_bin));
+  if (max_bin <= 0) return false;
+  double mean_bin_size = static_cast<double>(total_cnt) / max_bin;
+  std::vector<char>& is_big = *big_buf;
+  is_big.resize(n);
+  long long n_big = 0, big_cnt = 0;
+  for (long long i = 0; i < n; ++i) {
+    is_big[i] = static_cast<double>(c[i]) >= mean_bin_size;
+    if (is_big[i]) {
+      ++n_big;
+      big_cnt += c[i];
+    }
+  }
+  long long rest_bin_cnt = max_bin - n_big;
+  long long rest_sample_cnt = total_cnt - big_cnt;
+  mean_bin_size = static_cast<double>(rest_sample_cnt)
+      / std::max(rest_bin_cnt, 1LL);
+  std::vector<double> uppers, lowers;
+  lowers.push_back(d[0]);
+  long long cur = 0;
+  for (long long i = 0; i + 1 < n; ++i) {
+    if (!is_big[i]) rest_sample_cnt -= c[i];
+    cur += c[i];
+    if (is_big[i] || static_cast<double>(cur) >= mean_bin_size ||
+        (is_big[i + 1] && static_cast<double>(cur) >=
+                              std::max(1.0, mean_bin_size * 0.5))) {
+      uppers.push_back(d[i]);
+      lowers.push_back(d[i + 1]);
+      if (static_cast<long long>(uppers.size()) >= max_bin - 1) break;
+      cur = 0;
+      if (!is_big[i]) {
+        --rest_bin_cnt;
+        mean_bin_size = static_cast<double>(rest_sample_cnt)
+            / std::max(rest_bin_cnt, 1LL);
+      }
+    }
+  }
+  for (size_t i = 0; i < uppers.size(); ++i)
+    push_bound(bounds, (uppers[i] + lowers[i + 1]) / 2.0);
+  bounds->push_back(INFINITY);
+  return true;
+}
+
+// The value range split at zero, so that bin(0.0) is exact.
+bool find_bin_zero_as_one(const double* d, const long long* c, long long n,
+                          long long max_bin, long long total_cnt,
+                          long long min_data_in_bin,
+                          std::vector<char>* big_buf,
+                          std::vector<double>* bounds) {
+  long long left_cnt = 0, left_cnt_data = 0, cnt_zero = 0;
+  long long right_cnt_data = 0, right_start = -1;
+  for (long long i = 0; i < n; ++i) {
+    if (d[i] <= -kZeroThreshold) {
+      ++left_cnt;
+      left_cnt_data += c[i];
+    } else if (d[i] > kZeroThreshold) {
+      if (right_start < 0) right_start = i;
+      right_cnt_data += c[i];
+    } else {
+      cnt_zero += c[i];
+    }
+  }
+  bounds->clear();
+  if (left_cnt > 0) {
+    long long denom = std::max(total_cnt - cnt_zero, 1LL);
+    long long left_max_bin = std::max(1LL, static_cast<long long>(
+        static_cast<double>(left_cnt_data) / static_cast<double>(denom)
+        * static_cast<double>(max_bin - 1)));
+    if (!greedy_find_bin(d, c, left_cnt, left_max_bin, left_cnt_data,
+                         min_data_in_bin, big_buf, bounds))
+      return false;
+    bounds->back() = -kZeroThreshold;
+  }
+  if (right_start >= 0) {
+    long long right_max_bin =
+        max_bin - 1 - static_cast<long long>(bounds->size());
+    std::vector<double> right;
+    if (!greedy_find_bin(d + right_start, c + right_start, n - right_start,
+                         right_max_bin, right_cnt_data, min_data_in_bin,
+                         big_buf, &right))
+      return false;
+    bounds->push_back(kZeroThreshold);
+    bounds->insert(bounds->end(), right.begin(), right.end());
+  } else {
+    bounds->push_back(INFINITY);
+  }
+  return true;
+}
+
+// A thread's scratch for one column at a time, kept across columns: a
+// fresh allocation of a sample's size a column is an mmap and a munmap,
+// and those serialise the threads (150 ms a column against 20).
+struct FindBinScratch {
+  std::vector<double> distinct;
+  std::vector<long long> counts;
+  std::vector<char> is_big;
+  explicit FindBinScratch(size_t n) {
+    distinct.reserve(n);
+    counts.reserve(n);
+    is_big.reserve(n);
+  }
+};
+
+struct ColumnBins {
+  std::vector<double> bounds;
+  int missing_type = 0;
+  int default_bin = 0;
+  double min_val = 0.0, max_val = 0.0, sparse_rate = 0.0;
+};
+
+// BinMapper.find_bin for one numerical column whose every sampled row is
+// present in values[0, total) (no implicit zeros).  Reorders `values`.
+// false: a column the Python routine must take (a negative zero, whose
+// place among the zeros numpy's sort decides; a case it raises on).
+bool find_bin_numerical(double* values, long long total, long long max_bin,
+                        long long min_data_in_bin, bool use_missing,
+                        bool zero_as_missing, FindBinScratch* scratch,
+                        ColumnBins* out) {
+  double* first_nan = std::partition(
+      values, values + total, [](double v) { return !std::isnan(v); });
+  const long long na_cnt = values + total - first_nan;
+  for (double* v = values; v < first_nan; ++v)
+    if (*v == 0.0 && std::signbit(*v)) return false;
+  std::sort(values, first_nan);
+  std::vector<double>& d = scratch->distinct;
+  std::vector<long long>& c = scratch->counts;
+  d.clear();
+  c.clear();
+  for (double* v = values; v < first_nan; ++v) {
+    if (d.empty() || *v != d.back()) {
+      d.push_back(*v);
+      c.push_back(1);
+    } else {
+      ++c.back();
+    }
+  }
+  if (d.empty()) {
+    d.push_back(0.0);
+    c.push_back(1);
+  }
+  const long long n = static_cast<long long>(d.size());
+  out->min_val = d.front();
+  out->max_val = d.back();
+  out->missing_type = !use_missing ? 0 : zero_as_missing ? 1
+      : (na_cnt > 0 ? 2 : 0);
+  std::vector<double>& b = out->bounds;
+  if (out->missing_type == 2) {
+    if (!find_bin_zero_as_one(d.data(), c.data(), n, max_bin - 1,
+                              total - na_cnt, min_data_in_bin,
+                              &scratch->is_big, &b))
+      return false;
+    b.push_back(NAN);
+  } else {
+    if (!find_bin_zero_as_one(d.data(), c.data(), n, max_bin, total,
+                              min_data_in_bin, &scratch->is_big, &b))
+      return false;
+    if (out->missing_type == 1 && b.size() == 2) out->missing_type = 0;
+  }
+  const long long nb = static_cast<long long>(b.size());
+  // value_to_bin(0.0): the encode's search ranges
+  long long hi = out->missing_type == 2 ? (nb >= 2 ? nb - 2 : 0) : nb - 1;
+  out->default_bin = static_cast<int>(
+      std::lower_bound(b.begin(), b.begin() + hi, 0.0) - b.begin());
+  // _cnt_in_bin: rows of the sample in the default bin
+  long long in_default = 0;
+  for (long long i = 0; i < n; ++i) {
+    long long idx = std::lower_bound(b.begin(), b.begin() + (nb - 1), d[i])
+        - b.begin();
+    if (std::min(idx, nb - 1) == out->default_bin) in_default += c[i];
+  }
+  if (out->missing_type == 2 && out->default_bin == nb - 1)
+    in_default = na_cnt;
+  out->sparse_rate = static_cast<double>(in_default)
+      / static_cast<double>(std::max(total, 1LL));
+  return true;
+}
+
 }  // namespace
 
 extern "C" {
@@ -338,6 +552,81 @@ int LGBMT_EncodeBins(const double* X, long long n, int F,
       }
       out[static_cast<long long>(f) * n_stride + i] =
           static_cast<unsigned char>(idx);
+    }
+  }
+  return 0;
+}
+
+// Numerical find-bin over the columns of a matrix, in threads: for each
+// column j with skip[j] == 0, the sampled rows' values go through
+// BinMapper.find_bin's numerical branch (byte-equal upper bounds to
+// io/binning.py, the oracle and the fallback).  X is [*, F] of f64 (or
+// f32 when is_f32) with byte strides; sample_idx holds n_sample row
+// numbers, every sampled row present (no implicit zeros).  Column j's
+// bounds land in bounds_out[j * bounds_cap ...] and status[j] is 0, or 1
+// where the column is left to the Python routine (skipped, a negative
+// zero in the sample, more bounds than bounds_cap).  rc 0.
+int LGBMT_FindBinsNumerical(const void* X, int is_f32,
+                            long long row_stride, long long col_stride,
+                            const long long* sample_idx, long long n_sample,
+                            int F, const unsigned char* skip,
+                            int max_bin, int min_data_in_bin,
+                            int use_missing, int zero_as_missing,
+                            int bounds_cap, double* bounds_out,
+                            int* num_bin, int* missing_type,
+                            int* default_bin, double* min_val,
+                            double* max_val, double* sparse_rate,
+                            int* status) {
+  const char* base = static_cast<const char*>(X);
+  // rows in ascending order: the order of a sample does not reach its
+  // sorted values, and the gather then walks the matrix forwards
+  std::vector<long long> rows(sample_idx, sample_idx + n_sample);
+  std::sort(rows.begin(), rows.end());
+  // a block of adjacent columns a task: one pass over the sampled rows
+  // reads a block's values from each (adjacent in a row-major matrix);
+  // narrower blocks where the columns are few, so every thread has some
+  const int kBlock = std::max(1, std::min(16, F / (4 * num_threads())));
+  const int n_blocks = (F + kBlock - 1) / kBlock;
+#pragma omp parallel
+  {
+    // a thread's buffers, allocated once: the block's sampled values
+    // and one column's scratch
+    std::vector<double> cols(static_cast<size_t>(kBlock) * n_sample);
+    FindBinScratch scratch(n_sample);
+#pragma omp for schedule(dynamic, 1)
+    for (int blk = 0; blk < n_blocks; ++blk) {
+      const int j0 = blk * kBlock;
+      const int j1 = std::min(F, j0 + kBlock);
+      for (long long i = 0; i < n_sample; ++i) {
+        const char* row = base + rows[i] * row_stride;
+        for (int j = j0; j < j1; ++j) {
+          if (skip[j]) continue;
+          const char* p = row + j * col_stride;
+          cols[static_cast<size_t>(j - j0) * n_sample + i] = is_f32
+              ? static_cast<double>(*reinterpret_cast<const float*>(p))
+              : *reinterpret_cast<const double*>(p);
+        }
+      }
+      for (int j = j0; j < j1; ++j) {
+        status[j] = 1;
+        if (skip[j]) continue;
+        ColumnBins out;
+        if (!find_bin_numerical(
+                cols.data() + static_cast<size_t>(j - j0) * n_sample,
+                n_sample, max_bin, min_data_in_bin, use_missing != 0,
+                zero_as_missing != 0, &scratch, &out))
+          continue;
+        if (static_cast<int>(out.bounds.size()) > bounds_cap) continue;
+        std::copy(out.bounds.begin(), out.bounds.end(),
+                  bounds_out + static_cast<long long>(j) * bounds_cap);
+        num_bin[j] = static_cast<int>(out.bounds.size());
+        missing_type[j] = out.missing_type;
+        default_bin[j] = out.default_bin;
+        min_val[j] = out.min_val;
+        max_val[j] = out.max_val;
+        sparse_rate[j] = out.sparse_rate;
+        status[j] = 0;
+      }
     }
   }
   return 0;

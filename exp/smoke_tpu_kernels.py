@@ -3,19 +3,19 @@ the chip and compared with the portable lax engine (or with an already
 checked sibling kernel).
 
 One verdict per kernel.  The kernels on the default path (histogram with
-both one-hot expansions, RMW / accumulator / roll partition, precision)
-come first; the six staged ones behind ``pallas_segment.STAGED_FLAGS``
-follow with a fetch-forced race each, printed as information.  A section
-that raises records its error and the run carries on to the next kernel,
-so one call to the chip answers for all of them; the exit code is non-zero
-if any section failed.  The last stdout line is one JSON object, also
+both one-hot expansions, RMW / accumulator / roll / column-block
+partition, precision) come first; the five staged ones behind
+``pallas_segment.STAGED_FLAGS`` follow with a fetch-forced race each,
+printed as information.  A section that raises records its error and the
+run carries on to the next kernel, so one call to the chip answers for
+all of them; the exit code is non-zero if any section failed.  The last stdout line is one JSON object, also
 written to ``chiprun_out/smoke_tpu_kernels.json``.
 
 On the chip:   python exp/smoke_tpu_kernels.py [section ...]
 CPU rehearsal: JAX_PLATFORMS=cpu python exp/smoke_tpu_kernels.py --interpret
 (the Pallas interpreter at a reduced row count; proves the script, says
-nothing about Mosaic).  Section names (`partition_acc precision merged
-ring4` are the four that run `_acc_kernel`) keep the run to those.
+nothing about Mosaic).  Section names (`partition_acc blocks precision
+merged ring4` are the five that run `_acc_kernel`) keep the run to those.
 """
 import json
 import os
@@ -164,6 +164,81 @@ def partition_acc():
     return info
 
 
+def blocks():
+    """partition_segment_acc_blocks, the engine of payloads no single-pass
+    plan holds: bit-equal to the portable partition at a ragged 1,280
+    lanes (blocks of 512, 512 and 256, the last two chunks a trip), then
+    at the Epsilon cell's own shape (409,600 rows x 2,048 lanes, 2,000
+    columns x 64 bins, segments of 256 to 409,600 rows) against a stable
+    partition done in numpy on the host (the portable engine beside it
+    would not fit the chip), and the whole payload timed a block width."""
+    Fw, Bw = 1200, 64
+    Pw = -(-(Fw + 8) // 128) * 128
+    pay = make_payload(N, Fw, Bw, width=Pw)
+    pred = make_pred(700, 30, Bw)
+    check_partition(
+        lambda p, a, s, c: pseg.partition_segment_acc_blocks(
+            p, a, s, c, pred, LV, RV, Fw + 3, Bw, **IK),
+        pay, pred, Fw + 3, segs((128, 3000), (7, 8000), (513, 256)))
+    info = {"ragged_1280_ms": median_ms(
+                lambda: np.asarray(pseg.partition_segment_acc_blocks(
+                    pay, jnp.zeros_like(pay), jnp.int32(0), jnp.int32(N),
+                    pred, LV, RV, Fw + 3, Bw, **IK)[0])[0, 0]),
+            "ragged_1280_portable_ms": median_ms(
+                lambda: np.asarray(seg.partition_segment(
+                    pay, jnp.zeros_like(pay), jnp.int32(0), jnp.int32(N),
+                    pred, LV, RV, Fw + 3)[0])[0, 0])}
+    del pay
+
+    Fw, Pw = 2000, 2048
+    rows = N if INTERPRET else 409_600
+    host = np.zeros((rows + seg.GUARD, Pw), np.float32)
+    host[:rows, :Fw] = rng.integers(0, Bw, (rows, Fw), dtype=np.uint8)
+    host[:rows, Fw] = rng.standard_normal(rows)
+    host[:rows, Fw + 1] = rng.random(rows) + 0.1
+    host[:rows, Fw + 2] = 1.0
+    pay = jnp.asarray(host)
+    vcol = Fw + 3
+
+    def run(block_w):
+        # the copies are donated: payload, scratch and nothing else
+        return jax.jit(
+            lambda p, a, s, c, col, thr:
+            pseg.partition_segment_acc_blocks(
+                p, a, s, c, make_pred(col, thr, Bw), LV, RV, vcol, Bw,
+                block_w=block_w, **IK),
+            donate_argnums=(0, 1))
+
+    kernel = run(None)
+    cases = [(0, rows, 1300, 30), (7, 256, 3, 20), (128, 3000, 511, 40),
+             (513, 100_000, 512, 31), (300_001, 65_536, 1999, 10)]
+    for s0, c0, col, thr in cases:
+        if s0 + c0 > rows:
+            continue
+        out, _, nl = kernel(pay + 0.0, jnp.zeros_like(pay), jnp.int32(s0),
+                            jnp.int32(c0), jnp.int32(col), jnp.int32(thr))
+        got = np.asarray(out)
+        del out
+        left = host[s0:s0 + c0, col] <= thr
+        want = host.copy()
+        want[s0:s0 + c0] = np.concatenate([host[s0:s0 + c0][left],
+                                           host[s0:s0 + c0][~left]])
+        want[s0:s0 + c0, vcol] = np.where(
+            np.arange(c0) < left.sum(), np.float32(LV), np.float32(RV))
+        assert int(nl) == int(left.sum()), (s0, c0, int(nl), int(left.sum()))
+        assert np.array_equal(got, want), (s0, c0, col)
+    info["epsilon_rows"] = rows
+    for block_w in (512, 256):
+        fn = run(block_w)
+        info["epsilon_block%d_ms" % block_w] = median_ms(
+            lambda: int(fn(pay + 0.0, jnp.zeros_like(pay), jnp.int32(0),
+                           jnp.int32(rows), jnp.int32(1300),
+                           jnp.int32(30))[2]), reps=3)
+    info["epsilon_copy_ms"] = median_ms(
+        lambda: float((pay + 0.0)[0, 0]), reps=3)
+    return info
+
+
 def precision():
     """The MXU's default f32 matmul is one bf16 pass: the partition must
     still permute payload values and radix-4096 index columns exactly, and
@@ -296,27 +371,6 @@ def colblock():
                     pay, jnp.int32(0), jnp.int32(N), **kw))[0, 0, 2])}
 
 
-def blocks():
-    """partition_segment_acc_blocks at an ultra-wide payload (the snapshot
-    kernel's traced, 128-aligned lane base)."""
-    Fw, Bw = 1200, 64
-    Pw = -(-(Fw + 8) // 128) * 128
-    pay = make_payload(N, Fw, Bw, width=Pw)
-    pred = make_pred(700, 30, Bw)
-    check_partition(
-        lambda p, a, s, c: pseg.partition_segment_acc_blocks(
-            p, a, s, c, pred, LV, RV, Fw + 3, Bw, **IK),
-        pay, pred, Fw + 3, segs((128, 3000), (7, 8000), (513, 256)))
-    return {"blocks_ms": median_ms(
-                lambda: np.asarray(pseg.partition_segment_acc_blocks(
-                    pay, jnp.zeros_like(pay), jnp.int32(0), jnp.int32(N),
-                    pred, LV, RV, Fw + 3, Bw, **IK)[0])[0, 0]),
-            "portable_ms": median_ms(
-                lambda: np.asarray(seg.partition_segment(
-                    pay, jnp.zeros_like(pay), jnp.int32(0), jnp.int32(N),
-                    pred, LV, RV, Fw + 3)[0])[0, 0])}
-
-
 def ring4():
     """4-deep read ring of the accumulator partition and of the merged
     kernel, exact against depth 2."""
@@ -342,8 +396,8 @@ def ring4():
     return info
 
 
-DEFAULT_PATH = (hist_expand, partition_rmw, partition_acc, precision)
-STAGED = (merged, colblock, ring4, blocks, frontier, quant)
+DEFAULT_PATH = (hist_expand, partition_rmw, partition_acc, blocks, precision)
+STAGED = (merged, colblock, ring4, frontier, quant)
 
 
 def main():

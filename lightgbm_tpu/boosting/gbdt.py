@@ -176,7 +176,6 @@ def _cached_pgrower(meta_dev: FeatureMeta, cfg, max_num_bin: int,
            # as the flag docstrings promise
            _pseg.PARTITION_HIST_VALIDATED,
            _pseg.HIST_COLBLOCK_VALIDATED,
-           _pseg.PARTITION_BLOCKS_VALIDATED,
            _pseg.PARTITION_RING4_VALIDATED,
            _pseg.FRONTIER_BATCH_VALIDATED,
            _pseg.HIST_QUANT_VALIDATED,

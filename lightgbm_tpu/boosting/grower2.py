@@ -68,11 +68,13 @@ def partition_engine(hist_impl: str, payload_width: int,
                      num_bins: int) -> str:
     """The partition implementation for a [N, payload_width] payload,
     chosen from the platform and the shape: the accumulator kernel where
-    its VMEM plan fits, then the read-modify-write kernel, then the
-    column-block kernel (ultra-wide payloads, staged), else the portable
-    lax partition.  Gated separately from the histogram: the partition is
-    exact at any bin count but spans the full payload width, so a wide
-    payload can overflow it while the histogram kernel still fits."""
+    its VMEM plan fits, then the read-modify-write kernel, then, for a
+    payload neither single-pass plan holds (1,920 lanes and up), the
+    accumulator kernel a 512-lane column block at a time, else the
+    portable lax partition.  Gated separately from the histogram: the
+    partition is exact at any bin count but spans the full payload width,
+    so a wide payload can overflow it while the histogram kernel still
+    fits."""
     if hist_impl == "lax" or jax.default_backend() != "tpu":
         return "lax"
     from ..ops import pallas_segment as pseg
@@ -81,7 +83,7 @@ def partition_engine(hist_impl: str, payload_width: int,
         return "pallas-acc"
     if pseg.partition_fits_vmem(payload_width, num_bins):
         return "pallas-rmw"
-    if (pseg.PARTITION_BLOCKS_VALIDATED and payload_width % 128 == 0
+    if (payload_width % 128 == 0
             and pseg.partition_blocks_fits_vmem(payload_width, num_bins)):
         return "pallas-blocks"
     return "lax"
@@ -712,7 +714,15 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
                             lambda: st["hist"][jnp.maximum(pslot, 0)],
                             rebuild_parent)
                     else:
-                        hist_parent = st["hist"][best_leaf]
+                        # read out before the pool is written: left for
+                        # the compiler to fuse, this slice is re-read
+                        # from the old pool inside the children's slot
+                        # writes, which then cannot happen in place, and
+                        # the whole pool is copied twice a split (0.39 GB
+                        # at 2,000 columns x 63 bins: 4.6 ms a split,
+                        # 1.18 s a tree; PERF.md §6, PR 26)
+                        hist_parent = lax.optimization_barrier(
+                            st["hist"][best_leaf])
 
                 with phase("partition"):
                     payload, aux, nl_raw = part_fn(

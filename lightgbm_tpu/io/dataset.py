@@ -111,7 +111,9 @@ class BinnedDataset:
             else ["Column_%d" % i for i in range(f)]
 
         if bin_mappers is None:
-            with tracing.span("dataset/find_bins", features=f):
+            from .native import finds_bins
+            with tracing.span("dataset/find_bins", features=f,
+                              path="native" if finds_bins(X) else "python"):
                 bin_mappers = cls._find_bin_mappers(X, config,
                                                     categorical_feature)
         ds.bin_mappers = bin_mappers
@@ -323,6 +325,10 @@ class BinnedDataset:
     @staticmethod
     def _find_bin_mappers(X: np.ndarray, config,
                           categorical_feature: Sequence[int]) -> List[BinMapper]:
+        """A `BinMapper` a column from a sample of the rows.  The numerical
+        columns go through the native library's threads (cpp/ingest.cc) and
+        only what it leaves (categorical columns, a sample it hands back)
+        through the Python routine; with no such library, every column."""
         n, f = X.shape
         sample_cnt = min(int(getattr(config, "bin_construct_sample_cnt", 200000)), n)
         rng = Random(int(getattr(config, "data_random_seed", 1)))
@@ -342,12 +348,20 @@ class BinnedDataset:
                        use_missing=use_missing, zero_as_missing=zero_as_missing)
             return m
 
+        from .native import find_bins
+        skip = np.zeros(f, dtype=np.uint8)
+        skip[[j for j in cat if 0 <= j < f]] = 1
+        mappers = find_bins(X, sample_idx, skip, max_bin, min_data_in_bin,
+                            use_missing, zero_as_missing)
+        if mappers is not None:
+            mappers = [m if m is not None else find_one(j)
+                       for j, m in enumerate(mappers)]
         # feature-sharded find-bin (reference ParallelFindBin /
         # is_parallel_find_bin, src/io/dataset_loader.cpp:842-924: each rank
         # bins a feature slice and the mappers are allgathered; here the
         # shards are host worker threads, and the "allgather" is the shared
         # result list — one process owns all device shards)
-        if bool(getattr(config, "is_parallel_find_bin", True)) and f > 8:
+        elif bool(getattr(config, "is_parallel_find_bin", True)) and f > 8:
             import concurrent.futures as cf
             import os as _os
             nt = int(getattr(config, "num_threads", 0) or 0)

@@ -46,6 +46,17 @@ def _load():
                 ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_int),
                 ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_int),
                 ctypes.POINTER(ctypes.c_ubyte), ctypes.c_longlong]
+            lib.LGBMT_FindBinsNumerical.restype = ctypes.c_int
+            lib.LGBMT_FindBinsNumerical.argtypes = (
+                [ctypes.c_void_p, ctypes.c_int, ctypes.c_longlong,
+                 ctypes.c_longlong, ctypes.POINTER(ctypes.c_longlong),
+                 ctypes.c_longlong, ctypes.c_int,
+                 ctypes.POINTER(ctypes.c_ubyte)]
+                + [ctypes.c_int] * 5
+                + [ctypes.POINTER(ctypes.c_double)]
+                + [ctypes.POINTER(ctypes.c_int)] * 3
+                + [ctypes.POINTER(ctypes.c_double)] * 3
+                + [ctypes.POINTER(ctypes.c_int)])
             _lib = lib
         except Exception as e:      # noqa: BLE001 — no compiler, no make, ...
             _lib_failed = True
@@ -142,3 +153,64 @@ def encode_bins(X: np.ndarray, mappers: List,
         if rc != 0:
             return False
     return True
+
+
+def finds_bins(X: np.ndarray) -> bool:
+    """Whether `find_bins` takes X: the library is there and X is a
+    float32/float64 matrix (it reads the sampled rows in place)."""
+    return (X.ndim == 2 and X.dtype in (np.float32, np.float64)
+            and _load() is not None)
+
+
+def find_bins(X: np.ndarray, sample_idx, skip: np.ndarray, max_bin: int,
+              min_data_in_bin: int, use_missing: bool,
+              zero_as_missing: bool) -> Optional[List]:
+    """`BinMapper.find_bin` over the numerical columns of X from the
+    sampled rows, in native threads (the Python routine holds the GIL
+    through its loop over a column's distinct values, so a thread pool
+    round it buys nothing).  Returns a list with a `BinMapper` per column,
+    or None in the place of a column left to the Python routine (`skip`
+    set, or a sample the native routine hands back); None altogether,
+    before any work, unless `finds_bins(X)`.  The upper bounds are
+    byte-equal to the Python routine's."""
+    from .binning import BIN_TYPE_NUMERICAL, BinMapper
+    if not finds_bins(X):
+        return None
+    lib = _load()
+    F = X.shape[1]
+    rows = np.ascontiguousarray(sample_idx, dtype=np.int64)
+    skip = np.ascontiguousarray(skip, dtype=np.uint8)
+    cap = int(max_bin) + 4
+    bounds = np.empty((F, cap), dtype=np.float64)
+    ints = np.zeros((4, F), dtype=np.int32)     # num_bin, missing, default, status
+    dbls = np.zeros((3, F), dtype=np.float64)   # min, max, sparse rate
+
+    def ptr(a, ctype):
+        return a.ctypes.data_as(ctypes.POINTER(ctype))
+
+    rc = lib.LGBMT_FindBinsNumerical(
+        ctypes.c_void_p(X.ctypes.data), int(X.dtype == np.float32),
+        X.strides[0], X.strides[1], ptr(rows, ctypes.c_longlong), len(rows),
+        F, ptr(skip, ctypes.c_ubyte), int(max_bin), int(min_data_in_bin),
+        int(use_missing), int(zero_as_missing), cap,
+        ptr(bounds, ctypes.c_double), ptr(ints[0], ctypes.c_int),
+        ptr(ints[1], ctypes.c_int), ptr(ints[2], ctypes.c_int),
+        ptr(dbls[0], ctypes.c_double), ptr(dbls[1], ctypes.c_double),
+        ptr(dbls[2], ctypes.c_double), ptr(ints[3], ctypes.c_int))
+    assert rc == 0, rc      # the routine has no failure of its own
+    mappers: List = []
+    for j in range(F):
+        if ints[3, j] != 0:
+            mappers.append(None)
+            continue
+        m = BinMapper()
+        m.num_bin = int(ints[0, j])
+        m.missing_type = int(ints[1, j])
+        m.default_bin = int(ints[2, j])
+        m.is_trivial = m.num_bin <= 1
+        m.bin_type = BIN_TYPE_NUMERICAL
+        m.bin_upper_bound = bounds[j, :m.num_bin].copy()
+        m.min_val, m.max_val = float(dbls[0, j]), float(dbls[1, j])
+        m.sparse_rate = float(dbls[2, j])
+        mappers.append(m)
+    return mappers

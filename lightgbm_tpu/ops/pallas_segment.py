@@ -118,14 +118,6 @@ PARTITION_ACC_ROLL_VALIDATED = True
 PARTITION_RING4_VALIDATED = False
 
 
-#: True once the COLUMN-BLOCK partition kernel (ultra-wide payloads:
-#: Epsilon-dense 2048 lanes, raw-Allstate 4352) is hardware-validated:
-#: one accumulator-partition pass per 512-lane window, each pass routing
-#: rows from a separately-DMA'd 128-lane split-column window (a traced
-#: but 128-aligned lane base — the one Mosaic pattern in this family not
-#: yet proven on a chip).  OFF until the smoke's BLOCKS section is green.
-PARTITION_BLOCKS_VALIDATED = False
-
 #: True once the BATCHED segment-histogram kernel (frontier-batched tree
 #: growth: one grid-(K,) dispatch builds K smaller-child histograms) is
 #: hardware-validated.  The kernel is a grid-indexed sibling of
@@ -155,7 +147,6 @@ STAGED_FLAGS = {
     "merged": "PARTITION_HIST_VALIDATED",
     "colblock": "HIST_COLBLOCK_VALIDATED",
     "ring4": "PARTITION_RING4_VALIDATED",
-    "blocks": "PARTITION_BLOCKS_VALIDATED",
     "frontier": "FRONTIER_BATCH_VALIDATED",
     "quant": "HIST_QUANT_VALIDATED",
 }
@@ -1279,9 +1270,9 @@ def partition_segment(payload, aux, start, count, pred, left_value,
 C2 = 2 * CHUNK
 
 
-def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm,
-                payload_out, aux_out, nl_out, *rest,
-                P, B, value_col, roll_place=False, hist_cfg=None, group=1):
+def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
+                P, B, value_col, roll_place=False, hist_cfg=None, group=1,
+                lane_lo=None):
     """Accumulator-window partition: same contract as `_partition_kernel`,
     restructured around the measured bottleneck (per-chunk latency, not
     bandwidth).  Lefts and rights accumulate in VMEM windows [2C, P] that
@@ -1323,12 +1314,29 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm,
     row traffic, retiring the separate per-split histogram kernel, the
     parent histogram, the subtraction trick and the device histogram pool
     (reference FeatureHistogram::Subtract / HistogramPool,
-    feature_histogram.hpp:505-826, folded into the partition walk)."""
-    if hist_cfg is None:
-        (ring, lacc, racc, stage, rbuf, sem_ring, sem_w, sem_r) = rest
-    else:
-        (hl_ref, hr_ref, ring, lacc, racc, stage, rbuf,
-         sem_ring, sem_w, sem_r) = rest
+    feature_histogram.hpp:505-826, folded into the partition walk).
+
+    With `lane_lo` set (one pass of the column-block partition,
+    `partition_segment_acc_blocks`), the kernel moves only the payload's
+    lanes [lane_lo, lane_lo + P) and routes rows from a frozen copy of the
+    split column's 128-lane window (`route_hbm`, a further input, read
+    chunk by chunk beside the block into a ring of its own): every pass
+    over a segment then computes the same routing, whichever block holds
+    the split column, and the passes together apply one row permutation
+    to the whole width with VMEM bounded by the block's.  scalars[2]
+    arrives localized to that window; `value_col` is local to the block
+    (-1, which matches no lane, in every block but the value column's).
+    Pass B needs no routing: membership there is positional."""
+    blocks = lane_lo is not None
+    if blocks:
+        assert hist_cfg is None, "no merged histogram in a column block"
+        route_hbm, *rest = rest
+    payload_out, aux_out, nl_out, *rest = rest
+    if hist_cfg is not None:
+        hl_ref, hr_ref, *rest = rest
+    ring, lacc, racc, stage, rbuf, sem_ring, sem_w, sem_r, *rest = rest
+    if blocks:
+        route_ring, sem_route = rest
     start = scalars[0]
     count = scalars[1]
     left_value = fvals[0]
@@ -1340,11 +1348,31 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm,
     iota_c2 = lax.broadcasted_iota(jnp.int32, (C2, 1), 0)[:, 0]
     iota_p = lax.broadcasted_iota(jnp.int32, (1, P), 1)
     iota_2i = lax.broadcasted_iota(jnp.int32, (C2, CHUNK), 0)
+    # the lanes the routing reads: the rows' own, or the split window's
+    iota_route = (lax.broadcasted_iota(jnp.int32, (1, 128), 1) if blocks
+                  else iota_p)
+
+    def window(ref, row0):
+        """CHUNK rows of an HBM buffer from (8-aligned) `row0`, over the
+        lanes this kernel moves."""
+        rows = pl.ds(row0, CHUNK)
+        return ref.at[rows, pl.ds(lane_lo, P)] if blocks else ref.at[rows, :]
 
     def ring_dma(src_ref, k, slot):
         return pltpu.make_async_copy(
-            src_ref.at[pl.ds(pl.multiple_of(base + k * CHUNK, 8), CHUNK), :],
+            window(src_ref, pl.multiple_of(base + k * CHUNK, 8)),
             ring.at[slot], sem_ring.at[slot])
+
+    def read_a(k, slot):
+        """Pass A's reads of chunk k: the rows and, in a column block,
+        the frozen split window beside them."""
+        dmas = [ring_dma(payload_out, k, slot)]
+        if blocks:
+            dmas.append(pltpu.make_async_copy(
+                route_hbm.at[pl.ds(pl.multiple_of(base + k * CHUNK, 8),
+                                   CHUNK), :],
+                route_ring.at[slot], sem_route.at[slot]))
+        return dmas
 
     def valid_mask(k):
         return ((iota_rows >= shift - k * CHUNK) &
@@ -1404,8 +1432,7 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm,
         sizes the semaphore wait; the in-flight copy's target differs)."""
         @pl.when(pend > 0)
         def _():
-            pltpu.make_async_copy(
-                stage_buf, dst_ref.at[pl.ds(0, CHUNK), :], sem).wait()
+            pltpu.make_async_copy(stage_buf, window(dst_ref, 0), sem).wait()
 
     def flush(acc, dst_ref, wbase, stage_buf, sem, pend):
         """Write the full first window of the accumulator and slide.
@@ -1417,7 +1444,7 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm,
         drain(dst_ref, stage_buf, sem, pend)
         stage_buf[:] = acc[0:CHUNK]
         pltpu.make_async_copy(
-            stage_buf, dst_ref.at[pl.ds(pl.multiple_of(wbase, 8), CHUNK), :],
+            stage_buf, window(dst_ref, pl.multiple_of(wbase, 8)),
             sem).start()
         acc[0:CHUNK] = acc[CHUNK:C2]
 
@@ -1514,16 +1541,18 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm,
         for i in range((R - 1) * G):
             @pl.when(i < nch)
             def _start(i=i):
-                ring_dma(payload_out, i, i).start()
+                for dma in read_a(i, i):
+                    dma.start()
 
     # ---- pass A: one read of the segment; lefts accumulate toward payload
     # windows, rights accumulate toward aux staging windows -------------
-    def permuted(k, data):
+    def permuted(k, data, route):
         """(nlk, nrk, block) of chunk k: what of its placement depends on
         no cursor and no accumulator, so that the chunks of a trip are
-        independent up to here."""
+        independent up to here.  `route` holds the split column: the rows
+        themselves, or a column block's split window."""
         valid = valid_mask(k)
-        gl = _go_left_rows(scalars, bitset_ref, data, B, iota_p) * valid
+        gl = _go_left_rows(scalars, bitset_ref, route, B, iota_route) * valid
         keep_r = valid - gl
         if hist_cfg is not None:
             hist_accumulate(data, gl, keep_r)
@@ -1578,21 +1607,27 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm,
         for k in [k0 + (R - 1) * G + i for i in range(G)]:
             @pl.when(k < nch)
             def _prefetch_next(k=k):
-                ring_dma(payload_out, k, lax.rem(k, R * G)).start()
+                for dma in read_a(k, lax.rem(k, R * G)):
+                    dma.start()
 
         # every wait and load before any chunk's arithmetic: a DMA wait
         # is a barrier the scheduler moves nothing across
-        ring_dma(payload_out, k0, slots[0]).wait()
+        for dma in read_a(k0, slots[0]):
+            dma.wait()
         for i in range(1, G):
             @pl.when(k0 + i < nch)
             def _wait(i=i):
-                ring_dma(payload_out, k0 + i, slots[i]).wait()
+                for dma in read_a(k0 + i, slots[i]):
+                    dma.wait()
 
         # a chunk past the segment's last was not read: its slot holds an
         # older chunk or nothing yet, and 0 x NaN would poison the matmuls
         datas = [ring[slots[0]]] + [
             jnp.where(k0 + i < nch, ring[slots[i]], 0.0)
             for i in range(1, G)]
+        # (routing is integer arithmetic under the validity mask: what an
+        # unread split window holds cannot reach a row)
+        routes = [route_ring[slot] for slot in slots] if blocks else datas
 
         @pl.when(t == 0)
         def _seed():
@@ -1601,9 +1636,10 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm,
             # full-window write
             lacc[0:CHUNK] = datas[0]
 
-        blocks = [permuted(k0 + i, datas[i]) for i in range(G)]
-        for block in blocks:
-            carry = place(*block, carry)
+        permuted_chunks = [permuted(k0 + i, datas[i], routes[i])
+                           for i in range(G)]
+        for chunk in permuted_chunks:
+            carry = place(*chunk, carry)
         return carry
 
     (num_left, num_right, lo_, ro_, lfl, rfl, pl_, pr_) = lax.fori_loop(
@@ -1680,13 +1716,13 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm,
     def _final():
         wbase = pl.multiple_of(base + lfl * CHUNK, 8)
         dma_r = pltpu.make_async_copy(
-            payload_out.at[pl.ds(wbase, CHUNK), :], rbuf, sem_r)
+            window(payload_out, wbase), rbuf, sem_r)
         dma_r.start()
         dma_r.wait()
         region = (iota_rows < lo_)[:, None]
         stage[:] = jnp.where(region, lacc[0:CHUNK], rbuf[:])
         dma_w = pltpu.make_async_copy(
-            stage, payload_out.at[pl.ds(wbase, CHUNK), :], sem_w)
+            stage, window(payload_out, wbase), sem_w)
         dma_w.start()
         dma_w.wait()
 
@@ -1855,19 +1891,21 @@ def _partition_segment_hist(payload, aux, start, count, pred, left_value,
 
 def partition_blocks_fits_vmem(payload_width: int, num_bins: int,
                                block_w: int = None) -> bool:
-    """VMEM plan of ONE column-block partition pass: the acc kernel's plan
-    at the block width plus the split-column ring (128 lanes per slot)."""
+    """VMEM plan of ONE column-block partition pass: the accumulator
+    kernel's plan at the block width (pass A one chunk a trip) plus the
+    split-window ring (128 lanes a slot)."""
     if block_w is None:
         block_w = COLBLOCK_WIDTH
     ring_depth = _ring_depth_default()
-    C = CHUNK
-    bw = min(block_w, payload_width)
-    est = ((ring_depth - 2) * 4 * bw * C
-           + 4 * bw * 18 * C
-           + ring_depth * 4 * 128 * C          # split-column ring
-           + 4 * 8 * C * C
-           + 4 * C * num_bins)
-    return est <= _VMEM_BUDGET
+    return (_acc_plan_bytes(min(block_w, payload_width), num_bins,
+                            ring_depth, 1)
+            + _route_ring_bytes(ring_depth, 1)) <= _VMEM_BUDGET
+
+
+def _route_ring_bytes(ring_depth: int, group: int) -> int:
+    """The split-window ring of a column-block pass: one [C, 128] slot
+    beside each slot of the read ring."""
+    return ring_depth * group * 4 * 128 * CHUNK
 
 
 def _snap_window_kernel(scalars, payload_hbm, snap_out, buf, sem):
@@ -1897,249 +1935,6 @@ def _snap_window_kernel(scalars, payload_hbm, snap_out, buf, sem):
         return 0
 
     lax.fori_loop(0, nch, body, 0)
-
-
-def _acc_blocks_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm,
-                       snap_hbm, payload_out, aux_out, nl_out,
-                       ring, ringc, lacc, racc, stage, rbuf,
-                       sem_ring, sem_w, sem_r, *,
-                       BW, B, col_lo, value_col_local, roll_place=False):
-    """One column-block pass of the accumulator partition for payloads too
-    wide for `_acc_kernel`'s full-width VMEM plan (Epsilon-dense 2048
-    lanes, raw-Allstate 4352).  A sibling copy, NOT a refactor of the
-    hardware-validated parent (the merged/colblock precedent): each chunk
-    DMAs TWO lane windows — this block's columns [col_lo, col_lo+BW) and
-    the 128-lane window containing the split column (its base arrives as
-    scalars[11], a traced but 128-aligned offset) — routes rows from the
-    split window, and moves ONLY the block's lanes through the place/
-    accumulate/flush machinery.  Every pass over the same segment computes
-    the identical routing, so the passes together apply one consistent
-    row permutation to the full payload width with per-pass VMEM bounded
-    by the block width, at the price of re-reading the split window once
-    per block (128 lanes per 512-lane block: ~25%).
-
-    scalars[2] (the split column) arrives LOCALIZED to the split window
-    by the wrapper; scalars[11] is the window base in payload lanes."""
-    start = scalars[0]
-    count = scalars[1]
-    left_value = fvals[0]
-    right_value = fvals[1]
-    shift = lax.rem(start, 8)
-    base = start - shift
-    nch = jnp.where(count > 0, (shift + count + CHUNK - 1) // CHUNK, 0)
-    iota_rows = _row_iota()
-    iota_c2 = lax.broadcasted_iota(jnp.int32, (C2, 1), 0)[:, 0]
-    iota_p = lax.broadcasted_iota(jnp.int32, (1, BW), 1)
-    iota_w128 = lax.broadcasted_iota(jnp.int32, (1, 128), 1)
-    iota_2i = lax.broadcasted_iota(jnp.int32, (C2, CHUNK), 0)
-    R = ring.shape[0]
-
-    def ring_dmas(src_ref, k, slot):
-        rows = pl.ds(pl.multiple_of(base + k * CHUNK, 8), CHUNK)
-        return (pltpu.make_async_copy(
-                    src_ref.at[rows, pl.ds(col_lo, BW)],
-                    ring.at[slot], sem_ring.at[slot, 0]),
-                pltpu.make_async_copy(
-                    snap_hbm.at[rows, :],
-                    ringc.at[slot], sem_ring.at[slot, 1]))
-
-    def valid_mask(k):
-        return ((iota_rows >= shift - k * CHUNK) &
-                (iota_rows < shift + count - k * CHUNK)).astype(jnp.int32)
-
-    def go_left(cdata, k):
-        return _go_left_rows(scalars, bitset_ref, cdata, B, iota_w128) \
-            * valid_mask(k)
-
-    def rank_of(keep_i):
-        ri = lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 0)
-        rj = lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 1)
-        tri = (rj < ri).astype(jnp.float32)
-        return jnp.dot(tri, keep_i.astype(jnp.float32)[:, None],
-                       preferred_element_type=jnp.float32)[:, 0] \
-            .astype(jnp.int32)
-
-    def blend(acc, placed, cnt, off, value):
-        # value_col_local is -1 for every block except the one carrying
-        # the value column; -1 matches no lane and the write is a no-op
-        placed = jnp.where(iota_p == value_col_local, value, placed)
-        region = ((iota_c2 >= off) & (iota_c2 < off + cnt))[:, None]
-        acc[:] = jnp.where(region, placed, acc[:])
-
-    def place_matmul(parts, dest, member):
-        mat = ((iota_2i == dest[None, :]) &
-               (member[None, :] > 0)).astype(jnp.float32)
-        hi, mid, lo = parts
-        return (jnp.dot(mat, hi, preferred_element_type=jnp.float32) +
-                jnp.dot(mat, mid, preferred_element_type=jnp.float32) +
-                jnp.dot(mat, lo, preferred_element_type=jnp.float32))
-
-    def place_compact_roll(parts, rank, member, off):
-        matc = ((lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 0) ==
-                 rank[None, :]) &
-                (member[None, :] > 0)).astype(jnp.float32)
-        hi, mid, lo = parts
-        compacted = (jnp.dot(matc, hi, preferred_element_type=jnp.float32) +
-                     jnp.dot(matc, mid, preferred_element_type=jnp.float32) +
-                     jnp.dot(matc, lo, preferred_element_type=jnp.float32))
-        return pltpu.roll(jnp.concatenate([compacted, compacted], axis=0),
-                          off, axis=0)
-
-    def drain(dst_ref, stage_buf, sem, pend):
-        @pl.when(pend > 0)
-        def _():
-            pltpu.make_async_copy(
-                stage_buf,
-                dst_ref.at[pl.ds(0, CHUNK), pl.ds(col_lo, BW)], sem).wait()
-
-    def flush(acc, dst_ref, wbase, stage_buf, sem, pend):
-        drain(dst_ref, stage_buf, sem, pend)
-        stage_buf[:] = acc[0:CHUNK]
-        pltpu.make_async_copy(
-            stage_buf,
-            dst_ref.at[pl.ds(pl.multiple_of(wbase, 8), CHUNK),
-                       pl.ds(col_lo, BW)], sem).start()
-        acc[0:CHUNK] = acc[CHUNK:C2]
-
-    @pl.when(nch > 0)
-    def _prefetch_first():
-        for i in range(R - 1):
-            @pl.when(i < nch)
-            def _start(i=i):
-                for d in ring_dmas(payload_out, i, i):
-                    d.start()
-
-    def body_a(k, carry):
-        nl, nr, lo_, ro_, lfl, rfl, pl_, pr_ = carry
-        slot = lax.rem(k, R)
-
-        @pl.when(k + R - 1 < nch)
-        def _prefetch_next():
-            for d in ring_dmas(payload_out, k + R - 1,
-                               lax.rem(k + R - 1, R)):
-                d.start()
-
-        for d in ring_dmas(payload_out, k, slot):
-            d.wait()
-        data = ring[slot]
-        cdata = ringc[slot]
-
-        @pl.when(k == 0)
-        def _seed():
-            lacc[0:CHUNK] = data
-
-        gl = go_left(cdata, k)
-        keep_r = valid_mask(k) - gl
-        nlk = jnp.sum(gl)
-        nrk = jnp.sum(keep_r)
-        rank_l = rank_of(gl)
-        rank_r = rank_of(keep_r)
-
-        parts = _bf16_parts(data)
-        if roll_place:
-            placed_l = place_compact_roll(parts, rank_l, gl, lo_)
-            placed_r = place_compact_roll(parts, rank_r, keep_r, ro_)
-        else:
-            placed_l = place_matmul(parts, lo_ + rank_l, gl)
-            placed_r = place_matmul(parts, ro_ + rank_r, keep_r)
-        blend(lacc, placed_l, nlk, lo_, left_value)
-        fl = ((lo_ + nlk) >= CHUNK).astype(jnp.int32)
-
-        @pl.when(fl > 0)
-        def _flush_l():
-            flush(lacc, payload_out, base + lfl * CHUNK, stage, sem_w, pl_)
-
-        blend(racc, placed_r, nrk, ro_, right_value)
-        fr = ((ro_ + nrk) >= CHUNK).astype(jnp.int32)
-
-        @pl.when(fr > 0)
-        def _flush_r():
-            flush(racc, aux_out, base + rfl * CHUNK, rbuf, sem_r, pr_)
-
-        return (nl + nlk, nr + nrk, lo_ + nlk - fl * CHUNK,
-                ro_ + nrk - fr * CHUNK, lfl + fl, rfl + fr,
-                jnp.maximum(pl_, fl), jnp.maximum(pr_, fr))
-
-    (num_left, num_right, lo_, ro_, lfl, rfl, pl_, pr_) = lax.fori_loop(
-        0, nch, body_a,
-        (jnp.int32(0), jnp.int32(0), shift, shift,
-         jnp.int32(0), jnp.int32(0), jnp.int32(0), jnp.int32(0)))
-    nl_out[0] = num_left
-
-    @pl.when(ro_ > 0)
-    def _flush_r_tail():
-        flush(racc, aux_out, base + rfl * CHUNK, rbuf, sem_r, pr_)
-
-    drain(aux_out, rbuf, sem_r,
-          jnp.maximum(pr_, (ro_ > 0).astype(jnp.int32)))
-
-    # pass B: append the staged rights behind the lefts.  The staged rows
-    # live in the SAME block lane window of aux; the split-column ring is
-    # not needed (membership is positional), so only the block window
-    # streams.
-    nchb = jnp.where(num_right > 0,
-                     (shift + num_right + CHUNK - 1) // CHUNK, 0)
-
-    def ring_dma_b(k, slot):
-        rows = pl.ds(pl.multiple_of(base + k * CHUNK, 8), CHUNK)
-        return pltpu.make_async_copy(
-            aux_out.at[rows, pl.ds(col_lo, BW)],
-            ring.at[slot], sem_ring.at[slot, 0])
-
-    @pl.when(nchb > 0)
-    def _prefetch_b():
-        for i in range(R - 1):
-            @pl.when(i < nchb)
-            def _start(i=i):
-                ring_dma_b(i, i).start()
-
-    def body_b(k, carry):
-        lo_, lfl, pl_ = carry
-        slot = lax.rem(k, R)
-
-        @pl.when(k + R - 1 < nchb)
-        def _prefetch_next():
-            ring_dma_b(k + R - 1, lax.rem(k + R - 1, R)).start()
-
-        ring_dma_b(k, slot).wait()
-        j0 = jnp.maximum(shift - k * CHUNK, 0)
-        j1 = jnp.minimum(shift + num_right - k * CHUNK, CHUNK)
-        cnt = jnp.maximum(j1 - j0, 0)
-        member = ((iota_rows >= j0) & (iota_rows < j1)).astype(jnp.int32)
-        data = jnp.where(member[:, None] > 0, ring[slot], 0.0)
-        if roll_place:
-            placed = pltpu.roll(jnp.concatenate([data, data], axis=0),
-                                lo_ - j0 + C2, axis=0)
-        else:
-            parts = _bf16_parts(data)
-            placed = place_matmul(parts, iota_rows - j0 + lo_, member)
-        blend(lacc, placed, cnt, lo_, right_value)
-        fl = ((lo_ + cnt) >= CHUNK).astype(jnp.int32)
-
-        @pl.when(fl > 0)
-        def _flush_l():
-            flush(lacc, payload_out, base + lfl * CHUNK, stage, sem_w, pl_)
-
-        return (lo_ + cnt - fl * CHUNK, lfl + fl, jnp.maximum(pl_, fl))
-
-    lo_, lfl, pl_ = lax.fori_loop(0, nchb, body_b, (lo_, lfl, pl_))
-    drain(payload_out, stage, sem_w, pl_)
-
-    @pl.when((count > 0) & (lo_ > 0))
-    def _final():
-        wbase = pl.multiple_of(base + lfl * CHUNK, 8)
-        dma_r = pltpu.make_async_copy(
-            payload_out.at[pl.ds(wbase, CHUNK), pl.ds(col_lo, BW)],
-            rbuf, sem_r)
-        dma_r.start()
-        dma_r.wait()
-        region = (iota_rows < lo_)[:, None]
-        stage[:] = jnp.where(region, lacc[0:CHUNK], rbuf[:])
-        dma_w = pltpu.make_async_copy(
-            stage, payload_out.at[pl.ds(wbase, CHUNK), pl.ds(col_lo, BW)],
-            sem_w)
-        dma_w.start()
-        dma_w.wait()
 
 
 def partition_segment_acc_blocks(payload, aux, start, count, pred,
@@ -2200,14 +1995,18 @@ def _partition_segment_acc_blocks(payload, aux, start, count, pred,
         compiler_params=_SIDE_EFFECTS,
         interpret=interpret,
     )(scalars, payload)
+    # one pass a lane window, each an instance of the accumulator kernel
+    # that moves its own lanes and routes from the snapshot
     nl = None
-    c = 0
-    while c < P:
+    for c in range(0, P, block_w):
         bw = min(block_w, P - c)
         vloc = value_col - c if c <= value_col < c + bw else -1
-        kern = functools.partial(_acc_blocks_kernel, BW=bw, B=B, col_lo=c,
-                                 value_col_local=vloc,
-                                 roll_place=roll_place)
+        group = _pass_a_group(bw, B, ring_depth,
+                              _route_ring_bytes(ring_depth, _PASS_A_GROUP))
+        slots = ring_depth * group
+        kern = functools.partial(_acc_kernel, P=bw, B=B, value_col=vloc,
+                                 roll_place=roll_place, group=group,
+                                 lane_lo=c)
         payload, aux, nl_k = pl.pallas_call(
             kern,
             grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -2221,15 +2020,17 @@ def _partition_segment_acc_blocks(payload, aux, start, count, pred,
                            pl.BlockSpec(memory_space=pl.ANY),
                            pl.BlockSpec(memory_space=pltpu.SMEM)),
                 scratch_shapes=[
-                    pltpu.VMEM((ring_depth, CHUNK, bw), jnp.float32),
-                    pltpu.VMEM((ring_depth, CHUNK, 128), jnp.float32),
-                    pltpu.VMEM((C2, bw), jnp.float32),
-                    pltpu.VMEM((C2, bw), jnp.float32),
-                    pltpu.VMEM((CHUNK, bw), jnp.float32),
-                    pltpu.VMEM((CHUNK, bw), jnp.float32),
-                    pltpu.SemaphoreType.DMA((ring_depth, 2)),
+                    pltpu.VMEM((slots, CHUNK, bw), jnp.float32),  # read ring
+                    pltpu.VMEM((C2, bw), jnp.float32),    # left accumulator
+                    pltpu.VMEM((C2, bw), jnp.float32),    # right accumulator
+                    pltpu.VMEM((CHUNK, bw), jnp.float32),  # flush stage
+                    pltpu.VMEM((CHUNK, bw), jnp.float32),  # final blend read
+                    pltpu.SemaphoreType.DMA((slots,)),
                     pltpu.SemaphoreType.DMA(()),
                     pltpu.SemaphoreType.DMA(()),
+                    pltpu.VMEM((slots, CHUNK, 128),
+                               jnp.float32),              # split-window ring
+                    pltpu.SemaphoreType.DMA((slots,)),
                 ],
             ),
             out_shape=(jax.ShapeDtypeStruct(payload.shape, payload.dtype),
@@ -2240,5 +2041,4 @@ def _partition_segment_acc_blocks(payload, aux, start, count, pred,
             interpret=interpret,
         )(scalars, fvals, bitset, payload, aux, snap)
         nl = nl_k if nl is None else nl
-        c += bw
     return payload, aux, nl[0]

@@ -181,3 +181,108 @@ def test_python_binning_fallback_is_visible_not_silent(monkeypatch):
     assert ds.binning["seconds"] >= 0.0
     assert len(warnings) == 1 and "make: command not found" in warnings[0]
     np.testing.assert_array_equal(ds.bins, reference.bins)
+
+
+def _find_bin_columns():
+    """name -> a column of 5,000 sampled values, one kind of column a
+    case."""
+    rng = np.random.default_rng(26)
+    n = 5000
+    cols = {
+        "continuous": rng.standard_normal(n),
+        "continuous_f32": rng.standard_normal(n).astype(np.float32),
+        "few_distinct": rng.integers(-20, 20, n).astype(np.float64),
+        "mostly_zeros": np.where(rng.random(n) < 0.93, 0.0,
+                                 rng.standard_normal(n)),
+        "with_nans": np.where(rng.random(n) < 0.1, np.nan,
+                              rng.standard_normal(n)),
+        "all_nan": np.full(n, np.nan),
+        "constant": np.full(n, 3.25),
+        "two_values": (rng.random(n) < 0.3).astype(np.float64),
+        "heavy_value": np.where(rng.random(n) < 0.4, 1.5,
+                                rng.standard_normal(n)),
+        "positive_only": rng.exponential(2.0, n),
+        "negative_only": -rng.exponential(2.0, n),
+        "tiny_about_zero": rng.standard_normal(n) * 1e-36,
+        "with_infs": np.where(rng.random(n) < 0.01, np.inf,
+                              rng.standard_normal(n)),
+        # numpy's sort decides which zero stands for the run of zeros:
+        # the native routine hands such a column back
+        "negative_zero": np.where(rng.random(n) < 0.5, -0.0,
+                                  rng.integers(0, 3, n).astype(np.float64)),
+    }
+    return cols
+
+
+@needs_native
+@pytest.mark.parametrize("kwargs", [
+    dict(max_bin=63), dict(max_bin=255), dict(max_bin=15, min_data_in_bin=50),
+    dict(max_bin=63, zero_as_missing=True),
+    dict(max_bin=63, use_missing=False), dict(max_bin=2),
+], ids=lambda kw: "-".join("%s=%s" % kv for kv in kw.items()))
+@pytest.mark.parametrize("name", sorted(_find_bin_columns()))
+def test_native_find_bin_matches_python(name, kwargs):
+    """The native find-bin is the Python routine statement for statement:
+    byte-equal upper bounds, and every other field of the mapper equal,
+    on every kind of column; a column it hands back goes to the Python
+    routine (None in its place)."""
+    col = _find_bin_columns()[name]
+    kw = dict(dict(min_data_in_bin=3, use_missing=True,
+                   zero_as_missing=False), **kwargs)
+    # a strided view of a wider matrix, rows sampled out of order
+    X = np.zeros((len(col) + 100, 3), dtype=col.dtype)
+    X[:len(col), 1] = col
+    idx = np.random.default_rng(0).permutation(len(col))
+    want = BinMapper()
+    want.find_bin(col.astype(np.float64), len(col), kw["max_bin"],
+                  min_data_in_bin=kw["min_data_in_bin"],
+                  use_missing=kw["use_missing"],
+                  zero_as_missing=kw["zero_as_missing"])
+    got = native.find_bins(X, idx, np.array([1, 0, 1], np.uint8), **kw)
+    assert got[0] is None and got[2] is None        # skipped columns
+    if name == "negative_zero":
+        assert got[1] is None
+        return
+    got, want = got[1].to_arrays(), want.to_arrays()
+    assert got["bin_upper_bound"].tobytes() == want["bin_upper_bound"].tobytes()
+    assert got.keys() == want.keys()
+    for key in want:
+        np.testing.assert_array_equal(np.asarray(got[key]),
+                                      np.asarray(want[key]), err_msg=key)
+
+
+@needs_native
+def test_find_bins_native_and_python_give_one_dataset(monkeypatch):
+    """`from_matrix` through the native find-bin (numerical columns in
+    threads, the categorical one and a negative-zero one by the Python
+    routine) and, with no library, through the Python routine alone: the
+    same mappers and the same bins, and the one span says which path
+    ran."""
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.runtime import tracing
+    rng = np.random.default_rng(3)
+    X = rng.standard_normal((3000, 12))
+    X[:, 4] = rng.integers(0, 9, 3000)
+    X[::7, 5] = np.nan
+    X[:, 6] = np.where(rng.random(3000) < 0.5, -0.0, 1.0)
+    config = Config({"max_bin": 63})
+
+    def construct():
+        tracing.reset()
+        ds = BinnedDataset.from_matrix(X, config, categorical_feature=[4])
+        return ds, [e["args"].get("path")
+                    for e in tracing.export_chrome()["traceEvents"]
+                    if e["ph"] == "X" and e["name"] == "dataset/find_bins"]
+
+    native_ds, paths = construct()
+    assert paths == ["native"]
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_lib_failed", True)
+    python_ds, paths = construct()
+    assert paths == ["python"]
+    np.testing.assert_array_equal(native_ds.bins, python_ds.bins)
+    for a, b in zip(native_ds.bin_mappers, python_ds.bin_mappers):
+        assert a.to_arrays().keys() == b.to_arrays().keys()
+        for key, value in b.to_arrays().items():
+            np.testing.assert_array_equal(np.asarray(a.to_arrays()[key]),
+                                          np.asarray(value), err_msg=key)

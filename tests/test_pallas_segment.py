@@ -552,11 +552,26 @@ def test_colblock_matches_hist_kernel(expand):
 # column-block partition (ultra-wide payloads)
 # ---------------------------------------------------------------------------
 
-def test_blocks_flag_staged_off():
-    # pinned OFF until the smoke's BLOCKS section validates the dynamic
-    # 128-aligned split-window DMA on a chip; flip in the SAME commit as
-    # flip_validated.py blocks
-    assert pseg.PARTITION_BLOCKS_VALIDATED is False
+@pytest.mark.parametrize("hist_impl,backend,width,bins,engine", [
+    ("auto", "tpu", 128, 256, "pallas-acc"),      # higgs-train
+    ("auto", "tpu", 256, 256, "pallas-acc"),
+    ("auto", "tpu", 1024, 64, "pallas-rmw"),      # a single-pass plan fits
+    ("auto", "tpu", 2048, 64, "pallas-blocks"),   # epsilon-train
+    ("auto", "tpu", 4352, 256, "pallas-blocks"),  # raw Allstate
+    ("auto", "tpu", 2000, 64, "lax"),             # not lane-padded
+    ("lax", "tpu", 2048, 64, "lax"),
+    ("auto", "cpu", 2048, 64, "lax"),
+])
+def test_partition_engine_by_shape(monkeypatch, hist_impl, backend, width,
+                                   bins, engine):
+    """The partition engine follows from the platform and the payload's
+    shape alone: the column-block kernel serves what neither single-pass
+    plan holds, and nothing narrower resolves differently for it."""
+    if seg.CHUNK != 256:
+        pytest.skip("VMEM gate expectations assume the default CHUNK")
+    from lightgbm_tpu.boosting import grower2
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert grower2.partition_engine(hist_impl, width, bins) == engine
 
 
 def test_partition_blocks_vmem_gate():
@@ -567,6 +582,7 @@ def test_partition_blocks_vmem_gate():
     assert pseg.partition_blocks_fits_vmem(4352, 256)   # raw Allstate
     assert not pseg.partition_fits_vmem(2048, 64)
     assert not pseg.partition_acc_fits_vmem(4352, 256)
+    assert "blocks" not in pseg.STAGED_FLAGS
 
 
 @pytest.mark.parametrize("start,count,predkw", [
@@ -579,23 +595,20 @@ def test_partition_blocks_vmem_gate():
     # EFB bundle decode through the split-window scalars
     (64, 500, dict(feature=2, threshold=3, offset=5, identity=False,
                    num_bin=9, default_bin=0)),
+    # the split column in the last window but one, and in the ragged tail
+    (7, 777, dict(feature=1100, threshold=B // 2)),
+    (513, 300, dict(feature=1199, threshold=5)),
 ])
 @pytest.mark.parametrize("roll", [False, True])
 def test_partition_blocks_matches(start, count, predkw, roll):
-    """Ultra-wide payload (5 lane windows incl. a ragged 128-lane tail):
-    the per-block passes must reproduce the portable partition exactly —
-    one consistent permutation across every window, value column written
-    only by its own block."""
+    """Ultra-wide payload (three lane windows, the last a ragged 256 lanes
+    that takes pass A two chunks a trip): the per-block passes reproduce
+    the portable partition bit for bit -- one consistent permutation
+    across every window, value column written only by its own block."""
     Fw = 1200
     Pw = -(-(Fw + 8) // 128) * 128   # 1280: 2x512 + 1x256 windows
-    rng = np.random.default_rng(start + count)
-    n_pad = 1024
-    pay = np.zeros((n_pad + seg.GUARD, Pw), np.float32)
-    pay[:n_pad, :Fw] = rng.integers(0, B, size=(n_pad, Fw))
-    pay[:n_pad, Fw] = rng.standard_normal(n_pad)
-    pay[:n_pad, Fw + 1] = rng.random(n_pad)
-    pay[:n_pad, Fw + 2] = 1.0
-    pay = jnp.asarray(pay)
+    pay, _ = _wide_payload(1024, Fw, B, seed=start + count)
+    assert pay.shape[1] == Pw
     aux = jnp.zeros_like(pay)
     vcol = Fw + 3
     pred = _pred(**predkw)
@@ -606,13 +619,53 @@ def test_partition_blocks_matches(start, count, predkw, roll):
         pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv,
         vcol, B, interpret=True, roll_place=roll)
     assert int(got_nl) == int(ref_nl)
-    np.testing.assert_allclose(np.asarray(got_pay), np.asarray(ref_pay),
-                               rtol=1e-6, atol=0)
+    np.testing.assert_array_equal(np.asarray(got_pay), np.asarray(ref_pay))
+
+
+@pytest.mark.parametrize("start,count,feature", [
+    (0, 1024, 0), (7, 777, 511), (100, 254, 512), (513, 37, 1999),
+    (9, 1015, 1300), (256, 256, 700)])
+def test_partition_blocks_epsilon_shape(start, count, feature):
+    """The epsilon cell's own width: 2,000 bin columns at 64 bins in a
+    2,048-lane payload, four 512-lane blocks, the value columns in the
+    last; bit-equal to the portable partition wherever the split column
+    lies."""
+    Fw, Bw, Pw = 2000, 64, 2048
+    pay, _ = _wide_payload(1024, Fw, Bw, seed=start + count)
+    assert pay.shape[1] == Pw
+    aux = jnp.zeros_like(pay)
+    pred = SplitPredicate(
+        col=jnp.int32(feature), threshold=jnp.int32(Bw // 3),
+        default_left=jnp.bool_(False), is_cat=jnp.bool_(False),
+        bitset=jnp.zeros(Bw, bool), missing_type=jnp.int32(0),
+        num_bin=jnp.int32(Bw), default_bin=jnp.int32(0),
+        offset=jnp.int32(0), identity=jnp.bool_(True))
+    lv, rv = jnp.float32(-0.25), jnp.float32(0.75)
+    ref_pay, _, ref_nl = seg.partition_segment(
+        pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv, Fw + 3)
+    got_pay, _, got_nl = pseg.partition_segment_acc_blocks(
+        pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv,
+        Fw + 3, Bw, interpret=True)
+    assert int(got_nl) == int(ref_nl)
+    np.testing.assert_array_equal(np.asarray(got_pay), np.asarray(ref_pay))
+
+
+def test_partition_blocks_pass_a_is_one_permutation():
+    """Every column block runs `_acc_kernel`'s pass A: 4 MXU contractions
+    a chunk in its loop (the 256-lane tail takes two chunks a trip), none
+    in pass B; the snapshot kernel's loop holds none.  Traced only."""
+    pay, _ = _wide_payload(1024, 1200, B, seed=3)
+    closed = jax.make_jaxpr(
+        lambda p, a: pseg._partition_segment_acc_blocks(
+            p, a, jnp.int32(7), jnp.int32(777), _pred(), jnp.float32(1.0),
+            jnp.float32(-1.0), 1203, B, False, True, 2, 512))(
+        pay, jnp.zeros_like(pay))
+    assert _while_dots(closed.jaxpr) == [0, 4, 0, 4, 0, 8, 0]
 
 
 def test_partition_blocks_narrow_pin():
-    """At a width the validated acc kernel also handles, blocks (one
-    window) must agree with it bit-for-bit — the sibling-pin discipline."""
+    """At a width the single-pass kernel also handles, blocks (one
+    window) agree with it bit for bit."""
     pay = _payload(1024, seed=11)
     pay128 = jnp.pad(pay, ((0, 0), (0, 128 - pay.shape[1])))
     aux = jnp.zeros_like(pay128)

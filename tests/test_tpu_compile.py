@@ -1,6 +1,7 @@
-"""The accumulator partition kernels compile for a TPU v5e that is
-described, not attached: Mosaic's verdict on the kernel as it stands, at
-the Higgs cell's real shape, with no chip.  `test_pallas_segment.py` runs
+"""The segment kernels compile for a TPU v5e that is described, not
+attached: Mosaic's verdict on each kernel as it stands, at the real
+shapes of the Higgs cell (10.5M x 128 lanes) and of the Epsilon cell
+(409,600 x 2,048 lanes, 2,000 columns x 64 bins), with no chip.  `test_pallas_segment.py` runs
 the same kernels in interpret mode, which says nothing about what Mosaic
 accepts (an unaligned slice, a layout it cannot apply, too much VMEM).
 Nothing runs here, so nothing is said about results or times.
@@ -41,18 +42,23 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _partition_args(sharding):
+#: the Epsilon cell: 400,000 rows padded to whole chunks
+WIDE_ROWS, WIDE_LANES, WIDE_FEATURES, WIDE_BINS = 409_600, 2048, 2000, 64
+
+
+def _partition_args(sharding, rows=ROWS, lanes=LANES, features=FEATURES,
+                    bins=BINS):
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
 
     i32, f32, flag = (shape((), jnp.int32), shape((), jnp.float32),
                       shape((), jnp.bool_))
-    payload = shape((ROWS + seg.GUARD, LANES), jnp.float32)
+    payload = shape((rows + seg.GUARD, lanes), jnp.float32)
     pred = seg.SplitPredicate(
         col=i32, threshold=i32, default_left=flag, is_cat=flag,
         missing_type=i32, num_bin=i32, default_bin=i32, offset=i32,
-        identity=flag, bitset=shape((BINS,), jnp.int32))
-    return payload, payload, i32, i32, pred, f32, f32, FEATURES + 3, BINS
+        identity=flag, bitset=shape((bins,), jnp.int32))
+    return payload, payload, i32, i32, pred, f32, f32, features + 3, bins
 
 
 @pytest.mark.parametrize("ring_depth", [2, 4])
@@ -66,4 +72,29 @@ def test_partition_hist_merged_compiles_for_v5e(one_chip):
     lowered = pseg._partition_segment_hist.lower(
         *_partition_args(one_chip), FEATURES, FEATURES, FEATURES + 1,
         FEATURES + 2, False, True, "repeat", 2)
+    assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+def test_partition_blocks_compiles_for_v5e_at_epsilon(one_chip):
+    """Four 512-lane passes of the accumulator kernel and the split-window
+    snapshot, as `partition_engine` picks them at 2,048 lanes."""
+    assert pseg.partition_blocks_fits_vmem(WIDE_LANES, WIDE_BINS)
+    lowered = pseg._partition_segment_acc_blocks.lower(
+        *_partition_args(one_chip, WIDE_ROWS, WIDE_LANES, WIDE_FEATURES,
+                         WIDE_BINS),
+        False, True, 2, pseg.COLBLOCK_WIDTH)
+    assert lowered.compile().as_text().count("tpu_custom_call") >= 5
+
+
+def test_histogram_compiles_for_v5e_at_epsilon(one_chip):
+    """The single-pass histogram at 2,000 columns x 64 bins (63 tiles of
+    32 columns) over full 2,048-lane rows."""
+    assert pseg.fits_vmem(WIDE_FEATURES, WIDE_BINS, WIDE_LANES)
+    payload, _, i32 = _partition_args(
+        one_chip, WIDE_ROWS, WIDE_LANES, WIDE_FEATURES, WIDE_BINS)[:3]
+    lowered = pseg._segment_histogram.lower(
+        payload, i32, i32, num_features=WIDE_FEATURES, num_bins=WIDE_BINS,
+        grad_col=WIDE_FEATURES, hess_col=WIDE_FEATURES + 1,
+        cnt_col=WIDE_FEATURES + 2, interpret=False,
+        expand_impl=pseg._default_expand_impl(WIDE_FEATURES, WIDE_BINS))
     assert "tpu_custom_call" in lowered.compile().as_text()
