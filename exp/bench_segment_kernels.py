@@ -46,13 +46,12 @@ def timeit_fetch(fn, reps=7):
     return sorted(ts)[len(ts) // 2]
 
 
-def hist_call(count, expand_impl=None):
+def hist_call(count):
     def run(i):
         h = pseg.segment_histogram(
             payload, jnp.int32(0), jnp.int32(count - (i % 2)),
             num_features=F, num_bins=B, grad_col=GRAD, hess_col=HESS,
-            cnt_col=CNT, **({"expand_impl": expand_impl} if expand_impl
-                            else {}))
+            cnt_col=CNT)
         return float(np.asarray(h)[0, 0, 2])
     return run
 
@@ -90,11 +89,6 @@ for label, kw in (("acc", dict(roll_place=False)),
     t_p = timeit_self(part_call(pseg.partition_segment_acc, 1 << 20, **kw))
     print("part[%s] 1M rows: %8.2f ms (%6.2f ns/row)"
           % (label, t_p * 1e3, t_p / (1 << 20) * 1e9), flush=True)
-
-for impl in ("matmul", "repeat"):
-    t_h = timeit_fetch(hist_call(1 << 20, expand_impl=impl))
-    print("hist[%s] 1M rows: %8.2f ms (%6.2f ns/row)"
-          % (impl, t_h * 1e3, t_h / (1 << 20) * 1e9), flush=True)
 
 # dispatch floor: tiny count isolates the fixed per-dispatch cost
 t0 = timeit_fetch(hist_call(8))

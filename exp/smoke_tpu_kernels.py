@@ -2,9 +2,8 @@
 the chip and compared with the portable lax engine (or with an already
 checked sibling kernel).
 
-One verdict per kernel.  The kernels on the default path (histogram with
-both one-hot expansions, RMW / accumulator / roll / column-block
-partition, precision) come first; the five staged ones behind
+One verdict per kernel.  The kernels on the default path (histogram, RMW /
+accumulator / roll / column-block partition, precision) come first; the five staged ones behind
 ``pallas_segment.STAGED_FLAGS`` follow with a fetch-forced race each,
 printed as information.  A section that raises records its error and the
 run carries on to the next kernel, so one call to the chip answers for
@@ -109,30 +108,61 @@ LV, RV = jnp.float32(1.5), jnp.float32(-2.5)
 
 # ---- default path ---------------------------------------------------------
 
-def hist_expand():
-    """segment_histogram, both one-hot expansions, against the lax engine
-    at the Higgs / MS-LTR / Expo widths, plus feature-tiled wide shapes.
-    The repeat expansion assumes pltpu.repeat concatenates tiles (column
-    b*fw + f); a layout change shows here as a large error."""
+def histogram():
+    """segment_histogram (the bin id factored into a high and a low part)
+    at the three train cells' shapes and payload widths, segments of one
+    row to the whole payload: the counts equal the portable engine's to
+    the last digit, the sums a float64 histogram's within f32 accumulation
+    error, and gradients that differ only below bf16's 8 mantissa bits
+    keep those bits (a bf16-rounded sum is off by ~n * 2^-10 a bin).
+    Then the MS-LTR / Expo / Bosch widths against the portable engine."""
     info = {}
-    shapes = ((28, 256), (137, 256), (700, 256), (968, 64), (2000, 64))
-    for Fw, Bw in shapes[:2] if INTERPRET else shapes:
+    for Fw, Bw, Pw in ((28, 256, 128), (67, 256, 128), (2000, 64, 2048)):
+        if INTERPRET and Fw > 100:
+            continue
+        assert pseg.fits_vmem(Fw, Bw, Pw), (Fw, Bw, Pw)
+        gvals = (1.0 + rng.integers(1, 2 ** 11, N) * 2.0 ** -20).astype(
+            np.float32)
+        pay = make_payload(N, Fw, Bw, width=Pw, grads=gvals)
+        host = np.asarray(pay)
+        kw = hist_kw(Fw, Bw)
+        worst = 0.0
+        for s, c in segs((0, N), (128, N - 1000), (7, 1), (1023, 1),
+                         (513, 256), (9, 1015), (5, 4099), (0, 0)):
+            h = np.asarray(pseg.segment_histogram(
+                pay, jnp.int32(s), jnp.int32(c), **kw, **IK))
+            ref = np.asarray(seg.segment_histogram(
+                pay, jnp.int32(s), jnp.int32(c), **kw))
+            assert np.array_equal(h[..., 2], ref[..., 2]), (Fw, Bw, s, c)
+            h64 = np.zeros((Fw, Bw, 2), np.float64)
+            rows = host[s:s + c]
+            for f in range(Fw):
+                np.add.at(h64[f], rows[:, f].astype(np.int64),
+                          rows[:, Fw:Fw + 2].astype(np.float64))
+            # a bin holds ~c / B rows of ~1.0: an f32 sum carries a few
+            # 2^-24 of that, a bf16 gradient would lose 2^-10 of it
+            err = float(np.abs(h[..., :2] - h64).max())
+            assert err <= max(c / Bw, 1) * 2.0 ** -16, (Fw, Bw, s, c, err)
+            worst = max(worst, err)
+        info["%dx%d_err_vs_f64" % (Fw, Bw)] = worst
+        info["%dx%d_ms" % (Fw, Bw)] = median_ms(
+            lambda: np.asarray(pseg.segment_histogram(
+                pay, jnp.int32(0), jnp.int32(N), **kw, **IK))[0, 0, 2])
+    shapes = ((137, 256), (700, 256), (968, 64))
+    for Fw, Bw in shapes[:1] if INTERPRET else shapes:
         assert pseg.fits_vmem(Fw, Bw), (Fw, Bw)
         pay = make_payload(N, Fw, Bw)
         kw = hist_kw(Fw, Bw)
         ref = seg.segment_histogram(pay, jnp.int32(128), jnp.int32(N - 1000),
                                     **kw)
-        for impl in ("matmul", "repeat"):
-            h = pseg.segment_histogram(pay, jnp.int32(128),
-                                       jnp.int32(N - 1000),
-                                       expand_impl=impl, **kw, **IK)
-            err = float(jnp.abs(h - ref).max())
-            assert err < 1e-2, (Fw, Bw, impl, err)
-            info["%dx%d_%s_ms" % (Fw, Bw, impl)] = median_ms(
-                lambda: np.asarray(pseg.segment_histogram(
-                    pay, jnp.int32(0), jnp.int32(N), expand_impl=impl,
-                    **kw, **IK))[0, 0, 2])
-    info["default_at_higgs"] = pseg._default_expand_impl(28, 256)
+        h = pseg.segment_histogram(pay, jnp.int32(128), jnp.int32(N - 1000),
+                                   **kw, **IK)
+        err = float(jnp.abs(h - ref).max())
+        assert err < 1e-2, (Fw, Bw, err)
+        info["%dx%d_ms" % (Fw, Bw)] = median_ms(
+            lambda: np.asarray(pseg.segment_histogram(
+                pay, jnp.int32(0), jnp.int32(N), **kw, **IK))[0, 0, 2])
+    info["factor_at_256_64"] = [pseg._hist_factor(256), pseg._hist_factor(64)]
     return info
 
 
@@ -315,7 +345,8 @@ def frontier():
     hb = pseg.segment_histogram_batched(PAY, starts, counts, **KW, **IK)
     for k in range(6):
         h1 = pseg.segment_histogram(PAY, starts[k], counts[k], **KW, **IK)
-        assert float(jnp.abs(hb[k] - h1).max()) == 0.0, k
+        assert bool(jnp.array_equal(hb[k][..., 2], h1[..., 2])), k
+        assert float(jnp.abs(hb[k] - h1).max()) < 1e-3, k
 
     def seq_mode():
         for k in range(6):
@@ -396,7 +427,7 @@ def ring4():
     return info
 
 
-DEFAULT_PATH = (hist_expand, partition_rmw, partition_acc, blocks, precision)
+DEFAULT_PATH = (histogram, partition_rmw, partition_acc, blocks, precision)
 STAGED = (merged, colblock, ring4, frontier, quant)
 
 

@@ -7,9 +7,11 @@ kernels keep every one-hot in VMEM:
 
 - `segment_histogram`: walks a leaf's contiguous chunks with manual
   HBM->VMEM DMA at dynamic offsets (the trip count is a runtime scalar, so
-  one compilation serves every segment), builds the [C, F*B] one-hot in
-  VMEM and contracts it with the (grad, hess, count) columns on the MXU.
-  Mirrors the role of the reference OpenCL kernels
+  one compilation serves every segment).  The bin id is factored,
+  bin = hi * L + lo (`_hist_factor`): a feature's histogram over a chunk
+  is ONE MXU product of its low one-hot [L, C] with its (grad, hess,
+  count) parts masked by the high part [8H, C], both built in VMEM with
+  rows in lanes.  Mirrors the role of the reference OpenCL kernels
   (src/treelearner/ocl/histogram256.cl:73-121 and the 16/64 variants) —
   the B<=256/64/16 specialization falls out of the static num_bins arg.
 - `partition_segment`: the three compact passes of
@@ -38,7 +40,9 @@ from .split import MISSING_NAN, MISSING_ZERO
 #: writes must never be DCE'd or reordered
 _SIDE_EFFECTS = pltpu.CompilerParams(has_side_effects=True)
 
-# per-tile one-hot budget: the expand and one-hot intermediates over one
+# per-tile one-hot budget of the staged sibling kernels (batched, quantized,
+# column-block, merged; `segment_histogram` itself builds no B-wide
+# one-hot since PR 27): the expand and one-hot intermediates over one
 # FEATURE TILE are each [CHUNK, ~TILE_FB] f32 (2 MB).  Features are tiled
 # so any F streams through the same VMEM window — the role of the
 # workgroup grid in the reference OpenCL kernels
@@ -61,14 +65,60 @@ def _tiling(num_features: int, num_bins: int):
     return ft, n_tiles, _pad128(ft * num_bins)
 
 
+#: feature groups a loop trip of the histogram kernel takes.  A group's
+#: body is one dependent chain (row loads, compares, product, masked sum);
+#: the chains of the groups of a trip lie in one basic block for the
+#: scheduler to interleave and to spread over the four MXUs, and a tile of
+#: fewer than two trips is not a loop at all.  On the chip (PERF.md §6, PR
+#: 27; ns a 256-row chunk at 8 / 16 / 32 groups a trip): 1,804 / 1,509 /
+#: 1,222 at 67 x 256, 24,193 / 19,801 / 17,345 at 2,000 x 64, where a
+#: tile has 32 groups.  The compiler's time follows the unrolled code,
+#: and the column tiles are a loop, so it is 1 s at 2,000 x 64.
+_HIST_TRIP_GROUPS = 32
+
+
+def _hist_factor(num_bins: int):
+    """(L, H, G) of the factored bin id, bin = hi * L + lo, from the number
+    of bins alone.  A feature's histogram over a chunk is one product of
+    its seven value parts under the high part's mask [8H, C] with its low
+    one-hot [L, C]; G = 128 / L features share a product so that their low
+    one-hots fill the MXU's 128 columns.  A feature then costs L + 8H
+    one-hot rows a chunk and, on the MXU, 2L cycles of weight loads and
+    16B/L of streamed rows: both least at L = sqrt(8B).  L is the power of
+    two at or above it (64 at 256 bins, 32 at 64), the side on which the
+    weights cost more than the rows: the other side reads 34%, 62% and 59%
+    slower at the three cells' shapes (PERF.md §6, PR 27); and at least a
+    sublane tile.  H is rounded up to a power of two; the top high block
+    is then ragged (bins past num_bins - 1 never occur and are sliced
+    off)."""
+    L = max(8, 1 << ((8 * num_bins - 1).bit_length() + 1) // 2)
+    H = 1
+    while H * L < num_bins:
+        H *= 2
+    return L, H, 128 // L
+
+
+def _hist_groups(num_features: int, num_bins: int) -> int:
+    """Feature groups the histogram kernel accumulates: 128 / G a whole
+    128-column tile, and the last tile's."""
+    G = _hist_factor(num_bins)[2]
+    full = (num_features - 1) // 128
+    return full * (128 // G) + -(-(num_features - 128 * full) // G)
+
+
 def fits_vmem(num_features: int, num_bins: int,
               payload_width: int = None) -> bool:
-    """True when the tiled histogram kernel's VMEM plan fits the budget:
-    the expand + one-hot tile intermediates, the [8 * n_tiles, W]
-    accumulator and the double-buffered payload chunk.  Bins are capped at
-    256: the kernel's exactness argument needs every bin value and
-    within-window offset to be bf16-representable (the reference OpenCL
-    family has the same 256-bin kernel ceiling, ocl/histogram256.cl).
+    """True when the histogram kernel's VMEM plan fits the budget: the
+    double-buffered payload chunk, the [groups * 8H, 128] accumulator
+    (8 * F * H * L * 4 bytes), the transposed high / low parts of one
+    128-column tile, the hoisted index planes and tiled values, and the
+    selected rows, operands and product of four groups in flight (Mosaic
+    allots the scratches and the accumulator and next to nothing else:
+    at 28 x 256 over 8,192 lanes it asks for 16.48 MB, 16.47 of them the
+    chunk, the accumulator and the parts).  Bins are capped at 256: the
+    kernel's exactness argument needs every bin value to be
+    bf16-representable (the reference OpenCL family has the same 256-bin
+    kernel ceiling, ocl/histogram256.cl).
 
     payload_width, when known, sizes the chunk buffers with the REAL lane
     count the kernel DMAs (the num_features+32 estimate assumed the bin
@@ -77,13 +127,15 @@ def fits_vmem(num_features: int, num_bins: int,
     estimate under-budgeted VMEM by ~n x)."""
     if num_bins > 256:
         return False
-    ft, n_tiles, w = _tiling(num_features, num_bins)
+    _, H, G = _hist_factor(num_bins)
+    rows = 8 * H * G                        # masked value rows of a group
     chunk_w = (_pad128(payload_width) if payload_width is not None
                else _pad128(num_features + 32))
-    est = (2 * 4 * CHUNK * w                   # expand + one-hot tiles
-           + 4 * 8 * n_tiles * w               # accumulator
-           + 2 * 4 * CHUNK * chunk_w           # chunk x2 (DMA)
-           + 4 * ft * w)                       # window expander
+    est = (2 * 4 * CHUNK * chunk_w                          # chunk x2 (DMA)
+           + 4 * 128 * 8 * H * _hist_groups(num_features, num_bins)
+           + 2 * 4 * 128 * CHUNK                            # high / low parts
+           + 4 * CHUNK * (2 * rows + 128)                   # planes, values
+           + 4 * 4 * (2 * CHUNK * (rows + 128) + rows * 128))
     return est <= _VMEM_BUDGET
 
 
@@ -93,10 +145,14 @@ def fits_vmem(num_features: int, num_bins: int,
 #: Mosaic legality).
 PARTITION_ACC_VALIDATED = True
 
-#: True once the repeat-based one-hot expansion is hardware-validated; it
-#: halves the histogram kernel's MXU work (the expand matmul becomes a
-#: lane-repeat relayout) by building the one-hot in a bin-major tiled
-#: layout that the host epilogue transposes back.
+#: True once the repeat-based one-hot expansion is hardware-validated: the
+#: expand matmul of a B-wide one-hot becomes a lane-repeat relayout, in a
+#: bin-major tiled layout that the host epilogue transposes back.  Since
+#: PR 27 it decides the expand of the staged sibling kernels alone
+#: (batched, column-block, merged): `segment_histogram` builds its one-hots
+#: with rows in lanes and has no expand.  Either expand was most of the old
+#: body (PERF.md §6, PR 27: the repeat 58% of a chunk at 28 x 256, the
+#: matmul 74-75% at 67 x 256 and 2,000 x 64).
 HIST_REPEAT_VALIDATED = True
 
 #: True once the roll-based placement inside the accumulator kernel is
@@ -121,8 +177,8 @@ PARTITION_RING4_VALIDATED = False
 #: True once the BATCHED segment-histogram kernel (frontier-batched tree
 #: growth: one grid-(K,) dispatch builds K smaller-child histograms) is
 #: hardware-validated.  The kernel is a grid-indexed sibling of
-#: _hist_kernel — per-segment instruction sequence identical, scalars
-#: read at 2*program_id — but the multi-step grid over a scalar-prefetch
+#: _hist_kernel as it was before PR 27 (a B-wide one-hot a feature,
+#: scalars read at 2*program_id) — the multi-step grid over a scalar-prefetch
 #: spec is the one pattern in this family not yet proven on a chip.
 #: While OFF, a TPU pallas config keeps the SEQUENTIAL grower even when
 #: Config.tpu_frontier_batch > 1 (the CPU/lax path batches regardless —
@@ -332,13 +388,52 @@ def _go_left_rows(scalars, bitset_ref, data, B, iota_p):
 # histogram
 # ---------------------------------------------------------------------------
 
-def _hist_kernel(scalars, payload_hbm, out_ref, chunk, sem, *,
-                 F, B, Ft, W, grad_col, hess_col, cnt_col,
-                 expand_impl="matmul"):
+def _in_trips(n, per_trip, body):
+    """body(first, count) over the static range [0, n): whole trips of
+    `per_trip` as a loop where there are two or more, what is left (or
+    all of it) unrolled with a static `first`."""
+    trips = n // per_trip
+    done = 0
+    if trips > 1:
+        def trip(u, carry):
+            body(u * per_trip, per_trip)
+            return carry
+
+        lax.fori_loop(0, trips, trip, 0)
+        done = trips * per_trip
+    if n > done:
+        body(done, n - done)
+
+
+def _hist_kernel(scalars, payload_hbm, out_ref, chunk, sem, hi_rows, lo_rows,
+                 *, F, B, grad_col, hess_col, cnt_col):
     """chunk is a DOUBLE buffer [2, CHUNK, P]: while slot k%2 feeds the
-    one-hot matmuls, the DMA for chunk k+1 streams into the other slot —
-    the HBM read of the payload hides behind the MXU work (the round-3
-    kernel serialized them)."""
+    products, the DMA for chunk k+1 streams into the other slot.
+
+    The bin id is factored, bin = hi * L + lo (`_hist_factor`).  For one
+    feature f over a chunk's rows r
+
+        hist_f[(hi, part), lo] = sum_r vals[part, r] * [hi_f(r) == hi] * [lo_f(r) == lo]
+
+    is one product `V_f [8H, C] x LoT_f [L, C]^T`, and G = 128 / L features
+    share one: their masked values stacked in the rows, their low one-hots
+    in the 128 columns, `[G 8H, C] x [128, C]^T`.  Of its G x G blocks the
+    G on the diagonal are the features' histograms; a lane mask sums them
+    into the group's [8H, 128] rows of the accumulator (row hi * 8 + part,
+    lane g * L + lo), so the accumulator is 8 * F * H * L * 4 bytes and no
+    larger.  Both operands have ROWS IN LANES: one transposition of a
+    128-column block of the chunk gives every bin column of the block as a
+    row, a feature's row broadcast over sublanes and compared with a
+    sublane index is its one-hot, and nothing is expanded across lanes
+    (the expand of a B-wide one-hot was 58-75% of the body before; PERF.md
+    §6, PR 27).  The groups of a block are a loop, `_HIST_TRIP_GROUPS` a
+    trip, and so are the blocks.  When the segment is done the high blocks
+    of each group are moved beside each other, so that a part's row reads
+    (feature, bin) along the lanes as `_unfactor_hist` wants it."""
+    P = chunk.shape[2]
+    L, H, G = _hist_factor(B)
+    R, tile_groups = 8 * H, 128 // G
+    lo_bits = L.bit_length() - 1
     start = scalars[0]
     count = scalars[1]
     # HBM row slices must start at a multiple of the f32 sublane tiling (8);
@@ -347,7 +442,6 @@ def _hist_kernel(scalars, payload_hbm, out_ref, chunk, sem, *,
     shift = lax.rem(start, 8)
     base = start - shift
     nch = jnp.where(count > 0, (shift + count + CHUNK - 1) // CHUNK, 0)
-    n_tiles = -(-F // Ft)
     out_ref[:] = jnp.zeros(out_ref.shape, out_ref.dtype)
     iota_rows = _row_iota()
 
@@ -361,34 +455,23 @@ def _hist_kernel(scalars, payload_hbm, out_ref, chunk, sem, *,
     def _prefetch_first():
         dma_for(0, 0).start()
 
-    # one-hot machinery, built once before the chunk loop.  E[f, j] = 1 iff
-    # column j lies in tile-local feature f's B-wide window; expanding a
-    # [C, Ft] tile of bin values through E on the MXU broadcasts each
-    # feature's bin across its window, and a single [C, W] compare against
-    # the within-window offset finishes the one-hot — Mosaic supports
-    # neither 3D reshape/broadcast nor cheap per-feature lane writes, and
-    # this keeps VPU work at O(F*B) per row total across tiles.  The
-    # window geometry is identical for every tile, so E/jmod are built once
-    # at full tile width; a ragged last tile just row-slices E (its junk
-    # window columns read expand == 0 and land past Ft*B or in windows of
-    # features >= F — both discarded by the host-side slice).
-    if expand_impl == "repeat":
-        # one jdiv compare vector per distinct tile width (full + ragged),
-        # built once before the chunk loop
-        jdivs = {}
-        for t in range(n_tiles):
-            fw = min(Ft, F - t * Ft)
-            if fw not in jdivs:
-                jdivs[fw] = (lax.broadcasted_iota(jnp.int32, (1, fw * B), 1)
-                             // fw).astype(jnp.float32)
-    if expand_impl == "matmul":
-        iota_fr = lax.broadcasted_iota(jnp.int32, (Ft, W), 0)
-        iota_fc = lax.broadcasted_iota(jnp.int32, (Ft, W), 1)
-        d = iota_fc - iota_fr * B
-        in_win = (d >= 0) & (d < B)
-        E = in_win.astype(jnp.float32)                           # [Ft, W]
-        jmod = jnp.sum(jnp.where(in_win, d, 0), axis=0)          # [W] i32
-        jmod_f = jmod.astype(jnp.float32)
+    # what a group's selected rows are compared with, built once: the high
+    # part a row of V stands for, the low part a row of LoT stands for, the
+    # feature of the group a lane of the product belongs to
+    hi_of_row = (lax.broadcasted_iota(jnp.int32, (G * R, CHUNK), 0) // 8) % H
+    lo_of_row = lax.broadcasted_iota(jnp.int32, (128, CHUNK), 0) % L
+    feature_of_lane = lax.broadcasted_iota(jnp.int32, (R, 128), 1) // L
+    # the value columns lie in one or two 128-lane blocks of the payload;
+    # only those enter the extraction
+    value_cols = (grad_col, hess_col, cnt_col)
+    v_lo = min(value_cols) // 128 * 128
+    v_hi = min(P, max(value_cols) // 128 * 128 + 128)
+    iota_r8 = lax.broadcasted_iota(jnp.int32, (8, v_hi - v_lo), 0)
+    iota_pc = lax.broadcasted_iota(jnp.int32, (8, v_hi - v_lo), 1) + v_lo
+    sel = (((iota_r8 < 3) & (iota_pc == grad_col)) |
+           ((iota_r8 >= 3) & (iota_r8 < 6) & (iota_pc == hess_col)) |
+           ((iota_r8 == 6) & (iota_pc == cnt_col))).astype(jnp.float32)
+    part_of_row = lax.broadcasted_iota(jnp.int32, (8, CHUNK), 0)
 
     def body(k, _):
         slot = lax.rem(k, 2)
@@ -398,119 +481,159 @@ def _hist_kernel(scalars, payload_hbm, out_ref, chunk, sem, *,
             dma_for(k + 1, lax.rem(k + 1, 2)).start()
 
         dma_for(k, slot).wait()
-        data = chunk[slot]
         ok = ((iota_rows >= shift - k * CHUNK) &
               (iota_rows < shift + count - k * CHUNK)).astype(jnp.float32)
         # The MXU runs f32 matmuls as ONE bf16 pass by default, which would
         # round the gradients to 8 mantissa bits.  Instead of paying the
-        # 3-pass HIGHEST contract, the M dimension's unused rows carry an
-        # EXACT bf16 decomposition: rows (g_hi, g_mid, g_lo, h_hi, h_mid,
-        # h_lo, cnt) — each part is bf16-representable, so the one-pass
-        # contract is exact and the f32 histogram is recovered as the sum
-        # of three part-histograms.  (Extraction of the g/h/cnt columns is
-        # a tiny matmul — HIGHEST there costs nothing.)
-        P = data.shape[1]
-        iota_r8 = lax.broadcasted_iota(jnp.int32, (8, P), 0)
-        iota_pc = lax.broadcasted_iota(jnp.int32, (8, P), 1)
-        sel = (((iota_r8 < 3) & (iota_pc == grad_col)) |
-               ((iota_r8 >= 3) & (iota_r8 < 6) & (iota_pc == hess_col)) |
-               ((iota_r8 == 6) & (iota_pc == cnt_col))).astype(jnp.float32)
+        # 3-pass HIGHEST contract, the value rows carry an EXACT bf16
+        # decomposition: rows (g_hi, g_mid, g_lo, h_hi, h_mid, h_lo, cnt) —
+        # each part is bf16-representable and so is a part times a 0/1
+        # mask, so the one-pass contract is exact and the f32 histogram is
+        # recovered as the sum of three part-histograms.  (Extraction of
+        # the g/h/cnt columns is a tiny matmul — HIGHEST there costs
+        # nothing.)
         raw = lax.dot_general(
-            sel, data, dimension_numbers=(((1,), (1,)), ((), ())),
+            sel, chunk[slot, :, v_lo:v_hi],
+            dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=lax.Precision.HIGHEST)                     # [8, C]
         # astype round trips are safe HERE (unlike histogram.py, which
         # must use lax.reduce_precision): Mosaic lowers the trunc/ext pair
         # directly and never runs XLA's excess-precision simplifier that
         # would delete it — validated on hardware by exp/smoke_tpu_kernels
-        # (idx-multiset + grad-bit-survival + float64 checks).
+        # (count equality + grad-bit-survival + float64 checks).
         hi = raw.astype(jnp.bfloat16).astype(jnp.float32)
         r1 = raw - hi
         mid = r1.astype(jnp.bfloat16).astype(jnp.float32)
         lo = r1 - mid
-        rr = lax.broadcasted_iota(jnp.int32, raw.shape, 0)
-        vals = jnp.where((rr == 0) | (rr == 3), hi,
-                         jnp.where((rr == 1) | (rr == 4), mid,
-                                   jnp.where((rr == 2) | (rr == 5), lo,
-                                             raw)))
+        vals = jnp.where((part_of_row == 0) | (part_of_row == 3), hi,
+                         jnp.where((part_of_row == 1) | (part_of_row == 4),
+                                   mid,
+                                   jnp.where((part_of_row == 2) |
+                                             (part_of_row == 5), lo, raw)))
         vals = vals * ok[None, :]
-        # feature tiles walk the SAME resident chunk — the payload is read
-        # from HBM once per histogram no matter how wide it is
-        for t in range(n_tiles):
-            f0 = t * Ft
-            fw = min(Ft, F - f0)
-            binsf = data[:, f0:f0 + fw]                          # [C, fw] f32
-            if expand_impl == "repeat":
-                # bin-major tiled one-hot: repeat concatenates B copies of
-                # the tile, so column b*fw + f compares feature f's bin
-                # against b — no expand matmul, the relayout is VPU-cheap,
-                # and the host epilogue untransposes the [B, fw] blocks
-                rep = pltpu.repeat(binsf, B, axis=1)             # [C, fw*B]
-                onehot = (rep == jdivs[fw]).astype(jnp.float32)
-                out_ref[8 * t:8 * t + 8, :fw * B] += lax.dot_general(
-                    vals, onehot,
-                    dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)          # [8, fw*B]
-            else:
-                expand = lax.dot_general(
-                    binsf, E[:fw, :],
-                    dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)          # [C, W]
-                onehot = (expand == jmod_f[None, :]).astype(jnp.float32)
-                out_ref[8 * t:8 * t + 8, :] += lax.dot_general(
-                    vals, onehot,
-                    dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)          # [8, W]
+        vals_tiled = jnp.concatenate([vals] * (G * H), axis=0)   # [G R, C]
+
+        def tile(t, width, features):
+            """The bin columns [128 t, 128 t + features) of the resident
+            chunk: transposed once, then a product a group of G."""
+            lane0 = t * 128 if isinstance(t, int) \
+                else pl.multiple_of(t * 128, 128)
+            bins = chunk[slot, :, pl.ds(lane0, width)].T.astype(jnp.int32)
+            hi_rows[0:width] = bins >> lo_bits                   # [w, C]
+            lo_rows[0:width] = bins & (L - 1)
+
+            def group(j):
+                hi_sel = jnp.concatenate(
+                    [jnp.broadcast_to(hi_rows[pl.ds(j * G + g, 1), :],
+                                      (R, CHUNK)) for g in range(G)],
+                    axis=0)                                      # [G R, C]
+                lo_sel = jnp.concatenate(
+                    [jnp.broadcast_to(lo_rows[pl.ds(j * G + g, 1), :],
+                                      (L, CHUNK)) for g in range(G)],
+                    axis=0)                                      # [128, C]
+                masked = jnp.where(hi_sel == hi_of_row, vals_tiled, 0.0)
+                onehot = jnp.where(lo_sel == lo_of_row, 1.0, 0.0)
+                prod = lax.dot_general(
+                    masked, onehot,
+                    dimension_numbers=(((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)          # [G R, 128]
+                own = jnp.where(feature_of_lane == 0, prod[0:R], 0.0)
+                for g in range(1, G):
+                    own = own + jnp.where(feature_of_lane == g,
+                                          prod[g * R:(g + 1) * R], 0.0)
+                row0 = (t * tile_groups + j) * R
+                if not (isinstance(t, int) and isinstance(j, int)):
+                    row0 = pl.multiple_of(row0, R)
+                out_ref[pl.ds(row0, R), :] += own
+
+            # the last group may reach past the tile's features: it reads
+            # other columns' rows, and `_unfactor_hist` drops what they give
+            _in_trips(-(-features // G), _HIST_TRIP_GROUPS,
+                      lambda first, n: [group(first + i) for i in range(n)])
+
+        # column tiles walk the SAME resident chunk — the payload is read
+        # from HBM once per histogram no matter how wide it is.  Whole
+        # tiles of 128 bin columns are a loop; the last, with fewer, is
+        # its own code.  (A payload is lane-padded on the chip; only the
+        # interpreter sees a last tile narrower than 128 lanes.)
+        whole = F // 128
+        _in_trips(whole, 1, lambda t, _: tile(t, 128, 128))
+        if F % 128:
+            tile(whole, min(128, P - 128 * whole), F % 128)
         return 0
 
     lax.fori_loop(0, nch, body, 0)
 
+    if H == 1:
+        return
+    block_of_lane = lax.broadcasted_iota(jnp.int32, (8, 128), 1) // L
 
-#: widest F*B the repeat expansion is the default for.  The round-4
-#: hardware race (exp/smoke_tpu_kernels.py, fetch-forced medians at 8192
-#: rows): repeat wins at 28x256 (79.8 vs 91.8 ms), washes at 137x256
-#: (133.3 vs 131.2), loses at 700x256 (304.0 vs 252.9) — the bin-major
-#: epilogue's per-tile untranspose grows with the tile count.
+    def regrouped(src):
+        """A group's rows are (hi, part) and its lanes (g, lo); its
+        H * 128 numbers a part are wanted in the order (g, hi, lo).  Lane
+        block i of row block d is then the block g of the rows of hi with
+        g * H + hi = d * G + i: a lane rotation and a select each."""
+        dest = []
+        for d in range(H):
+            for i in range(G):
+                g, h = divmod(d * G + i, H)
+                piece = src[8 * h:8 * h + 8]
+                if i != g:
+                    piece = pltpu.roll(piece, ((i - g) * L) % 128, axis=1)
+                placed = piece if i == 0 \
+                    else jnp.where(block_of_lane == i, piece, placed)
+            dest.append(placed)
+        return jnp.concatenate(dest, axis=0)
+
+    def regroup(first, n):
+        """Groups first .. first + n - 1: every load, then every move, then
+        every store, so that the groups' chains interleave."""
+        rows = [first * R + i * R for i in range(n)]
+        if not isinstance(first, int):
+            rows = [pl.multiple_of(r, R) for r in rows]
+        moved = [regrouped(out_ref[pl.ds(r, R), :]) for r in rows]
+        for r, block in zip(rows, moved):
+            out_ref[pl.ds(r, R), :] = block
+
+    _in_trips(out_ref.shape[0] // R, 8, regroup)
+
+
+#: widest F*B the repeat expansion is the staged sibling kernels' default
+#: for.  The round-4 hardware race (exp/smoke_tpu_kernels.py, fetch-forced
+#: medians at 8192 rows): repeat wins at 28x256 (79.8 vs 91.8 ms), washes
+#: at 137x256 (133.3 vs 131.2), loses at 700x256 (304.0 vs 252.9) — the
+#: bin-major epilogue's per-tile untranspose grows with the tile count.
 REPEAT_MAX_FB = 16384
 
 
 def _default_expand_impl(num_features: int, num_bins: int) -> str:
     """Shared flag+shape default for every kernel with a one-hot expand
-    stage; resolved OUTSIDE the jit caches so a flag flip takes effect on
-    warm traces."""
+    stage (the staged siblings; `segment_histogram` has none); resolved
+    OUTSIDE the jit caches so a flag flip takes effect on warm traces."""
     return ("repeat" if HIST_REPEAT_VALIDATED
             and num_features * num_bins <= REPEAT_MAX_FB else "matmul")
 
 
 def segment_histogram(payload, start, count, *, num_features, num_bins,
-                      grad_col, hess_col, cnt_col, interpret=False,
-                      expand_impl=None):
+                      grad_col, hess_col, cnt_col, interpret=False):
     """hist[F, B, 3] over payload rows [start, start+count) — TPU kernel."""
-    if expand_impl is None:
-        expand_impl = _default_expand_impl(num_features, num_bins)
-    if expand_impl not in ("matmul", "repeat"):
-        raise ValueError("expand_impl must be matmul|repeat, got %r"
-                         % (expand_impl,))
     return _segment_histogram(payload, start, count,
                               num_features=num_features, num_bins=num_bins,
                               grad_col=grad_col, hess_col=hess_col,
-                              cnt_col=cnt_col, interpret=interpret,
-                              expand_impl=expand_impl)
+                              cnt_col=cnt_col, interpret=interpret)
 
 
 @functools.partial(xla_obs.jit, site="pallas.segment_histogram", static_argnames=("num_features", "num_bins",
                                              "grad_col", "hess_col",
-                                             "cnt_col", "interpret",
-                                             "expand_impl"))
+                                             "cnt_col", "interpret"))
 def _segment_histogram(payload, start, count, *, num_features, num_bins,
-                       grad_col, hess_col, cnt_col, interpret,
-                       expand_impl):
+                       grad_col, hess_col, cnt_col, interpret):
     F, B, P = num_features, num_bins, payload.shape[1]
-    Ft, n_tiles, W = _tiling(F, B)
+    H = _hist_factor(B)[1]
     scalars = jnp.stack([start, count]).astype(jnp.int32)
-    kern = functools.partial(_hist_kernel, F=F, B=B, Ft=Ft, W=W,
-                             grad_col=grad_col, hess_col=hess_col,
-                             cnt_col=cnt_col, expand_impl=expand_impl)
+    kern = functools.partial(_hist_kernel, F=F, B=B, grad_col=grad_col,
+                             hess_col=hess_col, cnt_col=cnt_col)
     out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -521,12 +644,29 @@ def _segment_histogram(payload, start, count, *, num_features, num_bins,
             scratch_shapes=[
                 pltpu.VMEM((2, CHUNK, P), jnp.float32),
                 pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((128, CHUNK), jnp.int32),
+                pltpu.VMEM((128, CHUNK), jnp.int32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((8 * n_tiles, W), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((_hist_groups(F, B) * 8 * H, 128),
+                                       jnp.float32),
         interpret=interpret,
     )(scalars, payload)
-    return _untile_hist(out, F, B, Ft, n_tiles, W, expand_impl)
+    return _unfactor_hist(out, F, B)
+
+
+def _unfactor_hist(out, F, B):
+    """[groups * 8H, 128] kernel accumulator -> [F, B, 3].  A group's 8H
+    rows are H blocks of the 8 parts, its H * 128 numbers a part the
+    histograms of its G features one after the other, bin by bin; the parts
+    are the exact bf16 decomposition (g_hi, g_mid, g_lo, h_hi, h_mid,
+    h_lo, cnt) — recombine, then slice off the padding."""
+    L, H, _ = _hist_factor(B)
+    r = out.reshape(-1, H, 8, 128)
+    ghc = jnp.stack([r[:, :, 0] + r[:, :, 1] + r[:, :, 2],
+                     r[:, :, 3] + r[:, :, 4] + r[:, :, 5],
+                     r[:, :, 6]])                          # [3, n, H, 128]
+    return ghc.reshape(3, -1, H * L)[:, :F, :B].transpose(1, 2, 0)
 
 
 def _untile_hist(out, F, B, Ft, n_tiles, W, expand_impl):
@@ -559,12 +699,25 @@ def _hist_batched_kernel(scalars, payload_hbm, out_ref, chunk, sem, *,
                          expand_impl="matmul"):
     """Grid-(K,) sibling of _hist_kernel: grid step i builds segment i's
     histogram from scalars[2i] / scalars[2i+1] into its own out block.
-    A sibling copy, not a parametrization of _hist_kernel, for the same
-    reason as the colblock kernel: _hist_kernel is hardware-validated and
-    must not be restructured blind (test_hist_batched_matches_portable
-    pins this one against the portable engine in interpret mode; the
-    smoke's FRONTIER section must prove the Mosaic lowering — the
-    multi-step grid over scalar prefetch — before the flag flips)."""
+    A body of its own, and since PR 27 the older one: the staged siblings
+    (this, the column-block and the merged kernel) keep the B-wide one-hot
+    a feature that _hist_kernel had before it factored the bin id, run by
+    no cell until C1 decides them (test_hist_batched_matches_portable pins
+    this one against the portable engine in interpret mode; the smoke's
+    FRONTIER section must prove the Mosaic lowering — the multi-step grid
+    over scalar prefetch — before the flag flips).
+
+    The B-wide one-hot machinery, built once before the chunk loop:
+    E[f, j] = 1 iff column j lies in tile-local feature f's B-wide window;
+    expanding a [C, Ft] tile of bin values through E on the MXU broadcasts
+    each feature's bin across its window, and a single [C, W] compare
+    against the within-window offset finishes the one-hot (`matmul`), or
+    `pltpu.repeat` concatenates B copies of the tile so that column
+    b*fw + f compares feature f's bin against b (`repeat`, bin-major, the
+    host epilogue untransposes).  A ragged last tile row-slices E: its
+    junk window columns land past Ft*B or in windows of features >= F,
+    both discarded by the host-side slice.  The value rows are the exact
+    bf16 decomposition described in _hist_kernel."""
     i = pl.program_id(0)
     start = scalars[2 * i]
     count = scalars[2 * i + 1]
@@ -902,10 +1055,10 @@ def _hist_colblock_kernel(scalars, payload_hbm, out_ref, chunk_blk,
                           chunk_aux, sem, *, Fb, B, Ft, W, col_lo, aux_lo,
                           g_off, h_off, c_off, expand_impl):
     """Sibling of _hist_kernel for ONE feature-column block of an
-    ultra-wide payload (a trace-time share was rejected for the same
-    reason as the merged kernel's: _hist_kernel is hardware-validated and
-    must not be restructured blind; test_colblock_matches_hist_kernel
-    pins the two against each other).
+    ultra-wide payload, with the B-wide one-hot body of
+    _hist_batched_kernel (see the notes there;
+    test_colblock_matches_hist_kernel pins it against the factored
+    kernel).
 
     Differences from the parent: each chunk DMAs TWO lane windows — the
     block's own columns [col_lo, col_lo+BW) and the aux window carrying
@@ -1379,7 +1532,7 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
                 (iota_rows < shift + count - k * CHUNK)).astype(jnp.int32)
 
     # chunk-independent [C, C] machinery, built once before the chunk loop
-    # (as _hist_kernel does for its one-hot machinery).  The iotas are
+    # (as the histogram kernels do for theirs).  The iotas are
     # built at [C, C] directly: slicing the [2C, C] ones (e.g.
     # iota_2i[:CHUNK]) crashes Mosaic's ApplyVectorLayout — a broadcasted
     # iota is stored replicated along its constant dim, and
@@ -1449,8 +1602,9 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
         acc[0:CHUNK] = acc[CHUNK:C2]
 
     if hist_cfg is not None:
-        # one-hot machinery identical to _hist_kernel (see the notes
-        # there); built once before the chunk loop, shared by both sides
+        # one-hot machinery identical to _hist_batched_kernel (see the
+        # notes there); built once before the chunk loop, shared by both
+        # sides
         Fh, Bh = hist_cfg["F"], hist_cfg["B"]
         Fth, Wh = hist_cfg["Ft"], hist_cfg["W"]
         n_tiles_h = -(-Fh // Fth)

@@ -27,51 +27,142 @@ def _payload(n_pad, seed=0):
     return jnp.asarray(pay)
 
 
+def _lanes(pay, lanes):
+    """The payload as it is (`ragged`: the interpreter alone sees a width
+    that is no multiple of 128) or lane-padded as the fast path's is on
+    the chip (`padded`)."""
+    if lanes == "ragged":
+        return pay
+    return jnp.pad(pay, ((0, 0), (0, -pay.shape[1] % 128)))
+
+
 @pytest.mark.parametrize("start,count", [(0, 1000), (256, 700), (100, 37),
                                          (0, 0), (513, 256), (7, 1),
                                          (9, 1015), (1023, 1)])
-@pytest.mark.parametrize("expand", ["matmul", "repeat"])
-def test_histogram_matches(start, count, expand):
-    pay = _payload(1024)
+@pytest.mark.parametrize("lanes", ["ragged", "padded"])
+def test_histogram_matches(start, count, lanes):
+    pay = _lanes(_payload(1024), lanes)
     ref = seg.segment_histogram(pay, jnp.int32(start), jnp.int32(count),
                                 num_features=F, num_bins=B, **COLS)
     got = pseg.segment_histogram(pay, jnp.int32(start), jnp.int32(count),
                                  num_features=F, num_bins=B, interpret=True,
-                                 expand_impl=expand, **COLS)
+                                 **COLS)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
 
 
+def _hist_payload(f, b, n_pad=640, width=None, seed=None, bins=None,
+                  grads=None):
+    """[n_pad + GUARD, width] payload of f bin columns at b bins, then
+    gradient, hessian and count; `bins` / `grads` replace the random
+    ones."""
+    rng = np.random.default_rng(f + b if seed is None else seed)
+    pay = np.zeros((n_pad + seg.GUARD, width or f + 4), np.float32)
+    pay[:n_pad, :f] = (rng.integers(0, b, size=(n_pad, f))
+                       if bins is None else bins)
+    pay[:n_pad, f] = rng.standard_normal(n_pad) if grads is None else grads
+    pay[:n_pad, f + 1] = rng.random(n_pad)
+    pay[:n_pad, f + 2] = 1.0
+    return jnp.asarray(pay), dict(grad_col=f, hess_col=f + 1, cnt_col=f + 2)
+
+
 @pytest.mark.parametrize("f,b,start,count", [
-    (137, 256, 0, 300),    # MS-LTR shape: tiles of 8, ragged last
-    (70, 64, 100, 351),    # tiles of 32, ragged last
-    (700, 256, 256, 260),  # Expo/Yahoo shape: 88 tiles, ragged last
+    (137, 256, 0, 300),    # MS-LTR shape: two column tiles, a loop of two trips
+    (70, 64, 100, 351),    # groups of 4, ragged last group
+    (700, 256, 256, 260),  # Expo/Yahoo shape: six column tiles
     (968, 64, 0, 300),     # Bosch shape at the GPU-recommended max_bin=63
 ])
-@pytest.mark.parametrize("expand", ["matmul", "repeat"])
-def test_histogram_matches_tiled(f, b, start, count, expand):
-    """Feature-tiled kernel vs portable engine at wide-feature shapes the
+@pytest.mark.parametrize("lanes", ["ragged", "padded"])
+def test_histogram_matches_tiled(f, b, start, count, lanes):
+    """Column-tiled kernel vs portable engine at wide-feature shapes the
     old F*B <= 8192 gate excluded (reference handles these through the
     OpenCL workgroup grid, ocl/histogram256.cl:73-121)."""
     if seg.CHUNK == 256:   # gate expectations assume the default chunk
         assert pseg.fits_vmem(f, b), "gate must admit this shape now"
-    cols = dict(grad_col=f, hess_col=f + 1, cnt_col=f + 2)
-    p = f + 4
-    rng = np.random.default_rng(f + b)
-    n_pad = 640
-    pay = np.zeros((n_pad + seg.GUARD, p), np.float32)
-    pay[:n_pad, :f] = rng.integers(0, b, size=(n_pad, f))
-    pay[:n_pad, f] = rng.standard_normal(n_pad)
-    pay[:n_pad, f + 1] = rng.random(n_pad)
-    pay[:n_pad, f + 2] = 1.0
-    pay = jnp.asarray(pay)
+    pay, cols = _hist_payload(f, b)
+    pay = _lanes(pay, lanes)
     ref = seg.segment_histogram(pay, jnp.int32(start), jnp.int32(count),
                                 num_features=f, num_bins=b, **cols)
     got = pseg.segment_histogram(pay, jnp.int32(start), jnp.int32(count),
                                  num_features=f, num_bins=b, interpret=True,
-                                 expand_impl=expand, **cols)
+                                 **cols)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("f,b,width,start,count", [
+    (28, 255, 128, 3, 600),      # higgs-train: L 64, H 4, two features a group
+    (67, 256, 128, 256, 260),    # criteo-dp4-train: 34 groups, the last ragged
+    (2000, 64, 2048, 7, 300),    # epsilon-train: 16 column tiles, L 32, H 2
+    (7, 255, 128, 100, 351),     # a feature-parallel shard's leading columns
+    (30, 100, 128, 9, 500),      # top high block ragged: 100 = 3 * 32 + 4
+    (19, 37, 128, 0, 640),       # 37 = 32 + 5: the top block holds 5 bins
+    (33, 255, 128, 100, 37),     # 255 = 3 * 64 + 63
+    (5, 3, 128, 0, 300),         # fewer bins than a sublane tile
+    (126, 256, 256, 513, 1),     # one row; the value columns in two blocks
+])
+def test_histogram_factored_shapes(f, b, width, start, count):
+    """The factored bin id (bin = hi * L + lo) at the cells' shapes and
+    payload widths, at bin counts that are no multiple of L (the top high
+    block is padded) and at feature counts that leave the last group and
+    the last loop trip ragged: equal to the portable engine, the counts to
+    the last digit."""
+    L, H, G = pseg._hist_factor(b)
+    assert H * L >= b and G * L == 128
+    pay, cols = _hist_payload(f, b, width=width)
+    ref = seg.segment_histogram(pay, jnp.int32(start), jnp.int32(count),
+                                num_features=f, num_bins=b, **cols)
+    got = pseg.segment_histogram(pay, jnp.int32(start), jnp.int32(count),
+                                 num_features=f, num_bins=b, interpret=True,
+                                 **cols)
+    assert got.shape == (f, b, 3)
+    np.testing.assert_array_equal(np.asarray(got[..., 2]),
+                                  np.asarray(ref[..., 2]))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("b", [256, 255, 100, 64, 37])
+@pytest.mark.parametrize("which", ["first", "last"])
+def test_histogram_one_bin(b, which):
+    """Every row in bin 0, or in bin b - 1 (the last low part of the top
+    high block): the whole segment lands in that bin of every feature."""
+    f, n = 9, 600
+    bins = np.full((640, f), 0 if which == "first" else b - 1)
+    pay, cols = _hist_payload(f, b, width=128, bins=bins)
+    got = np.asarray(pseg.segment_histogram(
+        pay, jnp.int32(5), jnp.int32(n), num_features=f, num_bins=b,
+        interpret=True, **cols))
+    at = 0 if which == "first" else b - 1
+    np.testing.assert_array_equal(got[:, at, 2], np.full(f, n))
+    assert np.count_nonzero(np.delete(got, at, axis=1)) == 0
+    exact = np.asarray(pay, np.float64)[5:5 + n, f:f + 2].sum(0)
+    np.testing.assert_allclose(got[0, at, :2], exact, rtol=2e-6)
+
+
+@pytest.mark.parametrize("f,b", [(28, 256), (70, 64)])
+def test_histogram_keeps_gradient_bits(f, b):
+    """No precision is given up: gradients whose low mantissa bits matter
+    (1 + k * 2^-20: bf16 rounds every one to 1.0) sum to the float64 sum
+    within f32 accumulation error.  A histogram that summed bf16-rounded
+    gradients would be off by up to n * 2^-9 a bin."""
+    n = 640
+    rng = np.random.default_rng(b)
+    grads = (1.0 + rng.integers(1, 2 ** 11, n) * 2.0 ** -20).astype(
+        np.float32)
+    bins = rng.integers(0, 4, size=(n, f)) * (b // 4)
+    pay, cols = _hist_payload(f, b, width=128, bins=bins, grads=grads)
+    got = np.asarray(pseg.segment_histogram(
+        pay, jnp.int32(0), jnp.int32(n), num_features=f, num_bins=b,
+        interpret=True, **cols))
+    exact = np.zeros((f, b))
+    for col in range(f):
+        np.add.at(exact[col], bins[:, col], grads.astype(np.float64))
+    # ~160 rows a bin: the sum is near 160, its f32 ulp 1.5e-5; a bf16
+    # gradient would lose 160 * 2^-10 = 0.16
+    np.testing.assert_allclose(got[..., 0], exact, rtol=0, atol=1e-4)
+    rounded = np.asarray(jnp.asarray(grads).astype(jnp.bfloat16), np.float64)
+    assert abs(rounded.sum() - grads.astype(np.float64).sum()) > 0.1
 
 
 def test_partition_vmem_gate():
@@ -96,6 +187,15 @@ def test_vmem_gate_admits_benchmark_shapes():
     assert pseg.fits_vmem(968, 64)    # Bosch at GPU max_bin=63
     assert pseg.fits_vmem(2000, 64)   # Epsilon at GPU max_bin=63
     assert not pseg.fits_vmem(4228, 256)  # raw Allstate: portable path
+    # the three train cells, at the widths their payloads have on the chip
+    assert pseg.fits_vmem(28, 255, 128)      # higgs-train
+    assert pseg.fits_vmem(67, 256, 128)      # criteo-dp4-train, a shard
+    assert pseg.fits_vmem(2000, 64, 2048)    # epsilon-train
+    # the plan counts the kernel's real buffers: the accumulator is
+    # 8 * F * H * L * 4 bytes however the bin id is factored
+    L, H, G = pseg._hist_factor(64)
+    assert (L, H, G) == (32, 2, 4) and pseg._hist_factor(256) == (64, 4, 2)
+    assert pseg._hist_groups(2000, 64) * 8 * H * 128 == 8 * 2000 * 64
 
 
 def _pred(feature=1, threshold=B // 2, default_left=False, is_cat=False,
@@ -286,8 +386,9 @@ def test_validated_flags_gate_product_paths():
     with _pytest.raises(ValueError):
         seg.resolve_impl("pallas", 28, 512)
     with _pytest.raises(ValueError):
-        pseg.segment_histogram(
-            _payload(64), jnp.int32(0), jnp.int32(8), num_features=F,
+        pseg.segment_histogram_batched(
+            _payload(64), jnp.asarray([0], jnp.int32),
+            jnp.asarray([8], jnp.int32), num_features=F,
             num_bins=B, interpret=True, expand_impl="typo", **COLS)
 
 
@@ -380,10 +481,9 @@ def test_partition_hist_flag_staged_off():
 
 @pytest.mark.parametrize("expand", ["matmul", "repeat"])
 def test_partition_hist_matches_hist_kernel(expand):
-    """The merged kernel's tile machinery is a sibling copy of
-    _hist_kernel's (a trace-time share was rejected: _hist_kernel is
-    hardware-validated and must not be restructured blind) — this pins
-    the two against each other so divergence is loud."""
+    """The merged kernel keeps the B-wide one-hot body `_hist_kernel` had
+    before PR 27 — this pins its two child histograms against the
+    factored kernel's so divergence is loud."""
     pay = _payload(1024, seed=42)
     aux = jnp.zeros_like(pay)
     pred = _pred(feature=2, threshold=B // 3)
@@ -392,12 +492,10 @@ def test_partition_hist_matches_hist_kernel(expand):
         jnp.float32(-1.0), VALUE_COL, B, num_features=F, interpret=True,
         expand_impl=expand, **COLS)
     hl_k = pseg.segment_histogram(p2, jnp.int32(64), nl, num_features=F,
-                                  num_bins=B, interpret=True,
-                                  expand_impl=expand, **COLS)
+                                  num_bins=B, interpret=True, **COLS)
     hr_k = pseg.segment_histogram(p2, jnp.int32(64) + nl,
                                   jnp.int32(900) - nl, num_features=F,
-                                  num_bins=B, interpret=True,
-                                  expand_impl=expand, **COLS)
+                                  num_bins=B, interpret=True, **COLS)
     np.testing.assert_allclose(np.asarray(hl), np.asarray(hl_k),
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(hr), np.asarray(hr_k),
@@ -448,15 +546,8 @@ def test_partition_hist_merged_predicates(predkw):
 def _wide_payload(n_pad, F_wide, B_wide, seed=0):
     """Ultra-wide payload: F_wide bin columns, aux (grad/hess/cnt) after
     them, lane-padded width like the fast path's _FastState.P."""
-    rng = np.random.default_rng(seed)
-    P_wide = -(-(F_wide + 8) // 128) * 128
-    pay = np.zeros((n_pad + seg.GUARD, P_wide), np.float32)
-    pay[:n_pad, :F_wide] = rng.integers(0, B_wide, size=(n_pad, F_wide))
-    pay[:n_pad, F_wide] = rng.standard_normal(n_pad)
-    pay[:n_pad, F_wide + 1] = rng.random(n_pad)
-    pay[:n_pad, F_wide + 2] = 1.0
-    cols = dict(grad_col=F_wide, hess_col=F_wide + 1, cnt_col=F_wide + 2)
-    return jnp.asarray(pay), cols
+    return _hist_payload(F_wide, B_wide, n_pad,
+                         width=-(-(F_wide + 8) // 128) * 128, seed=seed)
 
 
 def test_colblock_flag_staged_off():
@@ -531,21 +622,24 @@ def test_colblock_matches_portable_wide(start, count):
 
 @pytest.mark.parametrize("expand", ["matmul", "repeat"])
 def test_colblock_matches_hist_kernel(expand):
-    """At a width BOTH engines handle, the colblock sibling must equal the
-    hardware-validated single-pass kernel bit-for-bit (interpret mode) —
-    the same pinning discipline as the merged kernel."""
+    """At a width BOTH engines handle, the colblock sibling (the B-wide
+    one-hot body) must equal the single-pass kernel: the counts to the
+    last digit, the sums within f32 accumulation error (the factored
+    product associates them differently)."""
     pay = _payload(1024, seed=42)
     # the colblock engine requires a lane-padded payload (the fast path's
     # _FastState.P guarantee); pad the narrow test payload to 128 lanes
     pay128 = jnp.pad(pay, ((0, 0), (0, 128 - pay.shape[1])))
     ref = pseg.segment_histogram(pay128, jnp.int32(0), jnp.int32(1000),
                                  num_features=F, num_bins=B,
-                                 interpret=True, expand_impl=expand,
-                                 **COLS)
+                                 interpret=True, **COLS)
     got = pseg.segment_histogram_colblock(
         pay128, jnp.int32(0), jnp.int32(1000), num_features=F, num_bins=B,
         interpret=True, expand_impl=expand, **COLS)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+    np.testing.assert_array_equal(np.asarray(got[..., 2]),
+                                  np.asarray(ref[..., 2]))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
 
 
 # ---------------------------------------------------------------------------
@@ -710,9 +804,9 @@ def test_hist_batched_matches_portable(expand):
 
 
 def test_hist_batched_slice_matches_single_segment_kernel():
-    """Each batched-grid slice must agree with the hardware-validated
-    single-segment kernel on the same segment (sibling-pin discipline:
-    the batched kernel is a grid-indexed copy, not a restructure)."""
+    """Each batched-grid slice (the B-wide one-hot body) must agree with
+    the single-segment kernel on the same segment: the counts to the last
+    digit, the sums within f32 accumulation error."""
     pay = _payload(1024, seed=6)
     starts = jnp.asarray([9, 300], jnp.int32)
     counts = jnp.asarray([291, 700], jnp.int32)
@@ -722,9 +816,11 @@ def test_hist_batched_slice_matches_single_segment_kernel():
                                          expand_impl="matmul", **cols)
     for k in range(2):
         ref = pseg.segment_histogram(pay, starts[k], counts[k],
-                                     interpret=True, expand_impl="matmul",
-                                     **cols)
-        np.testing.assert_array_equal(np.asarray(got[k]), np.asarray(ref))
+                                     interpret=True, **cols)
+        np.testing.assert_array_equal(np.asarray(got[k][..., 2]),
+                                      np.asarray(ref[..., 2]))
+        np.testing.assert_allclose(np.asarray(got[k]), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-5)
 
 
 def test_hist_vmem_gate_uses_real_payload_width():
@@ -740,6 +836,10 @@ def test_hist_vmem_gate_uses_real_payload_width():
     assert pseg.fits_vmem(28, 255)
     assert pseg.fits_vmem(28, 255, payload_width=128)
     assert not pseg.fits_vmem(28, 255, payload_width=8192)
+    # feature-parallel on four chips: a shard's leading columns of
+    # full-width rows, at the Higgs and the Epsilon payloads
+    assert pseg.fits_vmem(7, 255, payload_width=128)
+    assert pseg.fits_vmem(500, 64, payload_width=2048)
     # resolve_impl threads the width through (TPU-only decision; on CPU
     # both resolve to lax)
     assert seg.resolve_impl("auto", 28, 255, 4224) in ("pallas", "lax")

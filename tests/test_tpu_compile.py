@@ -87,14 +87,27 @@ def test_partition_blocks_compiles_for_v5e_at_epsilon(one_chip):
 
 
 def test_histogram_compiles_for_v5e_at_epsilon(one_chip):
-    """The single-pass histogram at 2,000 columns x 64 bins (63 tiles of
-    32 columns) over full 2,048-lane rows."""
+    """The single-pass histogram at 2,000 columns x 64 bins (16 column
+    tiles of 32 groups of four) over full 2,048-lane rows."""
     assert pseg.fits_vmem(WIDE_FEATURES, WIDE_BINS, WIDE_LANES)
     payload, _, i32 = _partition_args(
         one_chip, WIDE_ROWS, WIDE_LANES, WIDE_FEATURES, WIDE_BINS)[:3]
     lowered = pseg._segment_histogram.lower(
         payload, i32, i32, num_features=WIDE_FEATURES, num_bins=WIDE_BINS,
         grad_col=WIDE_FEATURES, hess_col=WIDE_FEATURES + 1,
-        cnt_col=WIDE_FEATURES + 2, interpret=False,
-        expand_impl=pseg._default_expand_impl(WIDE_FEATURES, WIDE_BINS))
+        cnt_col=WIDE_FEATURES + 2, interpret=False)
+    assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("features,bins", [(FEATURES, 255), (67, 256),
+                                           (30, 100), (19, 37)])
+def test_histogram_compiles_for_v5e_narrow(one_chip, features, bins):
+    """The histogram at the Higgs and Criteo cells' shapes (128 lanes, two
+    features a group) and at bin counts whose top high block is ragged."""
+    assert pseg.fits_vmem(features, bins, LANES)
+    payload, _, i32 = _partition_args(one_chip)[:3]
+    lowered = pseg._segment_histogram.lower(
+        payload, i32, i32, num_features=features, num_bins=bins,
+        grad_col=features, hess_col=features + 1, cnt_col=features + 2,
+        interpret=False)
     assert "tpu_custom_call" in lowered.compile().as_text()
