@@ -802,8 +802,8 @@ def run(n_rows, n_test, num_leaves, measure_iters, n_feat=28, max_bin=255):
               "num_leaves": num_leaves, "max_bin": max_bin,
               "learning_rate": 0.1, "verbose": -1}
     # frontier batching (Config.tpu_frontier_batch): BENCH_FRONTIER_BATCH=K
-    # lets a session A/B the batched grower; on a TPU pallas config the
-    # grower additionally stages behind FRONTIER_BATCH_VALIDATED
+    # lets a session A/B the batched grower (the lax engine's only: with
+    # the Pallas histogram the sequential grower runs whatever K says)
     fbatch = int(os.environ.get("BENCH_FRONTIER_BATCH", "1") or 1)
     if fbatch > 1:
         params["tpu_frontier_batch"] = fbatch

@@ -1,35 +1,20 @@
 """In-kernel ablation of the histogram kernel's chunk body: what each
-piece costs a 256-row chunk on the chip, for the body before PR 27 (a
-B-wide one-hot a feature, M = 8 rows of values) and for the one since (the
-bin id factored into a high and a low part).
+piece costs a 256-row chunk on the chip.  (The body before PR 27, a B-wide
+one-hot a feature expanded along lanes, was timed by the same instrument;
+its table is in PERF.md §6 and its code went with the kernels that kept
+it.)
 
 The profiler's trace ends at the kernel's edge, so the body is timed by
 leaving pieces out, in the manner of `exp/ablate_partition_body.py`.
 
-`old`: `_old_kernel` is `pallas_segment._hist_kernel` as it stood at PR 26,
-kept here as the instrument's reference, with a static set of stubs:
+`_new_kernel` is `pallas_segment._hist_kernel` as it stands (with no stub
+and the product's own factoring and trip it returns the product's
+histogram bit for bit, which is checked), with a static set of stubs:
 
     body     nothing but the chunk's DMA wait and one add of 8 of its rows
              (the DMA floor)
     extract  no `sel x data^T` product and no bf16 split: the value rows
              are ones under the row mask
-    expand   no expand matmul / lane repeat: the [C, W] expand is read
-             from a scratch filled before the loop
-    compare  no compare and convert: the expand itself is the product's
-             right operand
-    product  no `vals x onehot` product: the one-hot is stored to a
-             scratch (every vreg of it stays alive) and 8 of its rows are
-             accumulated
-    bf16     (not a stub) both operands of the product cast to bf16 first:
-             the same numbers, half the vregs pushed
-
-`new`: `_new_kernel` is `pallas_segment._hist_kernel` as it stands (with no
-stub and the product's own factoring and trip it returns the product's
-histogram bit for bit, which is checked), with the stubs
-
-
-    body     as above
-    extract  as above
     transpose  the bin columns' transposition to rows-in-lanes and the
              split into high and low part: the scratches keep what the
              first chunk wrote
@@ -47,12 +32,12 @@ power of two at or above sqrt(8B) or the one below (`rule` / `half`), 8 /
 16 / 32 groups a loop trip, f32 / bf16 operands of the product.
 
 A stubbed kernel computes nonsense; only its time is read.  With no stub
-each kernel's output is checked against the portable engine.  Times are
+the kernel's output is checked against the portable engine.  Times are
 wall clock round a call whose scalar result is fetched, the median of
 five; the cost of a piece is full minus stubbed, per chunk of CHUNK rows.
 Pieces overlap in the kernel's schedule, so the costs need not add up.
 
-On the chip:   python exp/ablate_hist_body.py [--race] [old] [new] [higgs criteo epsilon]
+On the chip:   python exp/ablate_hist_body.py [--race] [higgs criteo epsilon]
 CPU rehearsal: JAX_PLATFORMS=cpu python exp/ablate_hist_body.py --interpret
 """
 import functools
@@ -75,171 +60,16 @@ from lightgbm_tpu.ops import segment as seg
 from lightgbm_tpu.ops import pallas_segment as pseg
 
 CHUNK = pseg.CHUNK
-OLD_STUBS = ("body", "extract", "expand", "compare", "product")
 NEW_STUBS = ("body", "extract", "transpose", "lo", "hi", "product", "acc",
              "regroup")
 
 #: the three train cells' kernel shapes: features, bins, payload lanes,
-#: rows timed, the old body's expand
+#: rows timed
 SHAPES = {
-    "higgs": (28, 256, 128, 1 << 22, "repeat"),
-    "criteo": (67, 256, 128, 1 << 22, "matmul"),
-    "epsilon": (2000, 64, 2048, 409_600, "matmul"),
+    "higgs": (28, 256, 128, 1 << 22),
+    "criteo": (67, 256, 128, 1 << 22),
+    "epsilon": (2000, 64, 2048, 409_600),
 }
-
-
-def _old_tiling(F, B):
-    ft = max(1, min(F, 2048 // B))
-    return ft, -(-F // ft), -(-ft * B // 128) * 128
-
-
-def _old_kernel(scalars, payload_hbm, out_ref, chunk, sem, dump, *,
-                F, B, Ft, W, grad_col, hess_col, cnt_col, expand_impl,
-                stubs):
-    start, count = scalars[0], scalars[1]
-    shift = lax.rem(start, 8)
-    base = start - shift
-    nch = jnp.where(count > 0, (shift + count + CHUNK - 1) // CHUNK, 0)
-    n_tiles = -(-F // Ft)
-    out_ref[:] = jnp.zeros(out_ref.shape, out_ref.dtype)
-    iota_rows = pseg._row_iota()
-
-    def dma_for(k, slot):
-        return pltpu.make_async_copy(
-            payload_hbm.at[pl.ds(pl.multiple_of(base + k * CHUNK, 8),
-                                 CHUNK), :],
-            chunk.at[slot], sem.at[slot])
-
-    @pl.when(nch > 0)
-    def _prefetch_first():
-        dma_for(0, 0).start()
-
-    if expand_impl == "repeat":
-        jdivs = {}
-        for t in range(n_tiles):
-            fw = min(Ft, F - t * Ft)
-            if fw not in jdivs:
-                jdivs[fw] = (lax.broadcasted_iota(jnp.int32, (1, fw * B), 1)
-                             // fw).astype(jnp.float32)
-    else:
-        iota_fr = lax.broadcasted_iota(jnp.int32, (Ft, W), 0)
-        iota_fc = lax.broadcasted_iota(jnp.int32, (Ft, W), 1)
-        d = iota_fc - iota_fr * B
-        in_win = (d >= 0) & (d < B)
-        E = in_win.astype(jnp.float32)
-        jmod_f = jnp.sum(jnp.where(in_win, d, 0), axis=0).astype(jnp.float32)
-    if "expand" in stubs or "product" in stubs:
-        dump[:] = (lax.broadcasted_iota(jnp.int32, dump.shape, 1)
-                   % B).astype(jnp.float32)
-
-    def body(k, _):
-        slot = lax.rem(k, 2)
-
-        @pl.when(k + 1 < nch)
-        def _prefetch_next():
-            dma_for(k + 1, lax.rem(k + 1, 2)).start()
-
-        dma_for(k, slot).wait()
-        data = chunk[slot]
-        if "body" in stubs:
-            out_ref[0:8, 0:128] += data[0:8, 0:128]
-            return 0
-        ok = ((iota_rows >= shift - k * CHUNK) &
-              (iota_rows < shift + count - k * CHUNK)).astype(jnp.float32)
-        if "extract" in stubs:
-            vals = jnp.ones((8, CHUNK), jnp.float32) * ok[None, :]
-        else:
-            P = data.shape[1]
-            iota_r8 = lax.broadcasted_iota(jnp.int32, (8, P), 0)
-            iota_pc = lax.broadcasted_iota(jnp.int32, (8, P), 1)
-            sel = (((iota_r8 < 3) & (iota_pc == grad_col)) |
-                   ((iota_r8 >= 3) & (iota_r8 < 6) & (iota_pc == hess_col)) |
-                   ((iota_r8 == 6) & (iota_pc == cnt_col))
-                   ).astype(jnp.float32)
-            raw = lax.dot_general(
-                sel, data, dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-                precision=lax.Precision.HIGHEST)
-            hi = raw.astype(jnp.bfloat16).astype(jnp.float32)
-            r1 = raw - hi
-            mid = r1.astype(jnp.bfloat16).astype(jnp.float32)
-            lo = r1 - mid
-            rr = lax.broadcasted_iota(jnp.int32, raw.shape, 0)
-            vals = jnp.where((rr == 0) | (rr == 3), hi,
-                             jnp.where((rr == 1) | (rr == 4), mid,
-                                       jnp.where((rr == 2) | (rr == 5), lo,
-                                                 raw)))
-            vals = vals * ok[None, :]
-        for t in range(n_tiles):
-            f0 = t * Ft
-            fw = min(Ft, F - f0)
-            binsf = data[:, f0:f0 + fw]
-            w = fw * B if expand_impl == "repeat" else W
-            if "expand" in stubs:
-                expand = dump[:, :w]
-            elif expand_impl == "repeat":
-                expand = pltpu.repeat(binsf, B, axis=1)
-            else:
-                expand = lax.dot_general(
-                    binsf, E[:fw, :],
-                    dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-            cmp = jdivs[fw] if expand_impl == "repeat" else jmod_f[None, :]
-            onehot = expand if "compare" in stubs \
-                else (expand == cmp).astype(jnp.float32)
-            if "product" in stubs:
-                dump[:, :w] = onehot
-                out_ref[8 * t:8 * t + 8, :w] += dump[0:8, :w]
-                continue
-            lhs = vals
-            if "bf16" in stubs:
-                lhs, onehot = (lhs.astype(jnp.bfloat16),
-                               onehot.astype(jnp.bfloat16))
-            out_ref[8 * t:8 * t + 8, :w] += lax.dot_general(
-                lhs, onehot, dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-        return 0
-
-    lax.fori_loop(0, nch, body, 0)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "F", "B", "interpret", "expand_impl", "stubs"))
-def old_hist(payload, start, count, *, F, B, interpret, expand_impl, stubs):
-    P = payload.shape[1]
-    Ft, n_tiles, W = _old_tiling(F, B)
-    scalars = jnp.stack([start, count]).astype(jnp.int32)
-    kern = functools.partial(_old_kernel, F=F, B=B, Ft=Ft, W=W, grad_col=F,
-                             hess_col=F + 1, cnt_col=F + 2,
-                             expand_impl=expand_impl, stubs=stubs)
-    out = pl.pallas_call(
-        kern,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(1,),
-            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-            scratch_shapes=[pltpu.VMEM((2, CHUNK, P), jnp.float32),
-                            pltpu.SemaphoreType.DMA((2,)),
-                            pltpu.VMEM((CHUNK, W), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((8 * n_tiles, W), jnp.float32),
-        interpret=interpret,
-    )(scalars, payload)
-    if stubs:
-        return out
-    # the parent's epilogue: parts recombined, windows untiled
-    r = out.reshape(n_tiles, 8, W)
-    ghc = jnp.stack([r[:, 0] + r[:, 1] + r[:, 2],
-                     r[:, 3] + r[:, 4] + r[:, 5], r[:, 6]], axis=1)
-    if expand_impl == "repeat":
-        tiles = []
-        for t in range(n_tiles):
-            fw = min(Ft, F - t * Ft)
-            tiles.append(ghc[t, :, :fw * B].reshape(3, B, fw)
-                         .transpose(0, 2, 1))
-        return jnp.concatenate(tiles, axis=1).transpose(1, 2, 0)
-    return (ghc[:, :, :Ft * B].reshape(n_tiles, 3, Ft, B)
-            .transpose(1, 0, 2, 3).reshape(3, n_tiles * Ft, B)[:, :F]
-            .transpose(1, 2, 0))
 
 
 def _new_plan(B, lo_round):
@@ -531,53 +361,46 @@ def main():
     if not interpret and jax.default_backend() != "tpu":
         sys.exit("ablate_hist_body: platform is %r, not tpu"
                  % jax.default_backend())
-    sides = [a for a in args if a in ("old", "new")] or ["old", "new"]
     shapes = [a for a in args if a in SHAPES] or list(SHAPES)
     out = {}
     for shape in shapes:
-        F, B, P, n, expand = SHAPES[shape]
+        F, B, P, n = SHAPES[shape]
         if interpret:
             n = 1024
             if shape == "epsilon":
                 F, P = 300, 384
         payload = make_payload(jax.random.PRNGKey(27), n=n, F=F, B=B, P=P)
         times = {}
-        if "old" in sides:
-            ablate("old", old_hist,
-                   [()] + [(s,) for s in OLD_STUBS] +
-                   [("bf16",), ("expand", "compare")],
-                   payload, n, F, B, interpret, times, expand_impl=expand)
-        if "new" in sides:
-            got = new_hist(payload, jnp.int32(128), jnp.int32(n - 1000), F=F,
-                           B=B, interpret=interpret, stubs=())
-            ref = pseg.segment_histogram(
-                payload, jnp.int32(128), jnp.int32(n - 1000), num_features=F,
-                num_bins=B, grad_col=F, hess_col=F + 1, cnt_col=F + 2,
-                interpret=interpret)
-            np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
-            if race:
-                for lo_round in ("rule", "half"):
-                    for U in (8, 16, 32):
-                        for mxu in ("f32", "bf16"):
-                            t = timed(new_hist, payload, n, F=F, B=B,
-                                      interpret=interpret, stubs=(),
-                                      lo_round=lo_round, U=U, mxu=mxu)
-                            label = "race %s U=%d %s" % (lo_round, U, mxu)
-                            times[label] = t
-                            if t is not None:
-                                print("%-44s %8.3f ms  %8.1f ns/chunk" % (
-                                    label, t * 1e3, t / (n // CHUNK) * 1e9),
-                                    flush=True)
-            ablate("new", new_hist,
-                   [()] + [(s,) for s in NEW_STUBS] + [("lo", "hi")],
-                   payload, n, F, B, interpret, times)
+        got = new_hist(payload, jnp.int32(128), jnp.int32(n - 1000), F=F,
+                       B=B, interpret=interpret, stubs=())
+        ref = pseg.segment_histogram(
+            payload, jnp.int32(128), jnp.int32(n - 1000), num_features=F,
+            num_bins=B, grad_col=F, hess_col=F + 1, cnt_col=F + 2,
+            interpret=interpret)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+        if race:
+            for lo_round in ("rule", "half"):
+                for U in (8, 16, 32):
+                    for mxu in ("f32", "bf16"):
+                        t = timed(new_hist, payload, n, F=F, B=B,
+                                  interpret=interpret, stubs=(),
+                                  lo_round=lo_round, U=U, mxu=mxu)
+                        label = "race %s U=%d %s" % (lo_round, U, mxu)
+                        times[label] = t
+                        if t is not None:
+                            print("%-44s %8.3f ms  %8.1f ns/chunk" % (
+                                label, t * 1e3, t / (n // CHUNK) * 1e9),
+                                flush=True)
+        ablate("new", new_hist,
+               [()] + [(s,) for s in NEW_STUBS] + [("lo", "hi")],
+               payload, n, F, B, interpret, times)
         out[shape] = {"features": F, "bins": B, "lanes": P, "rows": n,
                       "chunks": n // CHUNK, "seconds": times}
         del payload
     line = json.dumps({"interpret": interpret, "shapes": out})
     os.makedirs(os.path.join(REPO, "chiprun_out"), exist_ok=True)
     with open(os.path.join(REPO, "chiprun_out", "ablate_hist_body.%s.json"
-                           % "-".join(sides + shapes)), "w") as fh:
+                           % "-".join(["new"] + shapes)), "w") as fh:
         fh.write(line + "\n")
     print(line, flush=True)
 

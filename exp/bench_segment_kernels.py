@@ -56,14 +56,14 @@ def hist_call(count):
     return run
 
 
-def part_call(kernel, count, **kw):
+def part_call(kernel, count):
     def run(i):
         p_ = jnp.asarray(payload)
         a_ = jnp.zeros_like(p_)
         _ = np.asarray(p_)[0, 0]   # ensure uploaded before the clock
         t0 = time.perf_counter()
         out = kernel(p_, a_, jnp.int32(0), jnp.int32(count - (i % 2)), pred,
-                     jnp.float32(1.0), jnp.float32(-1.0), VAL, B, **kw)
+                     jnp.float32(1.0), jnp.float32(-1.0), VAL, B)
         nl = int(out[2])
         return time.perf_counter() - t0
     # upload time excluded: run() returns its own measured duration
@@ -84,11 +84,9 @@ for count in (1 << 15, 1 << 18, 1 << 20):
           "(%6.2f ns/row)" % (count, t_h * 1e3, t_h / count * 1e9,
                               t_p * 1e3, t_p / count * 1e9), flush=True)
 
-for label, kw in (("acc", dict(roll_place=False)),
-                  ("acc+roll", dict(roll_place=True))):
-    t_p = timeit_self(part_call(pseg.partition_segment_acc, 1 << 20, **kw))
-    print("part[%s] 1M rows: %8.2f ms (%6.2f ns/row)"
-          % (label, t_p * 1e3, t_p / (1 << 20) * 1e9), flush=True)
+t_p = timeit_self(part_call(pseg.partition_segment_acc, 1 << 20))
+print("part[acc] 1M rows: %8.2f ms (%6.2f ns/row)"
+      % (t_p * 1e3, t_p / (1 << 20) * 1e9), flush=True)
 
 # dispatch floor: tiny count isolates the fixed per-dispatch cost
 t0 = timeit_fetch(hist_call(8))

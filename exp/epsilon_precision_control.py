@@ -7,10 +7,10 @@ the program's own, both on one seed, and the two first trees side by side.
     python3 exp/epsilon_precision_control.py run --variant bf16 --seed N
     python3 exp/epsilon_precision_control.py compare --seed N
 
-`sound` is the program as it stands: the histogram kernels split every
+`sound` is the program as it stands: the histogram kernel splits every
 gradient and hessian into three bf16 parts, so the MXU's one bf16 pass sums
 them exactly into f32.  `bf16` is a copy of the package (under
-chiprun_out/, never the tree) in which the kernels keep the first part
+chiprun_out/, never the tree) in which the kernel keeps the first part
 only: each gradient rounded to 8 bits before it is summed, which is what
 the MXU does to an f32 matmul left at the default precision.  The
 partition, the split search and the harness are the same files in both.
@@ -30,18 +30,18 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 OUT = os.path.join(ROOT, "chiprun_out", "control")
 WORKLOAD = "epsilon-train"
 
-#: the second and third part of the histogram kernels' decomposition (eight
-#: spaces deep; the partition kernel's own, twelve deep, stays exact)
+#: the second and third part of the histogram kernel's decomposition (eight
+#: spaces deep; the partition kernel's own, in `_bf16_parts`, stays exact)
 PARTS = re.compile(
     r"^ {8}mid = r1\.astype\(jnp\.bfloat16\)\.astype\(jnp\.float32\)\n"
     r" {8}lo = r1 - mid\n", re.M)
 FIRST_PART_ONLY = ("        mid = jnp.zeros_like(r1)\n"
                    "        lo = jnp.zeros_like(r1)\n")
-HIST_KERNELS = 3
+HIST_KERNELS = 1
 
 
 def bf16_package():
-    """A copy of `lightgbm_tpu` whose histogram kernels sum bf16-rounded
+    """A copy of `lightgbm_tpu` whose histogram kernel sums bf16-rounded
     gradients; returns the directory to put first on `sys.path`."""
     src = os.path.join(OUT, "bf16_src")
     shutil.rmtree(src, ignore_errors=True)

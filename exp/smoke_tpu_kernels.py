@@ -2,19 +2,18 @@
 the chip and compared with the portable lax engine (or with an already
 checked sibling kernel).
 
-One verdict per kernel.  The kernels on the default path (histogram, RMW /
-accumulator / roll / column-block partition, precision) come first; the five staged ones behind
-``pallas_segment.STAGED_FLAGS`` follow with a fetch-forced race each,
-printed as information.  A section that raises records its error and the
-run carries on to the next kernel, so one call to the chip answers for
-all of them; the exit code is non-zero if any section failed.  The last stdout line is one JSON object, also
-written to ``chiprun_out/smoke_tpu_kernels.json``.
+One verdict per kernel (histogram, RMW / accumulator / column-block
+partition, precision), each with a fetch-forced time printed as
+information.  A section that raises records its error and the run carries
+on to the next kernel, so one call to the chip answers for all of them;
+the exit code is non-zero if any section failed.  The last stdout line is
+one JSON object, also written to ``chiprun_out/smoke_tpu_kernels.json``.
 
 On the chip:   python exp/smoke_tpu_kernels.py [section ...]
 CPU rehearsal: JAX_PLATFORMS=cpu python exp/smoke_tpu_kernels.py --interpret
 (the Pallas interpreter at a reduced row count; proves the script, says
-nothing about Mosaic).  Section names (`partition_acc blocks precision
-merged ring4` are the five that run `_acc_kernel`) keep the run to those.
+nothing about Mosaic).  Section names (`partition_acc blocks precision`
+are the three that run `_acc_kernel`) keep the run to those.
 """
 import json
 import os
@@ -106,8 +105,6 @@ KW = hist_kw(F, B)
 LV, RV = jnp.float32(1.5), jnp.float32(-2.5)
 
 
-# ---- default path ---------------------------------------------------------
-
 def histogram():
     """segment_histogram (the bin id factored into a high and a low part)
     at the three train cells' shapes and payload widths, segments of one
@@ -178,20 +175,15 @@ def partition_rmw():
 
 
 def partition_acc():
-    """partition_segment_acc, matmul placement and roll placement (a
-    traced sublane pltpu.roll of a [2C, P] concatenate)."""
-    info = {}
-    for roll in (False, True):
-        check_partition(
-            lambda p, a, s, c: pseg.partition_segment_acc(
-                p, a, s, c, PRED, LV, RV, VAL, B, roll_place=roll, **IK),
-            PAY, PRED, VAL,
-            segs((128, 3000), (7, 8000), (513, 256), (0, 8192)))
-        info["roll" if roll else "matmul"] = median_ms(
-            lambda: int(pseg.partition_segment_acc(
-                PAY, jnp.zeros_like(PAY), jnp.int32(0), jnp.int32(N), PRED,
-                LV, RV, VAL, B, roll_place=roll, **IK)[2]))
-    return info
+    """partition_segment_acc (placement is a traced sublane pltpu.roll of
+    a [2C, P] concatenate)."""
+    check_partition(
+        lambda p, a, s, c: pseg.partition_segment_acc(
+            p, a, s, c, PRED, LV, RV, VAL, B, **IK),
+        PAY, PRED, VAL, segs((128, 3000), (7, 8000), (513, 256), (0, 8192)))
+    return {"ms": median_ms(lambda: int(pseg.partition_segment_acc(
+        PAY, jnp.zeros_like(PAY), jnp.int32(0), jnp.int32(N), PRED, LV, RV,
+        VAL, B, **IK)[2]))}
 
 
 def blocks():
@@ -230,16 +222,16 @@ def blocks():
     pay = jnp.asarray(host)
     vcol = Fw + 3
 
-    def run(block_w):
+    def run(**kw):
         # the copies are donated: payload, scratch and nothing else
         return jax.jit(
             lambda p, a, s, c, col, thr:
             pseg.partition_segment_acc_blocks(
                 p, a, s, c, make_pred(col, thr, Bw), LV, RV, vcol, Bw,
-                block_w=block_w, **IK),
+                **kw, **IK),
             donate_argnums=(0, 1))
 
-    kernel = run(None)
+    kernel = run()
     cases = [(0, rows, 1300, 30), (7, 256, 3, 20), (128, 3000, 511, 40),
              (513, 100_000, 512, 31), (300_001, 65_536, 1999, 10)]
     for s0, c0, col, thr in cases:
@@ -259,7 +251,7 @@ def blocks():
         assert np.array_equal(got, want), (s0, c0, col)
     info["epsilon_rows"] = rows
     for block_w in (512, 256):
-        fn = run(block_w)
+        fn = run(block_w=block_w)
         info["epsilon_block%d_ms" % block_w] = median_ms(
             lambda: int(fn(pay + 0.0, jnp.zeros_like(pay), jnp.int32(0),
                            jnp.int32(rows), jnp.int32(1300),
@@ -297,138 +289,7 @@ def precision():
     return {"hist_grad_err_vs_f64": gerr}
 
 
-# ---- staged (flags in pallas_segment.STAGED_FLAGS; deciding them is a
-# later PR — the races are information) ------------------------------------
-
-def merged():
-    """partition_segment_hist: partition + both children's histograms."""
-    for s, c in segs((128, 3000), (7, 8000), (513, 256)):
-        pm, _, nlm, hl, hr = pseg.partition_segment_hist(
-            PAY, jnp.zeros_like(PAY), jnp.int32(s), jnp.int32(c), PRED,
-            LV, RV, VAL, B, num_features=F, grad_col=F, hess_col=F + 1,
-            cnt_col=F + 2, **IK)
-        pr, _, nlr = seg.partition_segment(
-            PAY, jnp.zeros_like(PAY), jnp.int32(s), jnp.int32(c), PRED,
-            LV, RV, VAL)
-        assert int(nlm) == int(nlr), (s, c)
-        assert float(jnp.abs(pm - pr).max()) == 0.0, (s, c)
-        hlr = seg.segment_histogram(pr, jnp.int32(s), nlr, **KW)
-        hrr = seg.segment_histogram(pr, jnp.int32(s) + nlr,
-                                    jnp.int32(c) - nlr, **KW)
-        herr = max(float(jnp.abs(hl - hlr).max()),
-                   float(jnp.abs(hr - hrr).max()))
-        assert herr < 1e-3, (s, c, herr)
-
-    def split_mode():
-        h_ = pseg.segment_histogram(PAY, jnp.int32(0), jnp.int32(N // 2),
-                                    **KW, **IK)
-        out = pseg.partition_segment_acc(
-            PAY, jnp.zeros_like(PAY), jnp.int32(0), jnp.int32(N), PRED,
-            LV, RV, VAL, B, **IK)
-        np.asarray(h_)[0, 0, 2]
-        return int(out[2])
-
-    def merged_mode():
-        return int(pseg.partition_segment_hist(
-            PAY, jnp.zeros_like(PAY), jnp.int32(0), jnp.int32(N), PRED,
-            LV, RV, VAL, B, num_features=F, grad_col=F, hess_col=F + 1,
-            cnt_col=F + 2, **IK)[2])
-
-    return {"split_ms": median_ms(split_mode),
-            "merged_ms": median_ms(merged_mode)}
-
-
-def frontier():
-    """segment_histogram_batched: K segments in one grid-(K,) dispatch."""
-    starts = jnp.asarray([0, 512, 1024, 7, 1536, 0], jnp.int32)
-    counts = jnp.asarray([500, 512, 250, 505, 500, 0], jnp.int32)
-    hb = pseg.segment_histogram_batched(PAY, starts, counts, **KW, **IK)
-    for k in range(6):
-        h1 = pseg.segment_histogram(PAY, starts[k], counts[k], **KW, **IK)
-        assert bool(jnp.array_equal(hb[k][..., 2], h1[..., 2])), k
-        assert float(jnp.abs(hb[k] - h1).max()) < 1e-3, k
-
-    def seq_mode():
-        for k in range(6):
-            np.asarray(pseg.segment_histogram(
-                PAY, starts[k], counts[k], **KW, **IK))[0, 0, 2]
-
-    return {"sequential6_ms": median_ms(seq_mode),
-            "batched6_ms": median_ms(
-                lambda: np.asarray(pseg.segment_histogram_batched(
-                    PAY, starts, counts, **KW, **IK))[0, 0, 0, 2])}
-
-
-def quant():
-    """segment_histogram_quant: int8 values x int8 one-hot -> int32; bit
-    equality with the portable integer engine."""
-    payq = np.array(PAY)
-    payq[:N, F] = rng.integers(-127, 128, N)
-    payq[:N, F + 1] = rng.integers(0, 128, N)
-    payq = jnp.asarray(payq)
-    for s, c in segs((0, 8000), (7, 4097), (1024, 1), (0, 0)):
-        hq = pseg.segment_histogram_quant(payq, jnp.int32(s), jnp.int32(c),
-                                          **KW, **IK)
-        hr = seg.segment_histogram(payq, jnp.int32(s), jnp.int32(c),
-                                   quantized=True, **KW)
-        assert int(jnp.abs(hq - hr).max()) == 0, (s, c)
-    return {"quant_int8_ms": median_ms(
-                lambda: np.asarray(pseg.segment_histogram_quant(
-                    payq, jnp.int32(0), jnp.int32(N), **KW, **IK))[0, 0, 2]),
-            "f32_kernel_ms": median_ms(
-                lambda: np.asarray(pseg.segment_histogram(
-                    payq, jnp.int32(0), jnp.int32(N), **KW, **IK))[0, 0, 2])}
-
-
-def colblock():
-    """segment_histogram_colblock at an ultra-wide payload (3 column
-    blocks + ragged tail; the two-window DMA)."""
-    Fw, Bw = 1500, 64
-    Pw = -(-(Fw + 8) // 128) * 128
-    pay = make_payload(N, Fw, Bw, width=Pw)
-    kw = hist_kw(Fw, Bw)
-    assert pseg.fits_vmem_colblock(Fw, Bw, Pw, Fw, Fw + 1, Fw + 2)
-    for s, c in segs((0, 8000), (7, 4097), (513, 256)):
-        h = pseg.segment_histogram_colblock(pay, jnp.int32(s), jnp.int32(c),
-                                            **kw, **IK)
-        ref = seg.segment_histogram(pay, jnp.int32(s), jnp.int32(c), **kw)
-        err = float(jnp.abs(h - ref).max())
-        assert err < 1e-3, (s, c, err)
-    return {"colblock_ms": median_ms(
-                lambda: np.asarray(pseg.segment_histogram_colblock(
-                    pay, jnp.int32(0), jnp.int32(N), **kw, **IK))[0, 0, 2]),
-            "portable_ms": median_ms(
-                lambda: np.asarray(seg.segment_histogram(
-                    pay, jnp.int32(0), jnp.int32(N), **kw))[0, 0, 2])}
-
-
-def ring4():
-    """4-deep read ring of the accumulator partition and of the merged
-    kernel, exact against depth 2."""
-    info = {}
-    fns = {
-        "acc": lambda rd, s, c: pseg.partition_segment_acc(
-            PAY, jnp.zeros_like(PAY), jnp.int32(s), jnp.int32(c), PRED,
-            LV, RV, VAL, B, ring_depth=rd, **IK),
-        "merged": lambda rd, s, c: pseg.partition_segment_hist(
-            PAY, jnp.zeros_like(PAY), jnp.int32(s), jnp.int32(c), PRED,
-            LV, RV, VAL, B, ring_depth=rd, num_features=F, grad_col=F,
-            hess_col=F + 1, cnt_col=F + 2, **IK),
-    }
-    for name, fn in fns.items():
-        o2, o4 = fn(2, 128, N - 1000), fn(4, 128, N - 1000)
-        assert int(o2[2]) == int(o4[2]), name
-        # output 1 is the aux scratch, whose leftovers may differ by depth
-        for i in (0,) + tuple(range(3, len(o2))):
-            assert float(jnp.abs(o2[i] - o4[i]).max()) == 0.0, (name, i)
-        for rd in (2, 4):
-            info["%s_ring%d_ms" % (name, rd)] = median_ms(
-                lambda: np.asarray(fn(rd, 0, N)[0])[0, 0])
-    return info
-
-
-DEFAULT_PATH = (histogram, partition_rmw, partition_acc, blocks, precision)
-STAGED = (merged, colblock, ring4, frontier, quant)
+SECTIONS = (histogram, partition_rmw, partition_acc, blocks, precision)
 
 
 def main():
@@ -437,14 +298,13 @@ def main():
     print("platform=%s kind=%s jax=%s interpret=%s rows=%d"
           % (device["platform"], device["kind"], jax.__version__, INTERPRET,
              N), flush=True)
-    assert {f.__name__ for f in STAGED} == set(pseg.STAGED_FLAGS)
-    sections = {f.__name__: f for f in DEFAULT_PATH + STAGED}
+    sections = {f.__name__: f for f in SECTIONS}
     wanted = [a for a in sys.argv[1:] if not a.startswith("--")]
     if set(wanted) - set(sections):
         sys.exit("smoke_tpu_kernels: no section %s (has: %s)"
                  % (sorted(set(wanted) - set(sections)), sorted(sections)))
     verdicts = {}
-    for fn in [sections[a] for a in wanted] or DEFAULT_PATH + STAGED:
+    for fn in [sections[a] for a in wanted] or SECTIONS:
         name = fn.__name__
         t0 = time.perf_counter()
         try:
@@ -454,12 +314,9 @@ def main():
             traceback.print_exc()
             verdicts[name] = {"ok": False, "error": "%s: %s" % (
                 type(e).__name__, str(e)[:2000])}
-        verdicts[name]["staged"] = fn in STAGED
         verdicts[name]["seconds"] = round(time.perf_counter() - t0, 1)
         print("%-14s %s" % (name, json.dumps(verdicts[name])), flush=True)
     out = {"device": device, "interpret": INTERPRET, "rows": N,
-           "flags": {k: getattr(pseg, v)
-                     for k, v in pseg.STAGED_FLAGS.items()},
            "verdicts": verdicts}
     line = json.dumps(out)
     os.makedirs(os.path.join(REPO, "chiprun_out"), exist_ok=True)

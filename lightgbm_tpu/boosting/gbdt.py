@@ -166,19 +166,9 @@ def _cached_pgrower(meta_dev: FeatureMeta, cfg, max_num_bin: int,
                     ds: BinnedDataset, cols: PayloadCols, payload_width: int,
                     bundle_map=None, forced=None, mesh=None, mesh_axis=None,
                     mode="data", top_k=20, quantized=False, qmax=0):
-    from ..ops import pallas_segment as _pseg
     key = (cfg, max_num_bin, ds.bins.shape, cols, payload_width,
            _bundle_key(ds), forced, mesh, mesh_axis, mode, top_k,
            quantized, qmax,
-           # every staged flag that flips grower structure or kernel
-           # choice when toggled: an in-process flip (a test, an
-           # exp/flip_validated.py rerun) must always rebuild the grower,
-           # as the flag docstrings promise
-           _pseg.PARTITION_HIST_VALIDATED,
-           _pseg.HIST_COLBLOCK_VALIDATED,
-           _pseg.PARTITION_RING4_VALIDATED,
-           _pseg.FRONTIER_BATCH_VALIDATED,
-           _pseg.HIST_QUANT_VALIDATED,
            tuple((m.num_bin, m.missing_type, m.default_bin, m.is_trivial, m.bin_type)
                  for m in ds.bin_mappers),
            ds.monotone_constraints.tobytes(), ds.feature_penalty.tobytes())

@@ -78,8 +78,7 @@ def partition_engine(hist_impl: str, payload_width: int,
     if hist_impl == "lax" or jax.default_backend() != "tpu":
         return "lax"
     from ..ops import pallas_segment as pseg
-    if (pseg.PARTITION_ACC_VALIDATED
-            and pseg.partition_acc_fits_vmem(payload_width, num_bins)):
+    if pseg.partition_acc_fits_vmem(payload_width, num_bins):
         return "pallas-acc"
     if pseg.partition_fits_vmem(payload_width, num_bins):
         return "pallas-rmw"
@@ -119,7 +118,6 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
                             forced: ForcedSchedule = None,
                             axis_name: str = None, mode: str = "data",
                             num_machines: int = 1, top_k: int = 20,
-                            merged_hist: bool = None,
                             payload_width: int = None,
                             quantized: bool = False, qmax: int = 0):
     """Returns grow(payload, aux, feature_mask[, qscale]) ->
@@ -171,7 +169,7 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
     [2] f32 (gradient, hessian) scale vector, dequantizing with
     `ops.split.dequantize_hist` exactly at the split-search boundary so
     the gain arithmetic is the f32 code unchanged.  Serial + mesh modes;
-    forced splits and the merged partition+hist kernel are f32-only.
+    forced splits are f32-only.
     """
     L = cfg.num_leaves
     B = num_bins_max
@@ -226,51 +224,26 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
                        hess_col=cols.hess, cnt_col=cols.cnt)
     if quantized:
         # f32-only machinery stays off the quantized path: forced splits
-        # read raw f32 hist views in their override, and the merged
-        # partition+hist kernel accumulates f32 (gbdt gates eligibility
-        # before building a quantized grower, so these are invariants)
+        # read raw f32 hist views in their override (gbdt gates
+        # eligibility before building a quantized grower, so these are
+        # invariants)
         assert forced is None, "quantized grower is unforced-only"
         assert qmax >= 2, "quantized grower needs the derive_qmax grid"
     # the real payload width reaches the VMEM gate: the kernel DMAs full
     # rows even when it histograms only the owned leading columns
     # (feature-parallel), so the num_features-based estimate under-budgeted
     # exactly where Ghist << payload_width
-    impl = seg.resolve_impl(cfg.hist_impl, Ghist, B, payload_width)
-    hist_engine = impl
+    hist_engine = seg.resolve_impl(cfg.hist_impl, Ghist, B, payload_width)
     if quantized:
-        from ..ops import pallas_segment as pseg
-        if (impl == "pallas" and pseg.HIST_QUANT_VALIDATED and qmax <= 127):
-            # staged int8 x one-hot -> int32 MXU kernel; bit-exact with
-            # the portable int engine (integer accumulation never rounds)
-            hist_fn = functools.partial(pseg.segment_histogram_quant,
-                                        **hist_kwargs)
-            hist_engine = "pallas-quant"
-        else:
-            hist_fn = functools.partial(seg.segment_histogram,
-                                        quantized=True, **hist_kwargs)
-            hist_engine = "lax"
-    elif impl == "pallas":
+        # the Pallas kernel sums f32 parts: int32 histograms of quantized
+        # gradients, like widths past its VMEM plan, take the lax engine
+        hist_engine = "lax"
+    if hist_engine == "pallas":
         from ..ops import pallas_segment as pseg
         hist_fn = functools.partial(pseg.segment_histogram, **hist_kwargs)
     else:
-        # ultra-wide payloads (raw Allstate 4228x256, Epsilon-dense) fall
-        # off the single-pass kernel's VMEM plan; the column-block sibling
-        # engine serves them once hardware-validated
-        from ..ops import pallas_segment as pseg
-        colblock = (cfg.hist_impl != "lax"
-                    and jax.default_backend() == "tpu"
-                    and pseg.HIST_COLBLOCK_VALIDATED
-                    and payload_width is not None
-                    and pseg.fits_vmem_colblock(
-                        Ghist, B, payload_width, cols.grad, cols.hess,
-                        cols.cnt))
-        if colblock:
-            hist_fn = functools.partial(pseg.segment_histogram_colblock,
-                                        **hist_kwargs)
-            hist_engine = "colblock"
-        else:
-            hist_fn = functools.partial(seg.segment_histogram, **hist_kwargs)
-            hist_engine = "lax"
+        hist_fn = functools.partial(seg.segment_histogram,
+                                    quantized=quantized, **hist_kwargs)
 
     def part_fn(payload, aux, start, count, pred, lv, rv):
         # the width is static at trace time; a caller that did not pass
@@ -286,8 +259,6 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
                   "pallas-blocks": pseg.partition_segment_acc_blocks}[part]
         return kernel(payload, aux, start, count, pred, lv, rv, cols.value, B)
 
-    pallas_part = (cfg.hist_impl != "lax"
-                   and jax.default_backend() == "tpu")
     #: what this build resolved, readable as ``grower.engines`` — the
     #: choice is made from platform and shape, never invisibly
     engines = {"histogram": hist_engine,
@@ -297,43 +268,6 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
              "(%d columns x %d bins, payload width %s)",
              engines["histogram"], engines["partition"] or "at first trace",
              Ghist, B, payload_width)
-
-    # ---- merged partition+hist mode (serial only): one kernel per split
-    # computes the partition AND both children's histograms from the same
-    # row pass — the parent histogram, subtraction trick and device
-    # histogram pool all retire (their roles fold into the partition walk;
-    # reference feature_histogram.hpp:505-826).  Auto = hardware-validated
-    # flag + pallas kernels + VMEM fit; tests may force it on the portable
-    # engines (partition, then walk each child's contiguous rows).
-    if merged_hist is None:
-        from ..ops import pallas_segment as _pseg
-        # the VMEM fit is part of the AUTO decision: a non-fitting shape
-        # would land on part_hist_fn's portable fallback, which walks BOTH
-        # children (strictly worse than smaller-child + subtraction)
-        merged_hist = (not meshed and pallas_part and impl == "pallas"
-                       and not quantized
-                       and _pseg.PARTITION_HIST_VALIDATED
-                       and payload_width is not None
-                       and _pseg.partition_hist_fits_vmem(payload_width,
-                                                          G, B))
-    merged_hist = bool(merged_hist) and not meshed and not quantized
-
-    if merged_hist:
-        from ..ops import pallas_segment as _pseg
-
-        def part_hist_fn(payload, aux, start, count, pred, lv, rv):
-            if (pallas_part and impl == "pallas"
-                    and _pseg.partition_hist_fits_vmem(
-                        payload.shape[1], G, B)):
-                return _pseg.partition_segment_hist(
-                    payload, aux, start, count, pred, lv, rv,
-                    cols.value, B, num_features=G, grad_col=cols.grad,
-                    hess_col=cols.hess, cnt_col=cols.cnt)
-            payload, aux, nl = part_fn(payload, aux, start, count, pred,
-                                       lv, rv)
-            hl = hist_fn(payload, start, nl)
-            hr = hist_fn(payload, start + nl, count - nl)
-            return payload, aux, nl, hl, hr
 
     def hist_view(hist_g):
         """[G, B, 3] bundle histogram -> [F, B, 3] per-feature split view."""
@@ -347,11 +281,8 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
     # parent was evicted recomputes it by walking the (still contiguous)
     # parent segment — cheap under the O(rows-touched) engine
     POOL = cfg.hist_pool_slots if 0 < cfg.hist_pool_slots < L else L
-    pooled = POOL < L and not merged_hist
-    if merged_hist:
-        POOL = 1   # no device hist state at all in merged mode
-    else:
-        assert POOL >= 2, "histogram pool needs at least 2 slots"
+    pooled = POOL < L
+    assert POOL >= 2, "histogram pool needs at least 2 slots"
 
     # ---- frontier batching (Config.tpu_frontier_batch > 1) --------------
     # A gain-ordered window of up to K frontier leaves is EVALUATED per
@@ -374,36 +305,15 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
     # contraction), and the ~1e-5 gain drift would break the batched
     # grower's byte-identical-model guarantee against the K = 1 grower
     stacked_find = not meshed and forced is None and not cfg.with_monotone
+    # on the lax engine only: the Pallas kernel takes one segment a call
+    # (2.17 us beyond its rows on the chip, PERF.md §5: nothing to batch)
     frontier_batched = (fb_req > 1 and L > 2 and stacked_find
-                       and not merged_hist and not pooled)
-    if frontier_batched and hist_engine == "pallas":
-        # staged OFF like the other TPU levers: the sequential grower
-        # stays the hardware-validated path until the batched kernel's
-        # Mosaic lowering is proven on a real chip (smoke FRONTIER
-        # section, then exp/flip_validated.py frontier)
-        from ..ops import pallas_segment as _pseg_fb
-        frontier_batched = _pseg_fb.FRONTIER_BATCH_VALIDATED
-    elif frontier_batched and quantized:
-        # quantized engines are bit-exact across dispatch shapes (integer
-        # accumulation never rounds), so the portable quantized batched
-        # engine serves every quantized config — including pallas-quant,
-        # which has no batched sibling (yet) — without an exactness gate
-        pass
-    elif frontier_batched and hist_engine != "lax":
-        frontier_batched = False   # no batched colblock sibling (yet)
+                        and not pooled and hist_engine == "lax")
     frontier_k = min(fb_req, L - 1) if frontier_batched else 1
     if frontier_batched:
-        if quantized:
-            hist_batched_fn = functools.partial(
-                seg.segment_histogram_batched, quantized=True,
-                **hist_kwargs)
-        elif hist_engine == "pallas":
-            from ..ops import pallas_segment as _pseg_fb2
-            hist_batched_fn = functools.partial(
-                _pseg_fb2.segment_histogram_batched, **hist_kwargs)
-        else:
-            hist_batched_fn = functools.partial(
-                seg.segment_histogram_batched, **hist_kwargs)
+        hist_batched_fn = functools.partial(
+            seg.segment_histogram_batched, quantized=quantized,
+            **hist_kwargs)
 
     if forced is not None:
         from .forced import make_forced_machinery
@@ -627,14 +537,12 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
             "internal_count": jnp.zeros(ni, jnp.float32),
             "num_leaves": jnp.int32(1),
         }
-        if not merged_hist:
-            # per-leaf (or pooled) histogram state exists only for the
-            # subtraction trick; merged mode gets both child histograms
-            # from the partition kernel itself.  int32 in quantized mode
-            # (the narrow-dtype plumbing: LRU slots, subtraction and the
-            # frontier-batch dispatch all carry the integer histograms)
-            state["hist"] = jnp.zeros((POOL, Gh, B, 3),
-                                      hist_root.dtype).at[0].set(hist_root)
+        # per-leaf (or pooled) histogram state for the subtraction trick.
+        # int32 in quantized mode (the narrow-dtype plumbing: LRU slots,
+        # subtraction and the frontier-batch dispatch all carry the
+        # integer histograms)
+        state["hist"] = jnp.zeros((POOL, Gh, B, 3),
+                                  hist_root.dtype).at[0].set(hist_root)
         if forced is not None:
             # pending forced rank per leaf, and the REAL (not priority) gain
             # of each leaf's stored best split, for honest split_gain records
@@ -684,67 +592,54 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
                           st["cnt"][best_leaf])
             rg, rh, rcnt = pg - lg, ph - lh, pc - lcnt
 
-            if merged_hist:
-                # one kernel: partition + BOTH children's histograms from
-                # the same row pass (no parent hist, no subtraction, no
-                # pool).  Serial-only, so reduce_hist is identity.
-                with phase("partition"):
-                    payload, aux, nl_raw, new_left, new_right = \
-                        part_hist_fn(
-                            st["payload"], st["aux"], start, count, pred,
-                            st["blo"][best_leaf], st["bro"][best_leaf])
-                nr_raw = count - nl_raw
-            else:
-                # parent histogram: read the pool slot, or rebuild it from
-                # the (still contiguous) parent segment if it was evicted
-                def rebuild_parent():
-                    with phase("hist"):
-                        h = hist_fn(st["payload"], start, count)
-                    return reduce_hist(h)
-
-                with phase("subtract"):
-                    if pooled:
-                        # NOTE: the rebuild branch runs a collective in
-                        # mesh modes; the pool bookkeeping is
-                        # replicated-in-value, so every shard takes the
-                        # same branch and the psum pairs up
-                        pslot = st["slot_of_leaf"][best_leaf]
-                        hist_parent = lax.cond(
-                            pslot >= 0,
-                            lambda: st["hist"][jnp.maximum(pslot, 0)],
-                            rebuild_parent)
-                    else:
-                        # read out before the pool is written: left for
-                        # the compiler to fuse, this slice is re-read
-                        # from the old pool inside the children's slot
-                        # writes, which then cannot happen in place, and
-                        # the whole pool is copied twice a split (0.39 GB
-                        # at 2,000 columns x 63 bins: 4.6 ms a split,
-                        # 1.18 s a tree; PERF.md §6, PR 26)
-                        hist_parent = lax.optimization_barrier(
-                            st["hist"][best_leaf])
-
-                with phase("partition"):
-                    payload, aux, nl_raw = part_fn(
-                        st["payload"], st["aux"], start, count, pred,
-                        st["blo"][best_leaf], st["bro"][best_leaf])
-                nr_raw = count - nl_raw
-
-                # histograms: build only the smaller child, derive the
-                # sibling by subtraction.  The choice uses masked counts
-                # (like grower.py and the reference's num_data comparison)
-                # so both growers build the direct histogram on the same
-                # child and stay bit-comparable.
-                left_smaller = lcnt <= rcnt
-                h_start = jnp.where(left_smaller, start, start + nl_raw)
-                h_count = jnp.where(left_smaller, nl_raw, nr_raw)
+            # parent histogram: read the pool slot, or rebuild it from the
+            # (still contiguous) parent segment if it was evicted
+            def rebuild_parent():
                 with phase("hist"):
-                    hist_small = hist_fn(payload, h_start, h_count)
-                hist_small = reduce_hist(hist_small)
-                with phase("subtract"):
-                    hist_big = hist_parent - hist_small
-                    new_left = jnp.where(left_smaller, hist_small, hist_big)
-                    new_right = jnp.where(left_smaller, hist_big, hist_small)
+                    h = hist_fn(st["payload"], start, count)
+                return reduce_hist(h)
+
+            with phase("subtract"):
+                if pooled:
+                    # NOTE: the rebuild branch runs a collective in mesh
+                    # modes; the pool bookkeeping is replicated-in-value,
+                    # so every shard takes the same branch and the psum
+                    # pairs up
+                    pslot = st["slot_of_leaf"][best_leaf]
+                    hist_parent = lax.cond(
+                        pslot >= 0,
+                        lambda: st["hist"][jnp.maximum(pslot, 0)],
+                        rebuild_parent)
+                else:
+                    # read out before the pool is written: left for the
+                    # compiler to fuse, this slice is re-read from the old
+                    # pool inside the children's slot writes, which then
+                    # cannot happen in place, and the whole pool is copied
+                    # twice a split (0.39 GB at 2,000 columns x 63 bins:
+                    # 4.6 ms a split, 1.18 s a tree; PERF.md §6, PR 26)
+                    hist_parent = lax.optimization_barrier(
+                        st["hist"][best_leaf])
+
+            with phase("partition"):
+                payload, aux, nl_raw = part_fn(
+                    st["payload"], st["aux"], start, count, pred,
+                    st["blo"][best_leaf], st["bro"][best_leaf])
+            nr_raw = count - nl_raw
+
+            # histograms: build only the smaller child, derive the sibling
+            # by subtraction.  The choice uses masked counts (like grower.py
+            # and the reference's num_data comparison) so both growers build
+            # the direct histogram on the same child and stay bit-comparable.
+            left_smaller = lcnt <= rcnt
+            h_start = jnp.where(left_smaller, start, start + nl_raw)
+            h_count = jnp.where(left_smaller, nl_raw, nr_raw)
+            with phase("hist"):
+                hist_small = hist_fn(payload, h_start, h_count)
+            hist_small = reduce_hist(hist_small)
+            with phase("subtract"):
+                hist_big = hist_parent - hist_small
+                new_left = jnp.where(left_smaller, hist_small, hist_big)
+                new_right = jnp.where(left_smaller, hist_big, hist_small)
             # the histogram pool's book-keeping and the two slot writes
             with phase("subtract"):
                 if pooled:
@@ -779,7 +674,7 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
                     slot_of_leaf = slot_of_leaf.at[s].set(rslot)
                     hist = st["hist"].at[lslot].set(new_left)
                     hist = hist.at[rslot].set(new_right)
-                elif not merged_hist:
+                else:
                     hist = st["hist"].at[best_leaf].set(new_left)
                     hist = hist.at[s].set(new_right)
 
@@ -841,8 +736,7 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
             st_new = dict(st)
             st_new["payload"] = payload
             st_new["aux"] = aux
-            if not merged_hist:
-                st_new["hist"] = hist
+            st_new["hist"] = hist
             if pooled:
                 st_new["slot_of_leaf"] = slot_of_leaf
                 st_new["leaf_of_slot"] = leaf_of_slot

@@ -98,9 +98,9 @@ class Config:
         # up to this many frontier leaves per round (one batched histogram
         # dispatch + one fused cross-leaf split search) and commits splits
         # in exact sequential argmax order — byte-identical models, fewer
-        # sequential rounds per tree.  1 keeps the classic one-leaf loop;
-        # the TPU pallas path additionally stages behind
-        # FRONTIER_BATCH_VALIDATED (docs/PERFORMANCE.md)
+        # sequential rounds per tree.  1 keeps the classic one-leaf loop,
+        # as does the Pallas histogram engine (the batched grower runs on
+        # the lax engine only)
         self.tpu_frontier_batch = 1
         # quantized-gradient training (Shi et al., NeurIPS 2022; ISSUE 2):
         # per-iteration int8/int16 gradient+hessian quantization with
@@ -108,9 +108,8 @@ class Config:
         # dequantize-at-the-split-boundary (ops/quantize.py).  Default
         # off: models stay byte-identical to f32 training.  The effective
         # grid is additionally capped by the int32 overflow bound
-        # (rows-per-leaf x max|q| < 2^31, checked at trace time); on a
-        # TPU pallas config the int8 MXU kernel stages behind
-        # HIST_QUANT_VALIDATED (docs/PERFORMANCE.md expiry table).
+        # (rows-per-leaf x max|q| < 2^31, checked at trace time); the
+        # int32 histograms are built by the lax engine on every platform.
         self.gradient_quantization = False
         self.gradient_quant_dtype = "int16"  # int16 | int8
         # non-finite sentinel policy (runtime/resilience.py, ISSUE 4):

@@ -14,13 +14,20 @@ kernels keep every one-hot in VMEM:
   rows in lanes.  Mirrors the role of the reference OpenCL kernels
   (src/treelearner/ocl/histogram256.cl:73-121 and the 16/64 variants) —
   the B<=256/64/16 specialization falls out of the static num_bins arg.
-- `partition_segment`: the three compact passes of
-  `ops.segment.partition_segment` fused into one kernel; each chunk's
-  stable compaction is a one-hot permutation matmul in VMEM, appended to
-  the scratch buffer by a dynamic-offset DMA, then blended back.
+- `partition_segment_acc`: the three compact passes of
+  `ops.segment.partition_segment` fused into one kernel (`_acc_kernel`);
+  each chunk's stable partition is a one-hot permutation matmul in VMEM,
+  rotated into per-side accumulator windows that flush whole aligned
+  chunks.  `partition_segment_acc_blocks` runs the same kernel once a
+  512-lane window of a payload too wide for one pass, routing every pass
+  from a snapshot of the split column (`_snap_window_kernel`);
+  `partition_segment` (`_partition_kernel`) is the older read-modify-write
+  kernel, the only one whose plan fits between the two (640-1,792 lanes).
 
-Both kernels alias payload/aux in/out so no copy of the [N, P] training
-state is ever made.
+The module holds what `grower2.partition_engine` and
+`ops.segment.resolve_impl` choose from shape and platform, and nothing
+else.  The partition kernels alias payload/aux in/out so no copy of the
+[N, P] training state is ever made.
 """
 from __future__ import annotations
 
@@ -40,15 +47,6 @@ from .split import MISSING_NAN, MISSING_ZERO
 #: writes must never be DCE'd or reordered
 _SIDE_EFFECTS = pltpu.CompilerParams(has_side_effects=True)
 
-# per-tile one-hot budget of the staged sibling kernels (batched, quantized,
-# column-block, merged; `segment_histogram` itself builds no B-wide
-# one-hot since PR 27): the expand and one-hot intermediates over one
-# FEATURE TILE are each [CHUNK, ~TILE_FB] f32 (2 MB).  Features are tiled
-# so any F streams through the same VMEM window — the role of the
-# workgroup grid in the reference OpenCL kernels
-# (ocl/histogram256.cl:73-121).
-TILE_FB = 2048
-
 #: VMEM the kernel may plan for (chip has ~16 MB/core; leave headroom for
 #: the compiler's own buffers)
 _VMEM_BUDGET = 13 * 2**20
@@ -56,13 +54,6 @@ _VMEM_BUDGET = 13 * 2**20
 
 def _pad128(n: int) -> int:
     return -(-n // 128) * 128
-
-
-def _tiling(num_features: int, num_bins: int):
-    """(features-per-tile, tile count, padded one-hot width)."""
-    ft = max(1, min(num_features, TILE_FB // num_bins))
-    n_tiles = -(-num_features // ft)
-    return ft, n_tiles, _pad128(ft * num_bins)
 
 
 #: feature groups a loop trip of the histogram kernel takes.  A group's
@@ -139,122 +130,29 @@ def fits_vmem(num_features: int, num_bins: int,
     return est <= _VMEM_BUDGET
 
 
-#: True once exp/smoke_tpu_kernels has validated the accumulator-window
-#: partition kernel on real hardware; until then the RMW kernel stays the
-#: product default (round 4's lesson: interpret mode proves nothing about
-#: Mosaic legality).
-PARTITION_ACC_VALIDATED = True
+#: slots (groups of chunks in pass A, chunks in pass B) of the accumulator
+#: partition's read ring: the one in use and one in flight.  The body runs
+#: at its dependent chain's latency, not the DMA's (PERF.md §6, PR 25); a
+#: ring of four was exact on the chip and read no win for its VMEM.
+_RING_DEPTH = 2
 
-#: True once the repeat-based one-hot expansion is hardware-validated: the
-#: expand matmul of a B-wide one-hot becomes a lane-repeat relayout, in a
-#: bin-major tiled layout that the host epilogue transposes back.  Since
-#: PR 27 it decides the expand of the staged sibling kernels alone
-#: (batched, column-block, merged): `segment_histogram` builds its one-hots
-#: with rows in lanes and has no expand.  Either expand was most of the old
-#: body (PERF.md §6, PR 27: the repeat 58% of a chunk at 28 x 256, the
-#: matmul 74-75% at 67 x 256 and 2,000 x 64).
-HIST_REPEAT_VALIDATED = True
-
-#: True once the roll-based placement inside the accumulator kernel is
-#: hardware-validated: a dynamic sublane rotate replaces the [2C, C]
-#: placement one-hot — pass A's matmul halves to [C, C] compaction and
-#: pass B's placement becomes a pure (exact, matmul-free) data movement.
-PARTITION_ACC_ROLL_VALIDATED = True
+#: lanes a pass of the column-block partition moves: the widest multiple
+#: of 128 whose accumulator plan fits VMEM (11.8 MB of 13 at one chunk a
+#: trip; 640 lanes would plan 14.2), since what does not grow with the
+#: width (routing, rank, one-hot) is paid once a pass (PERF.md §5).
+_BLOCK_WIDTH = 512
 
 
-#: True once the 4-deep read ring is hardware-validated for the
-#: accumulator partition kernel (and its merged variant).  The validated
-#: default is the 2-deep ring: prefetch issues one chunk ahead, so a DMA
-#: latency longer than one chunk's compute stalls every iteration —
-#: round 4 measured the kernel latency-bound at ~2% of HBM bandwidth.
-#: Depth 4 issues three chunks ahead (ring slots are a parameter, the
-#: instruction mix is unchanged), trading 2*C*P*4 bytes of VMEM for up
-#: to 3x more latency hiding.  OFF until the smoke's RING section
-#: proves it on a real chip and races the depths.
-PARTITION_RING4_VALIDATED = False
-
-
-#: True once the BATCHED segment-histogram kernel (frontier-batched tree
-#: growth: one grid-(K,) dispatch builds K smaller-child histograms) is
-#: hardware-validated.  The kernel is a grid-indexed sibling of
-#: _hist_kernel as it was before PR 27 (a B-wide one-hot a feature,
-#: scalars read at 2*program_id) — the multi-step grid over a scalar-prefetch
-#: spec is the one pattern in this family not yet proven on a chip.
-#: While OFF, a TPU pallas config keeps the SEQUENTIAL grower even when
-#: Config.tpu_frontier_batch > 1 (the CPU/lax path batches regardless —
-#: exactness is proven there by the byte-identical-model tests).
-FRONTIER_BATCH_VALIDATED = False
-
-#: True once the QUANTIZED histogram kernel (gradient_quantization mode:
-#: int8 value rows x int8 one-hot -> int32 MXU accumulation, up to 4x the
-#: f32 contraction throughput and no bf16 part decomposition) is
-#: hardware-validated.  The kernel's instruction mix differs from the
-#: validated f32 family in exactly one way — the s8xs8->s32 dot_general —
-#: which is the one pattern not yet proven legal under Mosaic on a real
-#: chip.  While OFF, quantized training on a TPU pallas config builds its
-#: int32 histograms through the portable lax engine instead (bit-exact
-#: with this kernel by construction: integer accumulation never rounds).
-HIST_QUANT_VALIDATED = False
-
-#: staged-flag registry: verdict/flip name -> module flag.  Shared by
-#: exp/flip_validated.py (human flips) and exp/smoke_tpu_kernels.py
-#: (verdict names) so the two can never disagree on names.
-STAGED_FLAGS = {
-    "merged": "PARTITION_HIST_VALIDATED",
-    "colblock": "HIST_COLBLOCK_VALIDATED",
-    "ring4": "PARTITION_RING4_VALIDATED",
-    "frontier": "FRONTIER_BATCH_VALIDATED",
-    "quant": "HIST_QUANT_VALIDATED",
-}
-
-
-def _ring_depth_default() -> int:
-    """Single source of the flag-to-depth mapping (kernels + VMEM gates
-    must agree on the scratch the flag buys)."""
-    return 4 if PARTITION_RING4_VALIDATED else 2
-
-
-#: True once the COLUMN-BLOCK histogram engine is hardware-validated: it
-#: serves ultra-wide payloads (raw Allstate 4228x256, Epsilon-dense 2000
-#: cols) that overflow the single-pass kernel's VMEM plan, by running the
-#: sibling kernel once per 128-aligned feature-column block — each pass
-#: DMAs only its own lane windows (block + aux columns), so total HBM
-#: traffic matches the single-pass kernel while VMEM stays bounded by the
-#: block width.  OFF until exp/smoke_tpu_kernels.py proves the Mosaic
-#: lowering on a real chip (round-4 discipline: interpret mode proves
-#: nothing about Mosaic legality, esp. the two-window DMA).
-HIST_COLBLOCK_VALIDATED = False
-
-#: feature-column block width (payload lanes) for the column-block engine;
-#: 128-aligned by construction.  512 keeps the per-pass plan ~10 MB at
-#: B=256 (64 tiles * 2048 accumulator + block/aux chunk buffers).
-COLBLOCK_WIDTH = 512
-
-
-#: True once the merged partition+histogram kernel is hardware-validated:
-#: pass A of the accumulator partition already has every parent row in
-#: VMEM, so BOTH children's histograms fall out of one shared one-hot per
-#: tile (only the [8, C] value rows differ by side mask) — the separate
-#: per-split histogram kernel, its row reads, the parent histogram, the
-#: subtraction trick and the device histogram pool all become dead code.
-#: OFF until exp/smoke_tpu_kernels.py proves the Mosaic lowering on a
-#: real chip (round-4 discipline).
-PARTITION_HIST_VALIDATED = False
-
-
-def _acc_plan_bytes(payload_width: int, num_bins: int, ring_depth: int,
-                    group: int) -> int:
+def _acc_plan_bytes(payload_width: int, num_bins: int, group: int) -> int:
     """VMEM plan of the accumulator-window partition kernel with pass A
-    taking `group` chunks a loop trip: the read ring (`ring_depth` groups
+    taking `group` chunks a loop trip: the read ring (`_RING_DEPTH` groups
     of chunks), two [2C, P] accumulators, stage/blend buffers, the P-wide
-    placement intermediates of each chunk in flight (budgeted for the
-    LARGER of the two placement modes — roll mode keeps parts + permuted +
-    doubled + rolled buffers live, ~10C rows), the [C, C] machinery (`tri`
-    and the row iota once, a one-hot and its relayouts a chunk in flight;
-    the matmul mode's [2C, C] pair fits the same 8C*C at group 1) and the
-    categorical bitset one-hot of each chunk's routing."""
+    placement intermediates of each chunk in flight (parts + permuted +
+    doubled + rolled buffers, ~10C rows), the [C, C] machinery (`tri` and
+    the row iota once, a one-hot and its relayouts a chunk in flight) and
+    the categorical bitset one-hot of each chunk's routing."""
     P, C = payload_width, CHUNK
-    return (4 * P * C * (ring_depth * group    # ring
+    return (4 * P * C * (_RING_DEPTH * group   # ring
                          + 6                   # accs(4C) + stage/rbuf(2C)
                          + 10 * group)         # placement intermediates
             + 4 * C * C * (6 + 2 * group)
@@ -273,47 +171,22 @@ def _acc_plan_bytes(payload_width: int, num_bins: int, ring_depth: int,
 _PASS_A_GROUP = 2
 
 
-def _pass_a_group(payload_width: int, num_bins: int, ring_depth: int,
+def _pass_a_group(payload_width: int, num_bins: int,
                   extra_bytes: int = 0) -> int:
     """Chunks a trip of pass A: `_PASS_A_GROUP` where its VMEM plan fits
-    beside `extra_bytes` (the merged kernel's histogram machinery), else
-    1, the plan the fits_vmem gates admit or refuse.  More chunks in
-    flight need more VMEM, so the width of the payload decides."""
-    fits = (_acc_plan_bytes(payload_width, num_bins, ring_depth,
-                            _PASS_A_GROUP) + extra_bytes <= _VMEM_BUDGET)
+    beside `extra_bytes` (a column block's split-window ring), else 1,
+    the plan the fits_vmem gates admit or refuse.  More chunks in flight
+    need more VMEM, so the width of the payload decides."""
+    fits = (_acc_plan_bytes(payload_width, num_bins, _PASS_A_GROUP)
+            + extra_bytes <= _VMEM_BUDGET)
     return _PASS_A_GROUP if fits else 1
 
 
-def _hist_plan_bytes(num_features: int, num_bins: int) -> int:
-    """What the merged kernel plans beside the partition: the histogram
-    tile machinery and TWO [8T, W] part-accumulators (left + right)."""
-    ft, n_tiles, w = _tiling(num_features, num_bins)
-    return (2 * 4 * CHUNK * w              # expand/rep + one-hot tile
-            + 2 * 4 * 8 * n_tiles * w      # two child accumulators
-            + 4 * ft * w)                  # window expander
-
-
-def partition_hist_fits_vmem(payload_width: int, num_features: int,
-                             num_bins: int) -> bool:
-    """VMEM plan of the merged partition+histogram kernel: the acc
-    partition's plan plus the histogram's.  Higgs/MS-LTR shapes fit;
-    Expo-wide accumulators (88 tiles) overflow and fall back to the
-    split kernels."""
-    if num_bins > 256:
-        return False
-    return (_acc_plan_bytes(payload_width, num_bins, _ring_depth_default(), 1)
-            + _hist_plan_bytes(num_features, num_bins)) <= _VMEM_BUDGET
-
-
-def partition_acc_fits_vmem(payload_width: int, num_bins: int,
-                            ring_depth: int = None) -> bool:
+def partition_acc_fits_vmem(payload_width: int, num_bins: int) -> bool:
     """True when the accumulator-window partition kernel's VMEM plan fits
     with pass A one chunk a trip (narrower payloads take more,
     `_pass_a_group`)."""
-    if ring_depth is None:
-        ring_depth = _ring_depth_default()
-    return _acc_plan_bytes(payload_width, num_bins, ring_depth,
-                           1) <= _VMEM_BUDGET
+    return _acc_plan_bytes(payload_width, num_bins, 1) <= _VMEM_BUDGET
 
 
 def partition_fits_vmem(payload_width: int, num_bins: int) -> bool:
@@ -599,36 +472,12 @@ def _hist_kernel(scalars, payload_hbm, out_ref, chunk, sem, hi_rows, lo_rows,
     _in_trips(out_ref.shape[0] // R, 8, regroup)
 
 
-#: widest F*B the repeat expansion is the staged sibling kernels' default
-#: for.  The round-4 hardware race (exp/smoke_tpu_kernels.py, fetch-forced
-#: medians at 8192 rows): repeat wins at 28x256 (79.8 vs 91.8 ms), washes
-#: at 137x256 (133.3 vs 131.2), loses at 700x256 (304.0 vs 252.9) — the
-#: bin-major epilogue's per-tile untranspose grows with the tile count.
-REPEAT_MAX_FB = 16384
-
-
-def _default_expand_impl(num_features: int, num_bins: int) -> str:
-    """Shared flag+shape default for every kernel with a one-hot expand
-    stage (the staged siblings; `segment_histogram` has none); resolved
-    OUTSIDE the jit caches so a flag flip takes effect on warm traces."""
-    return ("repeat" if HIST_REPEAT_VALIDATED
-            and num_features * num_bins <= REPEAT_MAX_FB else "matmul")
-
-
-def segment_histogram(payload, start, count, *, num_features, num_bins,
-                      grad_col, hess_col, cnt_col, interpret=False):
-    """hist[F, B, 3] over payload rows [start, start+count) — TPU kernel."""
-    return _segment_histogram(payload, start, count,
-                              num_features=num_features, num_bins=num_bins,
-                              grad_col=grad_col, hess_col=hess_col,
-                              cnt_col=cnt_col, interpret=interpret)
-
-
-@functools.partial(xla_obs.jit, site="pallas.segment_histogram", static_argnames=("num_features", "num_bins",
-                                             "grad_col", "hess_col",
-                                             "cnt_col", "interpret"))
+@functools.partial(xla_obs.jit, site="pallas.segment_histogram",
+                   static_argnames=("num_features", "num_bins", "grad_col",
+                                    "hess_col", "cnt_col", "interpret"))
 def _segment_histogram(payload, start, count, *, num_features, num_bins,
-                       grad_col, hess_col, cnt_col, interpret):
+                       grad_col, hess_col, cnt_col, interpret=False):
+    """hist[F, B, 3] over payload rows [start, start+count) — TPU kernel."""
     F, B, P = num_features, num_bins, payload.shape[1]
     H = _hist_factor(B)[1]
     scalars = jnp.stack([start, count]).astype(jnp.int32)
@@ -655,6 +504,13 @@ def _segment_histogram(payload, start, count, *, num_features, num_bins,
     return _unfactor_hist(out, F, B)
 
 
+#: the jitted functions keep their underscore: the profiler names a
+#: kernel's custom call after its jitted wrapper, and the benchmark's trace
+#: reader matches `_segment_histogram`, `_partition_segment_acc` and
+#: `_partition_segment_acc_blocks`
+segment_histogram = _segment_histogram
+
+
 def _unfactor_hist(out, F, B):
     """[groups * 8H, 128] kernel accumulator -> [F, B, 3].  A group's 8H
     rows are H blocks of the 8 parts, its H * 128 numbers a part the
@@ -667,558 +523,6 @@ def _unfactor_hist(out, F, B):
                      r[:, :, 3] + r[:, :, 4] + r[:, :, 5],
                      r[:, :, 6]])                          # [3, n, H, 128]
     return ghc.reshape(3, -1, H * L)[:, :F, :B].transpose(1, 2, 0)
-
-
-def _untile_hist(out, F, B, Ft, n_tiles, W, expand_impl):
-    """[8*T, W] kernel accumulator -> [F, B, 3].  Rows are the exact bf16
-    part-decomposition (g_hi, g_mid, g_lo, h_hi, h_mid, h_lo, cnt) —
-    recombine, then untile (feature-major windows in matmul mode,
-    bin-major [B, fw] blocks in repeat mode)."""
-    r = out.reshape(n_tiles, 8, W)
-    ghc = jnp.stack([r[:, 0] + r[:, 1] + r[:, 2],
-                     r[:, 3] + r[:, 4] + r[:, 5],
-                     r[:, 6]], axis=1)                           # [T, 3, W]
-    if expand_impl == "repeat":
-        tiles = []
-        for t in range(n_tiles):
-            fw = min(Ft, F - t * Ft)
-            tiles.append(ghc[t, :, :fw * B].reshape(3, B, fw)
-                         .transpose(0, 2, 1))                    # [3, fw, B]
-        return jnp.concatenate(tiles, axis=1).transpose(1, 2, 0)
-    return (ghc[:, :, :Ft * B]
-            .reshape(n_tiles, 3, Ft, B).transpose(1, 0, 2, 3)
-            .reshape(3, n_tiles * Ft, B)[:, :F].transpose(1, 2, 0))
-
-
-# ---------------------------------------------------------------------------
-# batched histogram (frontier-batched growth: K segments, one dispatch)
-# ---------------------------------------------------------------------------
-
-def _hist_batched_kernel(scalars, payload_hbm, out_ref, chunk, sem, *,
-                         F, B, Ft, W, grad_col, hess_col, cnt_col,
-                         expand_impl="matmul"):
-    """Grid-(K,) sibling of _hist_kernel: grid step i builds segment i's
-    histogram from scalars[2i] / scalars[2i+1] into its own out block.
-    A body of its own, and since PR 27 the older one: the staged siblings
-    (this, the column-block and the merged kernel) keep the B-wide one-hot
-    a feature that _hist_kernel had before it factored the bin id, run by
-    no cell until C1 decides them (test_hist_batched_matches_portable pins
-    this one against the portable engine in interpret mode; the smoke's
-    FRONTIER section must prove the Mosaic lowering — the multi-step grid
-    over scalar prefetch — before the flag flips).
-
-    The B-wide one-hot machinery, built once before the chunk loop:
-    E[f, j] = 1 iff column j lies in tile-local feature f's B-wide window;
-    expanding a [C, Ft] tile of bin values through E on the MXU broadcasts
-    each feature's bin across its window, and a single [C, W] compare
-    against the within-window offset finishes the one-hot (`matmul`), or
-    `pltpu.repeat` concatenates B copies of the tile so that column
-    b*fw + f compares feature f's bin against b (`repeat`, bin-major, the
-    host epilogue untransposes).  A ragged last tile row-slices E: its
-    junk window columns land past Ft*B or in windows of features >= F,
-    both discarded by the host-side slice.  The value rows are the exact
-    bf16 decomposition described in _hist_kernel."""
-    i = pl.program_id(0)
-    start = scalars[2 * i]
-    count = scalars[2 * i + 1]
-    shift = lax.rem(start, 8)
-    base = start - shift
-    nch = jnp.where(count > 0, (shift + count + CHUNK - 1) // CHUNK, 0)
-    n_tiles = -(-F // Ft)
-    out_ref[0] = jnp.zeros(out_ref.shape[1:], out_ref.dtype)
-    iota_rows = _row_iota()
-
-    def dma_for(k, slot):
-        return pltpu.make_async_copy(
-            payload_hbm.at[pl.ds(pl.multiple_of(base + k * CHUNK, 8),
-                                 CHUNK), :],
-            chunk.at[slot], sem.at[slot])
-
-    @pl.when(nch > 0)
-    def _prefetch_first():
-        dma_for(0, 0).start()
-
-    if expand_impl == "repeat":
-        jdivs = {}
-        for t in range(n_tiles):
-            fw = min(Ft, F - t * Ft)
-            if fw not in jdivs:
-                jdivs[fw] = (lax.broadcasted_iota(jnp.int32, (1, fw * B), 1)
-                             // fw).astype(jnp.float32)
-    if expand_impl == "matmul":
-        iota_fr = lax.broadcasted_iota(jnp.int32, (Ft, W), 0)
-        iota_fc = lax.broadcasted_iota(jnp.int32, (Ft, W), 1)
-        d = iota_fc - iota_fr * B
-        in_win = (d >= 0) & (d < B)
-        E = in_win.astype(jnp.float32)                           # [Ft, W]
-        jmod = jnp.sum(jnp.where(in_win, d, 0), axis=0)          # [W] i32
-        jmod_f = jmod.astype(jnp.float32)
-
-    def body(k, _):
-        slot = lax.rem(k, 2)
-
-        @pl.when(k + 1 < nch)
-        def _prefetch_next():
-            dma_for(k + 1, lax.rem(k + 1, 2)).start()
-
-        dma_for(k, slot).wait()
-        data = chunk[slot]
-        ok = ((iota_rows >= shift - k * CHUNK) &
-              (iota_rows < shift + count - k * CHUNK)).astype(jnp.float32)
-        P = data.shape[1]
-        iota_r8 = lax.broadcasted_iota(jnp.int32, (8, P), 0)
-        iota_pc = lax.broadcasted_iota(jnp.int32, (8, P), 1)
-        sel = (((iota_r8 < 3) & (iota_pc == grad_col)) |
-               ((iota_r8 >= 3) & (iota_r8 < 6) & (iota_pc == hess_col)) |
-               ((iota_r8 == 6) & (iota_pc == cnt_col))).astype(jnp.float32)
-        raw = lax.dot_general(
-            sel, data, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=lax.Precision.HIGHEST)                     # [8, C]
-        hi = raw.astype(jnp.bfloat16).astype(jnp.float32)
-        r1 = raw - hi
-        mid = r1.astype(jnp.bfloat16).astype(jnp.float32)
-        lo = r1 - mid
-        rr = lax.broadcasted_iota(jnp.int32, raw.shape, 0)
-        vals = jnp.where((rr == 0) | (rr == 3), hi,
-                         jnp.where((rr == 1) | (rr == 4), mid,
-                                   jnp.where((rr == 2) | (rr == 5), lo,
-                                             raw)))
-        vals = vals * ok[None, :]
-        for t in range(n_tiles):
-            f0 = t * Ft
-            fw = min(Ft, F - f0)
-            binsf = data[:, f0:f0 + fw]                          # [C, fw] f32
-            if expand_impl == "repeat":
-                rep = pltpu.repeat(binsf, B, axis=1)             # [C, fw*B]
-                onehot = (rep == jdivs[fw]).astype(jnp.float32)
-                out_ref[0, 8 * t:8 * t + 8, :fw * B] += lax.dot_general(
-                    vals, onehot,
-                    dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)          # [8, fw*B]
-            else:
-                expand = lax.dot_general(
-                    binsf, E[:fw, :],
-                    dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)          # [C, W]
-                onehot = (expand == jmod_f[None, :]).astype(jnp.float32)
-                out_ref[0, 8 * t:8 * t + 8, :] += lax.dot_general(
-                    vals, onehot,
-                    dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)          # [8, W]
-        return 0
-
-    lax.fori_loop(0, nch, body, 0)
-
-
-def segment_histogram_batched(payload, starts, counts, *, num_features,
-                              num_bins, grad_col, hess_col, cnt_col,
-                              interpret=False, expand_impl=None):
-    """hist[K, F, B, 3] over K disjoint segments in ONE pallas dispatch —
-    the frontier-batched grower's multi-leaf histogram engine (contract of
-    segment.segment_histogram_batched)."""
-    if expand_impl is None:
-        expand_impl = _default_expand_impl(num_features, num_bins)
-    if expand_impl not in ("matmul", "repeat"):
-        raise ValueError("expand_impl must be matmul|repeat, got %r"
-                         % (expand_impl,))
-    return _segment_histogram_batched(
-        payload, starts, counts, num_features=num_features,
-        num_bins=num_bins, grad_col=grad_col, hess_col=hess_col,
-        cnt_col=cnt_col, num_segments=int(starts.shape[0]),
-        interpret=interpret, expand_impl=expand_impl)
-
-
-@functools.partial(xla_obs.jit, site="pallas.segment_histogram_batched", static_argnames=("num_features", "num_bins",
-                                             "grad_col", "hess_col",
-                                             "cnt_col", "num_segments",
-                                             "interpret", "expand_impl"))
-def _segment_histogram_batched(payload, starts, counts, *, num_features,
-                               num_bins, grad_col, hess_col, cnt_col,
-                               num_segments, interpret, expand_impl):
-    F, B, P = num_features, num_bins, payload.shape[1]
-    K = num_segments
-    Ft, n_tiles, W = _tiling(F, B)
-    scalars = jnp.stack([starts, counts], axis=1).reshape(-1).astype(
-        jnp.int32)                                               # [2K]
-    kern = functools.partial(_hist_batched_kernel, F=F, B=B, Ft=Ft, W=W,
-                             grad_col=grad_col, hess_col=hess_col,
-                             cnt_col=cnt_col, expand_impl=expand_impl)
-    out = pl.pallas_call(
-        kern,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(K,),
-            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((1, 8 * n_tiles, W),
-                                   lambda i, s_ref: (i, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((2, CHUNK, P), jnp.float32),
-                pltpu.SemaphoreType.DMA((2,)),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((K, 8 * n_tiles, W), jnp.float32),
-        interpret=interpret,
-    )(scalars, payload)
-    return jax.vmap(
-        lambda o: _untile_hist(o, F, B, Ft, n_tiles, W, expand_impl))(out)
-
-
-# ---------------------------------------------------------------------------
-# quantized histogram (gradient_quantization: int8 x one-hot -> int32 MXU)
-# ---------------------------------------------------------------------------
-
-def _hist_quant_kernel(scalars, payload_hbm, out_ref, chunk, sem, *,
-                       F, B, Ft, W, grad_col, hess_col, cnt_col):
-    """Sibling of _hist_kernel for QUANTIZED payloads (ops.quantize): the
-    grad/hess columns hold integer values in [-127, 127], so the whole
-    bf16 hi/mid/lo decomposition retires — the value rows and the one-hot
-    are both int8-representable and ONE s8xs8->s32 dot_general per tile
-    accumulates the exact int32 histogram at up to 4x the f32 MXU
-    throughput.  A sibling copy, not a parametrization of _hist_kernel,
-    per the family discipline (the validated kernel must not be
-    restructured blind); matmul expand only — the repeat relayout's int8
-    interaction is unproven and buys nothing here (the expand matmul it
-    removes is the f32 family's overhead, already halved by dropping the
-    part rows)."""
-    start = scalars[0]
-    count = scalars[1]
-    shift = lax.rem(start, 8)
-    base = start - shift
-    nch = jnp.where(count > 0, (shift + count + CHUNK - 1) // CHUNK, 0)
-    n_tiles = -(-F // Ft)
-    out_ref[:] = jnp.zeros(out_ref.shape, out_ref.dtype)
-    iota_rows = _row_iota()
-
-    def dma_for(k, slot):
-        return pltpu.make_async_copy(
-            payload_hbm.at[pl.ds(pl.multiple_of(base + k * CHUNK, 8),
-                                 CHUNK), :],
-            chunk.at[slot], sem.at[slot])
-
-    @pl.when(nch > 0)
-    def _prefetch_first():
-        dma_for(0, 0).start()
-
-    iota_fr = lax.broadcasted_iota(jnp.int32, (Ft, W), 0)
-    iota_fc = lax.broadcasted_iota(jnp.int32, (Ft, W), 1)
-    d = iota_fc - iota_fr * B
-    in_win = (d >= 0) & (d < B)
-    E = in_win.astype(jnp.float32)                               # [Ft, W]
-    jmod = jnp.sum(jnp.where(in_win, d, 0), axis=0)              # [W] i32
-    jmod_f = jmod.astype(jnp.float32)
-
-    def body(k, _):
-        slot = lax.rem(k, 2)
-
-        @pl.when(k + 1 < nch)
-        def _prefetch_next():
-            dma_for(k + 1, lax.rem(k + 1, 2)).start()
-
-        dma_for(k, slot).wait()
-        data = chunk[slot]
-        ok = ((iota_rows >= shift - k * CHUNK) &
-              (iota_rows < shift + count - k * CHUNK)).astype(jnp.float32)
-        P = data.shape[1]
-        iota_r8 = lax.broadcasted_iota(jnp.int32, (8, P), 0)
-        iota_pc = lax.broadcasted_iota(jnp.int32, (8, P), 1)
-        sel = (((iota_r8 == 0) & (iota_pc == grad_col)) |
-               ((iota_r8 == 1) & (iota_pc == hess_col)) |
-               ((iota_r8 == 2) & (iota_pc == cnt_col))).astype(jnp.float32)
-        raw = lax.dot_general(
-            sel, data, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=lax.Precision.HIGHEST)                     # [8, C]
-        vals_i8 = (raw * ok[None, :]).astype(jnp.int8)           # exact: |q|<=127
-        for t in range(n_tiles):
-            f0 = t * Ft
-            fw = min(Ft, F - f0)
-            binsf = data[:, f0:f0 + fw]                          # [C, fw] f32
-            expand = lax.dot_general(
-                binsf, E[:fw, :],
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)              # [C, W]
-            onehot = (expand == jmod_f[None, :]).astype(jnp.int8)
-            out_ref[8 * t:8 * t + 8, :] += lax.dot_general(
-                vals_i8, onehot,
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.int32)                # [8, W] i32
-        return 0
-
-    lax.fori_loop(0, nch, body, 0)
-
-
-def segment_histogram_quant(payload, start, count, *, num_features,
-                            num_bins, grad_col, hess_col, cnt_col,
-                            interpret=False):
-    """int32 hist[F, B, 3] over payload rows [start, start+count) whose
-    grad/hess columns carry int8-range quantized values — TPU kernel
-    contract of `segment.segment_histogram(..., quantized=True)` (staged
-    behind HIST_QUANT_VALIDATED; callers must ensure qmax <= 127, the
-    int8 value-row range — grower2 falls back to the portable int engine
-    for wider grids)."""
-    return _segment_histogram_quant(
-        payload, start, count, num_features=num_features, num_bins=num_bins,
-        grad_col=grad_col, hess_col=hess_col, cnt_col=cnt_col,
-        interpret=interpret)
-
-
-@functools.partial(xla_obs.jit, site="pallas.segment_histogram_quant", static_argnames=("num_features", "num_bins",
-                                             "grad_col", "hess_col",
-                                             "cnt_col", "interpret"))
-def _segment_histogram_quant(payload, start, count, *, num_features,
-                             num_bins, grad_col, hess_col, cnt_col,
-                             interpret):
-    F, B, P = num_features, num_bins, payload.shape[1]
-    Ft, n_tiles, W = _tiling(F, B)
-    scalars = jnp.stack([start, count]).astype(jnp.int32)
-    kern = functools.partial(_hist_quant_kernel, F=F, B=B, Ft=Ft, W=W,
-                             grad_col=grad_col, hess_col=hess_col,
-                             cnt_col=cnt_col)
-    out = pl.pallas_call(
-        kern,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(1,),
-            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-            scratch_shapes=[
-                pltpu.VMEM((2, CHUNK, P), jnp.float32),
-                pltpu.SemaphoreType.DMA((2,)),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((8 * n_tiles, W), jnp.int32),
-        interpret=interpret,
-    )(scalars, payload)
-    # epilogue: rows (0, 1, 2) of each tile are the (g, h, cnt) int32 sums
-    # — no part recombination, just the feature-major untile
-    r = out.reshape(n_tiles, 8, W)[:, :3, :Ft * B]               # [T, 3, Ft*B]
-    return (r.reshape(n_tiles, 3, Ft, B).transpose(1, 0, 2, 3)
-            .reshape(3, n_tiles * Ft, B)[:, :F].transpose(1, 2, 0))
-
-
-# ---------------------------------------------------------------------------
-# column-block histogram engine (ultra-wide payloads)
-# ---------------------------------------------------------------------------
-
-def colblock_plan(num_features: int, num_bins: int, payload_width: int,
-                  grad_col: int, hess_col: int, cnt_col: int):
-    """Lane-window plan for the column-block engine, or None.
-
-    Returns (blocks, aux_lo, aux_w): blocks is [(col_lo, fcount, width)]
-    with col_lo/width multiples of 128 (Mosaic DMA slices span whole lane
-    tiles), and [aux_lo, aux_lo+aux_w) covers the grad/hess/cnt lanes."""
-    if num_bins > 256:
-        return None
-    P = payload_width
-    if P % 128 != 0:
-        # the engine slices lane windows; the training payload is always
-        # lane-padded on TPU (_FastState.P), so this only excludes ad-hoc
-        # callers, who keep the single-pass kernel or the portable path
-        return None
-    lo = min(grad_col, hess_col, cnt_col)
-    hi = max(grad_col, hess_col, cnt_col) + 1
-    aux_lo = (lo // 128) * 128
-    aux_w = -(-(hi - aux_lo) // 128) * 128
-    if aux_lo + aux_w > P or num_features > P:
-        return None
-    blocks = []
-    c = 0
-    while c < num_features:
-        bw = min(COLBLOCK_WIDTH, P - c)
-        blocks.append((c, min(num_features - c, bw), bw))
-        c += bw
-    return blocks, aux_lo, aux_w
-
-
-def fits_vmem_colblock(num_features: int, num_bins: int, payload_width: int,
-                       grad_col: int, hess_col: int, cnt_col: int) -> bool:
-    """True when every per-block pass of the column-block engine fits the
-    VMEM budget (same cost model as fits_vmem, but chunk buffers span only
-    the block + aux windows and the accumulator only the block's tiles)."""
-    plan = colblock_plan(num_features, num_bins, payload_width,
-                         grad_col, hess_col, cnt_col)
-    if plan is None:
-        return False
-    blocks, _, aux_w = plan
-    worst_f = max(f for _, f, _ in blocks)
-    worst_bw = max(bw for _, _, bw in blocks)
-    ft, n_tiles, w = _tiling(worst_f, num_bins)
-    est = (2 * 4 * CHUNK * w                   # expand + one-hot tiles
-           + 4 * 8 * n_tiles * w               # block accumulator
-           + 2 * 4 * CHUNK * (worst_bw + aux_w)  # block+aux chunks x2 (DMA)
-           + 4 * ft * w)                       # window expander
-    return est <= _VMEM_BUDGET
-
-
-def _hist_colblock_kernel(scalars, payload_hbm, out_ref, chunk_blk,
-                          chunk_aux, sem, *, Fb, B, Ft, W, col_lo, aux_lo,
-                          g_off, h_off, c_off, expand_impl):
-    """Sibling of _hist_kernel for ONE feature-column block of an
-    ultra-wide payload, with the B-wide one-hot body of
-    _hist_batched_kernel (see the notes there;
-    test_colblock_matches_hist_kernel pins it against the factored
-    kernel).
-
-    Differences from the parent: each chunk DMAs TWO lane windows — the
-    block's own columns [col_lo, col_lo+BW) and the aux window carrying
-    grad/hess/cnt — instead of the full payload width, so VMEM scales
-    with the block width.  Bin columns are read once across all blocks;
-    the aux window is re-read per block (~25% extra HBM traffic at
-    raw-Allstate geometry — the price of bounded VMEM)."""
-    start = scalars[0]
-    count = scalars[1]
-    shift = lax.rem(start, 8)
-    base = start - shift
-    nch = jnp.where(count > 0, (shift + count + CHUNK - 1) // CHUNK, 0)
-    n_tiles = -(-Fb // Ft)
-    BW = chunk_blk.shape[2]
-    AW = chunk_aux.shape[2]
-    out_ref[:] = jnp.zeros(out_ref.shape, out_ref.dtype)
-    iota_rows = _row_iota()
-
-    def dmas_for(k, slot):
-        rows = pl.ds(pl.multiple_of(base + k * CHUNK, 8), CHUNK)
-        return (pltpu.make_async_copy(
-                    payload_hbm.at[rows, pl.ds(col_lo, BW)],
-                    chunk_blk.at[slot], sem.at[slot, 0]),
-                pltpu.make_async_copy(
-                    payload_hbm.at[rows, pl.ds(aux_lo, AW)],
-                    chunk_aux.at[slot], sem.at[slot, 1]))
-
-    @pl.when(nch > 0)
-    def _prefetch_first():
-        for d in dmas_for(0, 0):
-            d.start()
-
-    if expand_impl == "repeat":
-        jdivs = {}
-        for t in range(n_tiles):
-            fw = min(Ft, Fb - t * Ft)
-            if fw not in jdivs:
-                jdivs[fw] = (lax.broadcasted_iota(jnp.int32, (1, fw * B), 1)
-                             // fw).astype(jnp.float32)
-    if expand_impl == "matmul":
-        iota_fr = lax.broadcasted_iota(jnp.int32, (Ft, W), 0)
-        iota_fc = lax.broadcasted_iota(jnp.int32, (Ft, W), 1)
-        d = iota_fc - iota_fr * B
-        in_win = (d >= 0) & (d < B)
-        E = in_win.astype(jnp.float32)
-        jmod = jnp.sum(jnp.where(in_win, d, 0), axis=0)
-        jmod_f = jmod.astype(jnp.float32)
-
-    def body(k, _):
-        slot = lax.rem(k, 2)
-
-        @pl.when(k + 1 < nch)
-        def _prefetch_next():
-            for d in dmas_for(k + 1, lax.rem(k + 1, 2)):
-                d.start()
-
-        for d in dmas_for(k, slot):
-            d.wait()
-        data = chunk_blk[slot]
-        aux = chunk_aux[slot]
-        ok = ((iota_rows >= shift - k * CHUNK) &
-              (iota_rows < shift + count - k * CHUNK)).astype(jnp.float32)
-        # exact bf16 part-decomposition of grad/hess (see _hist_kernel)
-        iota_r8 = lax.broadcasted_iota(jnp.int32, (8, AW), 0)
-        iota_pc = lax.broadcasted_iota(jnp.int32, (8, AW), 1)
-        sel = (((iota_r8 < 3) & (iota_pc == g_off)) |
-               ((iota_r8 >= 3) & (iota_r8 < 6) & (iota_pc == h_off)) |
-               ((iota_r8 == 6) & (iota_pc == c_off))).astype(jnp.float32)
-        raw = lax.dot_general(
-            sel, aux, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=lax.Precision.HIGHEST)                     # [8, C]
-        hi = raw.astype(jnp.bfloat16).astype(jnp.float32)
-        r1 = raw - hi
-        mid = r1.astype(jnp.bfloat16).astype(jnp.float32)
-        lo = r1 - mid
-        rr = lax.broadcasted_iota(jnp.int32, raw.shape, 0)
-        vals = jnp.where((rr == 0) | (rr == 3), hi,
-                         jnp.where((rr == 1) | (rr == 4), mid,
-                                   jnp.where((rr == 2) | (rr == 5), lo,
-                                             raw)))
-        vals = vals * ok[None, :]
-        for t in range(n_tiles):
-            f0 = t * Ft
-            fw = min(Ft, Fb - f0)
-            binsf = data[:, f0:f0 + fw]
-            if expand_impl == "repeat":
-                rep = pltpu.repeat(binsf, B, axis=1)
-                onehot = (rep == jdivs[fw]).astype(jnp.float32)
-                out_ref[8 * t:8 * t + 8, :fw * B] += lax.dot_general(
-                    vals, onehot,
-                    dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-            else:
-                expand = lax.dot_general(
-                    binsf, E[:fw, :],
-                    dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                onehot = (expand == jmod_f[None, :]).astype(jnp.float32)
-                out_ref[8 * t:8 * t + 8, :] += lax.dot_general(
-                    vals, onehot,
-                    dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-        return 0
-
-    lax.fori_loop(0, nch, body, 0)
-
-
-def segment_histogram_colblock(payload, start, count, *, num_features,
-                               num_bins, grad_col, hess_col, cnt_col,
-                               interpret=False, expand_impl=None):
-    """hist[F, B, 3] over an ULTRA-WIDE payload: one sibling-kernel pass
-    per 128-aligned feature-column block (colblock_plan)."""
-    plan = colblock_plan(num_features, num_bins, payload.shape[1],
-                         grad_col, hess_col, cnt_col)
-    if plan is None:
-        raise ValueError("column-block plan unavailable for this payload")
-    blocks, aux_lo, aux_w = plan
-    outs = []
-    for (col_lo, fb, bw) in blocks:
-        ei = expand_impl or _default_expand_impl(fb, num_bins)
-        outs.append(_segment_histogram_colblock(
-            payload, start, count, num_features=fb, num_bins=num_bins,
-            col_lo=col_lo, block_w=bw, aux_lo=aux_lo, aux_w=aux_w,
-            g_off=grad_col - aux_lo, h_off=hess_col - aux_lo,
-            c_off=cnt_col - aux_lo, interpret=interpret, expand_impl=ei))
-    return jnp.concatenate(outs, axis=0)
-
-
-@functools.partial(xla_obs.jit, site="pallas.segment_histogram_colblock", static_argnames=(
-    "num_features", "num_bins", "col_lo", "block_w", "aux_lo", "aux_w",
-    "g_off", "h_off", "c_off", "interpret", "expand_impl"))
-def _segment_histogram_colblock(payload, start, count, *, num_features,
-                                num_bins, col_lo, block_w, aux_lo, aux_w,
-                                g_off, h_off, c_off, interpret,
-                                expand_impl):
-    Fb, B = num_features, num_bins
-    Ft, n_tiles, W = _tiling(Fb, B)
-    scalars = jnp.stack([start, count]).astype(jnp.int32)
-    kern = functools.partial(_hist_colblock_kernel, Fb=Fb, B=B, Ft=Ft, W=W,
-                             col_lo=col_lo, aux_lo=aux_lo, g_off=g_off,
-                             h_off=h_off, c_off=c_off,
-                             expand_impl=expand_impl)
-    out = pl.pallas_call(
-        kern,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(1,),
-            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-            scratch_shapes=[
-                pltpu.VMEM((2, CHUNK, block_w), jnp.float32),
-                pltpu.VMEM((2, CHUNK, aux_w), jnp.float32),
-                pltpu.SemaphoreType.DMA((2, 2)),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((8 * n_tiles, W), jnp.float32),
-        interpret=interpret,
-    )(scalars, payload)
-    return _untile_hist(out, Fb, B, Ft, n_tiles, W, expand_impl)
 
 
 # ---------------------------------------------------------------------------
@@ -1424,8 +728,7 @@ C2 = 2 * CHUNK
 
 
 def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
-                P, B, value_col, roll_place=False, hist_cfg=None, group=1,
-                lane_lo=None):
+                P, B, value_col, group=1, lane_lo=None):
     """Accumulator-window partition: same contract as `_partition_kernel`,
     restructured around the measured bottleneck (per-chunk latency, not
     bandwidth).  Lefts and rights accumulate in VMEM windows [2C, P] that
@@ -1459,16 +762,6 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
     interleave.  The ring holds its depth in groups; a trip's chunks past
     the segment's last are not read and count as empty.
 
-    With `hist_cfg` set (the merged partition+hist kernel), pass A also
-    accumulates BOTH children's histograms from the resident ring chunks:
-    the per-tile one-hot is shared (bins don't depend on the side), only
-    the [8, C] part-value rows are masked per side — so two extra [8, W]
-    contractions per tile buy both child histograms with ZERO extra HBM
-    row traffic, retiring the separate per-split histogram kernel, the
-    parent histogram, the subtraction trick and the device histogram pool
-    (reference FeatureHistogram::Subtract / HistogramPool,
-    feature_histogram.hpp:505-826, folded into the partition walk).
-
     With `lane_lo` set (one pass of the column-block partition,
     `partition_segment_acc_blocks`), the kernel moves only the payload's
     lanes [lane_lo, lane_lo + P) and routes rows from a frozen copy of the
@@ -1482,11 +775,8 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
     Pass B needs no routing: membership there is positional."""
     blocks = lane_lo is not None
     if blocks:
-        assert hist_cfg is None, "no merged histogram in a column block"
         route_hbm, *rest = rest
     payload_out, aux_out, nl_out, *rest = rest
-    if hist_cfg is not None:
-        hl_ref, hr_ref, *rest = rest
     ring, lacc, racc, stage, rbuf, sem_ring, sem_w, sem_r, *rest = rest
     if blocks:
         route_ring, sem_route = rest
@@ -1500,7 +790,6 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
     iota_rows = _row_iota()
     iota_c2 = lax.broadcasted_iota(jnp.int32, (C2, 1), 0)[:, 0]
     iota_p = lax.broadcasted_iota(jnp.int32, (1, P), 1)
-    iota_2i = lax.broadcasted_iota(jnp.int32, (C2, CHUNK), 0)
     # the lanes the routing reads: the rows' own, or the split window's
     iota_route = (lax.broadcasted_iota(jnp.int32, (1, 128), 1) if blocks
                   else iota_p)
@@ -1532,12 +821,12 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
                 (iota_rows < shift + count - k * CHUNK)).astype(jnp.int32)
 
     # chunk-independent [C, C] machinery, built once before the chunk loop
-    # (as the histogram kernels do for theirs).  The iotas are
-    # built at [C, C] directly: slicing the [2C, C] ones (e.g.
-    # iota_2i[:CHUNK]) crashes Mosaic's ApplyVectorLayout — a broadcasted
-    # iota is stored replicated along its constant dim, and
-    # vector.extract_strided_slice asks that dim for more vregs than the
-    # replicated layout holds (hardware-bisected, round 4).
+    # (as the histogram kernel does for its own).  The iotas are built at
+    # [C, C] directly: slicing a [2C, C] one crashes Mosaic's
+    # ApplyVectorLayout — a broadcasted iota is stored replicated along
+    # its constant dim, and vector.extract_strided_slice asks that dim for
+    # more vregs than the replicated layout holds (hardware-bisected,
+    # round 4).
     iota_ci = lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 0)
     tri = (lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 1) <
            iota_ci).astype(jnp.float32)
@@ -1555,16 +844,6 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
         placed = jnp.where(iota_p == value_col, value, placed)
         region = ((iota_c2 >= off) & (iota_c2 < off + cnt))[:, None]
         acc[:] = jnp.where(region, placed, acc[:])
-
-    def place_matmul(parts, dest, member):
-        """[2C, P]: source rows j (member[j]=1) land at rows dest[j] via a
-        0/1 one-hot applied to the exact parts (three one-pass matmuls)."""
-        mat = ((iota_2i == dest[None, :]) &
-               (member[None, :] > 0)).astype(jnp.float32)        # [2C, C]
-        hi, mid, lo = parts
-        return (jnp.dot(mat, hi, preferred_element_type=jnp.float32) +
-                jnp.dot(mat, mid, preferred_element_type=jnp.float32) +
-                jnp.dot(mat, lo, preferred_element_type=jnp.float32))
 
     def permute_doubled(parts, dest, member):
         """[2C, P], twice the [C, P] block in which source row j
@@ -1601,93 +880,10 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
             sem).start()
         acc[0:CHUNK] = acc[CHUNK:C2]
 
-    if hist_cfg is not None:
-        # one-hot machinery identical to _hist_batched_kernel (see the
-        # notes there); built once before the chunk loop, shared by both
-        # sides
-        Fh, Bh = hist_cfg["F"], hist_cfg["B"]
-        Fth, Wh = hist_cfg["Ft"], hist_cfg["W"]
-        n_tiles_h = -(-Fh // Fth)
-        h_expand = hist_cfg["expand_impl"]
-        gcol, hcol, ccol = (hist_cfg["grad_col"], hist_cfg["hess_col"],
-                            hist_cfg["cnt_col"])
-        hl_ref[:] = jnp.zeros(hl_ref.shape, hl_ref.dtype)
-        hr_ref[:] = jnp.zeros(hr_ref.shape, hr_ref.dtype)
-        if h_expand == "repeat":
-            jdivs = {}
-            for t in range(n_tiles_h):
-                fw = min(Fth, Fh - t * Fth)
-                if fw not in jdivs:
-                    jdivs[fw] = (lax.broadcasted_iota(
-                        jnp.int32, (1, fw * Bh), 1) // fw).astype(jnp.float32)
-        else:
-            iota_fr = lax.broadcasted_iota(jnp.int32, (Fth, Wh), 0)
-            iota_fc = lax.broadcasted_iota(jnp.int32, (Fth, Wh), 1)
-            dwin = iota_fc - iota_fr * Bh
-            in_win = (dwin >= 0) & (dwin < Bh)
-            E = in_win.astype(jnp.float32)                       # [Ft, W]
-            jmod_f = jnp.sum(jnp.where(in_win, dwin, 0),
-                             axis=0).astype(jnp.float32)         # [W]
-        iota_r8 = lax.broadcasted_iota(jnp.int32, (8, P), 0)
-        iota_pc8 = lax.broadcasted_iota(jnp.int32, (8, P), 1)
-        sel8 = (((iota_r8 < 3) & (iota_pc8 == gcol)) |
-                ((iota_r8 >= 3) & (iota_r8 < 6) & (iota_pc8 == hcol)) |
-                ((iota_r8 == 6) & (iota_pc8 == ccol))).astype(jnp.float32)
-
-        def hist_accumulate(data, gl, keep_r):
-            """Both children's part-histograms from the resident chunk:
-            one shared one-hot per tile, one [8, W] contraction per side.
-            Rows are (g_hi, g_mid, g_lo, h_hi, h_mid, h_lo, cnt) exact
-            bf16 parts — same exactness argument as _hist_kernel."""
-            raw = lax.dot_general(
-                sel8, data, dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-                precision=lax.Precision.HIGHEST)                 # [8, C]
-            hi = raw.astype(jnp.bfloat16).astype(jnp.float32)
-            r1 = raw - hi
-            mid = r1.astype(jnp.bfloat16).astype(jnp.float32)
-            lo = r1 - mid
-            rr = lax.broadcasted_iota(jnp.int32, raw.shape, 0)
-            vals = jnp.where((rr == 0) | (rr == 3), hi,
-                             jnp.where((rr == 1) | (rr == 4), mid,
-                                       jnp.where((rr == 2) | (rr == 5), lo,
-                                                 raw)))
-            vl = vals * gl.astype(jnp.float32)[None, :]
-            vr = vals * keep_r.astype(jnp.float32)[None, :]
-            for t in range(n_tiles_h):
-                f0 = t * Fth
-                fw = min(Fth, Fh - f0)
-                binsf = data[:, f0:f0 + fw]                      # [C, fw]
-                if h_expand == "repeat":
-                    rep = pltpu.repeat(binsf, Bh, axis=1)
-                    onehot = (rep == jdivs[fw]).astype(jnp.float32)
-                    hl_ref[8 * t:8 * t + 8, :fw * Bh] += lax.dot_general(
-                        vl, onehot,
-                        dimension_numbers=(((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32)
-                    hr_ref[8 * t:8 * t + 8, :fw * Bh] += lax.dot_general(
-                        vr, onehot,
-                        dimension_numbers=(((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32)
-                else:
-                    expand = lax.dot_general(
-                        binsf, E[:fw, :],
-                        dimension_numbers=(((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32)      # [C, W]
-                    onehot = (expand == jmod_f[None, :]).astype(jnp.float32)
-                    hl_ref[8 * t:8 * t + 8, :] += lax.dot_general(
-                        vl, onehot,
-                        dimension_numbers=(((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32)
-                    hr_ref[8 * t:8 * t + 8, :] += lax.dot_general(
-                        vr, onehot,
-                        dimension_numbers=(((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32)
-
-    # the ring holds its depth (2 validated, 4 staged: RING4 flag) in
-    # GROUPS of chunks for pass A, in chunks for pass B
+    # the ring holds its depth in GROUPS of chunks for pass A, in chunks
+    # for pass B
     G = group
-    R = ring.shape[0] // G
+    R = _RING_DEPTH
 
     @pl.when(nch > 0)
     def _prefetch_first():
@@ -1708,8 +904,6 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
         valid = valid_mask(k)
         gl = _go_left_rows(scalars, bitset_ref, route, B, iota_route) * valid
         keep_r = valid - gl
-        if hist_cfg is not None:
-            hist_accumulate(data, gl, keep_r)
         nlk = jnp.sum(gl)
         nrk = jnp.sum(keep_r)
         rank_l = rank_of(gl)
@@ -1718,25 +912,18 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
         # are those that are not lefts: no second prefix count
         rank_r = jnp.maximum(
             iota_rows - jnp.maximum(shift - k * CHUNK, 0), 0) - rank_l
-        parts = _bf16_parts(data)
-        if roll_place:
-            # ONE stable partition of the chunk: lefts to [0, nlk), rights
-            # to [nlk, nlk + nrk), both in original order
-            return nlk, nrk, permute_doubled(
-                parts, jnp.where(gl > 0, rank_l, nlk + rank_r), valid)
-        return nlk, nrk, (parts, rank_l, gl, rank_r, keep_r)
+        # ONE stable partition of the chunk: lefts to [0, nlk), rights to
+        # [nlk, nlk + nrk), both in original order
+        return nlk, nrk, permute_doubled(
+            _bf16_parts(data), jnp.where(gl > 0, rank_l, nlk + rank_r),
+            valid)
 
     def place(nlk, nrk, block, carry):
         nl, nr, lo_, ro_, lfl, rfl, pl_, pr_ = carry
-        if roll_place:
-            # each side is a rotate of the same doubled block to its
-            # cursor (the rotate-by-a-difference of pass B)
-            placed_l = pltpu.roll(block, lo_, axis=0)
-            placed_r = pltpu.roll(block, ro_ - nlk + C2, axis=0)
-        else:
-            parts, rank_l, gl, rank_r, keep_r = block
-            placed_l = place_matmul(parts, lo_ + rank_l, gl)
-            placed_r = place_matmul(parts, ro_ + rank_r, keep_r)
+        # each side is a rotate of the same doubled block to its cursor
+        # (the rotate-by-a-difference of pass B)
+        placed_l = pltpu.roll(block, lo_, axis=0)
+        placed_r = pltpu.roll(block, ro_ - nlk + C2, axis=0)
         blend(lacc, placed_l, nlk, lo_, left_value)
         fl = ((lo_ + nlk) >= CHUNK).astype(jnp.int32)
 
@@ -1838,17 +1025,12 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
         cnt = jnp.maximum(j1 - j0, 0)
         member = ((iota_rows >= j0) & (iota_rows < j1)).astype(jnp.int32)
         # non-member rows of the staged window can be uninitialized aux
-        # memory; zero them BEFORE placement (0 x NaN = NaN would poison
-        # every matmul-placed row)
+        # memory; zero them BEFORE placement
         data = jnp.where(member[:, None] > 0, ring[slot], 0.0)
-        if roll_place:
-            # staged rights are already contiguous: placement is a pure
-            # rotate of the doubled window — no decomposition, no matmul
-            placed = pltpu.roll(jnp.concatenate([data, data], axis=0),
-                                lo_ - j0 + C2, axis=0)
-        else:
-            parts = _bf16_parts(data)
-            placed = place_matmul(parts, iota_rows - j0 + lo_, member)
+        # staged rights are already contiguous: placement is a pure rotate
+        # of the doubled window — no decomposition, no matmul
+        placed = pltpu.roll(jnp.concatenate([data, data], axis=0),
+                            lo_ - j0 + C2, axis=0)
         blend(lacc, placed, cnt, lo_, right_value)
         fl = ((lo_ + cnt) >= CHUNK).astype(jnp.int32)
 
@@ -1881,28 +1063,12 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
         dma_w.wait()
 
 
-def partition_segment_acc(payload, aux, start, count, pred, left_value,
-                          right_value, value_col, num_bins, interpret=False,
-                          roll_place=None, ring_depth=None):
-    """Same contract as `partition_segment`, accumulator-window kernel.
-    Flag defaults (roll_place, ring_depth) resolve OUTSIDE the jit cache
-    so flipping the validated flags takes effect on warm traces."""
-    if roll_place is None:
-        roll_place = PARTITION_ACC_ROLL_VALIDATED
-    if ring_depth is None:
-        ring_depth = _ring_depth_default()
-    return _partition_segment_acc(payload, aux, start, count, pred,
-                                  left_value, right_value, value_col,
-                                  num_bins, interpret, bool(roll_place),
-                                  int(ring_depth))
-
-
-@functools.partial(xla_obs.jit, site="pallas.partition_segment_acc", static_argnames=("value_col", "num_bins",
-                                             "interpret", "roll_place",
-                                             "ring_depth"))
+@functools.partial(xla_obs.jit, site="pallas.partition_segment_acc",
+                   static_argnames=("value_col", "num_bins", "interpret"))
 def _partition_segment_acc(payload, aux, start, count, pred, left_value,
-                           right_value, value_col, num_bins, interpret,
-                           roll_place, ring_depth):
+                           right_value, value_col, num_bins,
+                           interpret=False):
+    """Same contract as `partition_segment`, accumulator-window kernel."""
     P = payload.shape[1]
     B = num_bins
     scalars = jnp.stack([
@@ -1913,9 +1079,9 @@ def _partition_segment_acc(payload, aux, start, count, pred, left_value,
     ]).astype(jnp.int32)
     fvals = jnp.stack([left_value, right_value]).astype(jnp.float32)
     bitset = pred.bitset.astype(jnp.int32).reshape(1, B)
-    group = _pass_a_group(P, B, ring_depth)
+    group = _pass_a_group(P, B)
     kern = functools.partial(_acc_kernel, P=P, B=B, value_col=value_col,
-                             roll_place=roll_place, group=group)
+                             group=group)
     payload_new, aux_new, nl = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1928,13 +1094,13 @@ def _partition_segment_acc(payload, aux, start, count, pred, left_value,
                        pl.BlockSpec(memory_space=pl.ANY),
                        pl.BlockSpec(memory_space=pltpu.SMEM)),
             scratch_shapes=[
-                pltpu.VMEM((ring_depth * group, CHUNK, P),
+                pltpu.VMEM((_RING_DEPTH * group, CHUNK, P),
                            jnp.float32),                  # read ring
                 pltpu.VMEM((C2, P), jnp.float32),         # left accumulator
                 pltpu.VMEM((C2, P), jnp.float32),         # right accumulator
                 pltpu.VMEM((CHUNK, P), jnp.float32),      # flush stage
                 pltpu.VMEM((CHUNK, P), jnp.float32),      # final blend read
-                pltpu.SemaphoreType.DMA((ring_depth * group,)),
+                pltpu.SemaphoreType.DMA((_RING_DEPTH * group,)),
                 pltpu.SemaphoreType.DMA(()),
                 pltpu.SemaphoreType.DMA(()),
             ],
@@ -1949,117 +1115,25 @@ def _partition_segment_acc(payload, aux, start, count, pred, left_value,
     return payload_new, aux_new, nl[0]
 
 
-def partition_segment_hist(payload, aux, start, count, pred, left_value,
-                           right_value, value_col, num_bins, *,
-                           num_features, grad_col, hess_col, cnt_col,
-                           interpret=False, roll_place=None,
-                           expand_impl=None, ring_depth=None):
-    """Merged partition + both-child histograms (one kernel, one read of
-    the split leaf's rows).  Same partition contract as
-    `partition_segment_acc`, plus the two children's [F, B, 3] histograms
-    — the device-side subtraction trick and histogram pool become
-    unnecessary for callers of this kernel.  Flag defaults resolve
-    OUTSIDE the jit cache (see partition_segment_acc)."""
-    if roll_place is None:
-        roll_place = PARTITION_ACC_ROLL_VALIDATED
-    if ring_depth is None:
-        ring_depth = _ring_depth_default()
-    if expand_impl is None:
-        expand_impl = _default_expand_impl(num_features, num_bins)
-    return _partition_segment_hist(payload, aux, start, count, pred,
-                                   left_value, right_value, value_col,
-                                   num_bins, num_features, grad_col,
-                                   hess_col, cnt_col, interpret,
-                                   bool(roll_place), expand_impl,
-                                   int(ring_depth))
-
-
-@functools.partial(xla_obs.jit, site="pallas.partition_segment_hist", static_argnames=(
-    "value_col", "num_bins", "num_features", "grad_col", "hess_col",
-    "cnt_col", "interpret", "roll_place", "expand_impl", "ring_depth"))
-def _partition_segment_hist(payload, aux, start, count, pred, left_value,
-                            right_value, value_col, num_bins, num_features,
-                            grad_col, hess_col, cnt_col, interpret,
-                            roll_place, expand_impl, ring_depth):
-    P = payload.shape[1]
-    B = num_bins
-    F = num_features
-    Ft, n_tiles, W = _tiling(F, B)
-    scalars = jnp.stack([
-        start, count, pred.col, pred.threshold,
-        pred.default_left.astype(jnp.int32), pred.is_cat.astype(jnp.int32),
-        pred.missing_type, pred.num_bin, pred.default_bin,
-        pred.offset, pred.identity.astype(jnp.int32),
-    ]).astype(jnp.int32)
-    fvals = jnp.stack([left_value, right_value]).astype(jnp.float32)
-    bitset = pred.bitset.astype(jnp.int32).reshape(1, B)
-    hist_cfg = dict(F=F, B=B, Ft=Ft, W=W, grad_col=grad_col,
-                    hess_col=hess_col, cnt_col=cnt_col,
-                    expand_impl=expand_impl)
-    group = _pass_a_group(P, B, ring_depth, _hist_plan_bytes(F, B))
-    kern = functools.partial(_acc_kernel, P=P, B=B, value_col=value_col,
-                             roll_place=roll_place, hist_cfg=hist_cfg,
-                             group=group)
-    payload_new, aux_new, nl, hl, hr = pl.pallas_call(
-        kern,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(1,),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=(pl.BlockSpec(memory_space=pl.ANY),
-                       pl.BlockSpec(memory_space=pl.ANY),
-                       pl.BlockSpec(memory_space=pltpu.SMEM),
-                       pl.BlockSpec(memory_space=pltpu.VMEM),
-                       pl.BlockSpec(memory_space=pltpu.VMEM)),
-            scratch_shapes=[
-                pltpu.VMEM((ring_depth * group, CHUNK, P),
-                           jnp.float32),                  # read ring
-                pltpu.VMEM((C2, P), jnp.float32),         # left accumulator
-                pltpu.VMEM((C2, P), jnp.float32),         # right accumulator
-                pltpu.VMEM((CHUNK, P), jnp.float32),      # flush stage
-                pltpu.VMEM((CHUNK, P), jnp.float32),      # final blend read
-                pltpu.SemaphoreType.DMA((ring_depth * group,)),
-                pltpu.SemaphoreType.DMA(()),
-                pltpu.SemaphoreType.DMA(()),
-            ],
-        ),
-        out_shape=(jax.ShapeDtypeStruct(payload.shape, payload.dtype),
-                   jax.ShapeDtypeStruct(aux.shape, aux.dtype),
-                   jax.ShapeDtypeStruct((1,), jnp.int32),
-                   jax.ShapeDtypeStruct((8 * n_tiles, W), jnp.float32),
-                   jax.ShapeDtypeStruct((8 * n_tiles, W), jnp.float32)),
-        input_output_aliases={3: 0, 4: 1},
-        compiler_params=_SIDE_EFFECTS,
-        interpret=interpret,
-    )(scalars, fvals, bitset, payload, aux)
-    hist_l = _untile_hist(hl, F, B, Ft, n_tiles, W, expand_impl)
-    hist_r = _untile_hist(hr, F, B, Ft, n_tiles, W, expand_impl)
-    return payload_new, aux_new, nl[0], hist_l, hist_r
+partition_segment_acc = _partition_segment_acc
 
 
 # ---------------------------------------------------------------------------
 # partition, column-block variant (ultra-wide payloads)
 # ---------------------------------------------------------------------------
 
-def partition_blocks_fits_vmem(payload_width: int, num_bins: int,
-                               block_w: int = None) -> bool:
+def partition_blocks_fits_vmem(payload_width: int, num_bins: int) -> bool:
     """VMEM plan of ONE column-block partition pass: the accumulator
     kernel's plan at the block width (pass A one chunk a trip) plus the
     split-window ring (128 lanes a slot)."""
-    if block_w is None:
-        block_w = COLBLOCK_WIDTH
-    ring_depth = _ring_depth_default()
-    return (_acc_plan_bytes(min(block_w, payload_width), num_bins,
-                            ring_depth, 1)
-            + _route_ring_bytes(ring_depth, 1)) <= _VMEM_BUDGET
+    return (_acc_plan_bytes(min(_BLOCK_WIDTH, payload_width), num_bins, 1)
+            + _route_ring_bytes(1)) <= _VMEM_BUDGET
 
 
-def _route_ring_bytes(ring_depth: int, group: int) -> int:
+def _route_ring_bytes(group: int) -> int:
     """The split-window ring of a column-block pass: one [C, 128] slot
     beside each slot of the read ring."""
-    return ring_depth * group * 4 * 128 * CHUNK
+    return _RING_DEPTH * group * 4 * 128 * CHUNK
 
 
 def _snap_window_kernel(scalars, payload_hbm, snap_out, buf, sem):
@@ -2091,32 +1165,15 @@ def _snap_window_kernel(scalars, payload_hbm, snap_out, buf, sem):
     lax.fori_loop(0, nch, body, 0)
 
 
-def partition_segment_acc_blocks(payload, aux, start, count, pred,
-                                 left_value, right_value, value_col,
-                                 num_bins, interpret=False, roll_place=None,
-                                 ring_depth=None, block_w=None):
-    """Same contract as `partition_segment`, applied block-by-block over
-    the payload's lane windows (ultra-wide payloads).  Flag defaults
-    resolve OUTSIDE the jit cache (see partition_segment_acc)."""
-    if roll_place is None:
-        roll_place = PARTITION_ACC_ROLL_VALIDATED
-    if ring_depth is None:
-        ring_depth = _ring_depth_default()
-    if block_w is None:
-        block_w = COLBLOCK_WIDTH
-    return _partition_segment_acc_blocks(
-        payload, aux, start, count, pred, left_value, right_value,
-        value_col, num_bins, interpret, bool(roll_place), int(ring_depth),
-        int(block_w))
-
-
-@functools.partial(xla_obs.jit, site="pallas.partition_segment_acc_blocks", static_argnames=(
-    "value_col", "num_bins", "interpret", "roll_place", "ring_depth",
-    "block_w"))
+@functools.partial(xla_obs.jit, site="pallas.partition_segment_acc_blocks",
+                   static_argnames=("value_col", "num_bins", "interpret",
+                                    "block_w"))
 def _partition_segment_acc_blocks(payload, aux, start, count, pred,
                                   left_value, right_value, value_col,
-                                  num_bins, interpret, roll_place,
-                                  ring_depth, block_w):
+                                  num_bins, interpret=False,
+                                  block_w=_BLOCK_WIDTH):
+    """Same contract as `partition_segment`, applied block-by-block over
+    the payload's lane windows (ultra-wide payloads)."""
     P = payload.shape[1]
     if P % 128 != 0:
         raise ValueError("column-block partition requires a lane-padded "
@@ -2155,12 +1212,10 @@ def _partition_segment_acc_blocks(payload, aux, start, count, pred,
     for c in range(0, P, block_w):
         bw = min(block_w, P - c)
         vloc = value_col - c if c <= value_col < c + bw else -1
-        group = _pass_a_group(bw, B, ring_depth,
-                              _route_ring_bytes(ring_depth, _PASS_A_GROUP))
-        slots = ring_depth * group
+        group = _pass_a_group(bw, B, _route_ring_bytes(_PASS_A_GROUP))
+        slots = _RING_DEPTH * group
         kern = functools.partial(_acc_kernel, P=bw, B=B, value_col=vloc,
-                                 roll_place=roll_place, group=group,
-                                 lane_lo=c)
+                                 group=group, lane_lo=c)
         payload, aux, nl_k = pl.pallas_call(
             kern,
             grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -2196,3 +1251,6 @@ def _partition_segment_acc_blocks(payload, aux, start, count, pred,
         )(scalars, fvals, bitset, payload, aux, snap)
         nl = nl_k if nl is None else nl
     return payload, aux, nl[0]
+
+
+partition_segment_acc_blocks = _partition_segment_acc_blocks

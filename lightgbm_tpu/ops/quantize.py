@@ -12,8 +12,7 @@ accuracy cost.  Here that maps onto the payload engine (`ops.segment`):
   grad/hess columns (f32 lanes — small integers are exact), so every
   partition/ride-along mechanism is unchanged;
 - histograms accumulate the integers into an int32 [F, B, 3] state
-  (`segment_histogram(..., quantized=True)`, or the staged int8 MXU kernel
-  `pallas_segment.segment_histogram_quant`) — integer addition is exact and
+  (`segment_histogram(..., quantized=True)`) — integer addition is exact and
   order-independent, so subtraction-trick siblings, cross-engine results
   and cross-shard `psum`s of the histogram are all bit-exact;
 - the f32 view is recovered only at the split-search boundary

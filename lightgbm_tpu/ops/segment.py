@@ -346,9 +346,7 @@ def segment_histogram_batched(payload: jax.Array, starts: jax.Array,
     slots of a short frontier).  Each slice [k] is computed by the SAME
     per-chunk accumulation as `segment_histogram(payload, starts[k],
     counts[k])` — bit-identical per segment, which is what lets the batched
-    grower stay byte-identical to the sequential one.  The TPU-native
-    single-dispatch version is `pallas_segment.segment_histogram_batched`
-    (staged behind FRONTIER_BATCH_VALIDATED)."""
+    grower stay byte-identical to the sequential one."""
     K = starts.shape[0]
 
     def body(k, hist):

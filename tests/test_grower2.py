@@ -227,96 +227,6 @@ def test_histogram_pool_recompute_matches():
     assert abs(lt - lf) <= 0.01 * max(lf, 1e-6), (lt, lf)
 
 
-def _merged_vs_subtraction(X, y, num_leaves=31, min_data=20,
-                           lambda_l2=0.1):
-    """Grow one tree with merged_hist off and on; return both trees."""
-    config = Config({"objective": "binary", "max_bin": 63,
-                     "num_leaves": num_leaves, "min_data_in_leaf": min_data})
-    ds = BinnedDataset.from_matrix(X, config, row_chunk=1024)
-    meta = _feature_meta_device(ds)
-    n_pad = ds.num_data_padded
-    gcfg = GrowerConfig(num_leaves=num_leaves, max_depth=-1, lambda_l1=0.0,
-                        lambda_l2=lambda_l2, max_delta_step=0.0,
-                        min_data_in_leaf=min_data,
-                        min_sum_hessian_in_leaf=1e-3, min_gain_to_split=0.0,
-                        row_chunk=n_pad, with_categorical=False)
-    n = len(y)
-    grad = np.zeros(n_pad, np.float32)
-    hess = np.zeros(n_pad, np.float32)
-    grad[:n] = 0.5 - y
-    hess[:n] = 0.25
-    mask = np.zeros(n_pad, np.float32)
-    mask[:n] = 1.0
-    F = ds.num_features
-    cols = PayloadCols(grad=F, hess=F + 1, cnt=F + 2, value=F + 3)
-    P = F + 4
-    payload = np.zeros((n_pad + seg.GUARD, P), np.float32)
-    payload[:n_pad, :F] = ds.bins.T
-    payload[:n_pad, cols.grad] = grad * mask
-    payload[:n_pad, cols.hess] = hess * mask
-    payload[:n_pad, cols.cnt] = mask
-    fmask = jnp.ones(F, bool)
-    outs = []
-    for merged in (False, True):
-        grow = make_partitioned_grower(meta, gcfg, ds.max_num_bin, cols, F,
-                                       merged_hist=merged)
-        tree, _, _ = grow(jnp.asarray(payload),
-                          jnp.zeros_like(jnp.asarray(payload)), fmask)
-        outs.append(jax.device_get(tree))
-    return outs
-
-
-def test_merged_hist_mode_same_tree():
-    """merged_hist=True (partition emits both child histograms directly;
-    no parent hist, no subtraction, no pool) must grow the same tree as
-    the default subtraction engine — direct child sums only differ from
-    parent-minus-sibling at ulp level, which a benign problem never
-    turns into a structure flip."""
-    X, y = _make_problem(seed=13)
-    outs = _merged_vs_subtraction(X, y)
-    _assert_same_tree(outs[0], outs[1])
-    nl = int(outs[0]["num_leaves"])
-    assert nl > 4
-    np.testing.assert_array_equal(outs[0]["seg_start"][:nl],
-                                  outs[1]["seg_start"][:nl])
-    np.testing.assert_array_equal(outs[0]["seg_cnt"][:nl],
-                                  outs[1]["seg_cnt"][:nl])
-
-
-@pytest.mark.parametrize("seed", [0, 1, 2])
-def test_merged_hist_mode_near_tie_splits(seed):
-    """Adversarial near-tie gains: the merged mode's direct child sums
-    differ from parent-minus-sibling at ulp level, and THESE inputs make
-    ulp differences matter — duplicated features (exactly tied gains,
-    argmax must break ties identically), a near-duplicate feature
-    (gains ~1e-7 apart), coarse plateaus (many rows share a bin, split
-    candidates cluster), no L2, deep growth to tiny leaves where sums
-    are few-term and ties are common.  Structure equality here is the
-    evidence the PARTITION_HIST_VALIDATED flip needs (ADVICE round 4)."""
-    rng = np.random.default_rng(seed)
-    n = 4000
-    base = rng.integers(0, 8, size=n).astype(np.float64)  # coarse plateaus
-    X = np.stack([
-        base,
-        base.copy(),                              # exact duplicate
-        base + rng.normal(0, 1e-9, n),            # near-duplicate
-        rng.integers(0, 4, size=n).astype(np.float64),
-        rng.standard_normal(n).round(1),          # quantized
-        -base,                                    # mirrored (tied gains)
-    ], axis=1)
-    y = ((base + 0.3 * X[:, 3] + rng.standard_normal(n) * 0.5) > 4)
-    y = y.astype(np.float32)
-    outs = _merged_vs_subtraction(X, y, num_leaves=63, min_data=5,
-                                  lambda_l2=0.0)
-    _assert_same_tree(outs[0], outs[1])
-    nl = int(outs[0]["num_leaves"])
-    assert nl > 8
-    np.testing.assert_array_equal(outs[0]["seg_start"][:nl],
-                                  outs[1]["seg_start"][:nl])
-    np.testing.assert_array_equal(outs[0]["seg_cnt"][:nl],
-                                  outs[1]["seg_cnt"][:nl])
-
-
 def test_grower_reports_the_engines_it_resolved():
     """The grower names the histogram and partition implementations it
     chose (from platform and shape) as `.engines`, and the booster passes

@@ -100,6 +100,10 @@ def test_histogram_matches_tiled(f, b, start, count, lanes):
     (33, 255, 128, 100, 37),     # 255 = 3 * 64 + 63
     (5, 3, 128, 0, 300),         # fewer bins than a sublane tile
     (126, 256, 256, 513, 1),     # one row; the value columns in two blocks
+    # epsilon-train's shape over whole, shifted, one-row and empty segments
+    (2000, 64, 2048, 0, 1000), (2000, 64, 2048, 256, 700),
+    (2000, 64, 2048, 100, 37), (2000, 64, 2048, 0, 0),
+    (2000, 64, 2048, 7, 1), (2000, 64, 2048, 9, 1015),
 ])
 def test_histogram_factored_shapes(f, b, width, start, count):
     """The factored bin id (bin = hi * L + lo) at the cells' shapes and
@@ -109,7 +113,7 @@ def test_histogram_factored_shapes(f, b, width, start, count):
     the last digit."""
     L, H, G = pseg._hist_factor(b)
     assert H * L >= b and G * L == 128
-    pay, cols = _hist_payload(f, b, width=width)
+    pay, cols = _hist_payload(f, b, max(640, start + count + 9), width=width)
     ref = seg.segment_histogram(pay, jnp.int32(start), jnp.int32(count),
                                 num_features=f, num_bins=b, **cols)
     got = pseg.segment_histogram(pay, jnp.int32(start), jnp.int32(count),
@@ -198,6 +202,19 @@ def test_vmem_gate_admits_benchmark_shapes():
     assert pseg._hist_groups(2000, 64) * 8 * H * 128 == 8 * 2000 * 64
 
 
+#: the plans `grower2.partition_engine` chooses between, by kernel and
+#: payload lanes: the read-modify-write kernel; the accumulator kernel with
+#: pass A two chunks a trip (128, 256 lanes) and one (384); the
+#: column-block kernel over two 512-lane blocks
+PLANS = [("partition_segment", 128), ("partition_segment_acc", 128),
+         ("partition_segment_acc", 256), ("partition_segment_acc", 384),
+         ("partition_segment_acc_blocks", 1024)]
+
+
+def _widened(pay, width):
+    return jnp.pad(pay, ((0, 0), (0, width - pay.shape[1])))
+
+
 def _pred(feature=1, threshold=B // 2, default_left=False, is_cat=False,
           bitset=None, missing_type=0, num_bin=B, default_bin=0,
           offset=0, identity=True):
@@ -230,18 +247,11 @@ def _pred(feature=1, threshold=B // 2, default_left=False, is_cat=False,
     (64, 500, dict(feature=2, threshold=3, offset=5, identity=False,
                    num_bin=9, default_bin=0)),
 ])
-@pytest.mark.parametrize("impl", [
-    pseg.partition_segment,
-    pseg.partition_segment_acc,
-    lambda *a, **kw: pseg.partition_segment_acc(*a, roll_place=True, **kw),
-    # staged 4-deep read ring (PARTITION_RING4_VALIDATED): same instruction
-    # mix, deeper prefetch — exactness must be depth-independent
-    lambda *a, **kw: pseg.partition_segment_acc(*a, ring_depth=4, **kw),
-    lambda *a, **kw: pseg.partition_segment_acc(*a, roll_place=True,
-                                                ring_depth=4, **kw),
-])
-def test_partition_matches(start, count, predkw, impl):
-    pay = _payload(1024, seed=start + count)
+@pytest.mark.parametrize("kernel,width", PLANS)
+def test_partition_matches(start, count, predkw, kernel, width):
+    """Every plan `partition_engine` can choose, under every predicate."""
+    pay = _widened(_payload(1024, seed=start + count), width)
+    impl = getattr(pseg, kernel)
     aux = jnp.zeros_like(pay)
     pred = _pred(**predkw)
     lv, rv = jnp.float32(-0.25), jnp.float32(0.75)
@@ -286,29 +296,30 @@ def _routed_payload(skew, start, count):
 @pytest.mark.parametrize("skew", ["all_left", "all_right", "left_chunk",
                                   "right_chunk", "no_left_first",
                                   "no_right_first"])
-def test_partition_acc_skewed(start, count, skew):
+@pytest.mark.parametrize("kernel,width", [PLANS[1], PLANS[4]])
+def test_partition_acc_skewed(start, count, skew, kernel, width):
     """One-sided chunks exercise the accumulator kernel's empty-side and
     rare-flush paths, and the ends of the one permutation that places a
     chunk (lefts to [0, nl_k), rights behind them): all rows of the
     segment, of a middle chunk or of the first, shifted chunk route one
-    way.  Payload, the rights staged in aux and num_left, bit for bit."""
-    pay = _routed_payload(skew, start, count)
+    way, in one pass (`higgs-train`'s plan) and a column block at a time
+    (`epsilon-train`'s).  Payload, the rights staged in aux and num_left,
+    bit for bit."""
+    pay = _widened(_routed_payload(skew, start, count), width)
     aux = jnp.zeros_like(pay)
     pred = _pred()
     lv, rv = jnp.float32(1.5), jnp.float32(-2.5)
     ref_pay, _, ref_nl = seg.partition_segment(
         pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv, VALUE_COL)
     nl = int(ref_nl)
-    for roll in (False, True):
-        got_pay, got_aux, got_nl = pseg.partition_segment_acc(
-            pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv,
-            VALUE_COL, B, interpret=True, roll_place=roll)
-        assert int(got_nl) == nl
-        np.testing.assert_array_equal(np.asarray(got_pay),
-                                      np.asarray(ref_pay))
-        np.testing.assert_array_equal(
-            np.asarray(got_aux)[start:start + count - nl],
-            np.asarray(ref_pay)[start + nl:start + count])
+    got_pay, got_aux, got_nl = getattr(pseg, kernel)(
+        pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv,
+        VALUE_COL, B, interpret=True)
+    assert int(got_nl) == nl
+    np.testing.assert_array_equal(np.asarray(got_pay), np.asarray(ref_pay))
+    np.testing.assert_array_equal(
+        np.asarray(got_aux)[start:start + count - nl],
+        np.asarray(ref_pay)[start + nl:start + count])
 
 
 def _while_dots(jaxpr):
@@ -341,9 +352,9 @@ def test_pass_a_is_one_permutation():
     closed = jax.make_jaxpr(
         lambda p, a: pseg._partition_segment_acc(
             p, a, jnp.int32(7), jnp.int32(777), _pred(), jnp.float32(1.0),
-            jnp.float32(-1.0), VALUE_COL, B, False, True, 2))(
+            jnp.float32(-1.0), VALUE_COL, B))(
         pay, jnp.zeros_like(pay))
-    assert _while_dots(closed.jaxpr) == [4 * pseg._pass_a_group(P, B, 2), 0]
+    assert _while_dots(closed.jaxpr) == [4 * pseg._pass_a_group(P, B), 0]
 
 
 @pytest.mark.parametrize("width,group", [(P, 2), (256, 2), (384, 1)])
@@ -354,8 +365,8 @@ def test_partition_acc_groups(width, group, start, count):
     leaves VMEM for; every group size gives the portable partition bit
     for bit, whole trips and trips whose last chunks lie past the
     segment (1 to 5 chunks here) alike."""
-    assert pseg._pass_a_group(width, B, 2) == group
-    pay = jnp.pad(_payload(1024, seed=count), ((0, 0), (0, width - P)))
+    assert pseg._pass_a_group(width, B) == group
+    pay = _widened(_payload(1024, seed=count), width)
     aux = jnp.zeros_like(pay)
     pred = _pred(feature=2, threshold=B // 3)
     lv, rv = jnp.float32(-0.25), jnp.float32(0.75)
@@ -366,30 +377,6 @@ def test_partition_acc_groups(width, group, start, count):
         VALUE_COL, B, interpret=True)
     assert int(got_nl) == int(ref_nl)
     np.testing.assert_array_equal(np.asarray(got_pay), np.asarray(ref_pay))
-
-
-def test_validated_flags_gate_product_paths():
-    """The speculative kernel variants were hardware-validated in round
-    4's second window (exp/smoke_tpu_kernels.py: exact at every tested
-    geometry on a real v5e) and their flags flipped ON — this pins the
-    validated state so an accidental revert is loud.  The flags must be
-    consumed OUTSIDE the jit cache so a flip takes effect on warm traces
-    (both defaults resolve in plain Python wrappers)."""
-    assert pseg.PARTITION_ACC_VALIDATED is True
-    assert pseg.PARTITION_ACC_ROLL_VALIDATED is True
-    assert pseg.HIST_REPEAT_VALIDATED is True
-    # acc-kernel gate admits Higgs/Bosch-class widths, rejects Epsilon
-    assert pseg.partition_acc_fits_vmem(128, 256)
-    assert not pseg.partition_acc_fits_vmem(2048, 64)
-    # forcing pallas past the histogram kernel's bin ceiling raises loudly
-    import pytest as _pytest
-    with _pytest.raises(ValueError):
-        seg.resolve_impl("pallas", 28, 512)
-    with _pytest.raises(ValueError):
-        pseg.segment_histogram_batched(
-            _payload(64), jnp.asarray([0], jnp.int32),
-            jnp.asarray([8], jnp.int32), num_features=F,
-            num_bins=B, interpret=True, expand_impl="typo", **COLS)
 
 
 def test_payload_col_write_matches_dus():
@@ -426,220 +413,11 @@ def test_payload_col_write_matches_dus():
         via_traced_col(pay, jnp.int32(4), vec), pay.at[:, 4].add(vec))
 
 
-@pytest.mark.parametrize("start,count", [(0, 1000), (256, 700), (100, 37),
-                                         (513, 256), (7, 1), (0, 0)])
-@pytest.mark.parametrize("expand", ["matmul", "repeat"])
-def test_partition_hist_merged(start, count, expand):
-    """Merged partition+hist kernel: the partition must match the portable
-    engine exactly, and both child histograms must match portable segment
-    walks over the partitioned payload."""
-    pay = _payload(1024, seed=start + count + 1)
-    aux = jnp.zeros_like(pay)
-    pred = _pred(feature=1, threshold=B // 2)
-    lv, rv = jnp.float32(-0.25), jnp.float32(0.75)
-    p2, _, nl, hl, hr = pseg.partition_segment_hist(
-        pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv,
-        VALUE_COL, B, num_features=F, interpret=True, expand_impl=expand,
-        **COLS)
-    pr, _, nlr = seg.partition_segment(
-        pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv,
-        VALUE_COL)
-    assert int(nl) == int(nlr)
-    np.testing.assert_allclose(np.asarray(p2), np.asarray(pr),
-                               rtol=1e-6, atol=0)
-    hlr = seg.segment_histogram(pr, jnp.int32(start), nlr,
-                                num_features=F, num_bins=B, **COLS)
-    hrr = seg.segment_histogram(pr, jnp.int32(start) + nlr,
-                                jnp.int32(count) - nlr,
-                                num_features=F, num_bins=B, **COLS)
-    np.testing.assert_allclose(np.asarray(hl), np.asarray(hlr),
-                               rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(np.asarray(hr), np.asarray(hrr),
-                               rtol=1e-4, atol=1e-4)
-
-
-def test_partition_hist_flag_staged_off():
-    """The merged kernel's VMEM gate admits Higgs but not the wide
-    accumulator shapes.  The flag itself may be either state: False until
-    exp/smoke_tpu_kernels.py validates the Mosaic lowering on a real chip
-    (round-4 discipline), True once exp/flip_validated.py merged ran
-    after a green smoke."""
-    if seg.CHUNK != 256:
-        pytest.skip("VMEM gate expectations assume the default CHUNK")
-    # pinned OFF until a hardware smoke validates the merged kernel's
-    # Mosaic lowering; flip this expectation in the SAME commit as
-    # exp/flip_validated.py merged (matching the other three flag pins —
-    # the previous `in (False, True)` form could never fail)
-    assert pseg.PARTITION_HIST_VALIDATED is False
-    assert pseg.partition_hist_fits_vmem(128, 28, 256)    # Higgs
-    assert pseg.partition_hist_fits_vmem(128, 137, 64)    # MS-LTR @ 64 bins
-    # MS-LTR at 256 bins (13.1M plan) and Expo-wide (88 tiles) exceed the
-    # budget and fall back to the split acc-partition + hist kernels
-    assert not pseg.partition_hist_fits_vmem(256, 137, 256)
-    assert not pseg.partition_hist_fits_vmem(896, 700, 256)
-
-
-@pytest.mark.parametrize("expand", ["matmul", "repeat"])
-def test_partition_hist_matches_hist_kernel(expand):
-    """The merged kernel keeps the B-wide one-hot body `_hist_kernel` had
-    before PR 27 — this pins its two child histograms against the
-    factored kernel's so divergence is loud."""
-    pay = _payload(1024, seed=42)
-    aux = jnp.zeros_like(pay)
-    pred = _pred(feature=2, threshold=B // 3)
-    p2, _, nl, hl, hr = pseg.partition_segment_hist(
-        pay, aux, jnp.int32(64), jnp.int32(900), pred, jnp.float32(1.0),
-        jnp.float32(-1.0), VALUE_COL, B, num_features=F, interpret=True,
-        expand_impl=expand, **COLS)
-    hl_k = pseg.segment_histogram(p2, jnp.int32(64), nl, num_features=F,
-                                  num_bins=B, interpret=True, **COLS)
-    hr_k = pseg.segment_histogram(p2, jnp.int32(64) + nl,
-                                  jnp.int32(900) - nl, num_features=F,
-                                  num_bins=B, interpret=True, **COLS)
-    np.testing.assert_allclose(np.asarray(hl), np.asarray(hl_k),
-                               rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(hr), np.asarray(hr_k),
-                               rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.parametrize("predkw", [
-    dict(is_cat=True, bitset=(np.arange(B) % 3 == 0)),
-    dict(feature=2, threshold=3, offset=5, identity=False, num_bin=9,
-         default_bin=0),
-    dict(missing_type=2, default_left=True, threshold=3),
-])
-def test_partition_hist_merged_predicates(predkw):
-    """Merged kernel under categorical-bitset, EFB-decode and
-    missing-routing predicates, with some rows bagged out (zeroed
-    grad/hess/cnt must contribute nothing to either child histogram while
-    the rows still move)."""
-    pay = np.array(_payload(1024, seed=99))   # writable copy
-    rng = np.random.default_rng(7)
-    out_bag = rng.random(1024) < 0.3
-    pay[:1024][out_bag, F:F + 3] = 0.0
-    pay = jnp.asarray(pay)
-    aux = jnp.zeros_like(pay)
-    pred = _pred(**predkw)
-    lv, rv = jnp.float32(0.5), jnp.float32(-0.5)
-    p2, _, nl, hl, hr = pseg.partition_segment_hist(
-        pay, aux, jnp.int32(0), jnp.int32(1024), pred, lv, rv,
-        VALUE_COL, B, num_features=F, interpret=True, **COLS)
-    pr, _, nlr = seg.partition_segment(
-        pay, aux, jnp.int32(0), jnp.int32(1024), pred, lv, rv, VALUE_COL)
-    assert int(nl) == int(nlr)
-    np.testing.assert_allclose(np.asarray(p2), np.asarray(pr),
-                               rtol=1e-6, atol=0)
-    hlr = seg.segment_histogram(pr, jnp.int32(0), nlr, num_features=F,
-                                num_bins=B, **COLS)
-    hrr = seg.segment_histogram(pr, nlr, jnp.int32(1024) - nlr,
-                                num_features=F, num_bins=B, **COLS)
-    np.testing.assert_allclose(np.asarray(hl), np.asarray(hlr),
-                               rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(np.asarray(hr), np.asarray(hrr),
-                               rtol=1e-4, atol=1e-4)
-
-
-# ---------------------------------------------------------------------------
-# column-block engine (ultra-wide payloads)
-# ---------------------------------------------------------------------------
-
 def _wide_payload(n_pad, F_wide, B_wide, seed=0):
     """Ultra-wide payload: F_wide bin columns, aux (grad/hess/cnt) after
     them, lane-padded width like the fast path's _FastState.P."""
     return _hist_payload(F_wide, B_wide, n_pad,
                          width=-(-(F_wide + 8) // 128) * 128, seed=seed)
-
-
-def test_colblock_flag_staged_off():
-    # pinned OFF until a hardware smoke validates the two-window DMA
-    # lowering; flip in the SAME commit as exp/flip_validated.py colblock
-    assert pseg.HIST_COLBLOCK_VALIDATED is False
-
-
-@pytest.mark.parametrize("ring_depth", [2, 4])
-def test_merged_kernel_ring_depths(ring_depth):
-    """The ring flag also drives the merged kernel — exactness at both
-    depths (the flip's smoke validates Mosaic legality for BOTH)."""
-    pay = _payload(1024, seed=9)
-    aux = jnp.zeros_like(pay)
-    pred = _pred(feature=2, threshold=B // 3)
-    p4, a4, nl4, hl4, hr4 = pseg.partition_segment_hist(
-        pay, aux, jnp.int32(100), jnp.int32(800), pred,
-        jnp.float32(0.5), jnp.float32(-0.5), VALUE_COL, B,
-        num_features=F, interpret=True, ring_depth=ring_depth, **COLS)
-    ref_pay, _, ref_nl = seg.partition_segment(
-        pay, aux, jnp.int32(100), jnp.int32(800), pred,
-        jnp.float32(0.5), jnp.float32(-0.5), VALUE_COL)
-    assert int(nl4) == int(ref_nl)
-    np.testing.assert_allclose(np.asarray(p4), np.asarray(ref_pay),
-                               rtol=1e-6, atol=0)
-
-
-def test_ring4_flag_staged_off():
-    # pinned OFF until the smoke's RING section validates + races the
-    # 4-deep ring; flip in the SAME commit as flip_validated.py ring4
-    assert pseg.PARTITION_RING4_VALIDATED is False
-
-
-@pytest.mark.parametrize("fw,bw", [(4228, 256), (2000, 64), (700, 256)])
-def test_colblock_plan_and_gate(fw, bw):
-    """Raw-Allstate / Epsilon / Expo widths all get a colblock plan whose
-    per-pass VMEM fits, even where the single-pass kernel's plan cannot."""
-    pay, cols = _wide_payload(8, fw, min(bw, 32))  # tiny rows; plan only
-    P_wide = pay.shape[1]
-    assert pseg.fits_vmem_colblock(fw, bw, P_wide, **{
-        "grad_col": cols["grad_col"], "hess_col": cols["hess_col"],
-        "cnt_col": cols["cnt_col"]})
-    if (fw, bw) == (4228, 256):
-        # the one benchmark shape the single-pass kernel cannot plan
-        assert not pseg.fits_vmem(fw, bw)
-    blocks, aux_lo, aux_w = pseg.colblock_plan(
-        fw, bw, P_wide, cols["grad_col"], cols["hess_col"],
-        cols["cnt_col"])
-    assert sum(f for _, f, _ in blocks) == fw
-    assert all(lo % 128 == 0 and w % 128 == 0 for lo, _, w in blocks)
-    assert aux_lo % 128 == 0 and aux_lo + aux_w <= P_wide
-    assert aux_lo <= cols["grad_col"] < aux_lo + aux_w
-    assert aux_lo <= cols["cnt_col"] < aux_lo + aux_w
-
-
-@pytest.mark.parametrize("start,count", [(0, 1000), (256, 700), (100, 37),
-                                         (0, 0), (7, 1), (9, 1015)])
-def test_colblock_matches_portable_wide(start, count):
-    """Exactness at an ultra-wide shape (1500 features x 16 bins keeps
-    interpret-mode runtime sane while spanning multiple 512-lane blocks
-    and a ragged tail)."""
-    Fw, Bw = 1500, 16
-    pay, cols = _wide_payload(1024, Fw, Bw, seed=5)
-    ref = seg.segment_histogram(pay, jnp.int32(start), jnp.int32(count),
-                                num_features=Fw, num_bins=Bw, **cols)
-    got = pseg.segment_histogram_colblock(
-        pay, jnp.int32(start), jnp.int32(count), num_features=Fw,
-        num_bins=Bw, interpret=True, **cols)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.parametrize("expand", ["matmul", "repeat"])
-def test_colblock_matches_hist_kernel(expand):
-    """At a width BOTH engines handle, the colblock sibling (the B-wide
-    one-hot body) must equal the single-pass kernel: the counts to the
-    last digit, the sums within f32 accumulation error (the factored
-    product associates them differently)."""
-    pay = _payload(1024, seed=42)
-    # the colblock engine requires a lane-padded payload (the fast path's
-    # _FastState.P guarantee); pad the narrow test payload to 128 lanes
-    pay128 = jnp.pad(pay, ((0, 0), (0, 128 - pay.shape[1])))
-    ref = pseg.segment_histogram(pay128, jnp.int32(0), jnp.int32(1000),
-                                 num_features=F, num_bins=B,
-                                 interpret=True, **COLS)
-    got = pseg.segment_histogram_colblock(
-        pay128, jnp.int32(0), jnp.int32(1000), num_features=F, num_bins=B,
-        interpret=True, expand_impl=expand, **COLS)
-    np.testing.assert_array_equal(np.asarray(got[..., 2]),
-                                  np.asarray(ref[..., 2]))
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
 
 
 # ---------------------------------------------------------------------------
@@ -668,6 +446,49 @@ def test_partition_engine_by_shape(monkeypatch, hist_impl, backend, width,
     assert grower2.partition_engine(hist_impl, width, bins) == engine
 
 
+@pytest.mark.parametrize("backend,features,bins,width,kw,engine", [
+    ("tpu", 28, 256, 128, {}, "pallas"),          # higgs-train
+    ("tpu", 67, 256, 128, {}, "pallas"),          # criteo-dp4-train, a shard
+    ("tpu", 2000, 64, 2048, {}, "pallas"),        # epsilon-train
+    # a feature-parallel shard histograms its 500 owned columns of full rows
+    ("tpu", 2000, 64, 2048,
+     dict(axis_name="x", mode="feature", num_machines=4), "pallas"),
+    ("tpu", 28, 257, 128, {}, "lax"),             # past the bf16-exact bins
+    ("tpu", 4228, 256, 4352, {}, "lax"),          # raw Allstate: past VMEM
+    ("tpu", 28, 256, 128, dict(quantized=True, qmax=127), "lax"),
+    ("cpu", 28, 256, 128, {}, "lax"),
+])
+def test_histogram_engine_by_shape(monkeypatch, backend, features, bins,
+                                   width, kw, engine):
+    """The histogram engine, like the partition's, follows from the
+    platform and the shape alone: the one Pallas kernel wherever its plan
+    fits, the lax engine past 256 bins, past the plan, for the int32
+    histograms of quantized gradients and off the TPU."""
+    if seg.CHUNK != 256:
+        pytest.skip("VMEM gate expectations assume the default CHUNK")
+    from lightgbm_tpu.boosting import grower2
+    from lightgbm_tpu.boosting.grower import GrowerConfig
+    from lightgbm_tpu.ops.split import FeatureMeta
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    zeros = np.zeros(features, np.int32)
+    meta = FeatureMeta(num_bin=zeros + bins, missing_type=zeros,
+                       default_bin=zeros, is_trivial=zeros > 0,
+                       is_categorical=zeros > 0,
+                       penalty=np.ones(features, np.float32), monotone=zeros)
+    cfg = GrowerConfig(num_leaves=255, max_depth=-1, lambda_l1=0.0,
+                       lambda_l2=0.0, max_delta_step=0.0, min_data_in_leaf=20,
+                       min_sum_hessian_in_leaf=1e-3, min_gain_to_split=0.0)
+    cols = grower2.PayloadCols(grad=features, hess=features + 1,
+                               cnt=features + 2, value=features + 3)
+    grower = grower2.make_partitioned_grower(
+        meta, cfg, bins, cols, features, jit=False, payload_width=width,
+        **kw)
+    assert grower.engines["histogram"] == engine
+    if bins > 256:
+        with pytest.raises(ValueError):
+            seg.resolve_impl("pallas", features, bins)
+
+
 def test_partition_blocks_vmem_gate():
     if seg.CHUNK != 256:
         pytest.skip("VMEM gate expectations assume the default CHUNK")
@@ -676,7 +497,6 @@ def test_partition_blocks_vmem_gate():
     assert pseg.partition_blocks_fits_vmem(4352, 256)   # raw Allstate
     assert not pseg.partition_fits_vmem(2048, 64)
     assert not pseg.partition_acc_fits_vmem(4352, 256)
-    assert "blocks" not in pseg.STAGED_FLAGS
 
 
 @pytest.mark.parametrize("start,count,predkw", [
@@ -693,14 +513,15 @@ def test_partition_blocks_vmem_gate():
     (7, 777, dict(feature=1100, threshold=B // 2)),
     (513, 300, dict(feature=1199, threshold=5)),
 ])
-@pytest.mark.parametrize("roll", [False, True])
-def test_partition_blocks_matches(start, count, predkw, roll):
-    """Ultra-wide payload (three lane windows, the last a ragged 256 lanes
-    that takes pass A two chunks a trip): the per-block passes reproduce
-    the portable partition bit for bit -- one consistent permutation
-    across every window, value column written only by its own block."""
+@pytest.mark.parametrize("block_w", [640, 384])
+def test_partition_blocks_matches(start, count, predkw, block_w):
+    """Ultra-wide payload in two lane windows, and in four (the last a
+    ragged 128 lanes that takes pass A two chunks a trip): the per-block
+    passes reproduce the portable partition bit for bit -- one consistent
+    permutation across every window, value column written only by its own
+    block."""
     Fw = 1200
-    Pw = -(-(Fw + 8) // 128) * 128   # 1280: 2x512 + 1x256 windows
+    Pw = -(-(Fw + 8) // 128) * 128   # 1280: 2 x 640, or 3 x 384 + 128
     pay, _ = _wide_payload(1024, Fw, B, seed=start + count)
     assert pay.shape[1] == Pw
     aux = jnp.zeros_like(pay)
@@ -711,7 +532,7 @@ def test_partition_blocks_matches(start, count, predkw, roll):
         pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv, vcol)
     got_pay, _, got_nl = pseg.partition_segment_acc_blocks(
         pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv,
-        vcol, B, interpret=True, roll_place=roll)
+        vcol, B, interpret=True, block_w=block_w)
     assert int(got_nl) == int(ref_nl)
     np.testing.assert_array_equal(np.asarray(got_pay), np.asarray(ref_pay))
 
@@ -752,7 +573,7 @@ def test_partition_blocks_pass_a_is_one_permutation():
     closed = jax.make_jaxpr(
         lambda p, a: pseg._partition_segment_acc_blocks(
             p, a, jnp.int32(7), jnp.int32(777), _pred(), jnp.float32(1.0),
-            jnp.float32(-1.0), 1203, B, False, True, 2, 512))(
+            jnp.float32(-1.0), 1203, B))(
         pay, jnp.zeros_like(pay))
     assert _while_dots(closed.jaxpr) == [0, 4, 0, 4, 0, 8, 0]
 
@@ -760,8 +581,7 @@ def test_partition_blocks_pass_a_is_one_permutation():
 def test_partition_blocks_narrow_pin():
     """At a width the single-pass kernel also handles, blocks (one
     window) agree with it bit for bit."""
-    pay = _payload(1024, seed=11)
-    pay128 = jnp.pad(pay, ((0, 0), (0, 128 - pay.shape[1])))
+    pay128 = _widened(_payload(1024, seed=11), 128)
     aux = jnp.zeros_like(pay128)
     pred = _pred(feature=2, threshold=B // 3)
     lv, rv = jnp.float32(1.5), jnp.float32(-2.5)
@@ -773,54 +593,6 @@ def test_partition_blocks_narrow_pin():
         VALUE_COL, B, interpret=True)
     assert int(got_nl) == int(ref_nl)
     np.testing.assert_array_equal(np.asarray(got_pay), np.asarray(ref_pay))
-
-
-# ---------------------------------------------------------------------------
-# frontier batching: the batched histogram kernel + its staged flag
-# ---------------------------------------------------------------------------
-
-def test_frontier_flag_staged_off():
-    # pinned OFF until the smoke's FRONTIER section validates the
-    # multi-step scalar-prefetch grid on a chip; flip in the SAME commit
-    # as flip_validated.py frontier
-    assert pseg.FRONTIER_BATCH_VALIDATED is False
-    assert pseg.STAGED_FLAGS["frontier"] == "FRONTIER_BATCH_VALIDATED"
-
-
-@pytest.mark.parametrize("expand", ["matmul", "repeat"])
-def test_hist_batched_matches_portable(expand):
-    """Grid-(K,) batched kernel vs the portable batched engine, including
-    unaligned starts, a 1-row segment and a zero-count padding slot."""
-    pay = _payload(1024, seed=5)
-    starts = jnp.asarray([0, 256, 100, 513, 7, 0], jnp.int32)
-    counts = jnp.asarray([1000, 700, 37, 256, 1, 0], jnp.int32)
-    cols = dict(num_features=F, num_bins=B, **COLS)
-    ref = seg.segment_histogram_batched(pay, starts, counts, **cols)
-    got = pseg.segment_histogram_batched(pay, starts, counts,
-                                         interpret=True, expand_impl=expand,
-                                         **cols)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_hist_batched_slice_matches_single_segment_kernel():
-    """Each batched-grid slice (the B-wide one-hot body) must agree with
-    the single-segment kernel on the same segment: the counts to the last
-    digit, the sums within f32 accumulation error."""
-    pay = _payload(1024, seed=6)
-    starts = jnp.asarray([9, 300], jnp.int32)
-    counts = jnp.asarray([291, 700], jnp.int32)
-    cols = dict(num_features=F, num_bins=B, **COLS)
-    got = pseg.segment_histogram_batched(pay, starts, counts,
-                                         interpret=True,
-                                         expand_impl="matmul", **cols)
-    for k in range(2):
-        ref = pseg.segment_histogram(pay, starts[k], counts[k],
-                                     interpret=True, **cols)
-        np.testing.assert_array_equal(np.asarray(got[k][..., 2]),
-                                      np.asarray(ref[..., 2]))
-        np.testing.assert_allclose(np.asarray(got[k]), np.asarray(ref),
-                                   rtol=1e-5, atol=1e-5)
 
 
 def test_hist_vmem_gate_uses_real_payload_width():

@@ -13,7 +13,6 @@ import jax.numpy as jnp
 import pytest
 
 import lightgbm_tpu as lgb
-from lightgbm_tpu.ops import pallas_segment as pseg
 from lightgbm_tpu.ops import segment as seg
 from lightgbm_tpu.ops.quantize import (QUANT_DTYPE_MAX, derive_qmax,
                                        quantize_pair, stochastic_round)
@@ -127,45 +126,6 @@ def test_quant_hist_matches_f32_engine(start, count):
                                   np.asarray(hf).astype(np.int64))
 
 
-@pytest.mark.parametrize("start,count", [(0, 1000), (100, 37), (513, 256),
-                                         (7, 1), (0, 0)])
-def test_pallas_quant_kernel_matches_portable(start, count):
-    """The staged int8 x one-hot -> int32 MXU kernel, in interpret mode,
-    is BIT-equal to the portable integer engine (integer accumulation is
-    order-free, so no tolerance is needed or allowed)."""
-    pay = _quant_payload(1024, seed=3)
-    ref = seg.segment_histogram(pay, jnp.int32(start), jnp.int32(count),
-                                num_features=F, num_bins=B, quantized=True,
-                                **COLS)
-    got = pseg.segment_histogram_quant(pay, jnp.int32(start),
-                                       jnp.int32(count), num_features=F,
-                                       num_bins=B, interpret=True, **COLS)
-    assert got.dtype == jnp.int32
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
-
-
-def test_pallas_quant_kernel_tiled_shape():
-    """Feature-tiled path of the quant kernel (MS-LTR-ish shape)."""
-    f, b = 137, 64
-    cols = dict(grad_col=f, hess_col=f + 1, cnt_col=f + 2)
-    p = f + 4
-    rng = np.random.default_rng(9)
-    n = 600
-    pay = np.zeros((n + seg.GUARD, p), np.float32)
-    pay[:n, :f] = rng.integers(0, b, size=(n, f))
-    pay[:n, f] = rng.integers(-127, 128, n)
-    pay[:n, f + 1] = rng.integers(0, 128, n)
-    pay[:n, f + 2] = 1.0
-    pay = jnp.asarray(pay)
-    ref = seg.segment_histogram(pay, jnp.int32(8), jnp.int32(400),
-                                num_features=f, num_bins=b, quantized=True,
-                                **cols)
-    got = pseg.segment_histogram_quant(pay, jnp.int32(8), jnp.int32(400),
-                                       num_features=f, num_bins=b,
-                                       interpret=True, **cols)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
-
-
 def test_quant_hist_batched_matches_single():
     pay = _quant_payload(1024, seed=5)
     starts = jnp.asarray([0, 128, 900], jnp.int32)
@@ -178,14 +138,6 @@ def test_quant_hist_batched_matches_single():
                                    num_bins=B, quantized=True, **COLS)
         np.testing.assert_array_equal(np.asarray(hb[k]), np.asarray(hk))
     assert not np.asarray(hb[2]).any()
-
-
-def test_quant_flag_staged_off():
-    """Round-4 discipline: the int8 MXU kernel stays OFF until a hardware
-    window validates its Mosaic lowering (smoke 'quant' section, then
-    exp/flip_validated.py quant)."""
-    assert pseg.HIST_QUANT_VALIDATED is False
-    assert pseg.STAGED_FLAGS["quant"] == "HIST_QUANT_VALIDATED"
 
 
 # ---------------------------------------------------------------------------

@@ -61,17 +61,8 @@ def _partition_args(sharding, rows=ROWS, lanes=LANES, features=FEATURES,
     return payload, payload, i32, i32, pred, f32, f32, features + 3, bins
 
 
-@pytest.mark.parametrize("ring_depth", [2, 4])
-def test_partition_acc_compiles_for_v5e(one_chip, ring_depth):
-    lowered = pseg._partition_segment_acc.lower(
-        *_partition_args(one_chip), False, True, ring_depth)
-    assert "tpu_custom_call" in lowered.compile().as_text()
-
-
-def test_partition_hist_merged_compiles_for_v5e(one_chip):
-    lowered = pseg._partition_segment_hist.lower(
-        *_partition_args(one_chip), FEATURES, FEATURES, FEATURES + 1,
-        FEATURES + 2, False, True, "repeat", 2)
+def test_partition_acc_compiles_for_v5e(one_chip):
+    lowered = pseg._partition_segment_acc.lower(*_partition_args(one_chip))
     assert "tpu_custom_call" in lowered.compile().as_text()
 
 
@@ -81,8 +72,7 @@ def test_partition_blocks_compiles_for_v5e_at_epsilon(one_chip):
     assert pseg.partition_blocks_fits_vmem(WIDE_LANES, WIDE_BINS)
     lowered = pseg._partition_segment_acc_blocks.lower(
         *_partition_args(one_chip, WIDE_ROWS, WIDE_LANES, WIDE_FEATURES,
-                         WIDE_BINS),
-        False, True, 2, pseg.COLBLOCK_WIDTH)
+                         WIDE_BINS))
     assert lowered.compile().as_text().count("tpu_custom_call") >= 5
 
 
