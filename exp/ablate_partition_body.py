@@ -3,15 +3,22 @@ piece of the loop body costs a chunk on the chip.
 
 The profiler's trace ends at the kernel's edge (PERF.md §7: "time inside a
 Pallas kernel" is not seen), so the body is timed by leaving pieces out.
-`_pass_a_kernel` below is pass A of `pallas_segment._acc_kernel` as it
-stands (ring read, routing, rank mat-vec, one destination one-hot, three
-part matmuls, two rotates of the doubled block, two blends, the flushes),
-without pass B and the final blend read, and with a static set of stubs:
+`_pass_a_kernel` below is pass A of `pallas_segment._acc_kernel` (ring
+read, index arithmetic, one destination one-hot, three part matmuls, two
+rotates of the doubled block, two blends, the flushes), without pass B and
+the final blend read, in two bodies:
 
-    route    the routing is a parity of the row number (no lane reduction,
-             no [C, B] bitset one-hot)
-    rank     the lefts' ranks are the row number (no tri mat-vec)
-    onehot   the [C, C] one-hot is the hoisted `tri` (none built a chunk)
+    new   as the kernel stands since PR 29: the index arithmetic with rows
+          in lanes (`_acc_kernel.routed`), the chunks of a trip as rows of
+          one [8, C] vector
+    old   as it stood before: every per-row quantity a [C] vector born
+          from a lane reduction, one row a sublane (`_go_left_rows`, a
+          `tri x gl` mat-vec, a relayout of the destination into the
+          one-hot's compare), kept runnable so that both tables come from
+          one instrument
+
+and with a static set of stubs.  Both bodies:
+
     matmul2  one part matmul of the three (the two others' cost)
     parts    no bf16 hi/mid/lo split (the chunk stands in for each part)
     rotate   no dynamic rotate of the doubled block
@@ -19,24 +26,56 @@ without pass B and the final blend read, and with a static set of stubs:
     flush    no write of a full accumulator window to HBM
     body     nothing but the ring read and one add of the chunk (the DMA
              floor)
+    rank     the lefts' ranks are the row number (no product)
+    onehot   the [C, C] one-hot is the hoisted triangle (none built a
+             chunk); the old body's is split further:
+               relayout  the one-hot compares with a lane iota (the
+                         destination and the membership are not moved
+                         from sublanes to lanes)
+               compare   the relayout is made (its row stored) but no
+                         [C, C] compare, and, convert
+    route    the routing is a parity of the row number; split further:
+               colselect  the split column's bins are the row number (old:
+                          no [C, P] select and lane reduction; new: no
+                          masked NT product)
+               mask       (new) the window's other lanes are not zeroed
+                          before the product
+               predicate  the Bin::Split arithmetic is a parity of the bin
+               catonehot  (old) no [C, B] bitset one-hot and its lane
+                          reduction; `catword` (new): no word select chain
+
+the choices the new body was made from, by race (`--race`):
+
+    rank=roll  the exclusive prefix count by log-step roll-and-add along
+               lanes, not `[8, C] x tri_t` on the MXU
+    col=xpose  the split column by one XLU transposition of its window
+               and a row load at a dynamic sublane, not an NT product of a
+               one-hot row against the masked window; `col=nt` the product
+               with the window not masked (a NaN in another lane would
+               reach it), `col=nthigh` at the precision bins past 256 need
+    nl=scalar  the lefts' count reaches the destination through a scalar,
+               not as a lane-reduced [8, 1] vector
 
 and the loop's shape, `group2` / `group4`: that many chunks a loop trip on
-a ring twice as deep, every wait and load first, then every chunk's
-permuted block, then the placements in order, so that independent chains
-(routing, rank, one-hot, matmuls) lie in one basic block for the scheduler
-to interleave.  The product takes 2 at this width (`_pass_a_group`); the
-stubs above are read on one chunk a trip, where a piece's cost is not
-hidden behind another chunk's.
+a ring twice as deep.  The product takes 2 at 128 lanes (`_pass_a_group`)
+and 1 in a 512-lane block; the stubs are read on one chunk a trip, where a
+piece's cost is not hidden behind another chunk's.
+
+The argument `512` (beside `128`, the default being both) times one
+512-lane column block: the kernel moves a 512-lane payload and routes from
+a [N, 128] copy of the split window, read into a ring of its own, as
+`partition_segment_acc_blocks`' passes do.
 
 A stubbed kernel computes nonsense; only its time is read.  With no stub
-the lefts it writes are checked against the portable partition, so the
-copy is the product's body.  Times are wall clock round a call whose
-payload is donated (no copy in the program) and whose scalar result is
-fetched; the cost of a piece is full minus stubbed, per chunk of CHUNK
+the lefts it writes are checked against the portable partition, for a
+numerical and a categorical predicate.  Times are wall clock round a call
+whose payload is donated (no copy in the program) and whose scalar result
+is fetched; the cost of a piece is full minus stubbed, per chunk of CHUNK
 rows.  Pieces overlap in the kernel's schedule, so the costs need not add
 up to the body.
 
-On the chip:   python exp/ablate_partition_body.py
+On the chip:   python exp/ablate_partition_body.py [--race] [128] [512]
+               [--match A,B]   (only `full` and the labels that hold A or B)
 CPU rehearsal: JAX_PLATFORMS=cpu python exp/ablate_partition_body.py --interpret
 """
 import functools
@@ -59,14 +98,22 @@ from lightgbm_tpu.ops import segment as seg
 from lightgbm_tpu.ops import pallas_segment as pseg
 
 CHUNK, C2 = pseg.CHUNK, pseg.C2
-STUBS = ("route", "rank", "onehot", "matmul2", "parts", "rotate", "blend",
-         "flush", "body")
+SHARED_STUBS = ("rank", "onehot", "matmul2", "parts", "rotate", "blend",
+                "flush", "body", "route", "colselect", "predicate")
+OLD_STUBS = SHARED_STUBS + ("catonehot", "relayout", "compare")
+NEW_STUBS = SHARED_STUBS + ("mask", "catword")
+RACES = ("rank=roll", "col=xpose", "col=nt", "col=nthigh", "nl=scalar")
 
 
-def _pass_a_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm,
-                   payload_out, aux_out, nl_out,
-                   ring, lacc, racc, stage, rbuf, sem_ring, sem_w, sem_r, *,
-                   P, B, value_col, stubs, group):
+def _pass_a_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
+                   P, B, value_col, stubs, group, body, blocks):
+    if blocks:
+        route_hbm, *rest = rest
+    payload_out, aux_out, nl_out, *rest = rest
+    (ring, lacc, racc, stage, rbuf, win_t, dump, sem_ring, sem_w, sem_r,
+     *rest) = rest
+    if blocks:
+        route_ring, sem_route = rest
     start, count = scalars[0], scalars[1]
     left_value, right_value = fvals[0], fvals[1]
     shift = lax.rem(start, 8)
@@ -75,16 +122,29 @@ def _pass_a_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm,
     iota_rows = pseg._row_iota()
     iota_c2 = lax.broadcasted_iota(jnp.int32, (C2, 1), 0)[:, 0]
     iota_p = lax.broadcasted_iota(jnp.int32, (1, P), 1)
+    # the lanes of the split window: the 128-lane chunk's own, or those of
+    # a column block's copy (this script times no other width)
+    iota_route = lax.broadcasted_iota(jnp.int32, (1, 128), 1)
     iota_ci = lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 0)
-    tri = (lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 1) <
-           iota_ci).astype(jnp.float32)
-    R = ring.shape[0]
+    iota_cj = lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 1)
+    tri = (iota_cj < iota_ci).astype(jnp.float32)
+    tri_t = (iota_ci < iota_cj).astype(jnp.float32)
+    chunk_of_row = lax.broadcasted_iota(jnp.int32, (8, CHUNK), 0)
+    row_of_lane = lax.broadcasted_iota(jnp.int32, (8, CHUNK), 1)
+    R, G = ring.shape[0], group
+    route_col = scalars[2]
+    route_sel = (lax.broadcasted_iota(jnp.int32, (8, 128), 1) ==
+                 route_col).astype(jnp.float32)
 
-    def ring_dma(k, slot):
-        return pltpu.make_async_copy(
-            payload_out.at[pl.ds(pl.multiple_of(base + k * CHUNK, 8),
-                                 CHUNK), :],
-            ring.at[slot], sem_ring.at[slot])
+    def read_a(k, slot):
+        rows = pl.ds(pl.multiple_of(base + k * CHUNK, 8), CHUNK)
+        dmas = [pltpu.make_async_copy(payload_out.at[rows, :], ring.at[slot],
+                                      sem_ring.at[slot])]
+        if blocks:
+            dmas.append(pltpu.make_async_copy(
+                route_hbm.at[rows, :], route_ring.at[slot],
+                sem_route.at[slot]))
+        return dmas
 
     def blend(acc, placed, cnt, off, value):
         if "blend" in stubs:
@@ -110,21 +170,66 @@ def _pass_a_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm,
 
     @pl.when(nch > 0)
     def _prefetch_first():
-        for i in range(group if group > 1 else R - 1):
+        for i in range(G if G > 1 else R - 1):
             @pl.when(i < nch)
             def _start(i=i):
-                ring_dma(i, i).start()
+                for dma in read_a(i, i):
+                    dma.start()
 
-    def permuted(k, data):
-        """The chunk's doubled permuted block and its two counts: no
-        accumulator, cursor or DMA is touched."""
+    def placed_block(data, mat):
+        hi, mid, lo = (data, data, data) if "parts" in stubs \
+            else pseg._bf16_parts(data)
+        perm = jnp.dot(mat, hi, preferred_element_type=jnp.float32)
+        if "matmul2" in stubs:
+            perm = perm + mid + lo
+        else:
+            perm = (perm +
+                    jnp.dot(mat, mid, preferred_element_type=jnp.float32) +
+                    jnp.dot(mat, lo, preferred_element_type=jnp.float32))
+        return jnp.concatenate([perm, perm], axis=0)
+
+    # ---- the body before PR 29: one row a sublane --------------------------
+    def go_left_rows(route):
+        """`pseg._go_left_rows` with its three pieces stubbable."""
+        col, threshold, default_left = scalars[2], scalars[3], scalars[4]
+        is_cat, missing_type, num_bin = scalars[5], scalars[6], scalars[7]
+        default_bin, offset, identity = scalars[8], scalars[9], scalars[10]
+        if "colselect" in stubs:
+            raw = iota_rows
+        else:
+            raw = jnp.sum(jnp.where(iota_route == col, route, 0.0),
+                          axis=1).astype(jnp.int32)
+        if "predicate" in stubs:
+            fbin = raw
+            gl_num = raw & 1
+        else:
+            e = raw - offset
+            in_range = ((e >= 0) & (e < num_bin - 1)).astype(jnp.int32)
+            bump = (e >= default_bin).astype(jnp.int32)
+            decoded = in_range * (e + bump) + (1 - in_range) * default_bin
+            fbin = identity * raw + (1 - identity) * decoded
+            miss = (((missing_type == pseg.MISSING_NAN) &
+                     (fbin == num_bin - 1)).astype(jnp.int32) |
+                    ((missing_type == pseg.MISSING_ZERO) &
+                     (fbin == default_bin)).astype(jnp.int32))
+            gl_num = (miss * default_left +
+                      (1 - miss) * (fbin <= threshold).astype(jnp.int32))
+        if "catonehot" in stubs:
+            gl_cat = fbin & 1
+        else:
+            iota_b = lax.broadcasted_iota(jnp.int32, (CHUNK, B), 1)
+            hits = ((fbin[:, None] == iota_b) &
+                    (bitset_ref[:] > 0)).astype(jnp.int32)
+            gl_cat = (jnp.sum(hits, axis=1) > 0).astype(jnp.int32)
+        return is_cat * gl_cat + (1 - is_cat) * gl_num
+
+    def permuted_old(k, data, route):
         valid = ((iota_rows >= shift - k * CHUNK) &
                  (iota_rows < shift + count - k * CHUNK)).astype(jnp.int32)
         if "route" in stubs:
             gl = (iota_rows & 1) * valid
         else:
-            gl = pseg._go_left_rows(scalars, bitset_ref, data, B,
-                                    iota_p) * valid
+            gl = go_left_rows(route) * valid
         keep_r = valid - gl
         nlk = jnp.sum(gl)
         nrk = jnp.sum(keep_r)
@@ -139,19 +244,92 @@ def _pass_a_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm,
         dest = jnp.where(gl > 0, rank_l, nlk + rank_r)
         if "onehot" in stubs:
             mat = tri
+        elif "relayout" in stubs:
+            mat = ((iota_ci == iota_cj + nlk) &
+                   (iota_cj < nrk)).astype(jnp.float32)
+        elif "compare" in stubs:
+            dump[0:1, :] = dest[None, :]
+            dump[1:2, :] = valid[None, :]
+            mat = tri
         else:
             mat = ((iota_ci == dest[None, :]) &
                    (valid[None, :] > 0)).astype(jnp.float32)
-        hi, mid, lo = (data, data, data) if "parts" in stubs \
-            else pseg._bf16_parts(data)
-        perm = jnp.dot(mat, hi, preferred_element_type=jnp.float32)
-        if "matmul2" in stubs:
-            perm = perm + mid + lo
+        return placed_block(data, mat), nlk, nrk
+
+    # ---- the body since PR 29: rows in lanes, a trip's chunks together -----
+    def go_left_lanes(raw):
+        """`pseg._go_left_lanes` with its pieces stubbable."""
+        if "predicate" in stubs:
+            return raw & 1
+        if "catword" not in stubs:
+            return pseg._go_left_lanes(scalars, raw, B)
+        return pseg._go_left_lanes(scalars, raw, 0)   # no word, gl_cat 0
+
+    def routed(k0, windows):
+        if "colselect" in stubs:
+            raw = row_of_lane
+        elif "col=xpose" in stubs:
+            for g, window in enumerate(windows):
+                win_t[g] = window.T
+            raw = jnp.broadcast_to(win_t[0, pl.ds(route_col, 1), :],
+                                   (8, CHUNK))
+            for g in range(1, G):
+                raw = jnp.where(
+                    chunk_of_row == g,
+                    jnp.broadcast_to(win_t[g, pl.ds(route_col, 1), :],
+                                     (8, CHUNK)), raw)
+            raw = raw.astype(jnp.int32)
         else:
-            perm = (perm +
-                    jnp.dot(mat, mid, preferred_element_type=jnp.float32) +
-                    jnp.dot(mat, lo, preferred_element_type=jnp.float32))
-        return jnp.concatenate([perm, perm], axis=0), nlk, nrk
+            raw = None
+            for g, window in enumerate(windows):
+                if "mask" not in stubs and "col=nt" not in stubs:
+                    window = jnp.where(iota_route == route_col, window, 0.0)
+                column = lax.dot_general(
+                    route_sel, window, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                    precision=(lax.Precision.HIGHEST
+                               if "col=nthigh" in stubs else None))  # [8, C]
+                raw = column if raw is None \
+                    else jnp.where(chunk_of_row == g, column, raw)
+            raw = raw.astype(jnp.int32)
+        first = shift - (k0 + chunk_of_row) * CHUNK
+        valid = ((row_of_lane >= first) &
+                 (row_of_lane < first + count)).astype(jnp.int32)
+        if "route" in stubs:
+            gl = (row_of_lane & 1) * valid
+        else:
+            gl = go_left_lanes(raw) * valid
+        if "rank" in stubs:
+            rank_l = row_of_lane
+        elif "rank=roll" in stubs:
+            incl = gl
+            for step in (1, 2, 4, 8, 16, 32, 64, 128):
+                incl = incl + jnp.where(row_of_lane >= step,
+                                        pltpu.roll(incl, step, axis=1), 0)
+            rank_l = incl - gl
+        else:
+            rank_l = jnp.dot(gl.astype(jnp.float32), tri_t,
+                             preferred_element_type=jnp.float32
+                             ).astype(jnp.int32)
+        rank_r = jnp.maximum(row_of_lane - jnp.maximum(first, 0), 0) - rank_l
+        if "nl=scalar" in stubs:
+            nl = jnp.zeros_like(gl)
+            for g in range(G):
+                nl = jnp.where(chunk_of_row == g,
+                               jnp.sum(jnp.where(chunk_of_row == g, gl, 0)),
+                               nl)
+        else:
+            nl = jnp.sum(gl, axis=1, keepdims=True)
+        dest = jnp.where(gl > 0, rank_l, nl + rank_r)
+        return gl, jnp.where(valid > 0, dest, -1)
+
+    def permuted_new(g, k, data, gl, dest):
+        nlk = jnp.sum(jnp.where(chunk_of_row == g, gl, 0))
+        lo = jnp.maximum(shift - k * CHUNK, 0)
+        hi = jnp.minimum(shift + count - k * CHUNK, CHUNK)
+        mat = tri_t if "onehot" in stubs \
+            else (iota_ci == dest[g:g + 1, :]).astype(jnp.float32)
+        return placed_block(data, mat), nlk, jnp.maximum(hi - lo, 0) - nlk
 
     def place(both, nlk, nrk, carry):
         """Rotate the block to each accumulator's cursor, blend, flush."""
@@ -181,121 +359,128 @@ def _pass_a_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm,
                 ro_ + nrk - fr * CHUNK, lfl + fl, rfl + fr,
                 jnp.maximum(pl_, fl), jnp.maximum(pr_, fr))
 
-    def body_a(k, carry):
-        slot = lax.rem(k, R)
+    def body_trip(t, carry):
+        """Chunks G t .. G t + G - 1 (the caller's segment has a multiple
+        of G chunks).  Every wait and load comes before any chunk's
+        arithmetic: a DMA wait is a barrier the scheduler moves nothing
+        across."""
+        k0 = G * t
+        ahead = G if G > 1 else R - 1
+        for i in range(G):
+            @pl.when(k0 + ahead + i < nch)
+            def _prefetch(i=i):
+                for dma in read_a(k0 + ahead + i,
+                                  lax.rem(k0 + ahead + i, R)):
+                    dma.start()
 
-        @pl.when(k + R - 1 < nch)
-        def _prefetch_next():
-            ring_dma(k + R - 1, lax.rem(k + R - 1, R)).start()
-
-        ring_dma(k, slot).wait()
-        data = ring[slot]
-
-        if "body" in stubs:
-            lacc[0:CHUNK] += data
-            return (carry[0] + 1,) + carry[1:]
-
-        @pl.when(k == 0)
-        def _seed():
-            lacc[0:CHUNK] = data
-
-        return place(*permuted(k, data), carry)
-
-    def body_group(t, carry):
-        """Chunks group*t .. group*t + group - 1 (the caller's segment
-        has a multiple of `group` chunks); the trip before started their
-        reads.  Every wait and load comes before any chunk's arithmetic:
-        a DMA wait is a barrier the scheduler moves nothing across."""
-        k0 = group * t
-        for k in range(group):
-            @pl.when(k0 + group + k < nch)
-            def _prefetch(k=k):
-                ring_dma(k0 + group + k,
-                         lax.rem(k0 + group + k, R)).start()
-
-        for k in range(group):
-            ring_dma(k0 + k, lax.rem(k0 + k, R)).wait()
-        datas = [ring[lax.rem(k0 + k, R)] for k in range(group)]
+        slots = [lax.rem(k0 + i, R) for i in range(G)]
+        for i in range(G):
+            for dma in read_a(k0 + i, slots[i]):
+                dma.wait()
+        datas = [ring[slot] for slot in slots]
 
         if "body" in stubs:
             for data in datas:
                 lacc[0:CHUNK] += data
-            return (carry[0] + group,) + carry[1:]
+            return (carry[0] + G,) + carry[1:]
+
+        windows = [route_ring[slot] for slot in slots] if blocks else datas
 
         @pl.when(t == 0)
         def _seed():
             lacc[0:CHUNK] = datas[0]
 
-        blocks = [permuted(k0 + k, datas[k]) for k in range(group)]
-        for block in blocks:
-            carry = place(*block, carry)
+        if body == "old":
+            chunks = [permuted_old(k0 + i, datas[i], windows[i])
+                      for i in range(G)]
+        else:
+            gl, dest = routed(k0, windows)
+            chunks = [permuted_new(i, k0 + i, datas[i], gl, dest)
+                      for i in range(G)]
+        for chunk in chunks:
+            carry = place(*chunk, carry)
         return carry
 
-    carry0 = (jnp.int32(0), jnp.int32(0), shift, shift,
-              jnp.int32(0), jnp.int32(0), jnp.int32(0), jnp.int32(0))
-    if group > 1:
-        out = lax.fori_loop(0, nch // group, body_group, carry0)
-    else:
-        out = lax.fori_loop(0, nch, body_a, carry0)
+    out = lax.fori_loop(
+        0, nch // G, body_trip,
+        (jnp.int32(0), jnp.int32(0), shift, shift,
+         jnp.int32(0), jnp.int32(0), jnp.int32(0), jnp.int32(0)))
     nl_out[0] = out[0]
     drain(payload_out, stage, sem_w, out[6])
     drain(aux_out, rbuf, sem_r, out[7])
 
 
 @functools.partial(jax.jit, static_argnames=("value_col", "num_bins",
-                                             "interpret", "stubs", "group"),
+                                             "interpret", "stubs", "group",
+                                             "body"),
                    donate_argnums=(0, 1))
-def pass_a(payload, aux, start, count, pred, left_value, right_value,
-           value_col, num_bins, interpret, stubs, group=1):
+def pass_a(payload, aux, route, start, count, pred, left_value, right_value,
+           value_col, num_bins, interpret, stubs, group=1, body="new"):
+    """Pass A over `payload`; with `route` ([N, 128], the split window's
+    copy) as one column block's pass, else routed from the rows."""
     P, B = payload.shape[1], num_bins
-    scalars = jnp.stack([
-        start, count, pred.col, pred.threshold,
-        pred.default_left.astype(jnp.int32), pred.is_cat.astype(jnp.int32),
-        pred.missing_type, pred.num_bin, pred.default_bin,
-        pred.offset, pred.identity.astype(jnp.int32),
-    ]).astype(jnp.int32)
+    blocks = route is not None
+    win_lo = (pred.col // 128) * 128 if blocks else 0
+    scalars = pseg._acc_scalars(start, count, pred, pred.col - win_lo,
+                                win_lo, B)
     fvals = jnp.stack([left_value, right_value]).astype(jnp.float32)
     bitset = pred.bitset.astype(jnp.int32).reshape(1, B)
     kern = functools.partial(_pass_a_kernel, P=P, B=B, value_col=value_col,
-                             stubs=stubs, group=group)
+                             stubs=stubs, group=group, body=body,
+                             blocks=blocks)
     depth = 2 * group
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     payload_new, aux_new, nl = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(1,),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=(pl.BlockSpec(memory_space=pl.ANY),
-                       pl.BlockSpec(memory_space=pl.ANY),
-                       pl.BlockSpec(memory_space=pltpu.SMEM)),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM), hbm, hbm]
+            + [hbm] * blocks,
+            out_specs=(hbm, hbm, pl.BlockSpec(memory_space=pltpu.SMEM)),
             scratch_shapes=[
                 pltpu.VMEM((depth, CHUNK, P), jnp.float32),
                 pltpu.VMEM((C2, P), jnp.float32),
                 pltpu.VMEM((C2, P), jnp.float32),
                 pltpu.VMEM((CHUNK, P), jnp.float32),
                 pltpu.VMEM((CHUNK, P), jnp.float32),
+                pltpu.VMEM((group, 128, CHUNK), jnp.float32),
+                pltpu.VMEM((8, CHUNK), jnp.int32),
                 pltpu.SemaphoreType.DMA((depth,)),
                 pltpu.SemaphoreType.DMA(()),
                 pltpu.SemaphoreType.DMA(()),
-            ]),
+            ] + [pltpu.VMEM((depth, CHUNK, 128), jnp.float32),
+                 pltpu.SemaphoreType.DMA((depth,))] * blocks),
         out_shape=(jax.ShapeDtypeStruct(payload.shape, payload.dtype),
                    jax.ShapeDtypeStruct(aux.shape, aux.dtype),
                    jax.ShapeDtypeStruct((1,), jnp.int32)),
         input_output_aliases={3: 0, 4: 1},
         compiler_params=pseg._SIDE_EFFECTS,
         interpret=interpret,
-    )(scalars, fvals, bitset, payload, aux)
+    )(scalars, fvals, bitset, payload, aux, *([route] * blocks))
     return payload_new, aux_new, nl[0]
 
 
-def main():
-    interpret = "--interpret" in sys.argv[1:]
-    if not interpret and jax.default_backend() != "tpu":
-        sys.exit("ablate_partition_body: platform is %r, not tpu"
-                 % jax.default_backend())
-    n = 2048 if interpret else 1 << 22
-    F, B, P = 28, 256, 128
+def stub_sets(body, race):
+    """(label, stubs, group) of every kernel timed for `body`."""
+    names = OLD_STUBS if body == "old" else NEW_STUBS
+    sets = [("full", (), 1)] + [(s, (s,), 1) for s in names] + [
+        ("matmul2+onehot+rank", ("matmul2", "onehot", "rank"), 1),
+        ("blend+rotate", ("blend", "rotate"), 1),
+        ("index chain", ("onehot", "rank", "route"), 1),
+        ("group2", (), 2), ("group4", (), 4),
+        ("group2+route", ("route",), 2),
+        ("group2+index chain", ("onehot", "rank", "route"), 2),
+        ("group2+body", ("body",), 2)]
+    if body == "new" and race:
+        for choice in RACES:
+            sets += [(choice, (choice,), 1), ("group2+" + choice, (choice,), 2)]
+    return sets
+
+
+def ablate(P, interpret, race, times, match=None):
+    assert P in (128, 512), P
+    n = 2048 if interpret else (1 << 22 if P == 128 else 1 << 20)
+    F, B = 28, 256
     rng = np.random.default_rng(0)
     host = np.zeros((n + seg.GUARD, P), np.float32)
     host[:n, :F] = rng.integers(0, B, (n, F))
@@ -303,62 +488,88 @@ def main():
     host[:n, F + 1] = rng.random(n) + 0.1
     host[:n, F + 2] = 1.0
     payload = jnp.asarray(host)
-    pred = seg.SplitPredicate(
-        col=jnp.int32(2), threshold=jnp.int32(100),
-        default_left=jnp.bool_(True), is_cat=jnp.bool_(False),
-        missing_type=jnp.int32(0), num_bin=jnp.int32(B),
-        default_bin=jnp.int32(0), offset=jnp.int32(0),
-        identity=jnp.bool_(True), bitset=jnp.zeros(B, jnp.int32))
+    # one column block routes from a copy of the split window
+    route = payload[:, :128] + 0.0 if P > 128 else None
     lv, rv = jnp.float32(1.5), jnp.float32(-2.5)
     start, count = jnp.int32(0), jnp.int32(n)
     fresh = jax.jit(lambda x: x + 0.0)
 
-    def call(stubs, group=1):
+    def pred(**kw):
+        base = dict(
+            col=jnp.int32(2), threshold=jnp.int32(100),
+            default_left=jnp.bool_(True), is_cat=jnp.bool_(False),
+            missing_type=jnp.int32(0), num_bin=jnp.int32(B),
+            default_bin=jnp.int32(0), offset=jnp.int32(0),
+            identity=jnp.bool_(True), bitset=jnp.zeros(B, jnp.int32))
+        base.update(kw)
+        return seg.SplitPredicate(**base)
+
+    def call(pr, body, stubs, group):
         """Seconds of one donated call, its scalar fetched."""
         p_ = fresh(payload)
         a_ = jnp.zeros_like(p_)
         jax.block_until_ready((p_, a_))
         t0 = time.perf_counter()
-        out = pass_a(p_, a_, start, count, pred, lv, rv, F + 3, B,
-                     interpret, stubs, group)
+        out = pass_a(p_, a_, route, start, count, pr, lv, rv, F + 3, B,
+                     interpret, stubs, group, body)
         nl = int(out[2])
         return time.perf_counter() - t0, out, nl
 
     # the unstubbed copies write the lefts the portable partition writes
-    ref, _, ref_nl = seg.partition_segment(
-        payload, jnp.zeros_like(payload), start, count, pred, lv, rv, F + 3)
-    for group in (1, 2, 4):
-        _, out, nl = call((), group)
-        full = nl // CHUNK * CHUNK
-        assert nl == int(ref_nl), (group, nl, int(ref_nl))
-        assert bool(jnp.array_equal(out[0][:full], ref[:full])), \
-            "lefts differ (group=%d)" % group
-    del out, ref
+    cat = pred(is_cat=jnp.bool_(True), bitset=jnp.asarray(
+        np.isin(np.arange(B), (0, 31, 32, 63, 64, 100, B - 1)), jnp.int32))
+    for pr in (pred(), cat):
+        ref, _, ref_nl = seg.partition_segment(
+            payload, jnp.zeros_like(payload), start, count, pr, lv, rv, F + 3)
+        for body in ("old", "new"):
+            variants = [((), g) for g in ((1,) if P > 128 else (1, 2, 4))]
+            if body == "new" and race:
+                variants += [((c,), 1) for c in RACES]
+            for stubs, group in variants:
+                _, out, nl = call(pr, body, stubs, group)
+                full = nl // CHUNK * CHUNK
+                assert nl == int(ref_nl), (body, stubs, group, nl, int(ref_nl))
+                assert bool(jnp.array_equal(out[0][:full], ref[:full])), \
+                    "lefts differ (%s, %s, group=%d)" % (body, stubs, group)
+        del out, ref
 
     chunks = n // CHUNK
-    times = {}
-    for stubs in [()] + [(s,) for s in STUBS] + [
-            ("matmul2", "onehot", "rank"), ("blend", "rotate"),
-            ("blend", "flush", "matmul2", "onehot", "parts", "rank",
-             "rotate", "route"), ("group2",), ("group4",),
-            ("group2", "blend", "rotate"), ("group4", "blend", "rotate"),
-            ("group2", "route"), ("group4", "body")]:
-        name = "+".join(stubs) or "full"
-        group = max([int(s[5:]) for s in stubs if s.startswith("group")]
-                    or [1])
-        stubs = tuple(s for s in stubs if not s.startswith("group"))
-        if interpret:
-            call(stubs, group)
-            times[name] = None
-            continue
-        call(stubs, group)
-        ts = sorted(call(stubs, group)[0] for _ in range(5))
-        times[name] = ts[2]
-        print("%-60s %8.3f ms  %7.1f ns/chunk  (full - this: %7.1f ns/chunk)"
-              % (name, ts[2] * 1e3, ts[2] / chunks * 1e9,
-                 (times["full"] - ts[2]) / chunks * 1e9), flush=True)
-    line = json.dumps({"rows": n, "lanes": P, "chunks": chunks,
-                       "interpret": interpret, "seconds": times})
+    for body in ("old", "new"):
+        for label, stubs, group in stub_sets(body, race):
+            if P > 128 and group > 2:
+                continue        # four chunks of 512 lanes pass the VMEM plan
+            if match and label != "full" and not any(
+                    m in label for m in match.split(",")):
+                continue
+            name = "%d %s %s" % (P, body, label)
+            if interpret:
+                call(pred(), body, stubs, group)
+                times[name] = None
+                continue
+            call(pred(), body, stubs, group)
+            ts = sorted(call(pred(), body, stubs, group)[0] for _ in range(5))
+            times[name] = ts[2]
+            full = times["%d %s %s" % (P, body, "full")]
+            print("%-44s %8.3f ms  %7.1f ns/chunk  (full - this: %7.1f)"
+                  % (name, ts[2] * 1e3, ts[2] / chunks * 1e9,
+                     (full - ts[2]) / chunks * 1e9), flush=True)
+    return n
+
+
+def main():
+    argv = sys.argv[1:]
+    interpret = "--interpret" in argv
+    race = "--race" in argv
+    if not interpret and jax.default_backend() != "tpu":
+        sys.exit("ablate_partition_body: platform is %r, not tpu"
+                 % jax.default_backend())
+    lanes = [int(a) for a in argv if a.isdigit()] or [128, 512]
+    match = argv[argv.index("--match") + 1] if "--match" in argv else None
+    times, rows = {}, {}
+    for P in lanes:
+        rows[P] = ablate(P, interpret, race, times, match)
+    line = json.dumps({"rows": rows, "chunk": CHUNK, "interpret": interpret,
+                       "seconds": times})
     os.makedirs(os.path.join(REPO, "chiprun_out"), exist_ok=True)
     with open(os.path.join(REPO, "chiprun_out", "ablate_partition_body.json"),
               "w") as fh:

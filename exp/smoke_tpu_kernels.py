@@ -176,14 +176,43 @@ def partition_rmw():
 
 def partition_acc():
     """partition_segment_acc (placement is a traced sublane pltpu.roll of
-    a [2C, P] concatenate)."""
-    check_partition(
-        lambda p, a, s, c: pseg.partition_segment_acc(
-            p, a, s, c, PRED, LV, RV, VAL, B, **IK),
-        PAY, PRED, VAL, segs((128, 3000), (7, 8000), (513, 256), (0, 8192)))
-    return {"ms": median_ms(lambda: int(pseg.partition_segment_acc(
-        PAY, jnp.zeros_like(PAY), jnp.int32(0), jnp.int32(N), PRED, LV, RV,
-        VAL, B, **IK)[2]))}
+    a [2C, P] concatenate; the index arithmetic has rows in lanes), under
+    a numerical predicate, a categorical one whose set bits sit at the
+    packed bitset's word edges, and a missing-value one over an
+    EFB-decoded column: no cell has a categorical column, so the vector
+    shifts and the word select are seen by Mosaic here or nowhere."""
+    edges = np.isin(np.arange(B), (0, 31, 32, 63, 64, 100, 191, 192, B - 1))
+    preds = {
+        "numerical": PRED,
+        "categorical": PRED._replace(
+            col=jnp.int32(5), is_cat=jnp.bool_(True),
+            bitset=jnp.asarray(edges, jnp.int32)),
+        "missing_nan": PRED._replace(
+            col=jnp.int32(9), missing_type=jnp.int32(seg.MISSING_NAN),
+            default_left=jnp.bool_(False), threshold=jnp.int32(60),
+            num_bin=jnp.int32(120), default_bin=jnp.int32(7),
+            offset=jnp.int32(40), identity=jnp.bool_(False)),
+    }
+    for pred in preds.values():
+        check_partition(
+            lambda p, a, s, c: pseg.partition_segment_acc(
+                p, a, s, c, pred, LV, RV, VAL, B, **IK),
+            PAY, pred, VAL,
+            segs((128, 3000), (7, 8000), (513, 256), (0, 8192)))
+    # bins past 256: the column is read out at HIGHEST, 32 words of bitset
+    wide_b = 1000
+    wide = make_payload(N, F, wide_b, width=128)
+    for pred in (make_pred(3, 701, wide_b), make_pred(4, 0, wide_b)._replace(
+            is_cat=jnp.bool_(True),
+            bitset=jnp.asarray(np.arange(wide_b) % 7 == 3, jnp.int32))):
+        check_partition(
+            lambda p, a, s, c: pseg.partition_segment_acc(
+                p, a, s, c, pred, LV, RV, VAL, wide_b, **IK),
+            wide, pred, VAL, segs((7, 8000), (513, 256)))
+    return {"predicates": sorted(preds) + ["1000_bins"],
+            "ms": median_ms(lambda: int(pseg.partition_segment_acc(
+                PAY, jnp.zeros_like(PAY), jnp.int32(0), jnp.int32(N), PRED,
+                LV, RV, VAL, B, **IK)[2]))}
 
 
 def blocks():

@@ -137,8 +137,8 @@ def fits_vmem(num_features: int, num_bins: int,
 _RING_DEPTH = 2
 
 #: lanes a pass of the column-block partition moves: the widest multiple
-#: of 128 whose accumulator plan fits VMEM (11.8 MB of 13 at one chunk a
-#: trip; 640 lanes would plan 14.2), since what does not grow with the
+#: of 128 whose accumulator plan fits VMEM (11.4 MiB of 13 at one chunk a
+#: trip; 640 lanes would plan 13.6), since what does not grow with the
 #: width (routing, rank, one-hot) is paid once a pass (PERF.md §5).
 _BLOCK_WIDTH = 512
 
@@ -148,26 +148,32 @@ def _acc_plan_bytes(payload_width: int, num_bins: int, group: int) -> int:
     taking `group` chunks a loop trip: the read ring (`_RING_DEPTH` groups
     of chunks), two [2C, P] accumulators, stage/blend buffers, the P-wide
     placement intermediates of each chunk in flight (parts + permuted +
-    doubled + rolled buffers, ~10C rows), the [C, C] machinery (`tri` and
-    the row iota once, a one-hot and its relayouts a chunk in flight) and
-    the categorical bitset one-hot of each chunk's routing."""
+    doubled + rolled buffers, ~10C rows), the [C, C] machinery (`tri_t`
+    and the row iota once, a one-hot as a mask and as f32 a chunk in
+    flight, and the four more the plan has carried since the widths it
+    admits were proven on the chip) and the masked split window of each
+    chunk of a trip.  The index arithmetic itself is a few [8, C]
+    vectors, and the categorical bitset is `num_bins` bits of scalar
+    memory: neither is planned for, and `num_bins` no longer enters."""
     P, C = payload_width, CHUNK
     return (4 * P * C * (_RING_DEPTH * group   # ring
                          + 6                   # accs(4C) + stage/rbuf(2C)
                          + 10 * group)         # placement intermediates
             + 4 * C * C * (6 + 2 * group)
-            + 4 * C * num_bins * group)
+            + 4 * 128 * C * group)
 
 
 #: chunks pass A of the accumulator partition takes a loop trip where VMEM
 #: allows.  The body of one chunk is a dependent chain (routing -> rank ->
 #: one-hot -> matmuls) the scheduler cannot shorten; the chains of several
-#: chunks in one basic block interleave (PERF.md §6, PR 25: 1591 / 1350 /
-#: 1241 ns a chunk at 1 / 2 / 4 on the chip).  Not 4: every chunk of a
-#: trip is a copy of the body for Mosaic to compile, and at 4 the fused
-#: step compiled 4.6 s longer than the parent's on the chip's host (0.5 s
-#: at 2), which a training job pays for every new data set (`gbdt.step`
-#: is keyed on the data).
+#: chunks in one basic block interleave, and a trip's index arithmetic is
+#: done once for its chunks (PERF.md §6, PR 29: 981 / 800 / 765 ns a chunk
+#: at 1 / 2 / 4 on the chip at 128 lanes; 1,591 / 1,353 / 1,240 with the
+#: body PR 25 left).  Not 4: every chunk of a trip is a copy of the body
+#: for Mosaic to compile, and at 4 the fused step of PR 25's body compiled
+#: 4.6 s longer than the parent's on the chip's host (0.5 s at 2), which
+#: a training job pays for every new data set (`gbdt.step` is keyed on
+#: the data).  A 512-lane block reads SLOWER at 2 (3,213 against 2,916).
 _PASS_A_GROUP = 2
 
 
@@ -222,8 +228,10 @@ def _bf16_parts(data):
 
 def _go_left_rows(scalars, bitset_ref, data, B, iota_p):
     """[C] i32 0/1 routing of payload rows by the split predicate (without
-    the caller's window-validity mask) — Bin::Split semantics shared by
-    both partition kernels.  Selects the split feature's storage column by
+    the caller's window-validity mask) — Bin::Split semantics, one row a
+    SUBLANE.  The read-modify-write `_partition_kernel` is its one caller:
+    the accumulator kernel routes with rows in lanes (`_go_left_lanes`).
+    Selects the split feature's storage column by
     lane reduction (dynamic lane indexing is not a Mosaic primitive; the
     masked sum is), then decodes the EFB bundle value to the feature's own
     bin.  All predicate logic is i32 arithmetic — Mosaic cannot
@@ -254,6 +262,72 @@ def _go_left_rows(scalars, bitset_ref, data, B, iota_p):
     hits = ((fbin[:, None] == iota_b) &
             (bitset_ref[:] > 0)).astype(jnp.int32)
     gl_cat = (jnp.sum(hits, axis=1) > 0).astype(jnp.int32)
+    return is_cat * gl_cat + (1 - is_cat) * gl_num
+
+
+#: where the packed categorical bitset starts in the accumulator kernels'
+#: scalar-prefetch vector: behind the 11 scalars of the predicate and the
+#: split window's first lane (which only the column-block wrapper fills)
+_BITSET_WORD0 = 12
+
+
+def _acc_scalars(start, count, pred, col, win_lo, num_bins):
+    """The accumulator kernels' scalar-prefetch vector: segment, split
+    predicate (`col` as the kernel is to see it), the split window's
+    first lane, then `pred.bitset` packed into ceil(B / 32) int32 words,
+    bit b of word w the membership of bin 32 w + b."""
+    words = -(-num_bins // 32)
+    bits = jnp.pad(pred.bitset.astype(jnp.uint32),
+                   (0, 32 * words - num_bins)).reshape(words, 32)
+    packed = jnp.sum(bits << jnp.arange(32, dtype=jnp.uint32), axis=1,
+                     dtype=jnp.uint32)
+    return jnp.concatenate([
+        jnp.stack([
+            start, count, col, pred.threshold,
+            pred.default_left.astype(jnp.int32),
+            pred.is_cat.astype(jnp.int32), pred.missing_type, pred.num_bin,
+            pred.default_bin, pred.offset, pred.identity.astype(jnp.int32),
+            win_lo,
+        ]).astype(jnp.int32),
+        lax.bitcast_convert_type(packed, jnp.int32)])
+
+
+def _go_left_lanes(scalars, raw, B):
+    """i32 0/1 routing of rows by the split predicate, ROWS IN LANES: `raw`
+    holds the split column's stored bins as int32, a chunk a row ([8, C]:
+    two vregs where one row a sublane takes 32), and so does the result.
+    The same integer arithmetic as `_go_left_rows` (Bin::Split: EFB
+    decode, missing type, default direction, threshold).  Categorical
+    membership reads the packed bitset from scalar memory,
+    (word[fbin >> 5] >> (fbin & 31)) & 1, the word picked by a chain of
+    scalar-against-vector selects: no [C, B] one-hot, and no branch on
+    `is_cat` (a region boundary would stop a trip's chains from
+    interleaving).  A bin outside [0, B) picks no word and is no member,
+    as it matches no lane of the one-hot."""
+    threshold = scalars[3]
+    default_left = scalars[4]
+    is_cat = scalars[5]
+    missing_type = scalars[6]
+    num_bin = scalars[7]
+    default_bin = scalars[8]
+    offset = scalars[9]
+    identity = scalars[10]
+    e = raw - offset
+    in_range = ((e >= 0) & (e < num_bin - 1)).astype(jnp.int32)
+    bump = (e >= default_bin).astype(jnp.int32)
+    decoded = in_range * (e + bump) + (1 - in_range) * default_bin
+    fbin = identity * raw + (1 - identity) * decoded
+    miss = (((missing_type == MISSING_NAN) &
+             (fbin == num_bin - 1)).astype(jnp.int32) |
+            ((missing_type == MISSING_ZERO) &
+             (fbin == default_bin)).astype(jnp.int32))
+    gl_num = (miss * default_left +
+              (1 - miss) * (fbin <= threshold).astype(jnp.int32))
+    word_of = fbin >> 5
+    word = jnp.zeros_like(fbin)
+    for w in range(-(-B // 32)):
+        word = jnp.where(word_of == w, scalars[_BITSET_WORD0 + w], word)
+    gl_cat = (word >> (fbin & 31)) & 1
     return is_cat * gl_cat + (1 - is_cat) * gl_num
 
 
@@ -727,7 +801,7 @@ def partition_segment(payload, aux, start, count, pred, left_value,
 C2 = 2 * CHUNK
 
 
-def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
+def _acc_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
                 P, B, value_col, group=1, lane_lo=None):
     """Accumulator-window partition: same contract as `_partition_kernel`,
     restructured around the measured bottleneck (per-chunk latency, not
@@ -742,25 +816,41 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
 
     Pass A places a chunk's rows with ONE permutation, not one compaction
     per side: lefts in order, then rights in order, is a stable partition
-    of the chunk's valid rows, so one destination vector (one tri mat-vec
-    for the lefts' ranks; the rights' ranks are iota arithmetic, the valid
-    rows of a chunk being one contiguous range) and one [C, C] one-hot
-    applied to the three parts give a [C, P] block with the lefts at rows
-    [0, nl_k) and the rights at [nl_k, nl_k + nr_k).  Each side's
+    of the chunk's valid rows, so one destination vector and one [C, C]
+    one-hot applied to the three parts give a [C, P] block with the lefts
+    at rows [0, nl_k) and the rights at [nl_k, nl_k + nr_k).  Each side's
     placement is then a rotate of the SAME doubled block to its
-    accumulator's cursor (exact data movement).  Per chunk: 4 MXU
-    contractions (1 rank + 3 parts), one [C, C] matrix built on the VPU,
-    two rotates, two blends; what does not depend on the chunk (`tri`, the
-    [C, C] row iota) is built once before the loop.
+    accumulator's cursor (exact data movement).
+
+    Where the 256 rows of a chunk go is arithmetic on 256 numbers, and it
+    is done with ROWS IN LANES, the chunks of a trip as the rows of one
+    [8, C] vector (`routed`): an NT product of a one-hot row against the
+    128-lane window that holds the split column gives the column as a row
+    (its other lanes zeroed first, so that nothing they hold reaches the
+    product; one bf16 pass is exact for bins under 256, and HIGHEST is
+    asked for past them; a transposition and a row load read 9% slower,
+    PERF.md §6, PR 29); the predicate (`_go_left_lanes`), the validity
+    mask, the lefts' ranks (ONE product `[8, C] x tri_t` a trip; the
+    rights' ranks are iota arithmetic, the valid rows of a chunk being
+    one contiguous range) and the destination follow on two vregs; and a
+    chunk's row of the destination, non-members at -1, is broadcast along
+    sublanes into the one-hot's compare with no relayout.  (A per-row
+    quantity born from a lane reduction lives one row a SUBLANE, one lane
+    in 128 at work: that was 640 of a chunk's 1,600 ns.)  Per chunk: 4
+    MXU contractions (the column, the 3 parts) and a share of the trip's
+    rank product, one [C, C] matrix built on the VPU, two rotates, two
+    blends; what does not depend on the chunk (`tri_t`, the index planes,
+    the column's one-hot row) is built once before the loop.
 
     That body is one dependent chain (routing -> rank -> one-hot ->
     matmuls -> rotate -> blend) and the chip runs it at the chain's
     latency, not at any unit's rate (PERF.md §6, PR 25), so pass A takes
-    `group` chunks a loop trip: every wait and load first, then each
-    chunk's permuted block, which depends on no cursor, then the blocks
-    placed in order.  The chains of a trip share a basic block and
-    interleave.  The ring holds its depth in groups; a trip's chunks past
-    the segment's last are not read and count as empty.
+    `group` chunks a loop trip: every wait and load first, then the
+    trip's index arithmetic, then each chunk's permuted block, which
+    depends on no cursor, then the blocks placed in order.  The chains of
+    a trip share a basic block and interleave.  The ring holds its depth
+    in groups; a trip's chunks past the segment's last are not read and
+    count as empty.
 
     With `lane_lo` set (one pass of the column-block partition,
     `partition_segment_acc_blocks`), the kernel moves only the payload's
@@ -790,9 +880,23 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
     iota_rows = _row_iota()
     iota_c2 = lax.broadcasted_iota(jnp.int32, (C2, 1), 0)[:, 0]
     iota_p = lax.broadcasted_iota(jnp.int32, (1, P), 1)
-    # the lanes the routing reads: the rows' own, or the split window's
-    iota_route = (lax.broadcasted_iota(jnp.int32, (1, 128), 1) if blocks
-                  else iota_p)
+    # the split column's 128-lane window of a chunk (in ring slot `slot`,
+    # loaded as `data`) and the column's place in it: a column block's
+    # frozen copy, the chunk itself (at 128 lanes, and under the
+    # interpreter's ragged widths), or a lane slice of it
+    route_col = scalars[2]
+    if blocks:
+        def split_window(slot, data):
+            return route_ring[slot]
+    elif P % 128 or P == 128:
+        def split_window(slot, data):
+            return data
+    else:
+        win_lane0 = pl.multiple_of(route_col // 128 * 128, 128)
+        route_col = route_col - win_lane0
+
+        def split_window(slot, data):
+            return ring[slot, :, pl.ds(win_lane0, 128)]
 
     def window(ref, row0):
         """CHUNK rows of an HBM buffer from (8-aligned) `row0`, over the
@@ -816,25 +920,28 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
                 route_ring.at[slot], sem_route.at[slot]))
         return dmas
 
-    def valid_mask(k):
-        return ((iota_rows >= shift - k * CHUNK) &
-                (iota_rows < shift + count - k * CHUNK)).astype(jnp.int32)
-
-    # chunk-independent [C, C] machinery, built once before the chunk loop
-    # (as the histogram kernel does for its own).  The iotas are built at
+    # chunk-independent machinery, built once before the chunk loop (as
+    # the histogram kernel does for its own).  The iotas are built at
     # [C, C] directly: slicing a [2C, C] one crashes Mosaic's
     # ApplyVectorLayout — a broadcasted iota is stored replicated along
     # its constant dim, and vector.extract_strided_slice asks that dim for
     # more vregs than the replicated layout holds (hardware-bisected,
     # round 4).
     iota_ci = lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 0)
-    tri = (lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 1) <
-           iota_ci).astype(jnp.float32)
-
-    def rank_of(keep_i):
-        """Exclusive prefix count of kept rows (tri matvec; <= C, exact)."""
-        return jnp.dot(tri, keep_i.astype(jnp.float32)[:, None],
-                       preferred_element_type=jnp.float32)[:, 0].astype(jnp.int32)
+    # tri_t[j, i] = 1 where row j comes before row i: gl x tri_t is the
+    # exclusive prefix count of the lefts (<= C, exact in one bf16 pass)
+    tri_t = (iota_ci < lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 1)
+             ).astype(jnp.float32)
+    # the index planes of a trip: row g of an [8, C] vector is chunk
+    # k0 + g, a lane is a row of the chunk
+    chunk_of_row = lax.broadcasted_iota(jnp.int32, (8, CHUNK), 0)
+    row_of_lane = lax.broadcasted_iota(jnp.int32, (8, CHUNK), 1)
+    # the split column's lane of its window, as a mask and as the one-hot
+    # rows of the product that reads the column out
+    route_w = P if P % 128 else 128
+    lane_of_window = lax.broadcasted_iota(jnp.int32, (1, route_w), 1)
+    route_sel = (lax.broadcasted_iota(jnp.int32, (8, route_w), 1) ==
+                 route_col).astype(jnp.float32)
 
     def blend(acc, placed, cnt, off, value):
         """Write the child's tree output into the value column of the
@@ -845,13 +952,13 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
         region = ((iota_c2 >= off) & (iota_c2 < off + cnt))[:, None]
         acc[:] = jnp.where(region, placed, acc[:])
 
-    def permute_doubled(parts, dest, member):
-        """[2C, P], twice the [C, P] block in which source row j
-        (member[j]=1) sits at row dest[j]: one 0/1 one-hot applied to the
-        exact parts (three one-pass matmuls).  Doubled so that a rotate by
-        any cursor difference places a run of it without wrap-around."""
-        mat = ((iota_ci == dest[None, :]) &
-               (member[None, :] > 0)).astype(jnp.float32)        # [C, C]
+    def permute_doubled(parts, dest):
+        """[2C, P], twice the [C, P] block in which source row j sits at
+        row dest[0, j] (-1: nowhere): one 0/1 one-hot, the lane-major
+        destination broadcast along sublanes, applied to the exact parts
+        (three one-pass matmuls).  Doubled so that a rotate by any cursor
+        difference places a run of it without wrap-around."""
+        mat = (iota_ci == dest).astype(jnp.float32)              # [C, C]
         hi, mid, lo = parts
         perm = (jnp.dot(mat, hi, preferred_element_type=jnp.float32) +
                 jnp.dot(mat, mid, preferred_element_type=jnp.float32) +
@@ -896,27 +1003,50 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
 
     # ---- pass A: one read of the segment; lefts accumulate toward payload
     # windows, rights accumulate toward aux staging windows -------------
-    def permuted(k, data, route):
-        """(nlk, nrk, block) of chunk k: what of its placement depends on
-        no cursor and no accumulator, so that the chunks of a trip are
-        independent up to here.  `route` holds the split column: the rows
-        themselves, or a column block's split window."""
-        valid = valid_mask(k)
-        gl = _go_left_rows(scalars, bitset_ref, route, B, iota_route) * valid
-        keep_r = valid - gl
-        nlk = jnp.sum(gl)
-        nrk = jnp.sum(keep_r)
-        rank_l = rank_of(gl)
+    def routed(k0, windows):
+        """(gl, dest) of the trip's chunks k0 .. k0 + G - 1, [8, C] i32
+        with a chunk a row and the chunk's rows in lanes: the routing
+        under the validity mask, and where the chunk's ONE stable
+        partition puts each row (lefts to [0, nl_k), rights to
+        [nl_k, nl_k + nr_k), both in original order; a row outside the
+        segment to -1, which is no row).  `windows` hold the split
+        column: [C, 128] of the chunk, or of a column block's snapshot."""
+        raw = None
+        for g, window in enumerate(windows):
+            column = lax.dot_general(
+                route_sel, jnp.where(lane_of_window == route_col, window, 0.0),
+                dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=None if B <= 256 else lax.Precision.HIGHEST)
+            raw = column if raw is None \
+                else jnp.where(chunk_of_row == g, column, raw)   # [8, C]
+        first = shift - (k0 + chunk_of_row) * CHUNK
+        valid = ((row_of_lane >= first) &
+                 (row_of_lane < first + count)).astype(jnp.int32)
+        # (routing is integer arithmetic under the validity mask: what an
+        # unread split window holds cannot reach a row)
+        gl = _go_left_lanes(scalars, raw.astype(jnp.int32), B) * valid
+        rank_l = jnp.dot(gl.astype(jnp.float32), tri_t,
+                         preferred_element_type=jnp.float32
+                         ).astype(jnp.int32)
         # the chunk's valid rows are one contiguous range, so the valid
         # rows before row i are iota arithmetic and the rights among them
         # are those that are not lefts: no second prefix count
-        rank_r = jnp.maximum(
-            iota_rows - jnp.maximum(shift - k * CHUNK, 0), 0) - rank_l
-        # ONE stable partition of the chunk: lefts to [0, nlk), rights to
-        # [nlk, nlk + nrk), both in original order
-        return nlk, nrk, permute_doubled(
-            _bf16_parts(data), jnp.where(gl > 0, rank_l, nlk + rank_r),
-            valid)
+        rank_r = jnp.maximum(row_of_lane - jnp.maximum(first, 0), 0) - rank_l
+        nl = jnp.sum(gl, axis=1, keepdims=True)
+        dest = jnp.where(gl > 0, rank_l, nl + rank_r)
+        return gl, jnp.where(valid > 0, dest, -1)
+
+    def permuted(g, k, data, gl, dest):
+        """(nlk, nrk, block) of chunk k, row g of the trip's index
+        vectors: what of its placement depends on no cursor and no
+        accumulator, so that the chunks of a trip are independent up to
+        here."""
+        nlk = jnp.sum(jnp.where(chunk_of_row == g, gl, 0))
+        lo = jnp.maximum(shift - k * CHUNK, 0)
+        hi = jnp.minimum(shift + count - k * CHUNK, CHUNK)
+        return nlk, jnp.maximum(hi - lo, 0) - nlk, permute_doubled(
+            _bf16_parts(data), dest[g:g + 1, :])
 
     def place(nlk, nrk, block, carry):
         nl, nr, lo_, ro_, lfl, rfl, pl_, pr_ = carry
@@ -966,10 +1096,6 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
         datas = [ring[slots[0]]] + [
             jnp.where(k0 + i < nch, ring[slots[i]], 0.0)
             for i in range(1, G)]
-        # (routing is integer arithmetic under the validity mask: what an
-        # unread split window holds cannot reach a row)
-        routes = [route_ring[slot] for slot in slots] if blocks else datas
-
         @pl.when(t == 0)
         def _seed():
             # the first window's prologue rows belong to the previous
@@ -977,7 +1103,9 @@ def _acc_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
             # full-window write
             lacc[0:CHUNK] = datas[0]
 
-        permuted_chunks = [permuted(k0 + i, datas[i], routes[i])
+        gl, dest = routed(k0, [split_window(slot, data)
+                               for slot, data in zip(slots, datas)])
+        permuted_chunks = [permuted(i, k0 + i, datas[i], gl, dest)
                            for i in range(G)]
         for chunk in permuted_chunks:
             carry = place(*chunk, carry)
@@ -1071,14 +1199,8 @@ def _partition_segment_acc(payload, aux, start, count, pred, left_value,
     """Same contract as `partition_segment`, accumulator-window kernel."""
     P = payload.shape[1]
     B = num_bins
-    scalars = jnp.stack([
-        start, count, pred.col, pred.threshold,
-        pred.default_left.astype(jnp.int32), pred.is_cat.astype(jnp.int32),
-        pred.missing_type, pred.num_bin, pred.default_bin,
-        pred.offset, pred.identity.astype(jnp.int32),
-    ]).astype(jnp.int32)
+    scalars = _acc_scalars(start, count, pred, pred.col, 0, B)
     fvals = jnp.stack([left_value, right_value]).astype(jnp.float32)
-    bitset = pred.bitset.astype(jnp.int32).reshape(1, B)
     group = _pass_a_group(P, B)
     kern = functools.partial(_acc_kernel, P=P, B=B, value_col=value_col,
                              group=group)
@@ -1087,8 +1209,7 @@ def _partition_segment_acc(payload, aux, start, count, pred, left_value,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(1,),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
-                      pl.BlockSpec(memory_space=pl.ANY),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=(pl.BlockSpec(memory_space=pl.ANY),
                        pl.BlockSpec(memory_space=pl.ANY),
@@ -1108,10 +1229,10 @@ def _partition_segment_acc(payload, aux, start, count, pred, left_value,
         out_shape=(jax.ShapeDtypeStruct(payload.shape, payload.dtype),
                    jax.ShapeDtypeStruct(aux.shape, aux.dtype),
                    jax.ShapeDtypeStruct((1,), jnp.int32)),
-        input_output_aliases={3: 0, 4: 1},
+        input_output_aliases={2: 0, 3: 1},
         compiler_params=_SIDE_EFFECTS,
         interpret=interpret,
-    )(scalars, fvals, bitset, payload, aux)
+    )(scalars, fvals, payload, aux)
     return payload_new, aux_new, nl[0]
 
 
@@ -1180,14 +1301,8 @@ def _partition_segment_acc_blocks(payload, aux, start, count, pred,
                          "payload (P %% 128 == 0), got %d" % P)
     B = num_bins
     win_lo = (pred.col // 128) * 128
-    scalars = jnp.stack([
-        start, count, pred.col - win_lo, pred.threshold,
-        pred.default_left.astype(jnp.int32), pred.is_cat.astype(jnp.int32),
-        pred.missing_type, pred.num_bin, pred.default_bin,
-        pred.offset, pred.identity.astype(jnp.int32), win_lo,
-    ]).astype(jnp.int32)
+    scalars = _acc_scalars(start, count, pred, pred.col - win_lo, win_lo, B)
     fvals = jnp.stack([left_value, right_value]).astype(jnp.float32)
-    bitset = pred.bitset.astype(jnp.int32).reshape(1, B)
     # freeze the split column's window before any pass rewrites its lanes
     snap = pl.pallas_call(
         _snap_window_kernel,
@@ -1221,8 +1336,7 @@ def _partition_segment_acc_blocks(payload, aux, start, count, pred,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(1,),
-                in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
-                          pl.BlockSpec(memory_space=pl.ANY),
+                in_specs=[pl.BlockSpec(memory_space=pl.ANY),
                           pl.BlockSpec(memory_space=pl.ANY),
                           pl.BlockSpec(memory_space=pl.ANY)],
                 out_specs=(pl.BlockSpec(memory_space=pl.ANY),
@@ -1245,10 +1359,10 @@ def _partition_segment_acc_blocks(payload, aux, start, count, pred,
             out_shape=(jax.ShapeDtypeStruct(payload.shape, payload.dtype),
                        jax.ShapeDtypeStruct(aux.shape, aux.dtype),
                        jax.ShapeDtypeStruct((1,), jnp.int32)),
-            input_output_aliases={3: 0, 4: 1},
+            input_output_aliases={2: 0, 3: 1},
             compiler_params=_SIDE_EFFECTS,
             interpret=interpret,
-        )(scalars, fvals, bitset, payload, aux, snap)
+        )(scalars, fvals, payload, aux, snap)
         nl = nl_k if nl is None else nl
     return payload, aux, nl[0]
 

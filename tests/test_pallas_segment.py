@@ -215,17 +215,39 @@ def _widened(pay, width):
     return jnp.pad(pay, ((0, 0), (0, width - pay.shape[1])))
 
 
-def _pred(feature=1, threshold=B // 2, default_left=False, is_cat=False,
-          bitset=None, missing_type=0, num_bin=B, default_bin=0,
-          offset=0, identity=True):
+def _pred(feature=1, threshold=None, default_left=False, is_cat=False,
+          bitset=None, missing_type=0, num_bin=None, default_bin=0,
+          offset=0, identity=True, bins=B):
     return SplitPredicate(
-        col=jnp.int32(feature), threshold=jnp.int32(threshold),
+        col=jnp.int32(feature),
+        threshold=jnp.int32(bins // 2 if threshold is None else threshold),
         default_left=jnp.bool_(default_left), is_cat=jnp.bool_(is_cat),
         bitset=jnp.asarray(bitset if bitset is not None else
-                           np.zeros(B, bool)),
-        missing_type=jnp.int32(missing_type), num_bin=jnp.int32(num_bin),
+                           np.zeros(bins, bool)),
+        missing_type=jnp.int32(missing_type),
+        num_bin=jnp.int32(bins if num_bin is None else num_bin),
         default_bin=jnp.int32(default_bin), offset=jnp.int32(offset),
         identity=jnp.bool_(identity))
+
+
+def _check_exact(kernel, pay, start, count, pred, value_col, bins):
+    """Payload, the rights staged in aux and num_left of a Pallas
+    partition, bit for bit against the portable one."""
+    aux = jnp.zeros_like(pay)
+    lv, rv = jnp.float32(1.5), jnp.float32(-2.5)
+    ref_pay, _, ref_nl = seg.partition_segment(
+        pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv,
+        value_col)
+    nl = int(ref_nl)
+    got_pay, got_aux, got_nl = getattr(pseg, kernel)(
+        pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv,
+        value_col, bins, interpret=True)
+    assert int(got_nl) == nl
+    np.testing.assert_array_equal(np.asarray(got_pay), np.asarray(ref_pay))
+    np.testing.assert_array_equal(
+        np.asarray(got_aux)[start:start + count - nl],
+        np.asarray(ref_pay)[start + nl:start + count])
+    return nl
 
 
 @pytest.mark.parametrize("start,count,predkw", [
@@ -306,55 +328,59 @@ def test_partition_acc_skewed(start, count, skew, kernel, width):
     (`epsilon-train`'s).  Payload, the rights staged in aux and num_left,
     bit for bit."""
     pay = _widened(_routed_payload(skew, start, count), width)
-    aux = jnp.zeros_like(pay)
-    pred = _pred()
-    lv, rv = jnp.float32(1.5), jnp.float32(-2.5)
-    ref_pay, _, ref_nl = seg.partition_segment(
-        pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv, VALUE_COL)
-    nl = int(ref_nl)
-    got_pay, got_aux, got_nl = getattr(pseg, kernel)(
-        pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv,
-        VALUE_COL, B, interpret=True)
-    assert int(got_nl) == nl
-    np.testing.assert_array_equal(np.asarray(got_pay), np.asarray(ref_pay))
-    np.testing.assert_array_equal(
-        np.asarray(got_aux)[start:start + count - nl],
-        np.asarray(ref_pay)[start + nl:start + count])
+    _check_exact(kernel, pay, start, count, _pred(), VALUE_COL, B)
+
+
+def _whiles(jaxpr):
+    """The body jaxpr of each `while` of a jaxpr, in program order."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "while":
+            out.append(eqn.params["body_jaxpr"].jaxpr)
+        else:
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                out.extend(_whiles(sub))
+    return out
+
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
 
 
 def _while_dots(jaxpr):
     """dot_generals inside each `while` of a jaxpr, in program order."""
-    def dots(jp):
-        n = 0
-        for eqn in jp.eqns:
-            n += eqn.primitive.name == "dot_general"
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                n += dots(sub)
-        return n
+    return [sum(eqn.primitive.name == "dot_general" for eqn in _eqns(body))
+            for body in _whiles(jaxpr)]
 
-    out = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "while":
-            out.append(dots(eqn.params["body_jaxpr"].jaxpr))
-        else:
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                out.extend(_while_dots(sub))
-    return out
+
+def _while_shapes(jaxpr):
+    """The shapes of every value computed inside each `while`."""
+    return [{tuple(v.aval.shape) for eqn in _eqns(body) for v in eqn.outvars
+             if hasattr(v.aval, "shape")} for body in _whiles(jaxpr)]
 
 
 def test_pass_a_is_one_permutation():
     """Pass A of `_acc_kernel` places a chunk with ONE permutation: its
-    loop body holds 4 MXU contractions a chunk (1 rank mat-vec + the
-    one-hot against the 3 exact parts) for each chunk of a trip, and pass
-    B none.  A second compaction per side (8 a chunk, the body before PR
-    25) must not come back quietly; traced only, nothing runs."""
+    loop body holds 4 MXU contractions a chunk (the split column read out
+    as a row, the one-hot against the 3 exact parts) and ONE rank product
+    for the chunks of a trip together, and pass B none.  A second compaction per side (8 a chunk, the body
+    before PR 25) must not come back quietly, nor a rank mat-vec a chunk;
+    and the index arithmetic has rows in lanes: nothing [C, B]-shaped (the
+    categorical bitset's one-hot, before PR 29) and no per-row [C] vector
+    is computed in the loop.  Traced only, nothing runs."""
     pay = _payload(1024)
     closed = jax.make_jaxpr(
         lambda p, a: pseg._partition_segment_acc(
             p, a, jnp.int32(7), jnp.int32(777), _pred(), jnp.float32(1.0),
             jnp.float32(-1.0), VALUE_COL, B))(
         pay, jnp.zeros_like(pay))
-    assert _while_dots(closed.jaxpr) == [4 * pseg._pass_a_group(P, B), 0]
+    assert _while_dots(closed.jaxpr) == [4 * pseg._pass_a_group(P, B) + 1, 0]
+    pass_a = _while_shapes(closed.jaxpr)[0]
+    assert (seg.CHUNK, B) not in pass_a and (seg.CHUNK,) not in pass_a
+    assert (8, seg.CHUNK) in pass_a
 
 
 @pytest.mark.parametrize("width,group", [(P, 2), (256, 2), (384, 1)])
@@ -377,6 +403,131 @@ def test_partition_acc_groups(width, group, start, count):
         VALUE_COL, B, interpret=True)
     assert int(got_nl) == int(ref_nl)
     np.testing.assert_array_equal(np.asarray(got_pay), np.asarray(ref_pay))
+
+
+#: the accumulator kernel in one pass (`higgs-train`'s plan) and a 512-lane
+#: column block at a time (`epsilon-train`'s)
+ACC_PLANS = [("partition_segment_acc", 128),
+             ("partition_segment_acc_blocks", 1024)]
+
+
+@pytest.mark.parametrize("b", [64, 255, 256])
+@pytest.mark.parametrize("members", ["edges", "all_but_edges"])
+@pytest.mark.parametrize("start,count", [(0, 1000), (7, 777)])
+@pytest.mark.parametrize("kernel,width", ACC_PLANS)
+def test_partition_categorical_word_edges(b, members, start, count, kernel,
+                                          width):
+    """The categorical bitset reaches the accumulator kernel packed into
+    ceil(B / 32) int32 words: the bins at the words' edges (0, 31, 32, 63,
+    64, B - 1; bit 31 is the word's sign) are members and their
+    neighbours are not, or the other way round, and the split column holds
+    only those bins."""
+    edges = np.array(sorted({0, 31, 32, 63, 64, b - 1} & set(range(b))))
+    near = np.array(sorted({1, 30, 33, 62, 65, b - 2} & set(range(b))))
+    bitset = np.isin(np.arange(b), edges) == (members == "edges")
+    rng = np.random.default_rng(b + start)
+    bins = rng.integers(0, b, size=(1024, 6))
+    bins[:, 1] = rng.choice(np.concatenate([edges, near]), 1024)
+    pay, _ = _hist_payload(6, b, 1024, width=width, bins=bins)
+    nl = _check_exact(kernel, pay, start, count,
+                      _pred(bins=b, is_cat=True, bitset=bitset), 9, b)
+    want = np.isin(bins[start:start + count, 1], edges) \
+        == (members == "edges")
+    assert nl == want.sum() and 0 < nl < count
+
+
+@pytest.mark.parametrize("missing_type", [1, 2])
+@pytest.mark.parametrize("default_left", [False, True])
+@pytest.mark.parametrize("decode", ["identity", "efb"])
+@pytest.mark.parametrize("kernel,width", ACC_PLANS)
+def test_partition_missing_and_decode(missing_type, default_left, decode,
+                                      kernel, width):
+    """`MISSING_ZERO` (1: the default bin is missing) and `MISSING_NAN`
+    (2: the last bin is) with the missing rows sent each way, on a raw
+    column and on an EFB bundle's member (`identity` 0: stored value less
+    `offset`, out-of-range rows to the default bin, the default bin
+    skipped): the lane-major predicate is `Bin::Split` to the row."""
+    from lightgbm_tpu.ops.split import MISSING_NAN, MISSING_ZERO
+    assert (MISSING_ZERO, MISSING_NAN) == (1, 2)
+    kw = dict(missing_type=missing_type, default_left=default_left,
+              threshold=4)
+    if decode == "efb":
+        # the member's 9 bins (default bin 2 not stored) sit at stored
+        # values 5 .. 12 of storage column 3
+        kw.update(feature=3, offset=5, num_bin=9, default_bin=2,
+                  identity=False)
+    else:
+        kw.update(feature=2, default_bin=3)
+    pay = _widened(_payload(1024, seed=missing_type), width)
+    nl = _check_exact(kernel, pay, 9, 1015, _pred(**kw), VALUE_COL, B)
+    assert 0 < nl < 1015
+
+
+@pytest.mark.parametrize("width,feature", [(256, 5), (256, 130), (384, 5),
+                                           (384, 130), (384, 290)])
+@pytest.mark.parametrize("start,count", [(0, 1024), (7, 777), (100, 1),
+                                         (513, 37)])
+def test_partition_acc_split_window(width, feature, start, count):
+    """Past 128 lanes the one-pass kernel transposes the 128-lane window
+    of the chunk that holds the split column (a lane slice of the ring at
+    a traced, aligned offset): the column in each window of a 256- and a
+    384-lane payload, over whole, shifted and one-row segments."""
+    f, b = width - 60, 64
+    pay, _ = _hist_payload(f, b, 1024, width=width, seed=feature + count)
+    _check_exact("partition_segment_acc", pay, start, count,
+                 _pred(feature, b // 3, bins=b), f + 3, b)
+
+
+@pytest.mark.parametrize("start,count,feature,kind", [
+    (7, 1, 100, "numerical"), (100, 254, 600, "categorical"),
+    (513, 37, 1100, "missing"), (9, 1015, 1700, "categorical")])
+def test_partition_blocks_epsilon_predicates(start, count, feature, kind):
+    """The epsilon cell's width under the predicates its cell never
+    sends, the split column in each of the four blocks, a one-row
+    segment and shifted first chunks."""
+    Fw, Bw = 2000, 64
+    pay, _ = _wide_payload(1024, Fw, Bw, seed=start + count)
+    kw = {"numerical": {},
+          "categorical": dict(is_cat=True, bitset=np.isin(
+              np.arange(Bw) % 32, (0, 5, 31))),
+          "missing": dict(missing_type=2, default_left=True,
+                          threshold=9)}[kind]
+    _check_exact("partition_segment_acc_blocks", pay, start, count,
+                 _pred(feature, bins=Bw, **kw), Fw + 3, Bw)
+
+
+@pytest.mark.parametrize("kernel,width", ACC_PLANS)
+@pytest.mark.parametrize("kind", ["numerical", "categorical"])
+def test_partition_acc_past_256_bins(kernel, width, kind):
+    """The partition is exact at any bin count (`partition_engine` sends
+    a `max_bin` past the histogram kernel's 256 here all the same): bins
+    up to 999, thirty-two words of bitset, and the column read out at the
+    precision such bins need."""
+    b = 1000
+    rng = np.random.default_rng(b)
+    bins = rng.integers(0, b, size=(1024, 6))
+    pay, _ = _hist_payload(6, b, 1024, width=width, bins=bins)
+    kw = dict(threshold=701) if kind == "numerical" else dict(
+        is_cat=True, bitset=(np.arange(b) % 7 == 3) | (np.arange(b) > 990))
+    nl = _check_exact(kernel, pay, 7, 1000, _pred(bins=b, **kw), 9, b)
+    assert 0 < nl < 1000
+
+
+def test_acc_scalars_pack_the_bitset():
+    """Bit b of word w of the kernels' scalar vector is bin 32 w + b, the
+    words behind the predicate's scalars and the split window's lane."""
+    for b in (64, 255, 256, 16):
+        bitset = np.random.default_rng(b).random(b) < 0.5
+        bitset[[0, b - 1]] = True
+        got = np.asarray(pseg._acc_scalars(
+            jnp.int32(3), jnp.int32(9),
+            _pred(bins=b, bitset=bitset), jnp.int32(1), 128, b))
+        words = got[pseg._BITSET_WORD0:].astype(np.uint32)
+        assert got.shape == (pseg._BITSET_WORD0 + -(-b // 32),)
+        assert list(got[:3]) == [3, 9, 1] and got[11] == 128
+        unpacked = (words[:, None] >> np.arange(32, dtype=np.uint32)) & 1
+        np.testing.assert_array_equal(unpacked.reshape(-1)[:b], bitset)
+        assert not unpacked.reshape(-1)[b:].any()
 
 
 def test_payload_col_write_matches_dus():
@@ -567,15 +718,18 @@ def test_partition_blocks_epsilon_shape(start, count, feature):
 
 def test_partition_blocks_pass_a_is_one_permutation():
     """Every column block runs `_acc_kernel`'s pass A: 4 MXU contractions
-    a chunk in its loop (the 256-lane tail takes two chunks a trip), none
-    in pass B; the snapshot kernel's loop holds none.  Traced only."""
+    a chunk and one rank product a trip in its loop (the 256-lane tail
+    takes two chunks a trip), none in pass B; the snapshot kernel's loop
+    holds none; no [C, B]-shaped value in any loop.  Traced only."""
     pay, _ = _wide_payload(1024, 1200, B, seed=3)
     closed = jax.make_jaxpr(
         lambda p, a: pseg._partition_segment_acc_blocks(
             p, a, jnp.int32(7), jnp.int32(777), _pred(), jnp.float32(1.0),
             jnp.float32(-1.0), 1203, B))(
         pay, jnp.zeros_like(pay))
-    assert _while_dots(closed.jaxpr) == [0, 4, 0, 4, 0, 8, 0]
+    assert _while_dots(closed.jaxpr) == [0, 5, 0, 5, 0, 9, 0]
+    assert not any((seg.CHUNK, B) in shapes
+                   for shapes in _while_shapes(closed.jaxpr))
 
 
 def test_partition_blocks_narrow_pin():
