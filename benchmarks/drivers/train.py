@@ -1,55 +1,74 @@
 """Closed-loop training: one trainer calling `Booster.update()`.
 
-Set-up: the task and its held-out rows from the seed, `lgb.Dataset`
-constructed, the `Booster` (the configuration's `params`, with the mix's
-own `params` over them: bagging, GOSS or quantized gradients are mixes),
-`warmup_iters` iterations and a drain (every shape the window uses is
-compiled or loaded by then).  Window:
+Set-up: the task's data and its held-out rows from the seed,
+`lgb.Dataset` constructed, the `Booster` (the configuration's `params`,
+with the mix's own `params` over them: bagging, GOSS or quantized
+gradients are mixes), `warmup_iters` iterations and a drain (every
+shape the window uses is compiled or loaded by then).  Window:
 `update()` until the seconds are spent, then the drain that hands every
 tree to the host; the time is divided by the iterations that ran.  A
 traced window runs `trace_iters` iterations instead.
 
+What is being learned is not this file's business.  The configuration
+names a learning task in `task` (`binary` where it names none) and
+`tasks/<task>.py` makes the data, says what else `lgb.Dataset` needs of
+it (query sizes, categorical columns), recomputes the first tree for
+its objective and scores the held-out rows (tasks/binary.py lists the
+four functions).  Every learning task runs under this driver, so a
+per-layer reader with `DRIVERS = ("train",)` reads all of them.
+
 Correct means: the partition-ordered fast path with the engines the
 configuration names; on a mesh, the payload on as many distinct devices
 as the cell has chips; every tree of the window has more than one leaf;
-the first tree is the function the plain reference computes from the
-raw data (lib/reference.py: row counts equal -- past 2^24 rows, within
-what float32 counting loses -- and leaf values within
-LEAF_VALUE_ATOL); and the held-out metric of the model cut at
+the first tree is the function the task's plain reference computes from
+the raw data (row counts equal -- past 2^24 rows, within what float32
+counting loses -- and leaf values within the configuration's
+`leaf_value_atol`); and the held-out measure of the model cut at
 `quality_at_iter` lies in the band recorded when the cell was defined.
 """
+import os
+import sys
 import time
 
 import numpy as np
 
-from benchmarks.lib import quality, reference, synth
-
-#: the first tree's leaf values against float64 sums: the subtraction
+#: the first tree's leaf values against float64 sums, where a
+#: configuration states no `leaf_value_atol` of its own: the subtraction
 #: trick hands a small leaf the absolute rounding of its largest
 #: ancestor's f32 gradient sum (chip_smoke.LEAF_VALUE_ATOL, PR 21:
-#: largest seen 1.4e-4)
+#: largest seen 1.4e-4 at 10.5M rows)
 LEAF_VALUE_ATOL = 5e-4
+
+
+def load_task(run):
+    """tasks/<task>.py beside this run's drivers, by the loader that
+    found this file (run.py, as a script or as a module)."""
+    harness = sys.modules[type(run).__module__]
+    name = run.config.get("task", "binary")
+    return harness.load_module(
+        os.path.join(run.bench_dir, "tasks", name + ".py"))
 
 
 def setup(run):
     import lightgbm_tpu as lgb
     cfg, mix = run.config, run.traffic
     params = dict(cfg["params"], **mix.get("params", {}))
-    make = getattr(synth, cfg["generator"])
+    task = load_task(run)
     with run.timed("data_s"):
-        X, y = make(cfg["rows"], cfg["features"], (run.seed, 0))
-        Xh, yh = make(cfg["heldout_rows"], cfg["features"], (run.seed, 1))
+        data = task.make(cfg, run.seed, 0)
+        held = task.make(cfg, run.seed, 1)
     with run.timed("dataset_s"):
-        train_set = lgb.Dataset(X, label=y, params=params).construct()
+        train_set = lgb.Dataset(data["X"], label=data["y"], params=params,
+                                **task.dataset_args(data)).construct()
     with run.timed("booster_s"):
         bst = lgb.Booster(params, train_set)
     with run.timed("warmup_s"):
         for _ in range(mix["warmup_iters"]):
             bst.update()
         bst.current_iteration()             # drains the dispatch pipeline
-    run.state.update(bst=bst, X=X, y=y, Xh=Xh, yh=yh,
+    run.state.update(bst=bst, task=task, data=data, held=held,
                      binning=train_set.binned.binning)
-    run.say("train", rows=len(X), features=X.shape[1],
+    run.say("train", rows=len(data["X"]), features=data["X"].shape[1],
             binning=run.state["binning"], **run.setup)
 
 
@@ -102,30 +121,38 @@ def verify(run):
         "payload": {"rows": int(fast.n_rows), "lanes": int(fast.P),
                     "wide_index": bool(fast.wide_idx),
                     "devices": _on_distinct_devices(fast.payload)},
-        "trees_on_host": len(trees), "leaves_min": min(leaves),
+        "trees_on_host": len(trees), "leaves_min": min(leaves, default=0),
     }
     ok = (checks["fast_path"] and eng.engines == cfg["engines"]
           and checks["payload"]["devices"] == run.cell["chips"]
           and failed == 0)
 
     t0 = time.perf_counter()
-    p = cfg["params"]
-    checks["tree0"] = reference.tree0_check(
-        trees[0], run.state["X"], run.state["y"], p["learning_rate"],
-        p.get("lambda_l2", 0.0))
-    ok = ok and checks["tree0"]["counts_ok"] \
-        and checks["tree0"]["max_value_diff"] <= LEAF_VALUE_ATOL
+    task = run.state["task"]
+    atol = cfg.get("leaf_value_atol", LEAF_VALUE_ATOL)
+    tree0 = dict(task.first_tree(trees[0], run.state["data"], cfg),
+                 leaf_value_atol=atol)
+    checks["tree0"] = tree0
+    ok = ok and tree0["counts_ok"] and tree0["max_value_diff"] <= atol
 
     cut = cfg["quality_at_iter"]
     if len(trees) >= cut:
-        raw = reference.predict_raw(trees[:cut], run.state["Xh"])
-        value = float(quality.METRICS[cfg["quality_metric"]](
-            run.state["yh"], raw))
+        value = float(task.heldout(trees[:cut], run.state["held"], cfg))
         lo, hi = cfg["quality_band"]
         checks["heldout"] = {"metric": cfg["quality_metric"], "at_iter": cut,
                              "value": value, "band": [lo, hi]}
         ok = ok and lo <= value <= hi
         run.window["metrics"]["heldout_quality"] = value
     checks["verify_s"] = time.perf_counter() - t0
+    compared = {
+        "trees_failed": [int(failed), 0],
+        "payload_devices": [checks["payload"]["devices"], run.cell["chips"]],
+        "tree0_max_count_diff": [tree0.get("max_count_diff"),
+                                 tree0.get("count_slack_max")],
+        "tree0_max_value_diff": [tree0["max_value_diff"], atol],
+    }
+    if "heldout" in checks:
+        compared["heldout_in_band"] = [checks["heldout"]["value"],
+                                       checks["heldout"]["band"]]
     return {"correct": bool(ok), "attempted": iters, "failed": int(failed),
-            "checks": checks}
+            "checks": checks, "compared": compared}

@@ -19,7 +19,9 @@ through the program's own seam (`warmup.enable_compile_cache`:
 $JAX_COMPILATION_CACHE_DIR, else the checkout's .jax_cache/), sets up
 and warms this cell's shapes, measures for `--seconds`, verifies, and
 prints one JSON object as its LAST line: `correct`, `attempted`,
-`failed`, `metrics`, `device` (and `breakdown` with `--trace 1`).  With
+`failed`, `metrics`, `device` (and `breakdown` with `--trace 1`), then
+`compared`, each number the verdict rests on beside its limit, which are
+also the last lines on standard error.  With
 `--trace 0` the metrics are the cell's end-to-end ones, taken on the
 host's clock with the profiler off; with `--trace 1` a short window is
 traced and the metrics are the cell's per-layer ones.  Human-readable
@@ -255,6 +257,9 @@ def run_cell(workload, seed, seconds, trace, *, manifest_path=None,
         verdict["checks"]["compiled_in_window"] = {
             "ledger": run.counts["compiles"], "jax": run.counts["jax"]}
         verdict["correct"] = False
+    #: each number the verdict rests on beside its limit: [value, limit]
+    compared = dict(verdict.pop("compared", {}),
+                    compiled_in_window=[compiled, 0])
     run.say("verify", **verdict)
 
     run.device["memory_peak_bytes"] = memory_peak_bytes(jax)
@@ -288,6 +293,7 @@ def run_cell(workload, seed, seconds, trace, *, manifest_path=None,
                          for name, value in values.items()
                          if value is not None}
     result["device"] = device
+    result["compared"] = compared           # last on the line
 
     run.say("setup", **run.setup)
     run.say("counts", **run.counts)
@@ -320,6 +326,10 @@ def main(argv=None):
         print("benchmarks/run.py: refused: %s" % e, file=sys.stderr)
         return EXIT_REFUSED
     print(json.dumps(result), flush=True)
+    for name, (value, limit) in result["compared"].items():
+        print("[bench] compared %s %s limit %s"
+              % (name, json.dumps(value), json.dumps(limit)),
+              file=sys.stderr, flush=True)
     return 0
 
 

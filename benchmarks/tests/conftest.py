@@ -55,10 +55,10 @@ def metric_entry(bench_dir, name, **more):
 @pytest.fixture()
 def bench_tree(tmp_path):
     """A copy of the benchmark's data-driven parts with a rehearsal
-    manifest beside it: the real drivers, readers and mixes, tiny
+    manifest beside it: the real drivers, readers, tasks and mixes, tiny
     configurations and tiny mixes ADDED as new files."""
     bench_dir = tmp_path / "benchmarks"
-    for part in ("drivers", "layer_metrics", "traffic"):
+    for part in ("drivers", "layer_metrics", "tasks", "traffic"):
         shutil.copytree(os.path.join(BENCH, part), bench_dir / part)
     for name, (base, changes) in TINY_MIXES.items():
         with open(os.path.join(BENCH, "traffic", base + ".json")) as fh:

@@ -10,7 +10,8 @@ import pytest
 
 from conftest import BENCH, ROOT, metric_entry, run_tiny
 
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "compared"}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 
 
@@ -123,6 +124,127 @@ def test_new_config_mix_and_metric_are_found_as_new_files(bench_tree):
     assert result["metrics"]["loop.iterations"]["value"] == 3.0
     for path, content in before.items():
         assert open(path, "rb").read() == content
+
+
+#: a learning task as a later PR would bring it: ranking over query groups,
+#: its own data, walk, first-tree check and NDCG, in one new file
+TOY_GROUPS = '''"""A toy ranking task: queries of uneven length, graded labels."""
+import numpy as np
+
+
+def make(cfg, seed, part):
+    rng = np.random.default_rng([seed, part])
+    sizes = rng.integers(2, 40, cfg["heldout_queries"] if part
+                         else cfg["queries"])
+    X = rng.standard_normal((int(sizes.sum()), cfg["features"]))
+    score = X[:, 0] + 0.5 * X[:, 1] + 0.5 * rng.standard_normal(len(X))
+    y = np.clip(np.floor(score + 1.5), 0, 4).astype(np.float32)
+    return {"X": X, "y": y, "group": sizes}
+
+
+def dataset_args(data):
+    return {"group": data["group"]}
+
+
+def _leaf(tree, X):
+    """Numerical splits only: each row's leaf, node by node."""
+    out = np.empty(len(X), np.int64)
+    for i, x in enumerate(X):
+        node = 0
+        while node >= 0:
+            left = x[tree.split_feature[node]] <= tree.threshold[node]
+            node = (tree.left_child if left else tree.right_child)[node]
+        out[i] = ~node
+    return out
+
+
+def first_tree(tree, data, cfg):
+    nl = int(tree.num_leaves)
+    count = np.bincount(_leaf(tree, data["X"]), minlength=nl)
+    off = np.abs(count - np.asarray(tree.leaf_count[:nl], np.int64))
+    return {"leaves": nl, "counts_ok": bool((off == 0).all()),
+            "max_count_diff": int(off.max()), "max_value_diff": 0.0}
+
+
+def _ndcg(y, score, k):
+    gain = lambda order: ((2.0 ** y[order][:k] - 1)
+                          / np.log2(np.arange(2, 2 + min(k, len(y))))).sum()
+    best = gain(np.argsort(-y, kind="stable"))
+    return gain(np.argsort(-score, kind="stable")) / best if best else 1.0
+
+
+def heldout(trees, data, cfg):
+    values = [np.asarray(t.leaf_value, np.float64)[_leaf(t, data["X"])]
+              for t in trees]
+    score = np.sum(values, axis=0)
+    ends = np.cumsum(data["group"])
+    return float(np.mean([_ndcg(data["y"][lo:hi], score[lo:hi], 10)
+                          for lo, hi in zip(ends - data["group"], ends)]))
+'''
+
+
+def test_new_task_is_found_as_new_files(bench_tree):
+    """A later PR adds a learning task (here LambdaRank over query
+    groups: another objective, another `lgb.Dataset` keyword, another
+    held-out measure) as tasks/<task>.py and a configuration that names
+    it; the mix and the driver stay `train`, so the readers that say
+    `DRIVERS = ("train",)` read it, and no file that was there changes."""
+    root, bench_dir = bench_tree["root"], bench_tree["bench_dir"]
+    before = {}
+    for folder, _, names in os.walk(bench_dir):
+        for name in names:
+            path = os.path.join(folder, name)
+            before[path] = open(path, "rb").read()
+
+    with open(os.path.join(bench_dir, "tasks", "toy_groups.py"), "w") as fh:
+        fh.write(TOY_GROUPS)
+    config = {
+        "name": "toy-rank", "task": "toy_groups", "chips": 1,
+        "queries": 300, "heldout_queries": 100, "features": 10,
+        "params": {"objective": "lambdarank", "num_leaves": 15,
+                   "max_bin": 63, "learning_rate": 0.1,
+                   "min_data_in_leaf": 5, "verbose": -1},
+        "quality_metric": "ndcg@10", "quality_at_iter": 6,
+        "quality_band": [0.5, 1.0],
+        "engines": {"histogram": "lax", "partition": "lax"}}
+    with open(os.path.join(root, "toy-rank.json"), "w") as fh:
+        json.dump(config, fh)
+    manifest = bench_tree["manifest"]
+    manifest["configs"].append({"name": "toy-rank",
+                                "file": os.path.join(root, "toy-rank.json")})
+    manifest["workloads"].append({"name": "toy-rank-train",
+                                  "config": "toy-rank", "traffic": "train",
+                                  "chips": 1})
+    with open(bench_tree["manifest_path"], "w") as fh:
+        json.dump(manifest, fh)
+
+    result = run_tiny(bench_tree, "toy-rank-train", seconds=1.0, trace=True)
+    check_result(result, trace=True)
+    assert result["metrics"]["loop.dispatches_per_iter"]["value"] == 2.0
+    ndcg, band = result["compared"]["heldout_in_band"]
+    assert band == [0.5, 1.0] and 0.5 < ndcg < 1.0
+    assert result["compared"]["tree0_max_count_diff"][0] == 0
+    for path, content in before.items():
+        assert open(path, "rb").read() == content
+
+
+@pytest.mark.parametrize("seed", [3, 2**31 + 7])
+def test_binary_task_is_the_data_it_was(seed):
+    """tasks/binary.py hands on what `synth.binary_task` makes: the same
+    bytes for the same (seed, part), no copy, nothing else."""
+    from benchmarks.lib import synth
+    from benchmarks.run import load_module
+    task = load_module(os.path.join(BENCH, "tasks", "binary.py"))
+    cfg = {"generator": "binary_task", "rows": 5000, "heldout_rows": 1500,
+           "features": 28}
+    for part, rows in ((0, 5000), (1, 1500)):
+        data = task.make(cfg, seed, part)
+        X, y = synth.binary_task(rows, 28, (seed, part))
+        assert set(data) == {"X", "y"}
+        assert data["X"].tobytes() == X.tobytes()
+        assert data["y"].tobytes() == y.tobytes()
+        assert data["X"].dtype == X.dtype and data["y"].dtype == y.dtype
+        assert task.dataset_args(data) == {}
 
 
 def test_a_reader_that_finds_nothing_is_left_out(bench_tree):
