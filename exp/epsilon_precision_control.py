@@ -1,5 +1,6 @@
 #!/usr/bin/env python3
-"""A control for what `epsilon-train`'s `correct` can tell: the cell run
+"""A control for what a train cell's `correct` can tell (`epsilon-train`
+unless `--workload` names another, as `msltr-train`): the cell run
 once as it is and once with the histogram in the nearest precision below
 the program's own, both on one seed, and the two first trees side by side.
 
@@ -60,11 +61,13 @@ def bf16_package():
     return src
 
 
-def out_path(variant, seed):
-    return os.path.join(OUT, "%s.s%d.json" % (variant, seed))
+def out_path(variant, seed, workload=WORKLOAD):
+    name = variant if workload == WORKLOAD else "%s.%s" % (workload, variant)
+    return os.path.join(OUT, "%s.s%d.json" % (name, seed))
 
 
-def run(variant, seed, seconds, manifest=None, require_tpu=True):
+def run(variant, seed, seconds, manifest=None, require_tpu=True,
+        workload=WORKLOAD):
     os.makedirs(OUT, exist_ok=True)
     sys.path.insert(0, ROOT)
     if variant == "bf16":
@@ -72,7 +75,7 @@ def run(variant, seed, seconds, manifest=None, require_tpu=True):
     from benchmarks import run as bench
     import lightgbm_tpu
     _, cell, driver, counter = bench.open_cell(
-        WORKLOAD, seed, False, manifest_path=manifest,
+        workload, seed, False, manifest_path=manifest,
         require_tpu=require_tpu)
     driver.setup(cell)
     before = counter.snapshot()
@@ -89,7 +92,7 @@ def run(variant, seed, seconds, manifest=None, require_tpu=True):
         "tree0": {key: [float(v) for v in getattr(tree, key)[:splits]]
                   for key in ("split_feature", "threshold", "split_gain")},
     }
-    with open(out_path(variant, seed), "w") as fh:
+    with open(out_path(variant, seed, workload), "w") as fh:
         json.dump(record, fh, indent=1, default=str)
     print(json.dumps({k: record[k] for k in ("variant", "seed", "correct",
                                              "metrics")}
@@ -97,11 +100,11 @@ def run(variant, seed, seconds, manifest=None, require_tpu=True):
                         "engines": verdict["checks"]["engines"]}))
 
 
-def compare(seed):
+def compare(seed, workload=WORKLOAD):
     """Tree 0 of the two variants on one seed (so on the same bins): the
     splits made in the same order at the same place, and the control's
     readings beside the sound run's."""
-    sound, control = (json.load(open(out_path(v, seed)))
+    sound, control = (json.load(open(out_path(v, seed, workload)))
                       for v in ("sound", "bf16"))
     a, b = sound["tree0"], control["tree0"]
     pairs = [list(zip(t["split_feature"], t["threshold"])) for t in (a, b)]
@@ -130,6 +133,8 @@ def main():
     ap.add_argument("--variant", choices=("sound", "bf16"), default="sound")
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, default=20.0)
+    ap.add_argument("--workload", default=WORKLOAD,
+                    help="another train cell than %s" % WORKLOAD)
     ap.add_argument("--manifest", default=None,
                     help="a manifest whose `epsilon-train` is cut to size, "
                          "with --cpu: a rehearsal of this script, no reading")
@@ -137,9 +142,9 @@ def main():
     args = ap.parse_args()
     if args.action == "run":
         run(args.variant, args.seed, args.seconds, args.manifest,
-            not args.cpu)
+            not args.cpu, args.workload)
     else:
-        compare(args.seed)
+        compare(args.seed, args.workload)
 
 
 if __name__ == "__main__":

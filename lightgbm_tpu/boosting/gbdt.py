@@ -436,7 +436,6 @@ class _FastState:
                                          combined[read_idx(payload)])
 
         rowwise = getattr(obj, "is_rowwise", True) if obj is not None else True
-        label_orig, weight_orig = gbdt.label_dev, gbdt.weight_dev
 
         if rowwise:
             def _class_grads(payload, k):
@@ -451,24 +450,22 @@ class _FastState:
                         jnp.take(h, k, axis=0) * valid)
         else:
             def _class_grads(payload, k):
-                """Non-rowwise objectives (lambdarank/xendcg: gradients
-                couple rows within a query): scatter the snapshot scores
-                back to ORIGINAL row order through the index column,
-                compute gradients against the original-order label/weight
-                (where the query boundaries live), and gather the results
-                into the current partition order.  Two permutations per
-                class tree — cheap next to the histogram work."""
-                idx = read_idx(payload)
-                snap = payload[:, snap0:snap0 + K]
-                score_orig = jnp.zeros((K, n_pad + 1), jnp.float32) \
-                    .at[:, idx].set(snap.T)[:, :n_pad]
-                g, h = obj.get_gradients_multi(score_orig, label_orig,
-                                               weight_orig)
-                gp = jnp.pad(g, ((0, 0), (0, 1)))
-                hp = jnp.pad(h, ((0, 0), (0, 1)))
+                """Objectives that couple rows within a query (lambdarank):
+                the objective is handed the snapshot scores as they sit,
+                in partition order, with the index column that says which
+                original row each is, and answers in the same order
+                (`gradients_in_order`).  It owns the one map from
+                original rows to its query slots (and such an objective
+                trains one tree an iteration, K = 1), so the scores take ONE
+                permutation in and the gradients one out; nothing passes
+                through original row order.  On `msltr-train` the two
+                read 0.041 s on the chip, 13% of the iteration, where
+                three permutations round a padded [Q, S] layout read 0.64
+                (PERF.md section 6, PR 31)."""
+                g, h = obj.gradients_in_order(payload[:, snap0],
+                                              read_idx(payload))
                 valid = payload[:, cnt_col]
-                return (jnp.take(gp, k, axis=0)[idx] * valid,
-                        jnp.take(hp, k, axis=0)[idx] * valid)
+                return g * valid, h * valid
 
         def _fill_body(payload, k):
             """Write class k's gradients into the grad/hess columns —
@@ -1330,8 +1327,12 @@ class GBDT:
         collectives at the histogram boundary; tree_learner=feature runs
         it per feature shard over replicated rows with owned-first column
         permutation — except under forced splits or GOSS, which keep the
-        legacy masked engine), ranking objectives (original-order gradient
-        fill through the index column), leaf-output renewal (except under
+        legacy masked engine), ranking objectives (the objective is handed
+        the scores in partition order with the index column and answers
+        in that order, `gradients_in_order`; not under GOSS, whose fused
+        sampling step computes gradients row-wise from payload columns
+        and has no such call, so rank + GOSS trains on the legacy
+        engine), leaf-output renewal (except under
         GOSS), and row counts up to 2^31 (radix-split index columns past
         2^24)."""
         cfg = self.config
@@ -1345,9 +1346,9 @@ class GBDT:
                          and getattr(self, "_fast_sample_hook", None)
                          is None))
                 and self.objective is not None
-                # non-rowwise objectives (ranking) ride the fast path via
-                # the original-order gradient fill; GOSS's fused sampling
-                # step has no such fill, so rank+GOSS keeps the legacy path
+                # objectives that couple rows (ranking) ride the fast path
+                # through `gradients_in_order`; GOSS's fused sampling step
+                # makes no such call, so rank+GOSS keeps the legacy path
                 and (getattr(self.objective, "is_rowwise", True)
                      or getattr(self, "_fast_sample_hook", None) is None)
                 # leaf renewal runs on the fast path (per-segment leaf

@@ -44,9 +44,14 @@ from .grower import GrowerConfig, make_winner_sync
 
 
 #: the phases of the fused step (`gbdt.step`: gradients -> grow -> score
-#: add), by which its device time is told apart in a profiler trace
+#: add), by which its device time is told apart in a profiler trace.
+#: `grad_pairs` and `grad_permute` are entered inside `grad` by an
+#: objective that couples rows (objective/rank.py): the per-query
+#: pairwise program, and every move between partition order, original
+#: order and query slots
 PHASES = ("grad", "root_hist", "partition", "hist", "subtract",
-          "split_search", "tree_update", "score", "allreduce")
+          "split_search", "tree_update", "score", "allreduce",
+          "grad_pairs", "grad_permute")
 
 
 def phase(name: str):

@@ -278,7 +278,10 @@ def _step_op_names(extra):
     X, y = _synth(n=1024, f=6)
     params = dict({"objective": "binary", "num_leaves": 7, "verbose": -1},
                   **extra)
-    bst = lgb.Booster(params, lgb.Dataset(X, label=y, params=params))
+    # a ranking objective wants query groups: 16 queries of 64 rows
+    group = np.full(16, 64) if params["objective"] == "lambdarank" else None
+    bst = lgb.Booster(params, lgb.Dataset(X, label=y, group=group,
+                                          params=params))
     bst.update()
     eng = bst._engine
     fs = eng._fast
@@ -304,7 +307,7 @@ def mesh_step_op_names():
 
 def test_phase_helper_knows_its_names():
     from lightgbm_tpu.boosting import grower2
-    assert len(set(grower2.PHASES)) == 9
+    assert len(set(grower2.PHASES)) == 11
     with pytest.raises(ValueError):
         grower2.phase("probe")
 
@@ -317,6 +320,22 @@ def test_fused_step_carries_a_scope_for_every_phase(serial_step_op_names,
     assert [n for _, n in serial_step_op_names if "lgbm.%s" % name in n]
     # the serial step reduces nothing across devices
     assert not [n for _, n in serial_step_op_names if "lgbm.allreduce" in n]
+
+
+@pytest.mark.parametrize("name", ["grad_pairs", "grad_permute"])
+def test_ranking_step_scopes_its_pairwise_program_and_its_permutations(
+        name, serial_step_op_names):
+    """An objective that couples rows enters two phases of its own
+    inside `lgbm.grad`: every gather and scatter of its fill is a
+    permutation, and a row-wise objective's step has neither scope."""
+    names = _step_op_names({"objective": "lambdarank"})
+    inner = [(op, n) for op, n in names if "lgbm.%s" % name in n]
+    assert inner and all("lgbm.grad/" in n for _, n in inner)
+    moved = {op for op, n in names
+             if op in ("gather", "scatter") and "lgbm.grad/" in n
+             and "lgbm.grad_permute" not in n}
+    assert not moved
+    assert not [n for _, n in serial_step_op_names if "lgbm.%s" % name in n]
 
 
 @pytest.mark.parametrize("name", ["allreduce", "root_hist", "hist",
