@@ -1,13 +1,15 @@
 #!/usr/bin/env python3
-"""The device operations of a ranking cell's gradient fill, one by one:
-a traced run of the cell (the benchmark's own `run_cell`, three
-iterations), then every operation whose `tf_op` carries one of the fill's
-scopes (`lgbm.grad`, `lgbm.grad_pairs`, `lgbm.grad_permute`), summed by
-HLO instruction: self seconds an iteration, calls, the instruction's
-text.  The per-layer metrics give the phases' totals; this names what is
-inside them.
+"""The device operations of a train cell's phases, one by one: a traced
+run of the cell (the benchmark's own `run_cell`, three iterations), then
+every operation whose `tf_op` carries one of the named scopes (by default
+the ranking fill's: `lgbm.grad`, `lgbm.grad_pairs`, `lgbm.grad_permute`),
+summed by HLO instruction: self seconds an iteration, calls, the
+instruction's text.  The per-layer metrics give the phases' totals; this
+names what is inside them.  The phase `none` lists the operations that
+carry no scope at all (a while loop's entry copy, say).
 
     python3 exp/rank_phase_ops.py [--workload msltr-train] [--seed N]
+        [--phases grad,score,tree_update,none] [--out chiprun_out/x.json]
 """
 import argparse
 import json
@@ -18,6 +20,7 @@ from collections import defaultdict
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 PHASES = ("grad", "grad_pairs", "grad_permute")
+UNSCOPED = "none"
 
 
 def main():
@@ -25,7 +28,12 @@ def main():
     ap.add_argument("--workload", default="msltr-train")
     ap.add_argument("--seed", type=int, default=3000000412)
     ap.add_argument("--top", type=int, default=12)
+    ap.add_argument("--phases", default=",".join(PHASES),
+                    help="comma-separated phases of grower2.PHASES, and "
+                         "`none` for the operations under no scope")
+    ap.add_argument("--out", help="also write the report to this file")
     args = ap.parse_args()
+    phases_wanted = tuple(args.phases.split(","))
 
     from benchmarks import run as bench
     from benchmarks.lib import progspans, xplane
@@ -41,16 +49,18 @@ def main():
     for dev in trace.devices:
         phases = by_plane.get("/device:TPU:%d" % dev.ordinal, {})
         for op in dev.ops:
-            phase = phases.get(op.name)
-            if phase in PHASES and op.end_ns > lo and op.start_ns < hi:
+            phase = phases.get(op.name) or UNSCOPED
+            if phase in phases_wanted and op.end_ns > lo \
+                    and op.start_ns < hi:
                 rec = total[(phase, op.name)]
                 rec[0] += op.self_ns
                 rec[1] += 1
     os.remove(kept)
     report = {"workload": args.workload, "seed": args.seed,
               "metrics": {k: v["value"] for k, v in result["metrics"].items()
-                          if k.startswith(("rank.", "step."))}}
-    for phase in PHASES:
+                          if k.startswith(("rank.", "step.", "grower.",
+                                           "kernel.", "train_"))}}
+    for phase in phases_wanted:
         ops = sorted(((ns, n, name) for (p, name), (ns, n) in total.items()
                       if p == phase), reverse=True)
         report[phase] = {
@@ -58,7 +68,13 @@ def main():
             "ops": [{"s_per_iter": round(ns / 1e9 / iters, 6),
                      "calls_per_iter": n / iters, "hlo": name[:400]}
                     for ns, n, name in ops[:args.top]]}
-    print(json.dumps(report, indent=1))
+    text = json.dumps(report, indent=1)
+    if args.out:
+        os.makedirs(os.path.dirname(os.path.abspath(args.out)),
+                    exist_ok=True)
+        with open(args.out, "w") as f:
+            f.write(text)
+    print(text)
 
 
 if __name__ == "__main__":

@@ -13,7 +13,8 @@ On the chip:   python exp/smoke_tpu_kernels.py [section ...]
 CPU rehearsal: JAX_PLATFORMS=cpu python exp/smoke_tpu_kernels.py --interpret
 (the Pallas interpreter at a reduced row count; proves the script, says
 nothing about Mosaic).  Section names (`partition_acc blocks precision`
-are the three that run `_acc_kernel`) keep the run to those.
+are the three that run `_acc_kernel`; `state_cols` is the three kernels
+of `ops/state_columns.py`) keep the run to those.
 """
 import json
 import os
@@ -30,6 +31,7 @@ import jax.numpy as jnp
 
 from lightgbm_tpu.ops import segment as seg
 from lightgbm_tpu.ops import pallas_segment as pseg
+from lightgbm_tpu.ops import state_columns as scols
 
 INTERPRET = "--interpret" in sys.argv[1:]
 if not INTERPRET and jax.default_backend() != "tpu":
@@ -318,7 +320,108 @@ def precision():
     return {"hist_grad_err_vs_f64": gerr}
 
 
-SECTIONS = (histogram, partition_rmw, partition_acc, blocks, precision)
+def state_cols():
+    """The three state-column kernels (`ops/state_columns.py`) against
+    the lax form, bit for bit: columns inside one lane tile of 128, 256
+    and 2,048 lanes and across a tile edge, rows that fill no whole
+    block, values no arithmetic would survive (NaN, infinities, -0.0,
+    the largest index of the wide layout); then each kernel
+    timed at the Higgs cell's shape (10,502,408 x 128) and the Epsilon
+    cell's (409,864 x 2,048), a call in a chain of five, with the
+    payload's plain copy beside them, and (`--race`) at other block
+    heights."""
+    form = "pallas-interpret" if INTERPRET else "pallas"
+
+    def same(got, want, *what):
+        """Bit for bit, and where not: the first places and both values."""
+        got, want = np.asarray(got), np.asarray(want)
+        bad = np.argwhere((got.view(np.uint32) != want.view(np.uint32))
+                          & ~(np.isnan(got) & np.isnan(want)))
+        assert not len(bad), (what, len(bad), [
+            (tuple(map(int, at)), float(got[tuple(at)]),
+             float(want[tuple(at)])) for at in bad[:6]])
+
+    # (no denormal: the kernels keep one, XLA's select on the chip
+    # flushes it to zero, as every later use of it would)
+    odd = np.array([np.nan, np.inf, -np.inf, -0.0, 4095.0, 16777215.0,
+                    3.4e38, 1.1754944e-38], np.float32)
+    for P, cols in ((128, (28, 29, 30, 31, 32, 33, 35)),
+                    (256, (125, 126, 127, 128, 129)),
+                    (256, (137, 138, 139, 141, 145, 146, 147, 148)),
+                    (2048, (2000, 2001, 2002, 2004, 2005, 2012))):
+        n = 5000 + seg.GUARD
+        host = rng.standard_normal((n, P)).astype(np.float32)
+        host[:odd.size, cols[0]] = odd
+        host[-odd.size:, cols[-1]] = odd
+        pay = jnp.asarray(host)
+        same(scols.read_cols(pay, cols, form),
+             np.ascontiguousarray(host[:, cols].T), "read", P)
+        vals = rng.standard_normal((len(cols), n)).astype(np.float32)
+        vals[0, :odd.size] = odd
+        vals[-1, -odd.size:] = odd
+        for k in (2, 3, len(cols)):
+            same(scols.write_cols(pay, cols[-k:], jnp.asarray(vals[:k]),
+                                  form),
+                 scols.write_cols(pay, cols[-k:], jnp.asarray(vals[:k]),
+                                  "lax"), "write", P, k)
+        for dst in (cols[0], cols[2], cols[-1]):
+            for on in (True, False):
+                args = (jnp.int32(dst), (cols[0], cols[-1]), cols[1],
+                        jnp.float32(0.1), jnp.bool_(on))
+                same(scols.add_scaled(pay, *args, form),
+                     scols.add_scaled(pay, *args, "lax"), "add", P, dst, on)
+    if INTERPRET:
+        return {}
+
+    def chain_ms(step, pay, reps=5):
+        """ms a call of `step(payload) -> payload`, donated, in a chain."""
+        fn = jax.jit(step, donate_argnums=(0,))
+        pay = fn(pay)
+        float(pay[0, 0])
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            pay = fn(pay)
+        float(pay[0, 0])
+        return round((time.perf_counter() - t0) * 1e3 / reps, 3), pay
+
+    info = {}
+    heights = (1024, 2048, 4096) if "--race" in sys.argv[1:] \
+        else (scols._BLOCK_ROWS,)
+    for name, rows, P, c0 in (("higgs", 10_502_408, 128, 28),
+                              ("epsilon", 409_864, 2048, 2000)):
+        pay = jnp.zeros((rows, P), jnp.float32) + 1.0
+        label, weight, cnt, score, grad, hess, value = (
+            c0, c0 + 1, c0 + 2, c0 + 4, c0 + 5, c0 + 6, c0 + 7)
+        vals = jnp.ones((2, rows), jnp.float32)
+        info["%s_copy_ms" % name], pay = chain_ms(lambda p: p + 1.0, pay)
+        default = scols._BLOCK_ROWS
+        for height in heights:
+            scols._BLOCK_ROWS = height
+            for fn in (scols._state_cols_read, scols._state_cols_write,
+                       scols._state_cols_axpy):
+                fn.clear_cache()
+            tag = "%s_%%s_ms" % name if len(heights) == 1 \
+                else "%s_%%s_ms@%d" % (name, height)
+            # the read's vectors feed the next call's write, as the fill's
+            info[tag % "read_write"], pay = chain_ms(
+                lambda p: scols.write_cols(
+                    p, (grad, hess),
+                    scols.read_cols(p, (score, label, weight, cnt),
+                                    "pallas")[:2] * 0.5, "pallas"), pay)
+            info[tag % "write"], pay = chain_ms(
+                lambda p: scols.write_cols(p, (grad, hess), vals, "pallas"),
+                pay)
+            info[tag % "add"], pay = chain_ms(
+                lambda p: scols.add_scaled(
+                    p, jnp.int32(score), (score, score), value,
+                    jnp.float32(0.1), jnp.bool_(True), "pallas"), pay)
+        scols._BLOCK_ROWS = default
+        del pay, vals
+    return info
+
+
+SECTIONS = (histogram, partition_rmw, partition_acc, blocks, precision,
+            state_cols)
 
 
 def main():

@@ -33,6 +33,7 @@ from ..utils.log import Log
 from ..utils.random import Random, partition_seed
 from ..utils.timer import PhaseTimer
 from ..ops import segment as seg
+from ..ops import state_columns
 from ..ops.bundle import (BundleMap, bundle_map_from_info, decode_bin,
                           identity_bundle_map)
 from .grower import GrowerConfig, make_tree_grower
@@ -291,7 +292,8 @@ class _FastState:
         # the one-hot permutation matmuls (each output is a single-term sum)
         self.wide_idx = (n_pad + 1) >= _IDX_WIDE_THRESHOLD
         self.idxhi_col = self.gweight_col + 1 if self.wide_idx else None
-        self.P = (self.idxhi_col if self.wide_idx else self.gweight_col) + 1
+        last_col = self.idxhi_col if self.wide_idx else self.gweight_col
+        self.P = last_col + 1
         if jax.default_backend() == "tpu":
             # Mosaic DMA slices must span whole 128-lane tiles; a [N, P]
             # f32 array is physically padded to 128 lanes on TPU anyway,
@@ -319,13 +321,16 @@ class _FastState:
                 idx = jnp.remainder(idx, jnp.int32(_IDX_RADIX))
             return pay.at[rows, idx_col].set(idx.astype(jnp.float32))
 
-        def read_idx(payload):
-            """Integer row indices from the index column(s)."""
-            idx = payload[:, idx_col].astype(jnp.int32)
+        def decode_idx(lo, hi=None):
+            """Integer row indices from the index column(s)' values."""
+            idx = lo.astype(jnp.int32)
             if wide_idx:
-                idx = idx + payload[:, idxhi_col].astype(jnp.int32) \
-                    * jnp.int32(_IDX_RADIX)
+                idx = idx + hi.astype(jnp.int32) * jnp.int32(_IDX_RADIX)
             return idx
+
+        def read_idx(payload):
+            return decode_idx(payload[:, idx_col],
+                              payload[:, idxhi_col] if wide_idx else None)
 
         def build_block(bins, label, weight, vmask, score, idx0):
             """One device block: n_loc_b real rows + the GUARD-row tail,
@@ -411,12 +416,60 @@ class _FastState:
         obj = gbdt.objective
         snap0, cnt_col = self.snap0, self.cnt_col
         grad_col, hess_col = self.grad_col, self.hess_col
+        value_col = self.value_col
+
+        #: how the fused step reads and writes the state columns
+        #: (ops.state_columns): "pallas" on a TPU where they sit inside
+        #: two lane tiles, one pass over those tiles an update; else "lax"
+        self.state_form = form = state_columns.resolve_form(
+            self.P, (self.label_col, last_col))
+        Log.info("fast path state columns: lanes %d-%d of %d, form=%s",
+                 self.label_col, last_col, self.P, form)
+        if mesh is not None and form != "lax":
+            # GSPMD partitions the lax form's elementwise code; a Pallas
+            # call it does not: each device's block of rows (its own
+            # GUARD tail included) takes the call on its own
+            from jax.sharding import PartitionSpec as PS
+            by_rows, by_lanes, replicated = PS(gbdt.mesh_axis, None), \
+                PS(None, gbdt.mesh_axis), PS()
+
+            def per_block(fn, in_specs, out_specs):
+                return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                     out_specs=out_specs, check_vma=False)
+        else:
+            by_rows = by_lanes = replicated = None
+
+            def per_block(fn, in_specs, out_specs):
+                return fn
+
+        def read_state(payload, cols):
+            """[len(cols), N]: the state columns `cols`, rows in lanes."""
+            return per_block(
+                lambda pay: state_columns.read_cols(pay, cols, form),
+                (by_rows,), by_lanes)(payload)
+
+        def write_state(payload, cols, vecs):
+            """payload[:, cols[i]] = vecs[i], one pass for all of them."""
+            return per_block(
+                lambda pay, vals: state_columns.write_cols(pay, cols, vals,
+                                                           form),
+                (by_rows, by_lanes), by_rows)(payload, jnp.stack(vecs))
+
+        def add_value(payload, k, lr, moved):
+            """score[:, k] += value * lr where `moved`, inside the tile."""
+            return per_block(
+                lambda pay, dst, scale, on: state_columns.add_scaled(
+                    pay, dst, (score0, score0 + K - 1), value_col, scale,
+                    on, form),
+                (by_rows, replicated, replicated, replicated), by_rows)(
+                    payload, score0 + k, lr, moved)
 
         @functools.partial(xla_obs.jit, site="gbdt.snap_scores",
                            donate_argnums=(0,))
         def snap_scores(payload):
-            # K lane-masked passes, not a slice DUS — see
-            # seg.payload_col_write (the K wheres fuse into one pass)
+            # K lane-masked selects, not a slice DUS — see
+            # seg.payload_col_write (whether the K share a pass is the
+            # compiler's; no cell trains K > 1, so no trace says)
             for kk in range(K):
                 payload = seg.payload_col_write(payload, snap0 + kk,
                                                 payload[:, score0 + kk])
@@ -437,15 +490,25 @@ class _FastState:
 
         rowwise = getattr(obj, "is_rowwise", True) if obj is not None else True
 
+        snap_cols = tuple(range(snap0, snap0 + K))
+        idx_cols = (idx_col, idxhi_col) if wide_idx else (idx_col,)
+
+        def _all_grads(payload, *more):
+            """Every class's (gradient, hessian) from the snapshot scores,
+            and the further state columns `more` as vectors, out of ONE
+            read of the state columns."""
+            state = read_state(payload, snap_cols + (G, G + 1) + more)
+            g, h = obj.get_gradients_multi(state[:K], state[K],
+                                           state[K + 1])
+            return (g, h) + tuple(state[K + 2 + i]
+                                  for i in range(len(more)))
+
         if rowwise:
             def _class_grads(payload, k):
                 """Class k's masked (gradient, hessian) vectors in the
                 payload's current row order — shared by the f32 fill and
                 the quantized fill."""
-                snap = payload[:, snap0:snap0 + K].T
-                g, h = obj.get_gradients_multi(snap, payload[:, G],
-                                               payload[:, G + 1])
-                valid = payload[:, cnt_col]
+                g, h, valid = _all_grads(payload, cnt_col)
                 return (jnp.take(g, k, axis=0) * valid,
                         jnp.take(h, k, axis=0) * valid)
         else:
@@ -462,18 +525,17 @@ class _FastState:
                 read 0.041 s on the chip, 13% of the iteration, where
                 three permutations round a padded [Q, S] layout read 0.64
                 (PERF.md section 6, PR 31)."""
-                g, h = obj.gradients_in_order(payload[:, snap0],
-                                              read_idx(payload))
-                valid = payload[:, cnt_col]
+                score, valid, *idx = read_state(
+                    payload, (snap0, cnt_col) + idx_cols)
+                g, h = obj.gradients_in_order(score, decode_idx(*idx))
                 return g * valid, h * valid
 
         def _fill_body(payload, k):
             """Write class k's gradients into the grad/hess columns —
             shared by the piecewise (profiled) and fused paths."""
             with phase("grad"):
-                gk, hk = _class_grads(payload, k)
-                payload = seg.payload_col_write(payload, grad_col, gk)
-                return seg.payload_col_write(payload, hess_col, hk)
+                return write_state(payload, (grad_col, hess_col),
+                                   _class_grads(payload, k))
 
         @functools.partial(xla_obs.jit, site="gbdt.fill_class",
                            donate_argnums=(0,), static_argnames=("k",))
@@ -493,9 +555,8 @@ class _FastState:
                 with phase("grad"):
                     gk, hk = _class_grads(payload, k)
                     qg, qh, qscale = quantize_pair(gk, hk, qseed, qmax_f)
-                    payload = seg.payload_col_write(payload, grad_col, qg)
-                    payload = seg.payload_col_write(payload, hess_col, qh)
-                    return payload, qscale
+                    return write_state(payload, (grad_col, hess_col),
+                                       (qg, qh)), qscale
 
             @functools.partial(xla_obs.jit,
                                site="gbdt.fill_class_quant",
@@ -507,11 +568,9 @@ class _FastState:
         @functools.partial(xla_obs.jit, site="gbdt.apply_score",
                            donate_argnums=(0,), static_argnames=("k",))
         def apply_score(payload, lr, k):
-            upd = payload[:, self.value_col] * lr
-            return seg.payload_col_write(payload, score0 + k, upd, "add")
+            return add_value(payload, k, lr, True)
 
         grower = self.grower
-        value_col = self.value_col
         bvalid_col = self.bvalid_col
         sample_hook = getattr(gbdt, "_fast_sample_hook", None)
 
@@ -522,10 +581,7 @@ class _FastState:
                 if hasattr(grower, "__wrapped__") else grower(*args)
             # stumps must not move the scores (gbdt.cpp stops instead)
             with phase("score"):
-                upd = jnp.where(out["num_leaves"] > 1,
-                                payload[:, value_col] * lr, 0.0)
-                payload = seg.payload_col_write(payload, score0 + k, upd,
-                                                "add")
+                payload = add_value(payload, k, lr, out["num_leaves"] > 1)
             return out, payload, aux
 
         @functools.partial(xla_obs.jit, site="gbdt.step",
@@ -548,17 +604,14 @@ class _FastState:
                 payload, qscale = _fill_body_quant(payload, k, qseed)
                 return _grow_and_score(payload, aux, fmask, lr, k, qscale)
 
-        def _all_grads(payload):
-            snap = payload[:, snap0:snap0 + K].T
-            return obj.get_gradients_multi(snap, payload[:, G],
-                                           payload[:, G + 1])
-
-        def _write_sampled(payload, g, h, k, gw, cm):
-            payload = seg.payload_col_write(payload, grad_col,
-                                            jnp.take(g, k, axis=0) * gw)
-            payload = seg.payload_col_write(payload, hess_col,
-                                            jnp.take(h, k, axis=0) * gw)
-            return seg.payload_col_write(payload, cnt_col, cm)
+        def _write_sampled(payload, g, h, k, gw, cm=None):
+            """Class k's weighted gradients, and the selection's count
+            mask where it is new, in one write."""
+            cols, vecs = (grad_col, hess_col), (jnp.take(g, k, axis=0) * gw,
+                                                jnp.take(h, k, axis=0) * gw)
+            if cm is not None:
+                cols, vecs = cols + (cnt_col,), vecs + (cm,)
+            return write_state(payload, cols, vecs)
 
         @functools.partial(xla_obs.jit, site="gbdt.step_sampled",
                            donate_argnums=(0, 1))
@@ -569,8 +622,7 @@ class _FastState:
             pristine valid column, and class k's weighted gradients plus
             the selection mask land in the working columns."""
             with phase("grad"):
-                g, h = _all_grads(payload)
-                valid = payload[:, bvalid_col]
+                g, h, valid = _all_grads(payload, bvalid_col)
                 gw, cm = sample_hook(g * valid, h * valid, valid, key,
                                      enabled)
                 payload = _write_sampled(payload, g, h, k, gw, cm)
@@ -586,20 +638,17 @@ class _FastState:
             into payload COLUMNS (gweight + cnt) — each class tree
             repartitions the rows, and columns ride the partition while
             standalone mask arrays would go stale after the first tree."""
-            g, h = _all_grads(payload)
-            valid = payload[:, bvalid_col]
+            g, h, valid = _all_grads(payload, bvalid_col)
             gw, cm = sample_hook(g * valid, h * valid, valid, key, enabled)
-            payload = seg.payload_col_write(payload, gweight_col, gw)
-            return seg.payload_col_write(payload, cnt_col, cm)
+            return write_state(payload, (gweight_col, cnt_col), (gw, cm))
 
         @functools.partial(xla_obs.jit, site="gbdt.step_masked",
                            donate_argnums=(0, 1))
         def step_masked(payload, aux, fmask, lr, k):
             with phase("grad"):
-                g, h = _all_grads(payload)
-                payload = _write_sampled(payload, g, h, k,
-                                         payload[:, gweight_col],
-                                         payload[:, cnt_col])
+                # (the count mask is the prelude's: it rides the partition)
+                g, h, gw = _all_grads(payload, gweight_col)
+                payload = _write_sampled(payload, g, h, k, gw)
             return _grow_and_score(payload, aux, fmask, lr, k)
 
         bmap_fs = gbdt.bundle_map

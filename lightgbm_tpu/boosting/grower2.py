@@ -499,10 +499,13 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
                     jnp.int32(0), hist_view(hist_root), root_g, root_h,
                     root_c, res0)
 
-        # rows start as one root segment with the root Newton step as the
-        # per-row output (covers the unsplittable-stump case)
-        root_out = out_fn(root_g, root_h)
-        payload = seg.payload_col_write(payload, cols.value, root_out)
+        # rows start as one root segment.  The value column is NOT set to
+        # the root's Newton step: every reader of it is behind
+        # `num_leaves > 1` (a stump moves no score, gbdt.py), and a tree
+        # that splits writes its children's values over every row of the
+        # root segment in its first partition.  The write was a select
+        # over the whole payload, 16 ms a tree at 10.5M rows x 128 lanes
+        # (PERF.md section 6, PR 32).
 
         ni = max(L - 1, 1)
         state = {

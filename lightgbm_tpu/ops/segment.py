@@ -107,9 +107,17 @@ def payload_col_write(payload: jax.Array, col, vec, op: str = "set"):
     [N, 1] update operand re-tiled to the payload's T(8, 128) layout — a
     128x padding expansion.  At 10.5M rows that is 2 x 5 GB of HLO temp,
     which OOMs the 16 GB v5e (measured from the compiler's HBM breakdown,
-    round 4).  The masked select instead fuses into ONE in-place
-    elementwise pass over the donated buffer; consecutive writes fuse
-    together.  `col` may be a traced scalar; `vec` a [N] vector or scalar.
+    round 4).  The masked select instead is ONE in-place elementwise pass
+    over the donated buffer, over all P lanes: 16.3 ms at 10.5M rows x 128
+    lanes, 10.2 ms at 409,864 x 2,048, to change one lane.  Consecutive
+    writes of `[N]` vectors made by one fusion do share a pass (gradient
+    and hessian did); a write after another kind of operation, a scalar's
+    broadcast or an add does not, and each column sliced out for them is a
+    pass of its own (PERF.md section 6, PR 32: the chip's trace).  The
+    fused step's updates go through `ops.state_columns`, which touches
+    only the lane tile that holds the column; this stays for the edits no
+    cell runs and as that module's form off the TPU.
+    `col` may be a traced scalar; `vec` a [N] vector or scalar.
     """
     mask = lax.broadcasted_iota(jnp.int32, (1, payload.shape[1]), 1) == col
     v = vec if jnp.ndim(vec) == 0 else vec[:, None]

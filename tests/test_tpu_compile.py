@@ -16,16 +16,16 @@ import pytest
 
 from lightgbm_tpu.ops import pallas_segment as pseg
 from lightgbm_tpu.ops import segment as seg
+from lightgbm_tpu.ops import state_columns as scols
 
 ROWS, LANES, FEATURES, BINS = 10_502_408, 128, 28, 256
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     import os
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
         topo = topologies.get_topology_desc(platform="tpu",
@@ -37,9 +37,15 @@ def one_chip():
     cache_was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", cache_was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
 
 
 #: the Epsilon cell: 400,000 rows padded to whole chunks
@@ -115,3 +121,73 @@ def test_histogram_compiles_for_v5e_narrow(one_chip, features, bins):
         grad_col=features, hess_col=features + 1, cnt_col=features + 2,
         interpret=False)
     assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("rows,lanes,first", [
+    (ROWS + seg.GUARD, LANES, FEATURES),
+    (WIDE_ROWS + seg.GUARD, WIDE_LANES, WIDE_FEATURES),
+    (2_277_376 + seg.GUARD, 256, 125)])
+def test_state_column_kernels_compile_for_v5e(one_chip, rows, lanes, first):
+    """The three state-column kernels at the Higgs and Epsilon cells'
+    shapes (one lane tile of the payload's, the last block of rows
+    ragged, two- and three-row blocks of the compact vectors) and, at the
+    MS LTR cell's rows, with the columns across a tile edge: the
+    transposes, the single-sublane stores and the lane broadcast are
+    Mosaic's to accept."""
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    label, weight, cnt, idx, score, grad, hess, value = range(first,
+                                                              first + 8)
+    payload = shape((rows, lanes))
+    assert scols._tiles((label, value))[1] == (2 if first == 125 else 1)
+    for lowered in (
+            scols._state_cols_read.lower(
+                payload, cols=(score, label, weight, cnt, idx)),
+            scols._state_cols_write.lower(payload, shape((2, rows)),
+                                          cols=(grad, hess)),
+            scols._state_cols_write.lower(payload, shape((3, rows)),
+                                          cols=(grad, hess, cnt)),
+            scols._state_cols_axpy.lower(
+                payload, shape((), jnp.int32), shape(()),
+                shape((), jnp.bool_), src=value, dst_range=(score, score))):
+        assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+def test_state_column_kernels_compile_on_four_v5e(topo):
+    """As `_FastState` runs them on a mesh (`criteo-dp4-train`: 10M rows
+    and a GUARD tail a chip, the wide index layout's columns): each
+    device's block of rows takes the kernel on its own under `shard_map`,
+    and nothing is gathered to do it."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    by_rows, by_lanes = PS("data", None), PS(None, "data")
+    rows = 4 * (10_000_000 + seg.GUARD)
+
+    def shape(dims, spec, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    def on_blocks(fn, in_specs, out_specs):
+        return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                     out_specs=out_specs, check_vma=False),
+                       donate_argnums=(0,))
+
+    payload = shape((rows, LANES), by_rows)
+    for lowered in (
+            on_blocks(lambda p: scols.read_cols(p, (71, 67, 68, 69),
+                                                "pallas"),
+                      (by_rows,), by_lanes).lower(payload),
+            on_blocks(lambda p, v: scols.write_cols(p, (72, 73), v,
+                                                    "pallas"),
+                      (by_rows, by_lanes), by_rows).lower(
+                          payload, shape((2, rows), by_lanes)),
+            on_blocks(lambda p, d, s, o: scols.add_scaled(
+                p, d, (71, 71), 74, s, o, "pallas"),
+                      (by_rows, PS(), PS(), PS()), by_rows).lower(
+                          payload, shape((), PS(), jnp.int32),
+                          shape((), PS()), shape((), PS(), jnp.bool_))):
+        text = lowered.compile().as_text()
+        assert "tpu_custom_call" in text
+        assert "all-gather" not in text and "all-to-all" not in text
