@@ -516,12 +516,18 @@ int LGBMT_ParseDense(const char* path, char sep, int has_header,
   return bad_token ? -5 : 0;
 }
 
-// Numerical ValueToBin (bin.h:452-488 semantics, matching
-// BinMapper.values_to_bins): for each feature f with upper bounds
-// bounds[offs[f] : offs[f]+cnts[f]]:
+// ValueToBin (bin.h:452-488 semantics, matching
+// BinMapper.values_to_bins).  A numerical feature f (cat_len[f] < 0) has
+// upper bounds bounds[offs[f] : offs[f]+cnts[f]]:
 //   missing_type == 2 (NaN): NaN -> num_bin-1; values searchsorted-left
 //     over bounds[:cnt-2] (when num_bin >= 2)
 //   else: NaN treated as 0.0; searchsorted-left over bounds[:cnt-1]
+// A categorical feature f (cat_len[f] >= 0) has a dense table
+// cat_table[cat_offs[f] : cat_offs[f]+cat_len[f]] from category value to
+// bin, -1 where the value has none:
+//   the value truncated toward zero; negative, past the table or with no
+//     bin -> num_bin-1 (the last bin)
+//   NaN -> num_bin-1 when missing_type == 2, else the bin of category 0
 // X is row-major [n, F]; out is FEATURE-major uint8 [F, n_stride] (the
 // dataset's storage layout).  Features with trivial[f] != 0 are skipped.
 // rc 0 ok, -3 if any num_bin > 256 (caller must use the Python path).
@@ -529,6 +535,8 @@ int LGBMT_EncodeBins(const double* X, long long n, int F,
                      const double* bounds, const long long* offs,
                      const int* cnts, const int* missing_type,
                      const int* num_bin, const int* trivial,
+                     const int* cat_len, const long long* cat_offs,
+                     const int* cat_table,
                      unsigned char* out, long long n_stride) {
   for (int f = 0; f < F; ++f)
     if (!trivial[f] && num_bin[f] > 256) return -3;
@@ -537,18 +545,28 @@ int LGBMT_EncodeBins(const double* X, long long n, int F,
     const double* xrow = X + i * F;
     for (int f = 0; f < F; ++f) {
       if (trivial[f]) continue;
-      const double* b = bounds + offs[f];
-      const int cnt = cnts[f];
       const bool nan_mode = missing_type[f] == 2;
-      int hi = nan_mode ? (num_bin[f] >= 2 ? cnt - 2 : 0) : cnt - 1;
-      if (hi < 0) hi = 0;
       double v = xrow[f];
       int idx;
-      if (std::isnan(v)) {
-        idx = nan_mode ? num_bin[f] - 1
-                       : static_cast<int>(std::lower_bound(b, b + hi, 0.0) - b);
+      if (cat_len[f] >= 0) {
+        const int last = num_bin[f] > 0 ? num_bin[f] - 1 : 0;
+        const int* table = cat_table + cat_offs[f];
+        if (std::isnan(v)) v = nan_mode ? -1.0 : 0.0;
+        idx = -1;
+        if (v > -1.0 && v < static_cast<double>(cat_len[f]))
+          idx = table[static_cast<long long>(v)];
+        if (idx < 0) idx = last;
       } else {
-        idx = static_cast<int>(std::lower_bound(b, b + hi, v) - b);
+        const double* b = bounds + offs[f];
+        const int cnt = cnts[f];
+        int hi = nan_mode ? (num_bin[f] >= 2 ? cnt - 2 : 0) : cnt - 1;
+        if (hi < 0) hi = 0;
+        if (std::isnan(v)) {
+          idx = nan_mode ? num_bin[f] - 1
+                         : static_cast<int>(std::lower_bound(b, b + hi, 0.0) - b);
+        } else {
+          idx = static_cast<int>(std::lower_bound(b, b + hi, v) - b);
+        }
       }
       out[static_cast<long long>(f) * n_stride + i] =
           static_cast<unsigned char>(idx);

@@ -761,6 +761,11 @@ class _FastState:
         #: lazily by window_program(); survive sync-backs like the other
         #: jitted closures
         self._window_cache: Dict = {}
+        #: what the trees grown on this state split on, a tree an entry in
+        #: the order they were finished: read off the tree's own fetch by
+        #: `_finish_tree_host`, so it costs no dispatch and no transfer
+        self.counters: Dict[str, List[int]] = {"splits": [],
+                                               "categorical_splits": []}
 
     def window_program(self, J: int, with_bag: bool):
         """One jitted, donated device program for a whole boosting window:
@@ -2355,6 +2360,10 @@ class GBDT:
         self.split_rounds_total += int(host.get("split_rounds",
                                                 max(nl - 1, 0)))
         self.trees_finished += 1
+        if self._fast is not None:
+            self._fast.counters["splits"].append(nl - 1)
+            self._fast.counters["categorical_splits"].append(
+                int(host["split_is_cat"][:nl - 1].sum()))
         L = self.grower_cfg.num_leaves
         tree = Tree(max(L, 2))
         tree.num_leaves = nl

@@ -48,10 +48,11 @@ from .grower import GrowerConfig, make_winner_sync
 #: `grad_pairs` and `grad_permute` are entered inside `grad` by an
 #: objective that couples rows (objective/rank.py): the per-query
 #: pairwise program, and every move between partition order, original
-#: order and query slots
+#: order and query slots.  `cat_search` is entered inside `split_search`
+#: by ops/split.py (`CAT_SEARCH_SCOPE`) round the categorical search
 PHASES = ("grad", "root_hist", "partition", "hist", "subtract",
           "split_search", "tree_update", "score", "allreduce",
-          "grad_pairs", "grad_permute")
+          "grad_pairs", "grad_permute", "cat_search")
 
 
 def phase(name: str):

@@ -291,16 +291,23 @@ class BinMapper:
         if self.bin_type == BIN_TYPE_CATEGORICAL:
             # negative / unseen -> last bin; NaN -> last bin when
             # missing_type is NaN, else treated as category 0
-            # (bin.h ValueToBin:452-487)
+            # (bin.h ValueToBin:452-487); the value truncated toward zero
             last = max(self.num_bin - 1, 0)
-            out = np.full(len(values), last, dtype=np.int32)
-            for i, v in enumerate(values):
-                if np.isnan(v):
-                    if self.missing_type != MISSING_NAN:
-                        out[i] = self.categorical_2_bin.get(0, last)
-                elif int(v) >= 0:
-                    out[i] = self.categorical_2_bin.get(int(v), last)
-            return out
+            cats = np.array(sorted(c for c in self.categorical_2_bin
+                                   if c >= 0), dtype=np.int64)
+            if not len(cats):
+                return np.full(len(values), last, dtype=np.int32)
+            bins_of = np.array([self.categorical_2_bin[int(c)] for c in cats],
+                               dtype=np.int32)
+            v = np.where(np.isnan(values),
+                         -1.0 if self.missing_type == MISSING_NAN else 0.0,
+                         values)
+            # past the largest category on either side there is no bin
+            iv = np.trunc(np.clip(v, -1.0, float(cats[-1]) + 1.0)
+                          ).astype(np.int64)
+            pos = np.minimum(np.searchsorted(cats, iv), len(cats) - 1)
+            return np.where(cats[pos] == iv, bins_of[pos],
+                            last).astype(np.int32)
         nan_mask = np.isnan(values)
         if self.missing_type == MISSING_NAN:
             # non-NaN values bin over bounds[:-2] (last numeric bin), NaN → last bin
@@ -312,6 +319,20 @@ class BinMapper:
             vals = np.where(nan_mask, 0.0, values)  # NaN treated as zero
             idx = np.searchsorted(self.bin_upper_bound[:-1], vals, side="left")
         return idx.astype(np.int32)
+
+    def categorical_table(self, max_len: int) -> Optional[np.ndarray]:
+        """Dense int32 table from category value to bin for the native
+        encoder (cpp/ingest.cc LGBMT_EncodeBins), -1 where a value has no
+        bin of its own; None where the largest category value is past
+        `max_len`."""
+        cats = [c for c in self.categorical_2_bin if c >= 0]
+        size = max(cats, default=-1) + 1
+        if size > max_len:
+            return None
+        table = np.full(max(size, 1), -1, dtype=np.int32)
+        for c in cats:
+            table[c] = self.categorical_2_bin[c]
+        return table
 
     def bin_to_value(self, bin_idx: int) -> float:
         """Representative threshold for saving models (upper bound of the bin)."""

@@ -122,20 +122,31 @@ class BinnedDataset:
         n_pad = _round_up(n, row_chunk) if n > row_chunk else _round_up(max(n, 1), 128)
         dtype = np.uint8 if ds.max_num_bin <= 256 else np.uint16
         bins = np.zeros((f, n_pad), dtype=dtype)
-        # native OpenMP ValueToBin over the whole matrix (cpp/ingest.cc)
-        # when every non-trivial feature is numerical; otherwise (or with
-        # no native library) the per-feature Python path
+        # native OpenMP ValueToBin over the whole matrix (cpp/ingest.cc),
+        # numerical and categorical columns alike; with no native library,
+        # past 256 bins or for category values too large for a dense table,
+        # the per-feature Python path.  Either way the categorical columns
+        # are coded under `dataset/encode_categorical`, inside the encode
         from .native import encode_bins
         t0 = time.perf_counter()
         with tracing.span("dataset/encode", path="native"):
             native = encode_bins(X, bin_mappers, bins)
         if not native:
             with tracing.span("dataset/encode", path="python"):
-                for j, mapper in enumerate(bin_mappers):
-                    if mapper.is_trivial:
-                        continue
-                    bins[j, :n] = mapper.values_to_bins(
-                        X[:, j].astype(np.float64))
+                def code(columns):
+                    for j in columns:
+                        bins[j, :n] = bin_mappers[j].values_to_bins(
+                            X[:, j].astype(np.float64))
+
+                live = [j for j, m in enumerate(bin_mappers)
+                        if not m.is_trivial]
+                cats = [j for j in live
+                        if bin_mappers[j].bin_type == BIN_TYPE_CATEGORICAL]
+                code(j for j in live if j not in cats)
+                if cats:
+                    with tracing.span("dataset/encode_categorical",
+                                      columns=len(cats), path="python"):
+                        code(cats)
         ds.binning = {"path": "native" if native else "python",
                       "seconds": round(time.perf_counter() - t0, 3)}
         Log.info("binned %d x %d values through the %s path in %.2fs",

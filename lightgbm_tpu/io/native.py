@@ -10,6 +10,7 @@ minutes.
 """
 from __future__ import annotations
 
+import contextlib
 import ctypes
 from typing import List, Optional, Tuple
 
@@ -45,6 +46,9 @@ def _load():
                 ctypes.POINTER(ctypes.c_longlong),
                 ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_int),
                 ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_int),
+                ctypes.POINTER(ctypes.c_int),
+                ctypes.POINTER(ctypes.c_longlong),
+                ctypes.POINTER(ctypes.c_int),
                 ctypes.POINTER(ctypes.c_ubyte), ctypes.c_longlong]
             lib.LGBMT_FindBinsNumerical.restype = ctypes.c_int
             lib.LGBMT_FindBinsNumerical.argtypes = (
@@ -64,6 +68,11 @@ def _load():
                         "parsing and binning take the Python paths",
                         type(e).__name__, e)
     return _lib
+
+
+def ptr(a: np.ndarray, ctype):
+    """`a`'s buffer as a ctypes pointer to `ctype`."""
+    return a.ctypes.data_as(ctypes.POINTER(ctype))
 
 
 def parse_dense(path: str, sep: str, label_column: int, has_header: bool,
@@ -94,14 +103,25 @@ def parse_dense(path: str, sep: str, label_column: int, has_header: bool,
         return None
 
 
+#: the longest dense category-value -> bin table `encode_bins` builds for
+#: one column (int32 entries); a column whose largest category value is
+#: past it (hashed ids) keeps the Python lookup
+CAT_TABLE_MAX = 1 << 22
+
+
 def encode_bins(X: np.ndarray, mappers: List,
                 bins_out: np.ndarray) -> bool:
     """Native ValueToBin over the whole matrix into the feature-major
-    uint8 storage (bins_out [F, n_stride]).  Handles numerical features
-    only — returns False (caller keeps the Python path) when any
-    non-trivial feature is categorical, >256 bins, or the library is
-    missing.  Trivial features are skipped (their storage stays zeros),
-    matching the Python loop."""
+    uint8 storage (bins_out [F, n_stride]): numerical columns by their
+    upper bounds, categorical columns by a dense table from category value
+    to bin (`BinMapper.categorical_table`).  Returns False (caller keeps
+    the Python path) when a non-trivial feature has >256 bins or a
+    category value past `CAT_TABLE_MAX`, or the library is missing.
+    Trivial features are skipped (their storage stays zeros), matching
+    the Python loop.  Where there are categorical columns they are coded
+    in a pass of their own, under the span `dataset/encode_categorical`,
+    so that a trace tells their cost from the numerical columns'."""
+    from ..runtime import tracing
     from .binning import BIN_TYPE_CATEGORICAL
     lib = _load()
     if lib is None or bins_out.dtype != np.uint8:
@@ -114,23 +134,43 @@ def encode_bins(X: np.ndarray, mappers: List,
     miss = np.zeros(F, dtype=np.int32)
     nbin = np.zeros(F, dtype=np.int32)
     triv = np.zeros(F, dtype=np.int32)
-    chunks = []
-    off = 0
+    cat_len = np.full(F, -1, dtype=np.int32)
+    cat_offs = np.zeros(F, dtype=np.int64)
+    chunks, tables = [], []
+    off = cat_off = 0
     for f, m in enumerate(mappers):
         if m.is_trivial:
             triv[f] = 1
             continue
-        if m.bin_type == BIN_TYPE_CATEGORICAL or m.num_bin > 256:
+        if m.num_bin > 256:
             return False
+        miss[f] = int(m.missing_type)
+        nbin[f] = int(m.num_bin)
+        if m.bin_type == BIN_TYPE_CATEGORICAL:
+            table = m.categorical_table(CAT_TABLE_MAX)
+            if table is None:
+                return False
+            cat_offs[f] = cat_off
+            cat_len[f] = len(table)
+            tables.append(table)
+            cat_off += len(table)
+            continue
         b = np.asarray(m.bin_upper_bound, dtype=np.float64)
         offs[f] = off
         cnts[f] = len(b)
-        miss[f] = int(m.missing_type)
-        nbin[f] = int(m.num_bin)
         chunks.append(b)
         off += len(b)
     bounds = (np.concatenate(chunks) if chunks
               else np.zeros(1, dtype=np.float64))
+    cat_table = (np.concatenate(tables) if tables
+                 else np.zeros(1, dtype=np.int32))
+    is_cat = (cat_len >= 0) & (triv == 0)
+    # the passes: (columns left out, span or None)
+    if is_cat.any() and (~is_cat & (triv == 0)).any():
+        passes = [(triv | is_cat, None), (triv | ~is_cat, int(is_cat.sum()))]
+    else:
+        passes = [(triv, int(is_cat.sum()) or None)]
+
     # chunk the f64 conversion: a whole-matrix ascontiguousarray of a
     # float32 Higgs-scale X would be a multi-GB transient
     already = (X.dtype == np.float64 and X.flags.c_contiguous)
@@ -138,20 +178,24 @@ def encode_bins(X: np.ndarray, mappers: List,
     for b0 in range(0, n, block):
         b1 = min(b0 + block, n)
         Xc = np.ascontiguousarray(X[b0:b1], dtype=np.float64)
-        rc = lib.LGBMT_EncodeBins(
-            Xc.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
-            ctypes.c_longlong(b1 - b0), F,
-            bounds.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
-            offs.ctypes.data_as(ctypes.POINTER(ctypes.c_longlong)),
-            cnts.ctypes.data_as(ctypes.POINTER(ctypes.c_int)),
-            miss.ctypes.data_as(ctypes.POINTER(ctypes.c_int)),
-            nbin.ctypes.data_as(ctypes.POINTER(ctypes.c_int)),
-            triv.ctypes.data_as(ctypes.POINTER(ctypes.c_int)),
-            bins_out[:, b0:].ctypes.data_as(
-                ctypes.POINTER(ctypes.c_ubyte)),
-            ctypes.c_longlong(bins_out.shape[1]))
-        if rc != 0:
-            return False
+        for skip, n_cat in passes:
+            skip = np.ascontiguousarray(skip, dtype=np.int32)
+            span = (tracing.span("dataset/encode_categorical",
+                                 columns=n_cat, path="native")
+                    if n_cat else contextlib.nullcontext())
+            with span:
+                rc = lib.LGBMT_EncodeBins(
+                    ptr(Xc, ctypes.c_double), ctypes.c_longlong(b1 - b0), F,
+                    ptr(bounds, ctypes.c_double), ptr(offs, ctypes.c_longlong),
+                    ptr(cnts, ctypes.c_int), ptr(miss, ctypes.c_int),
+                    ptr(nbin, ctypes.c_int), ptr(skip, ctypes.c_int),
+                    ptr(cat_len, ctypes.c_int),
+                    ptr(cat_offs, ctypes.c_longlong),
+                    ptr(cat_table, ctypes.c_int),
+                    ptr(bins_out[:, b0:], ctypes.c_ubyte),
+                    ctypes.c_longlong(bins_out.shape[1]))
+            if rc != 0:
+                return False
     return True
 
 
@@ -184,9 +228,6 @@ def find_bins(X: np.ndarray, sample_idx, skip: np.ndarray, max_bin: int,
     bounds = np.empty((F, cap), dtype=np.float64)
     ints = np.zeros((4, F), dtype=np.int32)     # num_bin, missing, default, status
     dbls = np.zeros((3, F), dtype=np.float64)   # min, max, sparse rate
-
-    def ptr(a, ctype):
-        return a.ctypes.data_as(ctypes.POINTER(ctype))
 
     rc = lib.LGBMT_FindBinsNumerical(
         ctypes.c_void_p(X.ctypes.data), int(X.dtype == np.float32),

@@ -37,6 +37,11 @@ MISSING_NONE = 0
 MISSING_ZERO = 1
 MISSING_NAN = 2
 
+#: the categorical search's operations carry this scope INSIDE the
+#: grower's `lgbm.split_search` (grower2.PHASES); an operation's phase is
+#: its innermost scope, so a trace reads the two apart
+CAT_SEARCH_SCOPE = "lgbm.cat_search"
+
 
 class FeatureMeta(NamedTuple):
     """Static per-feature arrays mirrored from the BinMappers
@@ -245,6 +250,7 @@ def per_feature_best_gains(hist, sum_g, sum_h, num_data, feature_mask, *,
     return best
 
 
+@jax.named_scope(CAT_SEARCH_SCOPE)
 def _categorical_best(g, h, c, sum_g, sum_h, num_data, cat_mask, *, meta,
                       l1, l2, max_delta_step, min_data_in_leaf,
                       min_sum_hessian_in_leaf, max_cat_threshold, cat_l2,
@@ -254,9 +260,15 @@ def _categorical_best(g, h, c, sum_g, sum_h, num_data, cat_mask, *, meta,
 
     One-hot mode (num_bin <= max_cat_to_onehot) scans single-bin lefts as one
     [F, B] vector op.  Sorted-subset mode sorts bins by sum_g/(sum_h +
-    cat_smooth) and scans bounded prefixes from both ends; the reference's
+    cat_smooth) and walks bounded prefixes from both ends.  The reference's
     sequential walk (min_data_per_group grouping, break-on-starved-right)
-    becomes a batched `lax.scan` with [F] carries.
+    visits at most `max_cat_threshold` sorted positions from either end
+    whatever the bin count, so it is K = min(max_cat_threshold, B) unrolled
+    steps on [2, F] vectors, both directions side by side: the histogram
+    rides ONE stable sort as its payload, the walk from the top reads its K
+    positions by a one-hot sum, and the winner's bins come back from the
+    K positions it walked.  Nothing here loops, gathers or sorts a second
+    time over the B bins.
 
     Returns per-feature (raw_gain [F], bitset [F, B], left_g, left_h(+eps),
     left_c, used_sorted [F] bool).
@@ -285,80 +297,76 @@ def _categorical_best(g, h, c, sum_g, sum_h, num_data, cat_mask, *, meta,
     best_oh = jnp.take_along_axis(gain_oh, t_oh[:, None], 1)[:, 0]
 
     # ---- sorted subset ----------------------------------------------------
+    K = min(int(max_cat_threshold), B)
     keep = valid_t & (c >= cat_smooth)
     ctr = jnp.where(keep, g / (h + cat_smooth), jnp.inf)
-    order = jnp.argsort(ctr, axis=1).astype(jnp.int32)            # [F, B]
+    # one stable sort by ctr; the bin ids and the histogram ride along
+    _, order, gs, hs, cs = jax.lax.sort(
+        (ctr, jnp.broadcast_to(bins, (F, B)), g, h, c), dimension=1,
+        is_stable=True, num_keys=1)
     used = jnp.sum(keep, axis=1).astype(jnp.int32)                # [F]
     max_cat = jnp.minimum(max_cat_threshold, (used + 1) // 2)     # [F]
     l2s = l2 + cat_l2
-    gs = jnp.take_along_axis(g, order, 1)
-    hs = jnp.take_along_axis(h, order, 1)
-    cs = jnp.take_along_axis(c, order, 1)
-    slot_valid = bins < used[:, None]
-    gs = jnp.where(slot_valid, gs, 0.0)
-    hs = jnp.where(slot_valid, hs, 0.0)
-    cs = jnp.where(slot_valid, cs, 0.0)
+    steps = jnp.arange(K, dtype=jnp.int32)
+    # direction -1 walks the sorted bins from the top: step i reads sorted
+    # position used-1-i.  A one-hot sum over B reads the K of them (exact:
+    # every other term is a zero); steps past `used` read nothing the walk
+    # adds (`stepping` below)
+    top = (used[:, None] - 1 - steps[None, :])[:, :, None] == bins[None]
 
-    def scan_dir(flip: bool):
-        if flip:
-            # direction -1 walks sorted bins from the top (position used-1-i)
-            pos = used[:, None] - 1 - bins
-            posc = jnp.clip(pos, 0, B - 1)
-            gd = jnp.take_along_axis(gs, posc, 1)
-            hd = jnp.take_along_axis(hs, posc, 1)
-            cd = jnp.take_along_axis(cs, posc, 1)
-        else:
-            gd, hd, cd = gs, hs, cs
+    def from_top(a):                                              # -> [F, K]
+        return jnp.sum(jnp.where(top, a[:, None, :], jnp.zeros((), a.dtype)),
+                       axis=2)
 
-        def step(carry, xs):
-            lg, lh, lc, grp, stopped, bg, bi, blg, blh, blc = carry
-            gi, hi, ci, i = xs
-            stepping = (i < used) & (i < max_cat)
-            lg = jnp.where(stepping, lg + gi, lg)
-            lh = jnp.where(stepping, lh + hi, lh)
-            lc = jnp.where(stepping, lc + ci, lc)
-            grp = jnp.where(stepping, grp + ci, grp)
-            cont1 = (lc < min_data_in_leaf) | (lh < min_sum_hessian_in_leaf)
-            rc = num_data - lc
-            rh = sum_h - lh
-            brk = (rc < min_data_in_leaf) | (rc < min_data_per_group) | \
-                  (rh < min_sum_hessian_in_leaf)
-            # break only evaluated when the left side qualifies (reference
-            # `continue`s before the break checks, :205-212)
-            stopped_new = stopped | (stepping & ~cont1 & brk)
-            candidate = stepping & ~stopped & ~cont1 & ~brk & \
-                (grp >= min_data_per_group)
-            grp = jnp.where(candidate, 0.0, grp)
-            gain_i = pair_gain(lg, lh, sum_g - lg, rh, l2s)
-            take = candidate & (gain_i > bg)
-            bg = jnp.where(take, gain_i, bg)
-            bi = jnp.where(take, i, bi)
-            blg = jnp.where(take, lg, blg)
-            blh = jnp.where(take, lh, blh)
-            blc = jnp.where(take, lc, blc)
-            return (lg, lh, lc, grp, stopped_new, bg, bi, blg, blh, blc), None
+    # [K, 2, F]: a step's operands are one leading slice, +1 before -1
+    gd, hd, cd = (jnp.stack([a[:, :K], from_top(a)]).transpose(2, 0, 1)
+                  for a in (gs, hs, cs))
+    order_d = jnp.stack([order[:, :K], from_top(order)])          # [2, F, K]
 
-        zero = jnp.zeros(F, jnp.float32)
-        carry0 = (zero, jnp.full(F, eps, jnp.float32), zero, zero,
-                  jnp.zeros(F, bool), jnp.full(F, K_MIN_SCORE, jnp.float32),
-                  jnp.full(F, -1, jnp.int32), zero, zero, zero)
-        xs = (gd.T, hd.T, cd.T, jnp.arange(B, dtype=jnp.int32))
-        carry, _ = jax.lax.scan(step, carry0, xs)
-        _, _, _, _, _, bg, bi, blg, blh, blc = carry
-        return bg, bi, blg, blh, blc
+    lim = jnp.minimum(used, max_cat)[None, :]                     # [1, F]
+    zero = jnp.zeros((2, F), jnp.float32)
+    lg, lh, lc, grp = zero, jnp.full((2, F), eps, jnp.float32), zero, zero
+    stopped = jnp.zeros((2, F), bool)
+    bg = jnp.full((2, F), K_MIN_SCORE, jnp.float32)
+    bi = jnp.full((2, F), -1, jnp.int32)
+    blg, blh, blc = zero, zero, zero
+    for i in range(K):
+        stepping = i < lim
+        lg = jnp.where(stepping, lg + gd[i], lg)
+        lh = jnp.where(stepping, lh + hd[i], lh)
+        lc = jnp.where(stepping, lc + cd[i], lc)
+        grp = jnp.where(stepping, grp + cd[i], grp)
+        cont1 = (lc < min_data_in_leaf) | (lh < min_sum_hessian_in_leaf)
+        rc = num_data - lc
+        rh = sum_h - lh
+        brk = (rc < min_data_in_leaf) | (rc < min_data_per_group) | \
+              (rh < min_sum_hessian_in_leaf)
+        # break only evaluated when the left side qualifies (reference
+        # `continue`s before the break checks, :205-212)
+        candidate = stepping & ~stopped & ~cont1 & ~brk & \
+            (grp >= min_data_per_group)
+        stopped = stopped | (stepping & ~cont1 & brk)
+        grp = jnp.where(candidate, 0.0, grp)
+        gain_i = pair_gain(lg, lh, sum_g - lg, rh, l2s)
+        take = candidate & (gain_i > bg)
+        bg = jnp.where(take, gain_i, bg)
+        bi = jnp.where(take, i, bi)
+        blg = jnp.where(take, lg, blg)
+        blh = jnp.where(take, lh, blh)
+        blc = jnp.where(take, lc, blc)
 
-    bg1, bi1, blg1, blh1, blc1 = scan_dir(False)
-    bg2, bi2, blg2, blh2, blc2 = scan_dir(True)
-    use2 = bg2 > bg1
-    bg_s = jnp.where(use2, bg2, bg1)
-    bi_s = jnp.where(use2, bi2, bi1)
-    blg_s = jnp.where(use2, blg2, blg1)
-    blh_s = jnp.where(use2, blh2, blh1)
-    blc_s = jnp.where(use2, blc2, blc1)
-    # bitset: first bi+1 sorted bins (dir +1) or last bi+1 (dir -1) go left
-    rank = jnp.argsort(order, axis=1)                             # position of bin b
-    rank_dir = jnp.where(use2[:, None], used[:, None] - 1 - rank, rank)
-    bitset_s = keep & (rank_dir <= bi_s[:, None]) & (rank_dir >= 0)
+    use2 = bg[1] > bg[0]
+
+    def pick(a):
+        return jnp.where(use2, a[1], a[0])
+
+    bg_s, bi_s, blg_s, blh_s, blc_s = map(pick, (bg, bi, blg, blh, blc))
+    # bitset: the bins at the first bi+1 steps of the winning direction
+    walked = jnp.where(use2[:, None], order_d[1], order_d[0])     # [F, K]
+    left_step = (steps[None, :] <= bi_s[:, None]) & \
+        (steps[None, :] < used[:, None])
+    bitset_s = jnp.any(left_step[:, :, None]
+                       & (walked[:, :, None] == bins[None]), axis=1)
 
     # ---- choose one-hot vs sorted per feature ----------------------------
     use_onehot = (meta.num_bin <= max_cat_to_onehot)

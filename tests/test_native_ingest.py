@@ -106,14 +106,126 @@ def test_encode_bins_matches_python():
 
 
 @needs_native
-def test_encode_bins_declines_categorical():
+def test_encode_bins_declines_a_category_value_past_its_table(monkeypatch):
+    """Categorical columns go through the native encoder by a dense table
+    from category value to bin; only a value too large for such a table
+    (a hashed id) sends the matrix back to the Python path."""
     X = np.abs(np.random.default_rng(2).integers(0, 5, (256, 2))).astype(float)
     from lightgbm_tpu.config import Config
     ds = BinnedDataset.from_matrix(X, Config({"max_bin": 15}),
                                    categorical_feature=[0])
+    assert ds.binning["path"] == "native"
     mappers = ds.bin_mappers
     bins_out = np.zeros((2, 256), np.uint8)
+    assert native.encode_bins(X, mappers, bins_out) is True
+    np.testing.assert_array_equal(bins_out, np.asarray(ds.bins)[:, :256])
+    monkeypatch.setattr(native, "CAT_TABLE_MAX", 3)
     assert native.encode_bins(X, mappers, bins_out) is False
+
+
+def _mixed_matrix(n=6000, seed=4):
+    """Four categorical columns (one with NaN, negative, fractional,
+    never-sampled and huge values; one whose rare tail folds into the
+    last bin; one with category 0 the most frequent; one constant) among
+    numerical ones with NaN."""
+    rng = np.random.default_rng(seed)
+    X = rng.standard_normal((n, 7))
+    X[rng.random((n, 7)) < 0.05] = np.nan
+    X[:, 1] = rng.integers(0, 9, n)
+    X[rng.random(n) < 0.1, 1] = np.nan
+    X[:40, 1] = [-3.0, -0.5, 2.7, 8.2] * 10
+    heavy = 1.0 / np.arange(1, 400) ** 2.5
+    X[:, 3] = rng.choice(399, n, p=heavy / heavy.sum()) * 3 + 1
+    X[:, 4] = np.where(rng.random(n) < 0.7, 0, rng.integers(1, 6, n))
+    X[:, 6] = 5.0
+    return X, [1, 3, 4, 6]
+
+
+def _dirtied(X, cats):
+    """Rows the mappers never saw: values with no bin, past any table,
+    infinite, and NaN in every categorical column."""
+    D = X[:64].copy()
+    for j in cats:
+        D[:9, j] = [1e12, -1e12, np.inf, -np.inf, 400.0, 1e6 + 0.5, -1.0,
+                    np.nan, 2 ** 40]
+    return D
+
+
+@pytest.mark.parametrize("dtype", [np.float64, np.float32])
+@pytest.mark.parametrize("max_bin", [255, 16])
+def test_categorical_columns_encode_natively_as_the_python_lookup(
+        dtype, max_bin):
+    """A matrix of mixed column types goes native whole, and each
+    categorical column's bins are the mapper's own Python lookup's: NaN
+    by the missing type, negative, unseen, fractional and out-of-table
+    values included."""
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.io.binning import BIN_TYPE_CATEGORICAL, MISSING_NAN
+    from lightgbm_tpu.runtime import tracing
+    X, cats = _mixed_matrix()
+    X = X.astype(dtype)
+    tracing.reset()
+    ds = BinnedDataset.from_matrix(X, Config({"max_bin": max_bin}),
+                                   categorical_feature=cats)
+    if native._load() is not None:
+        assert ds.binning["path"] == "native"
+    spans = [e for e in tracing.export_chrome()["traceEvents"]
+             if e["ph"] == "X" and e["name"] == "dataset/encode_categorical"]
+    assert spans and all(e["args"]["columns"] == 3 for e in spans)
+    got = np.asarray(ds.bins)
+    kinds = set()
+    for j, m in enumerate(ds.bin_mappers):
+        if m.is_trivial:
+            assert j == 6 and not got[j].any()
+            continue
+        assert (m.bin_type == BIN_TYPE_CATEGORICAL) == (j in cats)
+        np.testing.assert_array_equal(
+            got[j, :len(X)], m.values_to_bins(X[:, j].astype(np.float64)))
+        kinds.add((j in cats, m.missing_type == MISSING_NAN))
+    assert {(True, True), (True, False)} <= kinds
+    # as a validation set: the training mappers over rows they never saw
+    D = _dirtied(X, cats)
+    vs = BinnedDataset.from_matrix(D, Config({"max_bin": max_bin}),
+                                   bin_mappers=ds.bin_mappers,
+                                   categorical_feature=cats)
+    assert vs.binning["path"] == ds.binning["path"]
+    for j, m in enumerate(ds.bin_mappers):
+        if not m.is_trivial:
+            np.testing.assert_array_equal(
+                np.asarray(vs.bins)[j, :len(D)],
+                m.values_to_bins(D[:, j].astype(np.float64)))
+    # the rare tail of column 3 shares the last bin
+    m3 = ds.bin_mappers[3]
+    assert len(np.unique(X[:, 3])) > m3.num_bin
+    tracing.reset()
+
+
+def _values_to_bins_one_by_one(m, values):
+    """The per-value dictionary lookup `values_to_bins` was before it was
+    vectorised (bin.h ValueToBin:452-487), kept here as its oracle."""
+    from lightgbm_tpu.io.binning import MISSING_NAN
+    last = max(m.num_bin - 1, 0)
+    out = np.full(len(values), last, dtype=np.int32)
+    for i, v in enumerate(values):
+        if np.isnan(v):
+            if m.missing_type != MISSING_NAN:
+                out[i] = m.categorical_2_bin.get(0, last)
+        elif np.isfinite(v) and int(v) >= 0:
+            out[i] = m.categorical_2_bin.get(int(v), last)
+    return out
+
+
+@pytest.mark.parametrize("col", [1, 3, 4])
+def test_vectorised_categorical_lookup_matches_the_per_value_one(col):
+    from lightgbm_tpu.config import Config
+    X, cats = _mixed_matrix(seed=9)
+    ds = BinnedDataset.from_matrix(X, Config({"max_bin": 32}),
+                                   categorical_feature=cats)
+    m = ds.bin_mappers[col]
+    probe = np.concatenate([X[:, col], _dirtied(X, cats)[:, col],
+                            [-0.999, 0.0, 0.5, 1e300, -1e300, 7.999]])
+    np.testing.assert_array_equal(m.values_to_bins(probe),
+                                  _values_to_bins_one_by_one(m, probe))
 
 
 @needs_native

@@ -268,20 +268,30 @@ def test_every_call_is_a_launch_span_beside_its_compile_event():
     tracing.reset()
 
 
-def _step_op_names(extra):
-    """`op_name` metadata of `gbdt.step` lowered for a small booster."""
+def _step_op_names(extra, categorical=()):
+    """[(opcode, `op_name`)] of `gbdt.step` lowered for a small booster
+    (instructions of one array; a tuple's shape has spaces in it)."""
     import re
+    return re.findall(r'= \S+ ([\w-]+)\(.*op_name="([^"]*)"',
+                      _step_hlo_text(extra, categorical))
 
+
+def _step_hlo_text(extra, categorical=()):
+    """`gbdt.step` lowered for a small booster, as HLO text with the
+    `op_name` metadata."""
     import jax.numpy as jnp
     from jax._src.lib import xla_client
 
     X, y = _synth(n=1024, f=6)
+    for j in categorical:           # twelve codes in place of the values
+        X[:, j] = np.floor(np.abs(X[:, j]) * 5) % 12
     params = dict({"objective": "binary", "num_leaves": 7, "verbose": -1},
                   **extra)
     # a ranking objective wants query groups: 16 queries of 64 rows
     group = np.full(16, 64) if params["objective"] == "lambdarank" else None
-    bst = lgb.Booster(params, lgb.Dataset(X, label=y, group=group,
-                                          params=params))
+    bst = lgb.Booster(params, lgb.Dataset(
+        X, label=y, group=group, params=params,
+        categorical_feature=list(categorical) or "auto"))
     bst.update()
     eng = bst._engine
     fs = eng._fast
@@ -290,9 +300,7 @@ def _step_op_names(extra):
                              jnp.float32(0.1), jnp.int32(0))
     opts = xla_client._xla.HloPrintOptions.short_parsable()
     opts.print_metadata = True
-    text = lowered.compiler_ir(dialect="hlo").get_hlo_module().to_string(opts)
-    # [(instruction's opcode, its op_name)]
-    return re.findall(r'= \S+ ([\w-]+)\(.*op_name="([^"]*)"', text)
+    return lowered.compiler_ir(dialect="hlo").get_hlo_module().to_string(opts)
 
 
 @pytest.fixture(scope="module")
@@ -307,7 +315,7 @@ def mesh_step_op_names():
 
 def test_phase_helper_knows_its_names():
     from lightgbm_tpu.boosting import grower2
-    assert len(set(grower2.PHASES)) == 11
+    assert len(set(grower2.PHASES)) == 12
     with pytest.raises(ValueError):
         grower2.phase("probe")
 
@@ -336,6 +344,28 @@ def test_ranking_step_scopes_its_pairwise_program_and_its_permutations(
              and "lgbm.grad_permute" not in n}
     assert not moved
     assert not [n for _, n in serial_step_op_names if "lgbm.%s" % name in n]
+
+
+def test_categorical_step_scopes_its_search_and_loops_over_no_bins(
+        serial_step_op_names):
+    """The categorical search enters `lgbm.cat_search` inside
+    `lgbm.split_search`: its one sort is there and no loop is (the walk
+    over the sorted bins is unrolled, `max_cat_threshold` steps whatever
+    the bin count); a step without a categorical column has no such
+    scope, so it compiles to the program it compiled to before."""
+    import re
+    text = _step_hlo_text({"min_data_per_group": 5, "cat_smooth": 1.0},
+                          categorical=(1, 4))
+    inner = re.findall(r'^.* = .*?\)? ([\w-]+)\(.*op_name="([^"]*lgbm\.'
+                       r'cat_search[^"]*)"', text, re.M)
+    # (the sort's comparator and the calls of inlined functions are
+    # computations of their own and keep the innermost scope only)
+    assert inner and all("lgbm.split_search/" in n
+                         or n.startswith("lgbm.cat_search") for _, n in inner)
+    ops = [op for op, _ in inner]
+    assert ops.count("sort") == 2                       # root, children
+    assert not {"while", "gather", "scatter"} & set(ops)
+    assert not [n for _, n in serial_step_op_names if "lgbm.cat_search" in n]
 
 
 @pytest.mark.parametrize("name", ["allreduce", "root_hist", "hist",
