@@ -422,7 +422,7 @@ def pass_a(payload, aux, route, start, count, pred, left_value, right_value,
     blocks = route is not None
     win_lo = (pred.col // 128) * 128 if blocks else 0
     scalars = pseg._acc_scalars(start, count, pred, pred.col - win_lo,
-                                win_lo, B)
+                                win_lo, B, False)
     fvals = jnp.stack([left_value, right_value]).astype(jnp.float32)
     bitset = pred.bitset.astype(jnp.int32).reshape(1, B)
     kern = functools.partial(_pass_a_kernel, P=P, B=B, value_col=value_col,

@@ -87,15 +87,18 @@ def segs(*pairs):
 
 
 def check_partition(fn, pay, pred, value_col, pairs):
-    """fn(payload, aux, start, count) against the portable partition."""
+    """fn(payload, aux, start, count, right_first) against the portable
+    partition, with the left child first and with the right one."""
     for s, c in pairs:
-        p, _, nl = fn(pay, jnp.zeros_like(pay), jnp.int32(s), jnp.int32(c))
-        pr, _, nlr = seg.partition_segment(
-            pay, jnp.zeros_like(pay), jnp.int32(s), jnp.int32(c), pred,
-            jnp.float32(1.5), jnp.float32(-2.5), value_col)
-        assert int(nl) == int(nlr), (s, c, int(nl), int(nlr))
-        err = float(jnp.abs(p - pr).max())
-        assert err == 0.0, (s, c, err)
+        for right_first in (jnp.bool_(False), jnp.bool_(True)):
+            p, _, nl = fn(pay, jnp.zeros_like(pay), jnp.int32(s),
+                          jnp.int32(c), right_first)
+            pr, _, nlr = seg.partition_segment(
+                pay, jnp.zeros_like(pay), jnp.int32(s), jnp.int32(c), pred,
+                jnp.float32(1.5), jnp.float32(-2.5), value_col, right_first)
+            assert int(nl) == int(nlr), (s, c, int(nl), int(nlr))
+            err = float(jnp.abs(p - pr).max())
+            assert err == 0.0, (s, c, bool(right_first), err)
 
 
 # Higgs-shaped payload shared by most sections
@@ -168,8 +171,8 @@ def histogram():
 def partition_rmw():
     """partition_segment (read-modify-write windows)."""
     check_partition(
-        lambda p, a, s, c: pseg.partition_segment(
-            p, a, s, c, PRED, LV, RV, VAL, B, **IK),
+        lambda p, a, s, c, rf: pseg.partition_segment(
+            p, a, s, c, PRED, LV, RV, VAL, B, rf, **IK),
         PAY, PRED, VAL, segs((128, 3000), (7, 8000), (513, 256)))
     return {"ms": median_ms(lambda: int(pseg.partition_segment(
         PAY, jnp.zeros_like(PAY), jnp.int32(0), jnp.int32(N), PRED, LV, RV,
@@ -197,8 +200,8 @@ def partition_acc():
     }
     for pred in preds.values():
         check_partition(
-            lambda p, a, s, c: pseg.partition_segment_acc(
-                p, a, s, c, pred, LV, RV, VAL, B, **IK),
+            lambda p, a, s, c, rf: pseg.partition_segment_acc(
+                p, a, s, c, pred, LV, RV, VAL, B, rf, **IK),
             PAY, pred, VAL,
             segs((128, 3000), (7, 8000), (513, 256), (0, 8192)))
     # bins past 256: the column is read out at HIGHEST, 32 words of bitset
@@ -208,8 +211,8 @@ def partition_acc():
             is_cat=jnp.bool_(True),
             bitset=jnp.asarray(np.arange(wide_b) % 7 == 3, jnp.int32))):
         check_partition(
-            lambda p, a, s, c: pseg.partition_segment_acc(
-                p, a, s, c, pred, LV, RV, VAL, wide_b, **IK),
+            lambda p, a, s, c, rf: pseg.partition_segment_acc(
+                p, a, s, c, pred, LV, RV, VAL, wide_b, rf, **IK),
             wide, pred, VAL, segs((7, 8000), (513, 256)))
     return {"predicates": sorted(preds) + ["1000_bins"],
             "ms": median_ms(lambda: int(pseg.partition_segment_acc(
@@ -230,8 +233,8 @@ def blocks():
     pay = make_payload(N, Fw, Bw, width=Pw)
     pred = make_pred(700, 30, Bw)
     check_partition(
-        lambda p, a, s, c: pseg.partition_segment_acc_blocks(
-            p, a, s, c, pred, LV, RV, Fw + 3, Bw, **IK),
+        lambda p, a, s, c, rf: pseg.partition_segment_acc_blocks(
+            p, a, s, c, pred, LV, RV, Fw + 3, Bw, rf, **IK),
         pay, pred, Fw + 3, segs((128, 3000), (7, 8000), (513, 256)))
     info = {"ragged_1280_ms": median_ms(
                 lambda: np.asarray(pseg.partition_segment_acc_blocks(
@@ -256,37 +259,42 @@ def blocks():
     def run(**kw):
         # the copies are donated: payload, scratch and nothing else
         return jax.jit(
-            lambda p, a, s, c, col, thr:
+            lambda p, a, s, c, col, thr, rf:
             pseg.partition_segment_acc_blocks(
-                p, a, s, c, make_pred(col, thr, Bw), LV, RV, vcol, Bw,
+                p, a, s, c, make_pred(col, thr, Bw), LV, RV, vcol, Bw, rf,
                 **kw, **IK),
             donate_argnums=(0, 1))
 
     kernel = run()
     cases = [(0, rows, 1300, 30), (7, 256, 3, 20), (128, 3000, 511, 40),
              (513, 100_000, 512, 31), (300_001, 65_536, 1999, 10)]
-    for s0, c0, col, thr in cases:
+    # the right child first in every other case
+    for i, (s0, c0, col, thr) in enumerate(cases):
         if s0 + c0 > rows:
             continue
+        right_first = bool(i % 2)
         out, _, nl = kernel(pay + 0.0, jnp.zeros_like(pay), jnp.int32(s0),
-                            jnp.int32(c0), jnp.int32(col), jnp.int32(thr))
+                            jnp.int32(c0), jnp.int32(col), jnp.int32(thr),
+                            jnp.bool_(right_first))
         got = np.asarray(out)
         del out
         left = host[s0:s0 + c0, col] <= thr
+        first = ~left if right_first else left
         want = host.copy()
-        want[s0:s0 + c0] = np.concatenate([host[s0:s0 + c0][left],
-                                           host[s0:s0 + c0][~left]])
+        want[s0:s0 + c0] = np.concatenate([host[s0:s0 + c0][first],
+                                           host[s0:s0 + c0][~first]])
+        values = (RV, LV) if right_first else (LV, RV)
         want[s0:s0 + c0, vcol] = np.where(
-            np.arange(c0) < left.sum(), np.float32(LV), np.float32(RV))
+            np.arange(c0) < first.sum(), *map(np.float32, values))
         assert int(nl) == int(left.sum()), (s0, c0, int(nl), int(left.sum()))
-        assert np.array_equal(got, want), (s0, c0, col)
+        assert np.array_equal(got, want), (s0, c0, col, right_first)
     info["epsilon_rows"] = rows
     for block_w in (512, 256):
         fn = run(block_w=block_w)
         info["epsilon_block%d_ms" % block_w] = median_ms(
             lambda: int(fn(pay + 0.0, jnp.zeros_like(pay), jnp.int32(0),
                            jnp.int32(rows), jnp.int32(1300),
-                           jnp.int32(30))[2]), reps=3)
+                           jnp.int32(30), jnp.bool_(False))[2]), reps=3)
     info["epsilon_copy_ms"] = median_ms(
         lambda: float((pay + 0.0)[0, 0]), reps=3)
     return info

@@ -38,7 +38,7 @@ from ..ops.bundle import (BundleMap, bundle_map_from_info, decode_bin,
                           identity_bundle_map)
 from .grower import GrowerConfig, make_tree_grower
 from .grower2 import (PayloadCols, TREE_DEVICE_FIELDS, phase,
-                      make_partitioned_grower)
+                      make_partitioned_grower, wide_count)
 from .pipeline import TreeAssembler
 
 K_EPSILON = 1e-15
@@ -202,6 +202,9 @@ def _cached_pgrower(meta_dev: FeatureMeta, cfg, max_num_bin: int,
             # per-device row segments come back stacked [ndev * L]
             tree_specs["seg_start"] = P(ax)
             tree_specs["seg_cnt"] = P(ax)
+            # and so do each block's row counters, a (high, low) pair each
+            tree_specs["rows_partitioned"] = P(ax)
+            tree_specs["rows_staged"] = P(ax)
             # quantized growers take the replicated [2] scale pair as a
             # fourth argument (scales are global maxima, so every shard
             # holds the same values)
@@ -761,11 +764,15 @@ class _FastState:
         #: lazily by window_program(); survive sync-backs like the other
         #: jitted closures
         self._window_cache: Dict = {}
-        #: what the trees grown on this state split on, a tree an entry in
-        #: the order they were finished: read off the tree's own fetch by
+        #: what the trees grown on this state split on and what their
+        #: partitions moved (`rows_staged`: the rows of the children that
+        #: lay second in their parents' ranges, which the Pallas kernels
+        #: stage and move once more), a tree an entry in the order they
+        #: were finished: read off the tree's own fetch by
         #: `_finish_tree_host`, so it costs no dispatch and no transfer
-        self.counters: Dict[str, List[int]] = {"splits": [],
-                                               "categorical_splits": []}
+        self.counters: Dict[str, List[int]] = {
+            "splits": [], "categorical_splits": [],
+            "rows_partitioned": [], "rows_staged": []}
 
     def window_program(self, J: int, with_bag: bool):
         """One jitted, donated device program for a whole boosting window:
@@ -2364,6 +2371,8 @@ class GBDT:
             self._fast.counters["splits"].append(nl - 1)
             self._fast.counters["categorical_splits"].append(
                 int(host["split_is_cat"][:nl - 1].sum()))
+            for name in ("rows_partitioned", "rows_staged"):
+                self._fast.counters[name].append(wide_count(host[name]))
         L = self.grower_cfg.num_leaves
         tree = Tree(max(L, 2))
         tree.num_leaves = nl

@@ -94,6 +94,28 @@ def partition_engine(hist_impl: str, payload_width: int,
     return "lax"
 
 
+#: a tree's row counters (`rows_partitioned`, `rows_staged`) are sums of
+#: up to num_leaves - 1 segment lengths: past int32 on a deep tree over
+#: 10^7 rows, and the tree's fetch carries float32, exact to 2^24.  They
+#: ride as [2] int32, (count >> WIDE_BITS, count & (2^WIDE_BITS - 1)),
+#: a pair a device block on a mesh; `wide_count` sums them on the host.
+WIDE_BITS = 20
+
+
+def _wide_add(acc, n):
+    """acc + n for a (high, low) pair and an int32 n."""
+    low = acc[1] + n
+    return jnp.stack([acc[0] + (low >> WIDE_BITS),
+                      low & ((1 << WIDE_BITS) - 1)])
+
+
+def wide_count(pairs) -> int:
+    """The Python int a fetched row counter holds: its (high, low) pairs,
+    one a device block, summed."""
+    values = [int(v) for v in pairs]
+    return (sum(values[0::2]) << WIDE_BITS) + sum(values[1::2])
+
+
 class PayloadCols(NamedTuple):
     """Static column indices of the value columns inside the payload
     (bin columns occupy [0, F))."""
@@ -251,19 +273,20 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
         hist_fn = functools.partial(seg.segment_histogram,
                                     quantized=quantized, **hist_kwargs)
 
-    def part_fn(payload, aux, start, count, pred, lv, rv):
+    def part_fn(payload, aux, start, count, pred, lv, rv, right_first):
         # the width is static at trace time; a caller that did not pass
         # payload_width gets its engine resolved (and reported) here
         part = partition_engine(cfg.hist_impl, payload.shape[1], B)
         engines["partition"] = part
         if part == "lax":
             return seg.partition_segment(payload, aux, start, count, pred,
-                                         lv, rv, cols.value)
+                                         lv, rv, cols.value, right_first)
         from ..ops import pallas_segment as pseg
         kernel = {"pallas-acc": pseg.partition_segment_acc,
                   "pallas-rmw": pseg.partition_segment,
                   "pallas-blocks": pseg.partition_segment_acc_blocks}[part]
-        return kernel(payload, aux, start, count, pred, lv, rv, cols.value, B)
+        return kernel(payload, aux, start, count, pred, lv, rv, cols.value, B,
+                      right_first)
 
     #: what this build resolved, readable as ``grower.engines`` — the
     #: choice is made from platform and shape, never invisibly
@@ -545,6 +568,11 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
             "internal_value": jnp.zeros(ni, jnp.float32),
             "internal_count": jnp.zeros(ni, jnp.float32),
             "num_leaves": jnp.int32(1),
+            # rows the tree's partitions took in, and of them the rows of
+            # the children that lay second (staged and moved once more by
+            # the Pallas kernels): raw counts, as the kernels returned them
+            "rows_partitioned": jnp.zeros(2, jnp.int32),
+            "rows_staged": jnp.zeros(2, jnp.int32),
         }
         # per-leaf (or pooled) histogram state for the subtraction trick.
         # int32 in quantized mode (the narrow-dtype plumbing: LRU slots,
@@ -629,19 +657,24 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
                     hist_parent = lax.optimization_barrier(
                         st["hist"][best_leaf])
 
-            with phase("partition"):
-                payload, aux, nl_raw = part_fn(
-                    st["payload"], st["aux"], start, count, pred,
-                    st["blo"][best_leaf], st["bro"][best_leaf])
-            nr_raw = count - nl_raw
-
             # histograms: build only the smaller child, derive the sibling
             # by subtraction.  The choice uses masked counts (like grower.py
             # and the reference's num_data comparison) so both growers build
             # the direct histogram on the same child and stay bit-comparable.
+            # That child lies SECOND in the parent's range: the kernels move
+            # the second child's rows twice, and no reader needs the left
+            # child first.  On a mesh the counts are all-reduced, so every
+            # shard makes the same choice.
             left_smaller = lcnt <= rcnt
-            h_start = jnp.where(left_smaller, start, start + nl_raw)
-            h_count = jnp.where(left_smaller, nl_raw, nr_raw)
+            right_first = left_smaller
+            with phase("partition"):
+                payload, aux, nl_raw = part_fn(
+                    st["payload"], st["aux"], start, count, pred,
+                    st["blo"][best_leaf], st["bro"][best_leaf], right_first)
+            nr_raw = count - nl_raw
+            h_count = jnp.where(right_first, nl_raw, nr_raw)
+            h_start = start + count - h_count
+            l_start, r_start = seg.first_second(right_first, start, h_start)
             with phase("hist"):
                 hist_small = hist_fn(payload, h_start, h_count)
             hist_small = reduce_hist(hist_small)
@@ -750,8 +783,11 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
                 st_new["slot_of_leaf"] = slot_of_leaf
                 st_new["leaf_of_slot"] = leaf_of_slot
                 st_new["slot_use"] = use
-            st_new["seg_start"] = set2(st["seg_start"], start, start + nl_raw)
+            st_new["seg_start"] = set2(st["seg_start"], l_start, r_start)
             st_new["seg_cnt"] = set2(st["seg_cnt"], nl_raw, nr_raw)
+            st_new["rows_partitioned"] = _wide_add(st["rows_partitioned"],
+                                                   count)
+            st_new["rows_staged"] = _wide_add(st["rows_staged"], h_count)
             st_new["sum_g"] = set2(st["sum_g"], lg, rg)
             st_new["sum_h"] = set2(st["sum_h"], lh, rh)
             st_new["cnt"] = set2(st["cnt"], lcnt, rcnt)
@@ -844,6 +880,15 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
             bcat_c = st["bcat"][cand]
             bbitset_c = st["bbitset"][cand]
             blo_c, bro_c = st["blo"][cand], st["bro"][cand]
+            lg_c, lh_c, lc_c = (st["blg"][cand], st["blh"][cand],
+                                st["blc"][cand])
+            pg_c, ph_c, pc_c = (st["sum_g"][cand], st["sum_h"][cand],
+                                st["cnt"][cand])
+            rg_c, rh_c, rc_c = pg_c - lg_c, ph_c - lh_c, pc_c - lc_c
+            # the sequential loop's rule: the smaller child (masked
+            # counts) is histogrammed and lies second
+            left_smaller = lc_c <= rc_c
+            right_first = left_smaller
 
             # eval phase A: STAGE every candidate's partition into the aux
             # scratch (passes A+B; payload is only read, so an evaluated
@@ -856,7 +901,7 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
             order = jnp.argsort(start_c)
 
             def eval_part(i, carry):
-                aux, nls = carry
+                aux, nfs = carry
                 k = order[i]
                 f = feat_c[k]
                 pred = SplitPredicate(
@@ -870,14 +915,20 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
                     default_bin=meta.default_bin[f],
                     offset=bmap.f_offset[f],
                     identity=bmap.f_identity[f])
-                aux, nl = seg.partition_segment_stage(
-                    st["payload"], aux, start_c[k], cnt_c[k], pred)
-                return aux, nls.at[k].set(nl)
+                aux, nf = seg.partition_segment_stage(
+                    st["payload"], aux, start_c[k], cnt_c[k], pred,
+                    right_first[k])
+                return aux, nfs.at[k].set(nf)
 
             with phase("partition"):
-                aux, nl_c = lax.fori_loop(
+                aux, nf_c = lax.fori_loop(
                     0, KB, eval_part, (st["aux"], jnp.zeros(KB, jnp.int32)))
             payload = st["payload"]
+            h_count = cnt_c - nf_c
+            h_start = start_c + nf_c
+            nl_c = jnp.where(right_first, h_count, nf_c)
+            lstart_c, rstart_c = seg.first_second(right_first, start_c,
+                                                  h_start)
 
             # eval phase B: ONE batched histogram dispatch over the K
             # smaller children, read from the STAGED aux rows — compacted
@@ -886,14 +937,6 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
             # to the sequential grower's post-partition build.  Siblings
             # by batched subtraction, same masked-count smaller-child
             # choice as the sequential path.
-            lg_c, lh_c, lc_c = (st["blg"][cand], st["blh"][cand],
-                                st["blc"][cand])
-            pg_c, ph_c, pc_c = (st["sum_g"][cand], st["sum_h"][cand],
-                                st["cnt"][cand])
-            rg_c, rh_c, rc_c = pg_c - lg_c, ph_c - lh_c, pc_c - lc_c
-            left_smaller = lc_c <= rc_c
-            h_start = jnp.where(left_smaller, start_c, start_c + nl_c)
-            h_count = jnp.where(left_smaller, nl_c, cnt_c - nl_c)
             with phase("hist"):
                 hist_small = hist_batched_fn(aux, h_start, h_count)
             with phase("subtract"):
@@ -949,9 +992,15 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
                 def setn(arr, v):
                     return arr.at[node].set(jnp.where(do, v, arr[node]))
 
-                start, nl = start_c[j], nl_c[j]
-                st2["seg_start"] = set2(st2["seg_start"], start, start + nl)
+                nl = nl_c[j]
+                st2["seg_start"] = set2(st2["seg_start"], lstart_c[j],
+                                        rstart_c[j])
                 st2["seg_cnt"] = set2(st2["seg_cnt"], nl, cnt_c[j] - nl)
+                done = do.astype(jnp.int32)
+                st2["rows_partitioned"] = _wide_add(
+                    st2["rows_partitioned"], done * cnt_c[j])
+                st2["rows_staged"] = _wide_add(
+                    st2["rows_staged"], done * h_count[j])
                 st2["sum_g"] = set2(st2["sum_g"], lg_c[j], rg_c[j])
                 st2["sum_h"] = set2(st2["sum_h"], lh_c[j], rh_c[j])
                 st2["cnt"] = set2(st2["cnt"], lc_c[j], rc_c[j])
@@ -1023,7 +1072,8 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
             def commit_part(j, pay):
                 cnt = jnp.where(committed[j], cnt_c[j], 0)
                 return seg.partition_segment_commit(
-                    pay, aux, start_c[j], cnt, nl_c[j], blo_c[j], bro_c[j],
+                    pay, aux, start_c[j], cnt, nf_c[j],
+                    *seg.first_second(right_first[j], blo_c[j], bro_c[j]),
                     cols.value)
 
             with phase("partition"):
@@ -1069,6 +1119,8 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
             "right_child": st["right_child"],
             "internal_value": st["internal_value"],
             "internal_count": st["internal_count"],
+            "rows_partitioned": st["rows_partitioned"],
+            "rows_staged": st["rows_staged"],
         }
         return tree, st["payload"], st["aux"]
 

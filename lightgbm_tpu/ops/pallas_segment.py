@@ -23,6 +23,11 @@ kernels keep every one-hot in VMEM:
   from a snapshot of the split column (`_snap_window_kernel`);
   `partition_segment` (`_partition_kernel`) is the older read-modify-write
   kernel, the only one whose plan fits between the two (640-1,792 lanes).
+  All three take `right_first`, data like the predicate: which child lies
+  FIRST in the parent's range (`ops.segment.partition_segment`).  The
+  first side is the one written in place, the other is staged in `aux`
+  and moved once more, so a caller that knows the children's sizes names
+  the larger one first.
 
 The module holds what `grower2.partition_engine` and
 `ops.segment.resolve_impl` choose from shape and platform, and nothing
@@ -40,7 +45,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..runtime import xla_obs
-from .segment import CHUNK, GUARD
+from .segment import CHUNK, GUARD, first_second
 from .split import MISSING_NAN, MISSING_ZERO
 
 #: the partition kernels write HBM in place through aliased outputs; those
@@ -266,16 +271,18 @@ def _go_left_rows(scalars, bitset_ref, data, B, iota_p):
 
 
 #: where the packed categorical bitset starts in the accumulator kernels'
-#: scalar-prefetch vector: behind the 11 scalars of the predicate and the
+#: scalar-prefetch vector: behind the 11 scalars of the predicate, the
 #: split window's first lane (which only the column-block wrapper fills)
-_BITSET_WORD0 = 12
+#: and `right_first`
+_BITSET_WORD0 = 13
 
 
-def _acc_scalars(start, count, pred, col, win_lo, num_bins):
+def _acc_scalars(start, count, pred, col, win_lo, num_bins, right_first):
     """The accumulator kernels' scalar-prefetch vector: segment, split
     predicate (`col` as the kernel is to see it), the split window's
-    first lane, then `pred.bitset` packed into ceil(B / 32) int32 words,
-    bit b of word w the membership of bin 32 w + b."""
+    first lane, whether the right child lies first, then `pred.bitset`
+    packed into ceil(B / 32) int32 words, bit b of word w the membership
+    of bin 32 w + b."""
     words = -(-num_bins // 32)
     bits = jnp.pad(pred.bitset.astype(jnp.uint32),
                    (0, 32 * words - num_bins)).reshape(words, 32)
@@ -287,7 +294,7 @@ def _acc_scalars(start, count, pred, col, win_lo, num_bins):
             pred.default_left.astype(jnp.int32),
             pred.is_cat.astype(jnp.int32), pred.missing_type, pred.num_bin,
             pred.default_bin, pred.offset, pred.identity.astype(jnp.int32),
-            win_lo,
+            win_lo, right_first,
         ]).astype(jnp.int32),
         lax.bitcast_convert_type(packed, jnp.int32)])
 
@@ -623,9 +630,14 @@ def _partition_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm,
                       chunk, wstage, wread, sem_in, sem_out, *,
                       P, B, value_col):
     """payload_hbm/aux_hbm are aliased with payload_out/aux_out — the kernel
-    reads and writes the same HBM buffers through the `_out` refs."""
+    reads and writes the same HBM buffers through the `_out` refs.
+    "Left" in the body is the FIRST side, "right" the staged one: with
+    scalars[11] (`right_first`) set the routing is turned round, the
+    wrapper hands the values over swapped and reads `nl_out` as the
+    right child's count."""
     start = scalars[0]
     count = scalars[1]
+    right_first = scalars[11]
     left_value = fvals[0]
     right_value = fvals[1]
     # reads stride CHUNK from the 8-aligned base below `start`; the first
@@ -650,8 +662,8 @@ def _partition_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm,
                 (iota_rows < shift + count - k * CHUNK)).astype(jnp.int32)
 
     def go_left(data, k):
-        return _go_left_rows(scalars, bitset_ref, data, B, iota_p) \
-            * valid_mask(k)                                  # [C] i32 0/1
+        return (_go_left_rows(scalars, bitset_ref, data, B, iota_p)
+                ^ right_first) * valid_mask(k)               # [C] i32 0/1
 
     def compact_rows(keep_i, data, value):
         """Stable forward compaction of data rows with keep_i=1 (exclusive
@@ -751,7 +763,8 @@ def _partition_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm,
 @functools.partial(xla_obs.jit, site="pallas.partition_segment", static_argnames=("value_col", "num_bins",
                                              "interpret"))
 def partition_segment(payload, aux, start, count, pred, left_value,
-                      right_value, value_col, num_bins, interpret=False):
+                      right_value, value_col, num_bins, right_first=False,
+                      interpret=False):
     """Same contract as ops.segment.partition_segment, fused on-chip."""
     P = payload.shape[1]
     B = num_bins
@@ -759,9 +772,10 @@ def partition_segment(payload, aux, start, count, pred, left_value,
         start, count, pred.col, pred.threshold,
         pred.default_left.astype(jnp.int32), pred.is_cat.astype(jnp.int32),
         pred.missing_type, pred.num_bin, pred.default_bin,
-        pred.offset, pred.identity.astype(jnp.int32),
+        pred.offset, pred.identity.astype(jnp.int32), right_first,
     ]).astype(jnp.int32)
-    fvals = jnp.stack([left_value, right_value]).astype(jnp.float32)
+    fvals = jnp.stack(first_second(
+        right_first, left_value, right_value)).astype(jnp.float32)
     bitset = pred.bitset.astype(jnp.int32).reshape(1, B)
     kern = functools.partial(_partition_kernel, P=P, B=B,
                              value_col=value_col)
@@ -791,7 +805,7 @@ def partition_segment(payload, aux, start, count, pred, left_value,
         compiler_params=_SIDE_EFFECTS,
         interpret=interpret,
     )(scalars, fvals, bitset, payload, aux)
-    return payload_new, aux_new, nl[0]
+    return payload_new, aux_new, jnp.where(right_first, count - nl[0], nl[0])
 
 
 # ---------------------------------------------------------------------------
@@ -813,6 +827,15 @@ def _acc_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
     bf16-exact hi/mid/lo decomposition instead of a 6-pass HIGHEST.
     Only the LAST window of a segment needs a blend read (its tail crosses
     into the next leaf's rows).
+
+    "Lefts" below are the rows of the FIRST side, which pass A writes in
+    place, and "rights" those of the STAGED side, which it parks in `aux`
+    and pass B reads back and appends: a row of the staged side is read
+    and written twice.  Which child is which is one prefetched scalar,
+    `right_first`: `routed` turns the predicate round under the validity
+    mask and nothing else in the body can tell; the wrapper hands over
+    the children's values in that order and reads `nl_out` as the first
+    side's count.  It is data, like `is_cat`: one body, one compilation.
 
     Pass A places a chunk's rows with ONE permutation, not one compaction
     per side: lefts in order, then rights in order, is a stable partition
@@ -872,6 +895,7 @@ def _acc_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
         route_ring, sem_route = rest
     start = scalars[0]
     count = scalars[1]
+    right_first = scalars[12]
     left_value = fvals[0]
     right_value = fvals[1]
     shift = lax.rem(start, 8)
@@ -1025,7 +1049,8 @@ def _acc_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
                  (row_of_lane < first + count)).astype(jnp.int32)
         # (routing is integer arithmetic under the validity mask: what an
         # unread split window holds cannot reach a row)
-        gl = _go_left_lanes(scalars, raw.astype(jnp.int32), B) * valid
+        gl = (_go_left_lanes(scalars, raw.astype(jnp.int32), B)
+              ^ right_first) * valid
         rank_l = jnp.dot(gl.astype(jnp.float32), tri_t,
                          preferred_element_type=jnp.float32
                          ).astype(jnp.int32)
@@ -1195,12 +1220,13 @@ def _acc_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
                    static_argnames=("value_col", "num_bins", "interpret"))
 def _partition_segment_acc(payload, aux, start, count, pred, left_value,
                            right_value, value_col, num_bins,
-                           interpret=False):
+                           right_first=False, interpret=False):
     """Same contract as `partition_segment`, accumulator-window kernel."""
     P = payload.shape[1]
     B = num_bins
-    scalars = _acc_scalars(start, count, pred, pred.col, 0, B)
-    fvals = jnp.stack([left_value, right_value]).astype(jnp.float32)
+    scalars = _acc_scalars(start, count, pred, pred.col, 0, B, right_first)
+    fvals = jnp.stack(first_second(
+        right_first, left_value, right_value)).astype(jnp.float32)
     group = _pass_a_group(P, B)
     kern = functools.partial(_acc_kernel, P=P, B=B, value_col=value_col,
                              group=group)
@@ -1233,7 +1259,7 @@ def _partition_segment_acc(payload, aux, start, count, pred, left_value,
         compiler_params=_SIDE_EFFECTS,
         interpret=interpret,
     )(scalars, fvals, payload, aux)
-    return payload_new, aux_new, nl[0]
+    return payload_new, aux_new, jnp.where(right_first, count - nl[0], nl[0])
 
 
 partition_segment_acc = _partition_segment_acc
@@ -1291,8 +1317,8 @@ def _snap_window_kernel(scalars, payload_hbm, snap_out, buf, sem):
                                     "block_w"))
 def _partition_segment_acc_blocks(payload, aux, start, count, pred,
                                   left_value, right_value, value_col,
-                                  num_bins, interpret=False,
-                                  block_w=_BLOCK_WIDTH):
+                                  num_bins, right_first=False,
+                                  interpret=False, block_w=_BLOCK_WIDTH):
     """Same contract as `partition_segment`, applied block-by-block over
     the payload's lane windows (ultra-wide payloads)."""
     P = payload.shape[1]
@@ -1301,8 +1327,10 @@ def _partition_segment_acc_blocks(payload, aux, start, count, pred,
                          "payload (P %% 128 == 0), got %d" % P)
     B = num_bins
     win_lo = (pred.col // 128) * 128
-    scalars = _acc_scalars(start, count, pred, pred.col - win_lo, win_lo, B)
-    fvals = jnp.stack([left_value, right_value]).astype(jnp.float32)
+    scalars = _acc_scalars(start, count, pred, pred.col - win_lo, win_lo, B,
+                           right_first)
+    fvals = jnp.stack(first_second(
+        right_first, left_value, right_value)).astype(jnp.float32)
     # freeze the split column's window before any pass rewrites its lanes
     snap = pl.pallas_call(
         _snap_window_kernel,
@@ -1364,7 +1392,7 @@ def _partition_segment_acc_blocks(payload, aux, start, count, pred,
             interpret=interpret,
         )(scalars, fvals, payload, aux, snap)
         nl = nl_k if nl is None else nl
-    return payload, aux, nl[0]
+    return payload, aux, jnp.where(right_first, count - nl[0], nl[0])
 
 
 partition_segment_acc_blocks = _partition_segment_acc_blocks

@@ -174,11 +174,19 @@ def _compact_matmul(chunk: jax.Array, keep: jax.Array) -> jax.Array:
     return jnp.matmul(perm, chunk, precision=jax.lax.Precision.HIGHEST)
 
 
+def first_second(right_first, left, right):
+    """(first, second) of a split's (left, right) pair, by which child
+    lies first in the parent's range."""
+    return (jnp.where(right_first, right, left),
+            jnp.where(right_first, left, right))
+
+
 def partition_segment_stage(payload: jax.Array, aux: jax.Array,
                             start: jax.Array, count: jax.Array,
-                            pred: SplitPredicate):
-    """Passes A+B of the stable partition: compact LEFT rows of
-    [start, start+count) into aux[start..], then RIGHT rows after them.
+                            pred: SplitPredicate, right_first=False):
+    """Passes A+B of the stable partition: compact the rows of the FIRST
+    child of [start, start+count) into aux[start..] (the left one, the
+    right one with `right_first`), then the other child's after them.
     payload is only READ — the frontier-batched grower stages candidate
     splits here and copies back (`partition_segment_commit`) only for the
     splits that commit, so an evaluated-but-uncommitted leaf's rows keep
@@ -186,7 +194,7 @@ def partition_segment_stage(payload: jax.Array, aux: jax.Array,
     chunk past the segment end in aux; callers staging several segments
     must stage them in ASCENDING start order so an overrun only ever
     clobbers a region that is (re)staged afterwards.
-    Returns (aux, num_left)."""
+    Returns (aux, num_first), the first child's row count."""
     C = CHUNK
     nch = (count + C - 1) // C
 
@@ -197,41 +205,49 @@ def partition_segment_stage(payload: jax.Array, aux: jax.Array,
     def valid_rows(k):
         return jnp.arange(C, dtype=jnp.int32) < (count - k * C)
 
-    # pass A: compact LEFT rows of each chunk, append at aux[start + running)
+    right_first = jnp.asarray(right_first, jnp.bool_)
+
+    def go_first(chunk):
+        return go_left_chunk(chunk, pred) ^ right_first
+
+    # pass A: compact the first child's rows of each chunk, append at
+    # aux[start + running)
     def body_a(carry):
-        k, nl, aux = carry
+        k, nf, aux = carry
         chunk = read(payload, k)
-        keep = go_left_chunk(chunk, pred) & valid_rows(k)
+        keep = go_first(chunk) & valid_rows(k)
         compact = _compact_matmul(chunk, keep)
-        aux = lax.dynamic_update_slice(aux, compact, (start + nl, 0))
-        return k + 1, nl + jnp.sum(keep.astype(jnp.int32)), aux
+        aux = lax.dynamic_update_slice(aux, compact, (start + nf, 0))
+        return k + 1, nf + jnp.sum(keep.astype(jnp.int32)), aux
 
-    _, num_left, aux = lax.while_loop(lambda c: c[0] < nch, body_a,
-                                      (jnp.int32(0), jnp.int32(0), aux))
+    _, num_first, aux = lax.while_loop(lambda c: c[0] < nch, body_a,
+                                       (jnp.int32(0), jnp.int32(0), aux))
 
-    # pass B: compact RIGHT rows, append at aux[start + num_left + running)
+    # pass B: compact the other child's rows, append at
+    # aux[start + num_first + running)
     def body_b(carry):
-        k, nr, aux = carry
+        k, ns, aux = carry
         chunk = read(payload, k)
-        keep = (~go_left_chunk(chunk, pred)) & valid_rows(k)
+        keep = (~go_first(chunk)) & valid_rows(k)
         compact = _compact_matmul(chunk, keep)
         aux = lax.dynamic_update_slice(aux, compact,
-                                       (start + num_left + nr, 0))
-        return k + 1, nr + jnp.sum(keep.astype(jnp.int32)), aux
+                                       (start + num_first + ns, 0))
+        return k + 1, ns + jnp.sum(keep.astype(jnp.int32)), aux
 
     _, _, aux = lax.while_loop(lambda c: c[0] < nch, body_b,
                                (jnp.int32(0), jnp.int32(0), aux))
-    return aux, num_left
+    return aux, num_first
 
 
 def partition_segment_commit(payload: jax.Array, aux: jax.Array,
                              start: jax.Array, count: jax.Array,
-                             num_left: jax.Array, left_value: jax.Array,
-                             right_value: jax.Array, value_col: int):
+                             num_first: jax.Array, first_value: jax.Array,
+                             second_value: jax.Array, value_col: int):
     """Pass C of the stable partition: blended copy-back aux -> payload
     over [start, start+count), writing the children's creation values
-    (Tree::Split leaf_value_) into the value column on the way through.
-    count = 0 is a no-op (uncommitted staged candidates)."""
+    (Tree::Split leaf_value_), the first child's over its `num_first`
+    rows and the second's behind them, into the value column on the way
+    through.  count = 0 is a no-op (uncommitted staged candidates)."""
     C = CHUNK
     nch = (count + C - 1) // C
     vcol_onehot = (jnp.arange(payload.shape[1]) == value_col)[None, :]
@@ -246,7 +262,7 @@ def partition_segment_commit(payload: jax.Array, aux: jax.Array,
         dst = read(payload, k)
         ok = (jnp.arange(C, dtype=jnp.int32) < (count - k * C))[:, None]
         pos = start + k * C + jnp.arange(C, dtype=jnp.int32)
-        val = jnp.where(pos < start + num_left, left_value, right_value)
+        val = jnp.where(pos < start + num_first, first_value, second_value)
         src = jnp.where(vcol_onehot, val[:, None], src)
         blended = jnp.where(ok, src, dst)
         payload = lax.dynamic_update_slice(payload, blended,
@@ -261,18 +277,26 @@ def partition_segment_commit(payload: jax.Array, aux: jax.Array,
 def partition_segment(payload: jax.Array, aux: jax.Array, start: jax.Array,
                       count: jax.Array, pred: SplitPredicate,
                       left_value: jax.Array, right_value: jax.Array,
-                      value_col: int):
-    """Stably partition payload rows [start, start+count) by the predicate:
-    left rows first.  Writes the children's leaf outputs into `value_col`.
+                      value_col: int, right_first=False):
+    """Stably partition payload rows [start, start+count) by the predicate.
+    The contract of every partition engine: rows of the FIRST child (the
+    left one, the right one with `right_first`, a traced bool) at
+    [start, start + n_first), of the other behind them, each in its
+    original order, the children's leaf outputs written into `value_col`.
+    Nothing downstream needs the left child first (children are found by
+    their segment tables), and the Pallas kernels move the second child's
+    rows twice, so the grower names the larger child first.
     Returns (payload, aux, num_left) — num_left counts only rows whose
     count-mask survives in the caller's accounting; here it is the raw
     routed-row count used for segment offsets.  Composed of the stage
     (A+B) and commit (C) passes the frontier-batched grower runs apart.
     """
-    aux, num_left = partition_segment_stage(payload, aux, start, count, pred)
-    payload = partition_segment_commit(payload, aux, start, count, num_left,
-                                       left_value, right_value, value_col)
-    return payload, aux, num_left
+    aux, num_first = partition_segment_stage(payload, aux, start, count,
+                                             pred, right_first)
+    payload = partition_segment_commit(
+        payload, aux, start, count, num_first,
+        *first_second(right_first, left_value, right_value), value_col)
+    return payload, aux, jnp.where(right_first, count - num_first, num_first)
 
 
 def segment_histogram(payload: jax.Array, start: jax.Array, count: jax.Array,

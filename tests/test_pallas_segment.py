@@ -515,16 +515,18 @@ def test_partition_acc_past_256_bins(kernel, width, kind):
 
 def test_acc_scalars_pack_the_bitset():
     """Bit b of word w of the kernels' scalar vector is bin 32 w + b, the
-    words behind the predicate's scalars and the split window's lane."""
+    words behind the predicate's scalars, the split window's lane and
+    `right_first`."""
     for b in (64, 255, 256, 16):
         bitset = np.random.default_rng(b).random(b) < 0.5
         bitset[[0, b - 1]] = True
         got = np.asarray(pseg._acc_scalars(
             jnp.int32(3), jnp.int32(9),
-            _pred(bins=b, bitset=bitset), jnp.int32(1), 128, b))
+            _pred(bins=b, bitset=bitset), jnp.int32(1), 128, b,
+            jnp.bool_(True)))
         words = got[pseg._BITSET_WORD0:].astype(np.uint32)
         assert got.shape == (pseg._BITSET_WORD0 + -(-b // 32),)
-        assert list(got[:3]) == [3, 9, 1] and got[11] == 128
+        assert list(got[:3]) == [3, 9, 1] and list(got[11:13]) == [128, 1]
         unpacked = (words[:, None] >> np.arange(32, dtype=np.uint32)) & 1
         np.testing.assert_array_equal(unpacked.reshape(-1)[:b], bitset)
         assert not unpacked.reshape(-1)[b:].any()

@@ -64,7 +64,9 @@ def _partition_args(sharding, rows=ROWS, lanes=LANES, features=FEATURES,
         col=i32, threshold=i32, default_left=flag, is_cat=flag,
         missing_type=i32, num_bin=i32, default_bin=i32, offset=i32,
         identity=flag, bitset=shape((bins,), jnp.int32))
-    return payload, payload, i32, i32, pred, f32, f32, features + 3, bins
+    # `right_first` is data to the kernels, as the grower passes it
+    return (payload, payload, i32, i32, pred, f32, f32, features + 3, bins,
+            flag)
 
 
 @pytest.mark.parametrize("lanes,bins", [(LANES, BINS), (LANES, 255),
