@@ -504,7 +504,7 @@ def bench_telemetry():
     bst._engine.flush()
 
     ops0 = telemetry.REGISTRY.ops
-    ev0 = tracing.ring_summary()["recorded_total"]
+    ev0 = tracing.export_chrome()["otherData"]["recorded_total"]
     t0 = time.perf_counter()
     for _ in range(iters):
         bst.update()
@@ -512,7 +512,7 @@ def bench_telemetry():
     dt_on = time.perf_counter() - t0
     ops_per_iter = (telemetry.REGISTRY.ops - ops0) / iters
     trace_events_per_iter = \
-        (tracing.ring_summary()["recorded_total"] - ev0) / iters
+        (tracing.export_chrome()["otherData"]["recorded_total"] - ev0) / iters
 
     prev = telemetry.set_enabled(False)
     prev_tr = tracing.set_enabled(False)

@@ -42,8 +42,10 @@ class TreeAssembler:
     def __init__(self, depth: int):
         self.depth = max(1, int(depth))
         self._cv = threading.Condition()
-        self._fifo: Deque[Tuple[Callable[[], None], int]] = \
+        #: (host half, trees it carries, index of its first tree)
+        self._fifo: Deque[Tuple[Callable[[], None], int, int]] = \
             collections.deque()
+        self._submitted = 0             # trees handed to submit() so far
         self._thread: Optional[threading.Thread] = None
         self._error: Optional[BaseException] = None
         self._stopping = False
@@ -59,7 +61,7 @@ class TreeAssembler:
         """Trees carried by the pending drain units (a boosting-window
         unit counts its whole J*K batch)."""
         with self._cv:
-            return sum(n for _, n in self._fifo)
+            return sum(n for _, n, _ in self._fifo)
 
     def submit(self, fn: Callable[[], None], trees: int = 1) -> None:
         """Enqueue one drain unit carrying `trees` parked trees; blocks
@@ -71,22 +73,36 @@ class TreeAssembler:
         # on the worker thread but belongs to the dispatching iteration's
         # causal chain — capture the dispatcher's context here and replay
         # it (plus a drain span) around the deferred fn.  Disabled
-        # tracing returns fn unchanged.
-        fn = tracing.bind(fn, "assembler/drain", trees=trees)
+        # tracing returns fn unchanged.  The span says which unit it
+        # drains (ISSUE 35): the index of its first tree, and the
+        # iteration it was dispatched under where the caller's span
+        # says one, so an update() that waits can be paired with the
+        # tree it waited for by name and not by order.
+        trees = max(1, int(trees))
+        first = self._submitted
+        self._submitted += trees
+        if tracing.enabled():
+            unit = {"trees": trees, "tree": first}
+            iteration = tracing.ambient("iteration")
+            if iteration is not None:
+                unit["iteration"] = iteration
+            fn = tracing.bind(fn, "assembler/drain", **unit)
         with self._cv:
             if self._error is not None:
                 err, self._error = self._error, None
                 raise err
             if len(self._fifo) >= self.depth:
                 # back-pressure: the seconds the dispatch thread waits
-                # for the device (and the worker) to catch up
-                with tracing.span("assembler/wait", pending=len(self._fifo)):
+                # for the device (and the worker) to catch up; the unit
+                # in flight is the one whose end lets this one in
+                with tracing.span("assembler/wait", pending=len(self._fifo),
+                                  awaits=self._fifo[0][2]):
                     while len(self._fifo) >= self.depth:
                         self._cv.wait()
                         if self._error is not None:
                             err, self._error = self._error, None
                             raise err
-            self._fifo.append((fn, max(1, int(trees))))
+            self._fifo.append((fn, trees, first))
             # live queue depth (ISSUE 9): how far the device is running
             # ahead of the host model right now
             telemetry.gauge("lgbm_pipeline_queue_depth").set(
@@ -105,7 +121,7 @@ class TreeAssembler:
                     self._cv.wait()
                 if not self._fifo:
                     return
-                fn, _n = self._fifo[0]  # keep queued: in-flight counts
+                fn = self._fifo[0][0]   # keep queued: in-flight counts
                                         # against the depth bound
             try:
                 fn()
@@ -124,7 +140,8 @@ class TreeAssembler:
         first deferred error.  Idempotent; cheap when already empty."""
         with self._cv:
             if self._fifo:
-                with tracing.span("assembler/wait", pending=len(self._fifo)):
+                with tracing.span("assembler/wait", pending=len(self._fifo),
+                                  awaits=self._fifo[-1][2]):
                     while self._fifo:
                         self._cv.wait()
             self._stopping = True

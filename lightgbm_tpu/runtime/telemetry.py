@@ -54,6 +54,7 @@ import json
 import math
 import os
 import re
+import statistics
 import sys
 import threading
 import time
@@ -762,6 +763,7 @@ def render_prometheus() -> str:
 
 def reset() -> None:
     REGISTRY.reset()
+    _ITERATION_WALLS.clear()
 
 
 # ---------------------------------------------------------------------------
@@ -962,11 +964,28 @@ def span(name: str):
 # per-iteration training instrumentation (the Booster.update seam)
 # ---------------------------------------------------------------------------
 
+#: wall ns of the last iterations in which nothing compiled, this one's
+#: yardstick (ISSUE 35).  It starts over when one compiles: a new
+#: program has a new pace, and a process may train more than one model
+_ITERATION_WALLS: "collections.deque[int]" = collections.deque(maxlen=32)
+#: an iteration this many times the median of `_ITERATION_WALLS`, once
+#: that holds `STALL_MIN_HISTORY`, is a stall and gets its account,
+#: unless it lost less than `STALL_MIN_EXCESS_NS` by it: a 4 ms
+#: iteration that once takes 13 holds nobody up
+STALL_RATIO = 3.0
+STALL_MIN_HISTORY = 8
+STALL_MIN_EXCESS_NS = 50_000_000
+
+
 @contextlib.contextmanager
 def train_iteration():
     """Wraps one boosting iteration: wall time into the iteration
     histogram, the iteration counter, the per-iteration sync-audit
-    gauges (total + critical path), and the training profiler hook."""
+    gauges (total + critical path), the training profiler hook, and
+    the iteration's own account: the `train/iteration` span carries its
+    number in the process and what the kernel says the thread and the
+    process did meanwhile (`tracing.Live.account`), and one that took
+    `STALL_RATIO` times the usual gets a verdict (`_report_stall`)."""
     if not _enabled:
         yield
         return
@@ -974,17 +993,181 @@ def train_iteration():
     profile_hook("train").tick()
     s0 = syncs.snapshot()
     t0 = time.monotonic()
+    done = REGISTRY.counter("lgbm_train_iterations_total")
     # one causal slice per boosting iteration: dispatch marks and the
     # assembler drain hand-off recorded inside parent under it
-    with tracing.span("train/iteration"):
+    with tracing.span("train/iteration", iteration=int(done.value())) as it:
+        if it is not None:
+            it.account(process=True)
+            place = tracing.mark()
         yield
     dt = time.monotonic() - t0
     d = syncs.delta(s0)
     REGISTRY.histogram("lgbm_train_iteration_seconds").observe(dt)
-    REGISTRY.counter("lgbm_train_iterations_total").inc()
+    done.inc()
     g = REGISTRY.gauge("lgbm_train_host_syncs_per_iter")
     g.set(d["total"], path="total")
     g.set(d["critical_path"], path="critical")
+    if it is not None:
+        _judge_iteration(it, tracing.since(place))
+
+
+def _judge_iteration(it: "tracing.Live", events: List[dict]) -> None:
+    """The stall rule: `it` (closed) against the median of the
+    iterations before it; `events` are the ring's since it opened."""
+    compiles = [e for e in events if e["name"].startswith("xla compile ")]
+    walls = _ITERATION_WALLS
+    if len(walls) >= STALL_MIN_HISTORY:
+        median = int(statistics.median(walls))
+        if it.dur_ns > max(STALL_RATIO * median,
+                           median + STALL_MIN_EXCESS_NS):
+            _report_stall(it, events, compiles, median)
+    if compiles:
+        walls.clear()
+    else:
+        walls.append(it.dur_ns)
+
+
+def _own_times(root: dict, events: List[dict]) -> List[Tuple[dict, int]]:
+    """(span, own ns) for `root` and every span under it on ITS thread:
+    a span's own time is its length less its children's there."""
+    kids: Dict[str, List[dict]] = {}
+    for e in events:
+        if e["ph"] == "X" and e["tid"] == root["tid"] and "parent" in e:
+            kids.setdefault(e["parent"], []).append(e)
+    out, todo = [], [root]
+    while todo:
+        s = todo.pop()
+        own = kids.get(s["span"], ())
+        out.append((s, s["dur_ns"] - sum(k["dur_ns"] for k in own)))
+        todo.extend(own)
+    return out
+
+
+def _span_kind(name: str) -> str:
+    if name == "host/gc":
+        return "gc"
+    if name == "assembler/wait":
+        return "wait"
+    for kind in ("launch", "fetch"):
+        if name.startswith(kind + "/"):
+            return kind
+    return "self"
+
+
+def _thread_account(root: dict, events: List[dict]) -> Dict[str, Any]:
+    """One thread's seconds inside `root`, split twice, each adding up
+    to the span's wall: by span (own time of `launch/*`,
+    `assembler/wait`, `fetch/*`, `host/gc`; `self` is whatever else ran
+    there, the root itself included) and by what the thread was doing
+    (on a CPU, runnable and waiting for one, asleep: the rest).
+    `busiest` is the span with the most own time outside waits,
+    fetches and collections, where Python itself ran."""
+    by_span = dict.fromkeys(("launch", "wait", "fetch", "gc", "self"), 0)
+    busiest, busiest_ns = root["name"], -1
+    for s, own in _own_times(root, events):
+        kind = _span_kind(s["name"])
+        by_span[kind] += own
+        if kind in ("self", "launch") and own > busiest_ns:
+            busiest, busiest_ns = s["name"], own
+    labels = root.get("args", {})
+    cpu, runq = labels.get("cpu_ns", 0), labels.get("runq_ns", 0)
+    return {"wall": root["dur_ns"], "by_span": by_span, "busiest": busiest,
+            "cpu": cpu, "runq": runq,
+            "asleep": max(root["dur_ns"] - cpu - runq, 0)}
+
+
+def _longest(events: List[dict], tid: int, kind: str) -> Optional[str]:
+    """The name of the longest span of that kind on the thread."""
+    found = [e for e in events
+             if e["tid"] == tid and _span_kind(e["name"]) == kind]
+    return max(found, key=lambda e: e["dur_ns"])["name"] if found else None
+
+
+def _report_stall(it: "tracing.Live", events: List[dict],
+                  compiles: List[dict], median: int) -> None:
+    """One iteration out of line: its account, ONE verdict, one
+    `train/stall` event in the ring and one warning line.
+
+    The verdict is the first of these that explains half of what the
+    iteration took beyond the median: `compile` (a program was traced
+    and built inside it), `gc` (a collection, on any thread: it holds
+    the interpreter), `host_runq` (the dispatch thread or the awaited
+    drain was runnable and not run), `host_cpu` (Python itself was
+    busy: `what` names the span), `launch_blocked` (the runtime's
+    enqueue did not return: asleep inside `launch/*`), `tree_late` (the
+    dispatch thread slept in `assembler/wait` and the awaited drain in
+    its fetch, or the dispatch thread in a fetch of its own: the
+    device or the runtime delivered late), else `unnamed`."""
+    from ..utils.log import Log
+    root = next((e for e in events if e.get("span") == it[1]), None)
+    if root is None:                # the ring was emptied meanwhile
+        return
+    lo, hi = it.t0_ns, it.t0_ns + it.dur_ns
+    mine = _thread_account(root, events)
+    awaited = {e["args"]["awaits"] for e in events
+               if e["name"] == "assembler/wait" and e["tid"] == root["tid"]
+               and e["t_ns"] >= lo and "awaits" in e.get("args", {})}
+    drains = [_thread_account(e, events) for e in events
+              if e["name"] == "assembler/drain"
+              and e.get("args", {}).get("tree") in awaited]
+    drain = {key: sum(d[key] for d in drains)
+             for key in ("wall", "cpu", "runq", "asleep")}
+    drain["by_span"] = {kind: sum(d["by_span"][kind] for d in drains)
+                        for kind in mine["by_span"]}
+    # one thread collects at a time: the spans never overlap
+    collections_ns = sum(
+        max(0, min(e["t_ns"] + e["dur_ns"], hi) - max(e["t_ns"], lo))
+        for e in events if e["name"] == "host/gc")
+
+    busy = max(drains + [mine],
+               key=lambda t: t["cpu"] - t["by_span"]["gc"])
+    candidates = [
+        ("compile", sum(e["dur_ns"] for e in compiles),
+         max(compiles, key=lambda e: e["dur_ns"],
+             default=root).get("args", {}).get("site")),
+        ("gc", collections_ns, None),
+        ("host_runq", mine["runq"] + drain["runq"], None),
+        ("host_cpu", mine["cpu"] + drain["cpu"] - mine["by_span"]["gc"]
+         - drain["by_span"]["gc"], busy["busiest"]),
+        ("launch_blocked", mine["by_span"]["launch"],
+         _longest(events, root["tid"], "launch")),
+        ("tree_late", mine["by_span"]["fetch"]
+         + min(mine["by_span"]["wait"], drain["by_span"]["fetch"]),
+         "tree %s" % ",".join(str(t) for t in sorted(awaited))
+         if awaited else _longest(events, root["tid"], "fetch")),
+    ]
+    excess = it.dur_ns - median
+    verdict, what = next(((v, w) for v, ns, w in candidates
+                          if 2 * ns >= excess), ("unnamed", None))
+
+    labels = it.labels
+    fields: Dict[str, Any] = {
+        "iteration": labels.get("iteration"), "wall_ns": it.dur_ns,
+        "median_ns": median, "verdict": verdict}
+    if what is not None:
+        fields["what"] = what
+    for kind, ns in mine["by_span"].items():
+        fields[kind + "_ns"] = ns
+    fields.update(cpu_ns=mine["cpu"], runq_ns=mine["runq"],
+                  asleep_ns=mine["asleep"], gc_any_thread_ns=collections_ns,
+                  compile_ns=candidates[0][1])
+    if drains:
+        fields["drain_ns"] = drain["wall"]
+        for kind in ("launch", "fetch", "gc", "self"):
+            fields["drain_%s_ns" % kind] = drain["by_span"][kind]
+        fields.update(drain_cpu_ns=drain["cpu"], drain_runq_ns=drain["runq"],
+                      drain_asleep_ns=drain["asleep"])
+    for key in ("majflt", "minflt", "sys_ns", "nivcsw"):
+        if key in labels:
+            fields[key] = labels[key]
+    with tracing.attach(it):
+        tracing.instant("train/stall", **fields)
+    Log.warning("train/stall " + " ".join(
+        "%s=%s" % (key[:-3] + "_ms" if key.endswith("_ns") else key,
+                   "%.3f" % (value / 1e6) if key.endswith("_ns")
+                   else str(value).replace(" ", "_"))
+        for key, value in fields.items()))
 
 
 # ---------------------------------------------------------------------------

@@ -44,6 +44,17 @@ publish/subscribe, subprocess launches):
   events (`record`: watchdog stage closes, compiles) have no live
   interval and stay ring-only.
 
+* **What the host did to a span** (ISSUE 35).  A live span's handle
+  takes labels until the span closes (`Live.labels`), and `Live.account`
+  makes the kernel's own account of the thread between that call and
+  the close into labels of the ONE event: CPU time (``cpu_ns``), time
+  runnable and not run (``runq_ns``, Linux) and, for the process, system
+  time, page faults and involuntary switches.  A garbage collection is
+  a live span too, ``host/gc``, on the thread that collected
+  (`gc.callbacks`; there while the recorder is enabled).  So a long
+  wait can be told from a thread that was not scheduled, one that
+  collected garbage, and a device that delivered late.
+
 The hot-loop contract matches PR 9's: every recording call checks the
 module enable flag first, so with tracing disabled each site costs one
 global read + an early return (the BENCH ``telemetry`` section asserts
@@ -58,6 +69,8 @@ from __future__ import annotations
 import atexit
 import collections
 import contextlib
+import gc
+import itertools
 import json
 import os
 import socket
@@ -70,15 +83,20 @@ from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 from .resilience import atomic_write
 
+try:
+    import resource
+except ImportError:             # no Unix: a span carries no process account
+    resource = None
+
 __all__ = [
     "TRACE_RING_EVENTS", "TRACE_DIR_ENV", "TRACEPARENT_ENV",
     "TRACE_ENABLED_ENV",
     "set_enabled", "enabled", "reset", "set_context",
-    "span", "instant", "record",
+    "span", "instant", "record", "Live", "ambient", "mark", "since",
     "current", "current_traceparent", "context", "attach", "bind",
     "make_traceparent", "parse_traceparent", "process_root",
     "flow_id", "flow_start", "flow_end",
-    "export_chrome", "export_to_dir", "merge_traces", "ring_summary",
+    "export_chrome", "export_to_dir", "merge_traces",
     "maybe_autostart",
 ]
 
@@ -117,10 +135,12 @@ _ANCHOR_UNIX_NS = time.time_ns()
 
 def set_enabled(on: bool) -> bool:
     """Flip the recorder; returns the previous state.  Disabled, every
-    recording call is one global read + an early return."""
+    recording call is one global read + an early return, and the
+    collector calls nothing of ours."""
     global _enabled
     prev = _enabled
     _enabled = bool(on)
+    _hook_gc(_enabled)
     return prev
 
 
@@ -136,17 +156,18 @@ def mono_to_unix_ns(t_ns: int) -> int:
 # ids + traceparent
 # ---------------------------------------------------------------------------
 
-_id_lock = threading.Lock()
-_id_state = struct.unpack("<Q", os.urandom(8))[0] | 1
+_id_seed = struct.unpack("<Q", os.urandom(8))[0] | 1
+_id_step = itertools.count(1)
 
 
 def _next_id64() -> int:
-    """Cheap process-unique 64-bit id stream (splitmix64): one lock'd
-    integer step beats an os.urandom syscall on the request path."""
-    global _id_state
-    with _id_lock:
-        _id_state = (_id_state + 0x9E3779B97F4A7C15) & 0xFFFFFFFFFFFFFFFF
-        z = _id_state
+    """Cheap process-unique 64-bit id stream (splitmix64): one integer
+    step beats an os.urandom syscall on the request path.  The step is
+    `next()` of a counter, atomic without a lock: a collection can start
+    between any two bytecodes of a thread, its `host/gc` span needs an
+    id, and a lock held just then would never be released."""
+    z = (_id_seed + next(_id_step) * 0x9E3779B97F4A7C15) \
+        & 0xFFFFFFFFFFFFFFFF
     z = ((z ^ (z >> 30)) * 0xBF58476D1CE4E5B9) & 0xFFFFFFFFFFFFFFFF
     z = ((z ^ (z >> 27)) * 0x94D049BB133111EB) & 0xFFFFFFFFFFFFFFFF
     return (z ^ (z >> 31)) or 1
@@ -215,6 +236,13 @@ class _Ring:
     def snapshot(self) -> List[dict]:
         with self._lock:
             return list(self._events)
+
+    def newest(self, n: int) -> List[dict]:
+        """The last `n` events, oldest first, at a cost that follows
+        `n` and not the ring."""
+        tail = list(itertools.islice(reversed(self._events), max(n, 0)))
+        tail.reverse()
+        return tail
 
     def clear(self) -> None:
         with self._lock:
@@ -339,7 +367,8 @@ def attach(ctx: Optional[Tuple[str, str]]):
 def bind(fn, name: Optional[str] = None, **labels):
     """Wrap `fn` so it runs under THIS thread's current context when
     invoked later on another thread (the assembler hand-off seam).  With
-    a `name`, the invocation is additionally recorded as a span.
+    a `name`, the invocation is additionally recorded as a span that
+    carries the other thread's host account (`Live.account`).
     Disabled, returns `fn` unchanged — zero indirection on the off
     path."""
     if not _enabled:
@@ -351,7 +380,9 @@ def bind(fn, name: Optional[str] = None, **labels):
     def bound(*a, **k):
         with attach(ctx):
             if name is not None:
-                with span(name, **labels):
+                with span(name, **labels) as handle:
+                    if handle is not None:
+                        handle.account()
                     return fn(*a, **k)
             return fn(*a, **k)
     return bound
@@ -411,40 +442,172 @@ def _annotation(name: str):
     return profiler.TraceAnnotation(ANNOTATION_PREFIX + name)
 
 
+# -- what the kernel says a thread did ---------------------------------------
+
+class _SchedStat:
+    """One thread's ``/proc/thread-self/schedstat``, opened once and
+    read in place: `runq_ns` is its second field, the ns the thread has
+    been runnable and waiting for a CPU.  `fd` is None where the file
+    is not (no Linux, no scheduler statistics)."""
+
+    def __init__(self):
+        try:
+            self.fd = os.open("/proc/thread-self/schedstat", os.O_RDONLY)
+        except OSError:
+            self.fd = None
+
+    def runq_ns(self) -> Optional[int]:
+        if self.fd is None:
+            return None
+        try:
+            return int(os.pread(self.fd, 96, 0).split()[1])
+        except (OSError, IndexError, ValueError):
+            return None
+
+    def __del__(self):
+        if self.fd is not None:
+            os.close(self.fd)
+
+
+def _host_now(process: bool) -> tuple:
+    """(CPU ns, run-queue ns or None) of THIS thread so far and, with
+    `process`, the process's `getrusage`."""
+    stat = getattr(_tls, "schedstat", None)
+    if stat is None:
+        stat = _tls.schedstat = _SchedStat()
+    return (time.thread_time_ns(), stat.runq_ns(),
+            resource.getrusage(resource.RUSAGE_SELF)
+            if process and resource is not None else None)
+
+
+def _host_labels(then: tuple) -> Dict[str, int]:
+    """The labels for what happened since `then` (a `_host_now`)."""
+    cpu0, runq0, ru0 = then
+    cpu1, runq1, ru1 = _host_now(ru0 is not None)
+    out = {"cpu_ns": cpu1 - cpu0}
+    if runq0 is not None and runq1 is not None:
+        out["runq_ns"] = runq1 - runq0
+    if ru0 is not None:
+        out.update(sys_ns=int((ru1.ru_stime - ru0.ru_stime) * 1e9),
+                   minflt=ru1.ru_minflt - ru0.ru_minflt,
+                   majflt=ru1.ru_majflt - ru0.ru_majflt,
+                   nivcsw=ru1.ru_nivcsw - ru0.ru_nivcsw)
+    return out
+
+
+# -- live spans ---------------------------------------------------------------
+
+class Live(tuple):
+    """The handle of an OPEN span: the ``(trace_id, span_id)`` pair a
+    span has always yielded (it compares, indexes and unpacks as one,
+    and is what sits on the thread's context stack), which also takes
+    labels until the span closes.  They land in the span's ONE event."""
+
+    name: str
+    parent: Optional[str]
+    labels: Dict[str, Any]
+    t0_ns: int
+    dur_ns: Optional[int]        # set at the close
+
+    def account(self, process: bool = False) -> None:
+        """From here to the close, on this thread: its CPU time and its
+        time runnable but not run become the labels ``cpu_ns`` and
+        ``runq_ns`` (the second on Linux only); with `process` also the
+        process's ``sys_ns``, ``minflt``, ``majflt`` and ``nivcsw``."""
+        self._host = _host_now(process)
+
+
+def _open(name: str, labels: Dict[str, Any]) -> Live:
+    ctx = current()
+    handle = Live((ctx[0] if ctx is not None else new_trace_id(),
+                   new_span_id()))
+    handle.name = name
+    handle.parent = ctx[1] if ctx is not None else None
+    handle.labels = labels
+    handle._host = None
+    handle.dur_ns = None
+    handle._ann = _annotation(name)
+    if handle._ann is not None:
+        handle._ann.__enter__()
+    _stack().append(handle)
+    handle.t0_ns = time.monotonic_ns()
+    return handle
+
+
+def _close(handle: Live, status: str = "ok") -> None:
+    handle.dur_ns = time.monotonic_ns() - handle.t0_ns
+    if handle._host is not None:
+        handle.labels.update(_host_labels(handle._host))
+    _stack().pop()
+    labels = _clean_labels(handle.labels)
+    if handle._ann is not None:
+        if labels:      # the trace's event takes them as its stats
+            handle._ann.set_metadata(**labels)
+        handle._ann.__exit__(None, None, None)
+    record(handle.name, handle.t0_ns, handle.dur_ns, trace=handle[0],
+           span_id=handle[1], parent=handle.parent, status=status, **labels)
+
+
 @contextlib.contextmanager
 def span(name: str, **labels):
     """Open a live span: a child of the current context (or a fresh
     trace root when there is none), ambient for everything recorded in
-    the scope, one 'X' event at close carrying ok/error status.  While
-    it is open it also holds the profiler annotation of the same name
+    the scope, one 'X' event at close carrying ok/error status and the
+    labels, those given here and those its handle (`Live`, what the
+    `with` yields; None when disabled) was given meanwhile.  While it
+    is open it also holds the profiler annotation of the same name
     (`_annotation`): the ring and the device trace are two sinks of ONE
     span source, and the enable flag turns off both."""
     if not _enabled:
         yield None
         return
-    ctx = current()
-    trace = ctx[0] if ctx is not None else new_trace_id()
-    parent = ctx[1] if ctx is not None else None
-    sid = new_span_id()
-    ann = _annotation(name)
-    if ann is not None:
-        ann.__enter__()
-    st = _stack()
-    st.append((trace, sid))
-    t0 = time.monotonic_ns()
+    handle = _open(name, labels)
     status = "ok"
     try:
-        yield (trace, sid)
+        yield handle
     except BaseException:
         status = "error"
         raise
     finally:
-        dur = time.monotonic_ns() - t0
-        st.pop()
-        if ann is not None:
-            ann.__exit__(None, None, None)
-        record(name, t0, dur, trace=trace,
-               span_id=sid, parent=parent, status=status, **labels)
+        _close(handle, status)
+
+
+def ambient(key: str) -> Any:
+    """The label `key` of the nearest span open on this thread that has
+    it (None when none does): what a seam takes from the span it runs
+    under without being handed it."""
+    for ctx in reversed(_stack()):
+        labels = getattr(ctx, "labels", None)
+        if labels and key in labels:
+            return labels[key]
+    return None
+
+
+# -- a collection is a span ----------------------------------------------------
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    """The `gc.callbacks` entry: ``host/gc`` from a collection's start
+    to its stop, on the thread that collects, under whatever span is
+    open there."""
+    if phase == "start":
+        if _enabled:
+            _tls.gc = _open("host/gc", {"generation": info["generation"]})
+        return
+    handle = getattr(_tls, "gc", None)
+    if handle is not None:
+        _tls.gc = None
+        handle.labels["collected"] = info["collected"]
+        _close(handle)
+
+
+def _hook_gc(on: bool) -> None:
+    if on and _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+    elif not on and _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
+
+
+_hook_gc(_enabled)
 
 
 def instant(name: str, track: Optional[str] = None, **labels) -> None:
@@ -517,12 +680,17 @@ def set_context(name: str) -> None:
     _context_name = str(name)
 
 
-def ring_summary() -> Dict[str, Any]:
-    evs = _RING.snapshot()
-    return {"events": len(evs), "recorded_total": _RING.total,
-            "dropped": _RING.dropped, "capacity": _RING.maxlen,
-            "threads": len({e["tid"] for e in evs}),
-            "traces": len({e.get("trace") for e in evs} - {None})}
+def mark() -> int:
+    """A place in the ring: hand it to `since` later."""
+    return _RING.total
+
+
+def since(place: int) -> List[dict]:
+    """The events recorded since `mark()` returned `place`, oldest
+    first, as the ring holds them (``name``, ``ph``, ``t_ns``,
+    ``dur_ns``, ``tid``, ``span``, ``parent``, ``args``: read, do not
+    write)."""
+    return _RING.newest(_RING.total - place)
 
 
 def export_chrome(path: Optional[str] = None,

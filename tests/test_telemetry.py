@@ -435,6 +435,202 @@ def test_telemetry_disabled_training_still_works():
 
 
 # ---------------------------------------------------------------------------
+# ISSUE 35: an iteration out of line gets its account and ONE verdict
+# ---------------------------------------------------------------------------
+
+@pytest.fixture()
+def stall_log():
+    """The warning lines and `train/stall` events of a test, with the
+    rule's history and the ring empty at both ends."""
+    import gc
+
+    from lightgbm_tpu.runtime import tracing
+    from lightgbm_tpu.utils import log
+    lines = []
+    level = log._level
+    telemetry._ITERATION_WALLS.clear()
+    tracing.reset()
+    log.reset_callback(lines.append)
+    log.reset_log_level(log.LogLevel.WARNING)   # an earlier `verbose: -1`
+
+    class Seen:
+        warnings = lines
+
+        @staticmethod
+        def stalls():
+            return [e for e in tracing.export_chrome()["traceEvents"]
+                    if e["name"] == "train/stall"]
+    try:
+        yield Seen
+    finally:
+        log.reset_callback(None)
+        log.reset_log_level(level)
+        gc.enable()
+        telemetry._ITERATION_WALLS.clear()
+        tracing.reset()
+
+
+def _warmed_booster():
+    """A small booster past its compiles, with the rule's history
+    full enough to judge the next iteration."""
+    from lightgbm_tpu.utils import log
+    bst = _small_booster(n=2000, rounds=3)
+    log.reset_log_level(log.LogLevel.WARNING)   # `verbose: -1` muted it
+    while len(telemetry._ITERATION_WALLS) < telemetry.STALL_MIN_HISTORY + 2:
+        bst.update()
+    bst._drain()
+    return bst
+
+
+def _inject(cause, bst, monkeypatch, seconds=0.4):
+    """Arm ONE stall of the given cause in a coming `update()`."""
+    import gc
+
+    import jax
+
+    eng = bst._engine
+    real_iter = eng.train_one_iter
+    armed = [True]
+
+    def once():
+        fire, armed[0] = armed[0], False
+        return fire
+
+    def before_the_iteration(what):
+        def train_one_iter(*a, **k):
+            if once():
+                what()
+            return real_iter(*a, **k)
+        monkeypatch.setattr(eng, "train_one_iter", train_one_iter)
+
+    def spin():
+        t_end = time.thread_time() + seconds
+        while time.thread_time() < t_end:
+            pass
+
+    if cause == "tree_late":
+        real_get = jax.device_get
+        worker = []
+
+        def slow_get(x):
+            name = threading.current_thread().name
+            if name == "lgbm-tpu-assembler" and once():
+                worker.append(name)
+                time.sleep(seconds)
+            return real_get(x)
+        monkeypatch.setattr(jax, "device_get", slow_get)
+    elif cause == "gc":
+        gc.disable()                    # the garbage waits for collect()
+        heap = []
+        for _ in range(400_000):
+            a, b = [], []
+            a.append(b)
+            b.append(a)
+            heap.append(a)
+        del heap, a, b
+        before_the_iteration(gc.collect)
+    elif cause == "host_cpu":
+        before_the_iteration(spin)
+    elif cause == "unnamed":
+        before_the_iteration(lambda: time.sleep(seconds))
+    elif cause == "launch_blocked":
+        step = eng._fast._step
+        enqueue = step._jitted
+
+        def slow_enqueue(*a, **k):
+            if once():
+                time.sleep(seconds)
+            return enqueue(*a, **k)
+        monkeypatch.setattr(step, "_jitted", slow_enqueue)
+    elif cause == "compile":
+        eng._fast._step.clear_cache()
+    else:
+        raise AssertionError(cause)
+
+
+@pytest.mark.parametrize("cause", ["tree_late", "gc", "host_cpu",
+                                   "launch_blocked", "compile", "unnamed"])
+def test_a_stalled_iteration_gets_one_verdict(cause, stall_log, monkeypatch):
+    from lightgbm_tpu.runtime import tracing
+    bst = _warmed_booster()
+    assert stall_log.stalls() == []
+    _inject(cause, bst, monkeypatch)
+    for _ in range(3):
+        bst.update()
+    bst._drain()
+    stalls = [e for e in stall_log.stalls()
+              if e["args"]["verdict"] == cause]
+    assert len(stalls) == 1, stall_log.stalls()
+    a = stalls[0]["args"]
+    assert stalls[0]["ph"] == "i"
+    assert a["wall_ns"] > telemetry.STALL_RATIO * a["median_ns"]
+    # under the iteration it judges, which carries the same number
+    [it] = [e for e in tracing.export_chrome()["traceEvents"]
+            if e["name"] == "train/iteration"
+            and e["args"]["span"] == a["parent"]]
+    assert it["args"]["iteration"] == a["iteration"]
+    # the two splits of the iteration each add up to its wall
+    by_span = sum(a[k] for k in ("launch_ns", "wait_ns", "fetch_ns",
+                                 "gc_ns", "self_ns"))
+    by_state = a["cpu_ns"] + a["runq_ns"] + a["asleep_ns"]
+    assert by_span == pytest.approx(a["wall_ns"], rel=0.01)
+    assert by_state == pytest.approx(a["wall_ns"], rel=0.01)
+    if cause == "tree_late":
+        # and so do the awaited drain's
+        drain = sum(a["drain_%s_ns" % k]
+                    for k in ("launch", "fetch", "gc", "self"))
+        assert drain == pytest.approx(a["drain_ns"], rel=0.01)
+        assert a["drain_cpu_ns"] + a["drain_runq_ns"] \
+            + a["drain_asleep_ns"] == pytest.approx(a["drain_ns"], rel=0.01)
+        assert a["wait_ns"] >= 0.3e9 and a["drain_fetch_ns"] >= 0.3e9
+        assert a["what"].startswith("tree ")
+    what = {"host_cpu": "train/iteration", "launch_blocked":
+            "launch/gbdt.step", "compile": "gbdt.step"}.get(cause)
+    if what:
+        assert a["what"] == what
+    # ONE line for it, with the same fields, through Log
+    mine = [ln for ln in stall_log.warnings
+            if "train/stall" in ln and "verdict=%s " % cause in ln]
+    assert len(mine) == 1 and mine[0].count("\n") == 1
+    assert "[Warning] train/stall iteration=%d " % a["iteration"] in mine[0]
+    assert "wall_ms=%.3f" % (a["wall_ns"] / 1e6) in mine[0]
+
+
+def _driven_iterations(seconds):
+    for s in seconds:
+        with telemetry.train_iteration():
+            time.sleep(s)
+
+
+@pytest.mark.parametrize("history, expect", [
+    ([0.02] * 14, 0),                   # nothing out of line
+    ([0.005] * 7 + [0.2], 0),           # too little history to judge by
+    ([0.005] * 8 + [0.2], 1),           # the same stall, one more before
+])
+def test_no_stall_no_event_and_no_verdict_on_little_history(
+        history, expect, stall_log):
+    _driven_iterations(history)
+    assert len(stall_log.stalls()) == expect
+    assert len([ln for ln in stall_log.warnings
+                if "train/stall" in ln]) == expect
+    if expect:
+        assert stall_log.stalls()[0]["args"]["verdict"] == "unnamed"
+
+
+def test_a_compile_restarts_the_stall_rules_history(stall_log):
+    from lightgbm_tpu.runtime import tracing
+    _driven_iterations([0.002] * 9)
+    assert len(telemetry._ITERATION_WALLS) == 9
+    with telemetry.train_iteration():
+        tracing.record("xla compile t.site", time.monotonic_ns(), 1000,
+                       track="xla compile", site="t.site")
+    assert len(telemetry._ITERATION_WALLS) == 0
+    _driven_iterations([0.002])
+    assert len(telemetry._ITERATION_WALLS) == 1
+    assert stall_log.stalls() == []
+
+
+# ---------------------------------------------------------------------------
 # acceptance gate 1: live serving /metrics quantiles vs client clocks
 # ---------------------------------------------------------------------------
 

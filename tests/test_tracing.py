@@ -2,6 +2,8 @@
 context propagation across threads and processes, the Chrome-trace /
 merge exporters, the serving stage decomposition pin, and the satellite
 fixes (span-name digit normalization, concurrent-writer integrity)."""
+import contextlib
+import gc
 import json
 import os
 import threading
@@ -128,7 +130,7 @@ def test_disabled_path_records_nothing_and_bind_is_identity():
         assert tracing.bind(fn, "name") is fn
     finally:
         tracing.set_enabled(prev)
-    assert tracing.ring_summary()["recorded_total"] == 0
+    assert tracing.export_chrome()["otherData"]["recorded_total"] == 0
 
 
 # ---------------------------------------------------------------------------
@@ -144,17 +146,21 @@ def test_concurrent_writers_no_torn_or_out_of_order_events(monkeypatch):
             with tracing.span("w%d" % i, j=j):
                 tracing.instant("m%d" % i)
     ts = [threading.Thread(target=work, args=(i,)) for i in range(threads)]
-    for t in ts:
-        t.start()
-    for t in ts:
-        t.join()
-    summary = tracing.ring_summary()
+    gc.disable()                    # a collection would be a span of its own
+    try:
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join()
+        doc = tracing.export_chrome()
+    finally:
+        gc.enable()
+    summary = doc["otherData"]
     assert summary["recorded_total"] == threads * per * 2
-    # bounded: the ring holds the newest `capacity`, the rest counted
-    assert summary["events"] == 1024
-    assert summary["dropped"] == threads * per * 2 - 1024
-    doc = tracing.export_chrome()
     evs = [e for e in doc["traceEvents"] if e["ph"] in ("X", "i")]
+    # bounded: the ring holds the newest `capacity`, the rest counted
+    assert len(evs) == 1024
+    assert summary["dropped"] == threads * per * 2 - 1024
     # no torn event: every record is structurally complete
     for e in evs:
         assert e["name"] and isinstance(e["ts"], float)
@@ -163,7 +169,6 @@ def test_concurrent_writers_no_torn_or_out_of_order_events(monkeypatch):
     # export order is globally monotonic (sorted on the shared clock)
     stamps = [e["ts"] for e in evs]
     assert stamps == sorted(stamps)
-    assert doc["otherData"]["dropped"] == summary["dropped"]
 
 
 # ---------------------------------------------------------------------------
@@ -488,6 +493,147 @@ def test_disabled_recorder_writes_no_annotation_either(tmp_path):
     assert host                     # the session itself recorded
 
 
+# ---------------------------------------------------------------------------
+# ISSUE 35: a span takes labels until it closes, carries what the host
+# did to its thread, and a collection is a span
+# ---------------------------------------------------------------------------
+
+def _one_event(name):
+    [ev] = [e for e in tracing.export_chrome()["traceEvents"]
+            if e["name"] == name]
+    return ev
+
+
+@pytest.mark.parametrize("where", ["same_thread", "bound_thread",
+                                   "error_exit"])
+def test_labels_given_before_the_close_land_in_the_one_event(where):
+    if where == "bound_thread":
+        # the span `bind` opens on the other thread takes that thread's
+        # account at its close
+        with tracing.span("iteration"):
+            fn = tracing.bind(lambda: None, "unit", early=1)
+        t = threading.Thread(target=fn)
+        t.start()
+        t.join(10)
+        assert not t.is_alive()
+    else:
+        with pytest.raises(RuntimeError) if where == "error_exit" \
+                else contextlib.nullcontext():
+            with tracing.span("unit", early=1) as handle:
+                handle.labels["late"] = 7
+                assert tracing.current() == handle
+                assert tracing.ambient("early") == 1
+                if where == "error_exit":
+                    raise RuntimeError("x")
+    ev = _one_event("unit")
+    assert ev["ph"] == "X" and ev["args"]["early"] == 1
+    if where == "bound_thread":
+        assert ev["args"]["cpu_ns"] >= 0
+    else:
+        assert ev["args"]["late"] == 7
+    assert ev["args"].get("status") == \
+        ("error" if where == "error_exit" else None)
+    assert tracing.current() is None and tracing.ambient("early") is None
+
+
+def test_late_labels_are_stats_of_the_profilers_event(tmp_path):
+    import glob
+
+    import jax
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracing.span("unit", early=1) as handle:
+            handle.account()
+            handle.labels["verdict"] = "gc"
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                       recursive=True)
+    found = [dict(e.stats)
+             for plane in jax.profiler.ProfileData.from_file(path).planes
+             for line in plane.lines for e in line.events
+             if e.name == "lgbm/unit"]
+    assert len(found) == 1              # the name stays bare: one event
+    assert found[0]["early"] == 1 and found[0]["verdict"] == "gc"
+    assert found[0]["cpu_ns"] == _one_event("unit")["args"]["cpu_ns"]
+
+
+@pytest.mark.parametrize("process", [False, True])
+def test_account_says_what_the_thread_and_the_process_did(process):
+    with tracing.span("busy") as handle:
+        handle.account(process=process)
+        t_end = time.thread_time() + 0.02
+        while time.thread_time() < t_end:
+            pass
+        time.sleep(0.03)
+    args = _one_event("busy")["args"]
+    wall_ns = _one_event("busy")["dur"] * 1e3
+    assert 19e6 <= args["cpu_ns"] <= wall_ns - 25e6     # it slept too
+    if os.path.exists("/proc/thread-self/schedstat"):
+        assert 0 <= args["runq_ns"] <= wall_ns
+    assert {"sys_ns", "minflt", "majflt", "nivcsw"} <= set(args) \
+        if process else "minflt" not in args
+    # the thread's file stays open between spans: one descriptor
+    stat = tracing._tls.schedstat
+    with tracing.span("again") as handle:
+        handle.account()
+    assert tracing._tls.schedstat is stat
+
+
+@pytest.mark.parametrize("thread", ["main", "other"])
+def test_a_collection_is_a_span_on_the_collecting_thread(thread):
+    seen = {}
+
+    def collect():
+        with tracing.span("outer") as handle:
+            seen["outer"] = handle
+            seen["tid"] = threading.get_ident()
+            gc.collect(1)
+    if thread == "main":
+        collect()
+    else:
+        t = threading.Thread(target=collect)
+        t.start()
+        t.join(10)
+        assert not t.is_alive()
+    evs = [e for e in tracing.export_chrome()["traceEvents"]
+           if e["name"] == "host/gc"
+           and e["args"].get("parent") == seen["outer"][1]]
+    assert [e["args"]["generation"] for e in evs] == [1]
+    assert evs[0]["tid"] == seen["tid"] and evs[0]["ph"] == "X"
+    assert evs[0]["args"]["collected"] >= 0
+    assert evs[0]["args"]["trace"] == seen["outer"][0]
+
+
+def test_no_collection_span_and_no_callback_with_the_recorder_off():
+    assert tracing._on_gc in gc.callbacks
+    prev = tracing.set_enabled(False)
+    try:
+        assert tracing._on_gc not in gc.callbacks
+        gc.collect()
+    finally:
+        tracing.set_enabled(prev)
+    assert (tracing._on_gc in gc.callbacks) == prev
+    assert gc.callbacks.count(tracing._on_gc) <= 1
+    assert not [e for e in tracing.export_chrome()["traceEvents"]
+                if e["name"] == "host/gc"]
+
+
+def test_since_a_mark_is_what_was_recorded_after_it():
+    with tracing.span("before"):
+        pass
+    place = tracing.mark()
+    assert tracing.since(place) == []
+    gc.disable()
+    try:
+        with tracing.span("a"):
+            tracing.instant("b")
+        names = [e["name"] for e in tracing.since(place)]
+    finally:
+        gc.enable()
+    assert names == ["b", "a"]          # in the order they were recorded
+
+
 def test_runtime_package_and_tracing_load_no_jax():
     """`tracing` takes the annotation class from `sys.modules` and does
     without it when jax is not loaded.  The top-level package imports
@@ -505,7 +651,8 @@ def test_runtime_package_and_tracing_load_no_jax():
         "with tracing.span('x'):\n"
         "    pass\n"
         "assert 'jax' not in sys.modules, 'span loaded jax'\n"
-        "assert tracing.ring_summary()['events'] == 1\n"
+        "events = tracing.export_chrome()['traceEvents']\n"
+        "assert 'x' in [e['name'] for e in events if e['ph'] == 'X']\n"
         % os.path.join(root, "lightgbm_tpu"))
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=120)
