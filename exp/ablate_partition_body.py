@@ -4,48 +4,57 @@ piece of the loop body costs a chunk on the chip.
 The profiler's trace ends at the kernel's edge (PERF.md §7: "time inside a
 Pallas kernel" is not seen), so the body is timed by leaving pieces out.
 `_pass_a_kernel` below is pass A of `pallas_segment._acc_kernel` (ring
-read, index arithmetic, one destination one-hot, three part matmuls, two
-rotates of the doubled block, two blends, the flushes), without pass B and
-the final blend read, in two bodies:
+read, index arithmetic with rows in lanes, one destination one-hot, three
+part matmuls, the placement into the two accumulators, the flushes),
+without pass B and the final blend read, in two bodies that differ in the
+placement alone:
 
-    new   as the kernel stands since PR 29: the index arithmetic with rows
-          in lanes (`_acc_kernel.routed`), the chunks of a trip as rows of
-          one [8, C] vector
-    old   as it stood before: every per-row quantity a [C] vector born
-          from a lane reduction, one row a sublane (`_go_left_rows`, a
-          `tri x gl` mat-vec, a relayout of the destination into the
-          one-hot's compare), kept runnable so that both tables come from
+    new   as the kernel stands since PR 36: the sub-tile part of each
+          cursor rides in the one-hot's destination, the [C + 24, P] block
+          is stored once to a scratch and each side is ONE masked store
+          into the tile-aligned [C + 8, P] window of its accumulator
+    old   as it stood from PR 29 to PR 35: a [C, P] block doubled to
+          [2C, P], rotated by a dynamic amount once a side and selected
+          into the whole [2C, P] accumulator, the value column's select
+          over [2C, P] twice; kept runnable so that both tables come from
           one instrument
 
 and with a static set of stubs.  Both bodies:
 
     matmul2  one part matmul of the three (the two others' cost)
     parts    no bf16 hi/mid/lo split (the chunk stands in for each part)
-    rotate   no dynamic rotate of the doubled block
-    blend    the accumulators take 8 rows, not a [2C, P] select
     flush    no write of a full accumulator window to HBM
     body     nothing but the ring read and one add of the chunk (the DMA
              floor)
     rank     the lefts' ranks are the row number (no product)
-    onehot   the [C, C] one-hot is the hoisted triangle (none built a
-             chunk); the old body's is split further:
-               relayout  the one-hot compares with a lane iota (the
-                         destination and the membership are not moved
-                         from sublanes to lanes)
-               compare   the relayout is made (its row stored) but no
-                         [C, C] compare, and, convert
+    onehot   the one-hot is a hoisted triangle (none built a chunk)
     route    the routing is a parity of the row number; split further:
-               colselect  the split column's bins are the row number (old:
-                          no [C, P] select and lane reduction; new: no
+               colselect  the split column's bins are the row number (no
                           masked NT product)
-               mask       (new) the window's other lanes are not zeroed
-                          before the product
+               mask       the window's other lanes are not zeroed before
+                          the product
                predicate  the Bin::Split arithmetic is a parity of the bin
-               catonehot  (old) no [C, B] bitset one-hot and its lane
-                          reduction; `catword` (new): no word select chain
+               catword    no word select chain of the categorical bitset
+
+the placement's own, by body:
+
+    place       (new) the accumulators take 8 rows of the block, not a
+                masked store of an aligned [C + 8, P] window a side
+    blockstore  (new) the block is not stored to its scratch (the value
+                column's select goes with it)
+    rotate      (old) no dynamic rotate of the doubled block
+    blend       (old) the accumulators take 8 rows, not a [2C, P] select
 
 the choices the new body was made from, by race (`--race`):
 
+    place=where   each side a select of the block's window against the
+                  accumulator's window read back, not a masked store
+    onehot=sides  a [C + 8, C] one-hot a side, each applied to the three
+                  parts (six products a chunk), no scratch and no dynamic
+                  slice of the block
+    above=roll    the lefts of a trip's earlier chunks by static sublane
+                  rotates of the lane-reduced counts, not by a second lane
+                  sum over the earlier chunks' rows broadcast down
     rank=roll  the exclusive prefix count by log-step roll-and-add along
                lanes, not `[8, C] x tri_t` on the MXU
     col=xpose  the split column by one XLU transposition of its window
@@ -58,8 +67,9 @@ the choices the new body was made from, by race (`--race`):
 
 and the loop's shape, `group2` / `group4`: that many chunks a loop trip on
 a ring twice as deep.  The product takes 2 at 128 lanes (`_pass_a_group`)
-and 1 in a 512-lane block; the stubs are read on one chunk a trip, where a
-piece's cost is not hidden behind another chunk's.
+and 1 in a 512-lane block; the stubs are read at one chunk a trip, where a
+piece's cost is not hidden behind another chunk's, and the placement's
+also at the shipped trip.
 
 The argument `512` (beside `128`, the default being both) times one
 512-lane column block: the kernel moves a 512-lane payload and routes from
@@ -97,38 +107,46 @@ from jax.experimental.pallas import tpu as pltpu
 from lightgbm_tpu.ops import segment as seg
 from lightgbm_tpu.ops import pallas_segment as pseg
 
-CHUNK, C2 = pseg.CHUNK, pseg.C2
-SHARED_STUBS = ("rank", "onehot", "matmul2", "parts", "rotate", "blend",
-                "flush", "body", "route", "colselect", "predicate")
-OLD_STUBS = SHARED_STUBS + ("catonehot", "relayout", "compare")
-NEW_STUBS = SHARED_STUBS + ("mask", "catword")
-RACES = ("rank=roll", "col=xpose", "col=nt", "col=nthigh", "nl=scalar")
+CHUNK, C2, WIN, BLOCK_ROWS = pseg.CHUNK, pseg.C2, pseg.WIN, pseg.BLOCK_ROWS
+SHARED_STUBS = ("rank", "onehot", "matmul2", "parts", "flush", "body",
+                "route", "colselect", "mask", "predicate", "catword")
+#: the stubs of a body's placement, together its ceiling
+PLACEMENT = {"old": ("rotate", "blend"), "new": ("place", "blockstore")}
+RACES = ("place=where", "onehot=sides", "above=roll", "rank=roll",
+         "col=xpose", "col=nt", "col=nthigh", "nl=scalar")
 
 
-def _pass_a_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
+def _pass_a_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
                    P, B, value_col, stubs, group, body, blocks):
     if blocks:
         route_hbm, *rest = rest
     payload_out, aux_out, nl_out, *rest = rest
-    (ring, lacc, racc, stage, rbuf, win_t, dump, sem_ring, sem_w, sem_r,
+    (ring, lacc, racc, stage, rbuf, blk, win_t, sem_ring, sem_w, sem_r,
      *rest) = rest
     if blocks:
         route_ring, sem_route = rest
+    old, sides_race = body == "old", "onehot=sides" in stubs
     start, count = scalars[0], scalars[1]
     left_value, right_value = fvals[0], fvals[1]
     shift = lax.rem(start, 8)
     base = start - shift
     nch = jnp.where(count > 0, (shift + count + CHUNK - 1) // CHUNK, 0)
-    iota_rows = pseg._row_iota()
     iota_c2 = lax.broadcasted_iota(jnp.int32, (C2, 1), 0)[:, 0]
+    iota_win = lax.broadcasted_iota(jnp.int32, (WIN, 1), 0)
     iota_p = lax.broadcasted_iota(jnp.int32, (1, P), 1)
     # the lanes of the split window: the 128-lane chunk's own, or those of
     # a column block's copy (this script times no other width)
     iota_route = lax.broadcasted_iota(jnp.int32, (1, 128), 1)
     iota_ci = lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 0)
     iota_cj = lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 1)
-    tri = (iota_cj < iota_ci).astype(jnp.float32)
     tri_t = (iota_ci < iota_cj).astype(jnp.float32)
+    # the one-hot's rows: the block's (new), a side's window, the chunk's
+    hot_rows = CHUNK if old else WIN if sides_race else BLOCK_ROWS
+    iota_hot = iota_ci if old else lax.broadcasted_iota(
+        jnp.int32, (hot_rows, CHUNK), 0)
+    iota_b = lax.broadcasted_iota(jnp.int32, (BLOCK_ROWS, 1), 0)
+    tri_hot = (iota_hot < lax.broadcasted_iota(
+        jnp.int32, (hot_rows, CHUNK), 1)).astype(jnp.float32)
     chunk_of_row = lax.broadcasted_iota(jnp.int32, (8, CHUNK), 0)
     row_of_lane = lax.broadcasted_iota(jnp.int32, (8, CHUNK), 1)
     R, G = ring.shape[0], group
@@ -145,14 +163,6 @@ def _pass_a_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
                 route_hbm.at[rows, :], route_ring.at[slot],
                 sem_route.at[slot]))
         return dmas
-
-    def blend(acc, placed, cnt, off, value):
-        if "blend" in stubs:
-            acc[0:8] = placed[0:8]
-            return
-        placed = jnp.where(iota_p == value_col, value, placed)
-        region = ((iota_c2 >= off) & (iota_c2 < off + cnt))[:, None]
-        acc[:] = jnp.where(region, placed, acc[:])
 
     def drain(dst_ref, stage_buf, sem, pend):
         @pl.when(pend > 0)
@@ -176,87 +186,7 @@ def _pass_a_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
                 for dma in read_a(i, i):
                     dma.start()
 
-    def placed_block(data, mat):
-        hi, mid, lo = (data, data, data) if "parts" in stubs \
-            else pseg._bf16_parts(data)
-        perm = jnp.dot(mat, hi, preferred_element_type=jnp.float32)
-        if "matmul2" in stubs:
-            perm = perm + mid + lo
-        else:
-            perm = (perm +
-                    jnp.dot(mat, mid, preferred_element_type=jnp.float32) +
-                    jnp.dot(mat, lo, preferred_element_type=jnp.float32))
-        return jnp.concatenate([perm, perm], axis=0)
-
-    # ---- the body before PR 29: one row a sublane --------------------------
-    def go_left_rows(route):
-        """`pseg._go_left_rows` with its three pieces stubbable."""
-        col, threshold, default_left = scalars[2], scalars[3], scalars[4]
-        is_cat, missing_type, num_bin = scalars[5], scalars[6], scalars[7]
-        default_bin, offset, identity = scalars[8], scalars[9], scalars[10]
-        if "colselect" in stubs:
-            raw = iota_rows
-        else:
-            raw = jnp.sum(jnp.where(iota_route == col, route, 0.0),
-                          axis=1).astype(jnp.int32)
-        if "predicate" in stubs:
-            fbin = raw
-            gl_num = raw & 1
-        else:
-            e = raw - offset
-            in_range = ((e >= 0) & (e < num_bin - 1)).astype(jnp.int32)
-            bump = (e >= default_bin).astype(jnp.int32)
-            decoded = in_range * (e + bump) + (1 - in_range) * default_bin
-            fbin = identity * raw + (1 - identity) * decoded
-            miss = (((missing_type == pseg.MISSING_NAN) &
-                     (fbin == num_bin - 1)).astype(jnp.int32) |
-                    ((missing_type == pseg.MISSING_ZERO) &
-                     (fbin == default_bin)).astype(jnp.int32))
-            gl_num = (miss * default_left +
-                      (1 - miss) * (fbin <= threshold).astype(jnp.int32))
-        if "catonehot" in stubs:
-            gl_cat = fbin & 1
-        else:
-            iota_b = lax.broadcasted_iota(jnp.int32, (CHUNK, B), 1)
-            hits = ((fbin[:, None] == iota_b) &
-                    (bitset_ref[:] > 0)).astype(jnp.int32)
-            gl_cat = (jnp.sum(hits, axis=1) > 0).astype(jnp.int32)
-        return is_cat * gl_cat + (1 - is_cat) * gl_num
-
-    def permuted_old(k, data, route):
-        valid = ((iota_rows >= shift - k * CHUNK) &
-                 (iota_rows < shift + count - k * CHUNK)).astype(jnp.int32)
-        if "route" in stubs:
-            gl = (iota_rows & 1) * valid
-        else:
-            gl = go_left_rows(route) * valid
-        keep_r = valid - gl
-        nlk = jnp.sum(gl)
-        nrk = jnp.sum(keep_r)
-        if "rank" in stubs:
-            rank_l = iota_rows
-        else:
-            rank_l = jnp.dot(tri, gl.astype(jnp.float32)[:, None],
-                             preferred_element_type=jnp.float32)[:, 0] \
-                .astype(jnp.int32)
-        rank_r = jnp.maximum(
-            iota_rows - jnp.maximum(shift - k * CHUNK, 0), 0) - rank_l
-        dest = jnp.where(gl > 0, rank_l, nlk + rank_r)
-        if "onehot" in stubs:
-            mat = tri
-        elif "relayout" in stubs:
-            mat = ((iota_ci == iota_cj + nlk) &
-                   (iota_cj < nrk)).astype(jnp.float32)
-        elif "compare" in stubs:
-            dump[0:1, :] = dest[None, :]
-            dump[1:2, :] = valid[None, :]
-            mat = tri
-        else:
-            mat = ((iota_ci == dest[None, :]) &
-                   (valid[None, :] > 0)).astype(jnp.float32)
-        return placed_block(data, mat), nlk, nrk
-
-    # ---- the body since PR 29: rows in lanes, a trip's chunks together -----
+    # ---- the index arithmetic, rows in lanes: both bodies' ------------------
     def go_left_lanes(raw):
         """`pseg._go_left_lanes` with its pieces stubbable."""
         if "predicate" in stubs:
@@ -265,7 +195,20 @@ def _pass_a_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
             return pseg._go_left_lanes(scalars, raw, B)
         return pseg._go_left_lanes(scalars, raw, 0)   # no word, gl_cat 0
 
-    def routed(k0, windows):
+    def above(x):
+        """[8, C]: under each chunk of a trip, the sum of `x`'s rows of
+        the trip's earlier chunks (a sublane broadcast each; none at
+        G = 1)."""
+        out = jnp.zeros_like(x)
+        for g in range(G - 1):
+            out = out + jnp.where(chunk_of_row > g, x[g:g + 1, :], 0)
+        return out
+
+    def routed(k0, windows, lo_, ro_):
+        """(gl, dest, dest_r): the routing and the one-hot's destination,
+        the parent's (lefts from row 0, rights behind them) or the new
+        body's (each side from its cursor's part under 8); `dest_r` the
+        second one-hot's under `onehot=sides`."""
         if "colselect" in stubs:
             raw = row_of_lane
         elif "col=xpose" in stubs:
@@ -320,36 +263,125 @@ def _pass_a_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
                                nl)
         else:
             nl = jnp.sum(gl, axis=1, keepdims=True)
-        dest = jnp.where(gl > 0, rank_l, nl + rank_r)
-        return gl, jnp.where(valid > 0, dest, -1)
+        if old:
+            dest = jnp.where(gl > 0, rank_l, nl + rank_r)
+            return gl, jnp.where(valid > 0, dest, -1), None
+        # the trip's earlier chunks: their lefts by a second lane sum,
+        # their rows from where the segment lies in the stream
+        if "above=roll" in stubs:
+            nl_above = sum(
+                jnp.where(chunk_of_row >= d, pltpu.roll(
+                    jnp.broadcast_to(nl, gl.shape), d, axis=0), 0)
+                for d in range(1, G)) if G > 1 else 0
+        else:
+            nl_above = jnp.sum(above(gl), axis=1, keepdims=True)
+        span = chunk_of_row * CHUNK
+        n_above = (jnp.clip(shift + count - k0 * CHUNK, 0, span) -
+                   jnp.clip(shift - k0 * CHUNK, 0, span))
+        r_l = (lo_ + nl_above) & 7
+        r_r = (ro_ + n_above - nl_above) & 7
+        if sides_race:
+            return (gl, jnp.where(gl > 0, r_l + rank_l, -1),
+                    jnp.where(valid > gl, r_r + rank_r, -1))
+        dest = jnp.where(gl > 0, r_l + rank_l,
+                         ((r_l + nl + 7) & -8) + r_r + rank_r)
+        return gl, jnp.where(valid > 0, dest, -1), None
 
-    def permuted_new(g, k, data, gl, dest):
+    def product(mat, data):
+        hi, mid, lo = (data, data, data) if "parts" in stubs \
+            else pseg._bf16_parts(data)
+        perm = jnp.dot(mat, hi, preferred_element_type=jnp.float32)
+        if "matmul2" in stubs:
+            rest = mid + lo
+            if perm.shape[0] > CHUNK:
+                rest = jnp.pad(rest, ((0, perm.shape[0] - CHUNK), (0, 0)))
+            return perm + rest
+        return (perm + jnp.dot(mat, mid, preferred_element_type=jnp.float32)
+                + jnp.dot(mat, lo, preferred_element_type=jnp.float32))
+
+    def permuted(g, k, data, gl, dest, dest_r, lo_):
+        """(nlk, nrk, block) of chunk k: the parent's doubled block, the
+        two sides' windows, or None with the block in its scratch."""
         nlk = jnp.sum(jnp.where(chunk_of_row == g, gl, 0))
         lo = jnp.maximum(shift - k * CHUNK, 0)
         hi = jnp.minimum(shift + count - k * CHUNK, CHUNK)
-        mat = tri_t if "onehot" in stubs \
-            else (iota_ci == dest[g:g + 1, :]).astype(jnp.float32)
-        return placed_block(data, mat), nlk, jnp.maximum(hi - lo, 0) - nlk
+        nrk = jnp.maximum(hi - lo, 0) - nlk
 
-    def place(both, nlk, nrk, carry):
-        """Rotate the block to each accumulator's cursor, blend, flush."""
-        nl, nr, lo_, ro_, lfl, rfl, pl_, pr_ = carry
-        if "rotate" in stubs:
-            placed_l = placed_r = both
+        def hot(dest):
+            return tri_hot if "onehot" in stubs \
+                else (iota_hot == dest[g:g + 1, :]).astype(jnp.float32)
+
+        if old:
+            perm = product(hot(dest), data)
+            return nlk, nrk, jnp.concatenate([perm, perm], axis=0)
+        if sides_race:
+            return nlk, nrk, tuple(
+                jnp.where(iota_p == value_col, value, product(hot(d), data))
+                for d, value in ((dest, left_value), (dest_r, right_value)))
+        perm = product(hot(dest), data)
+        if "blockstore" not in stubs:
+            blk[g, 0:BLOCK_ROWS] = jnp.where(
+                iota_p == value_col,
+                jnp.where(iota_b < (lo_ & 7) + nlk, left_value, right_value),
+                perm)
         else:
-            placed_l = pltpu.roll(both, lo_, axis=0)
-            placed_r = pltpu.roll(both, ro_ - nlk + C2, axis=0)
-        blend(lacc, placed_l, nlk, lo_, left_value)
+            blk[g, 0:8] = perm[0:8]
+        return nlk, nrk, None
+
+    # ---- the parent's placement: rotate the doubled block, blend ------------
+    def blend(acc, placed, cnt, off, value):
+        if "blend" in stubs:
+            acc[0:8] = placed[0:8]
+            return
+        placed = jnp.where(iota_p == value_col, value, placed)
+        region = ((iota_c2 >= off) & (iota_c2 < off + cnt))[:, None]
+        acc[:] = jnp.where(region, placed, acc[:])
+
+    # ---- the new placement: a tile-aligned window a side --------------------
+    def put(acc, cursor, cnt, rows):
+        if "place" in stubs:
+            acc[0:8] = rows[0:8]
+            return
+        r = cursor & 7
+        win = pl.ds(pl.multiple_of(cursor - r, 8), WIN)
+        region = (iota_win >= r) & (iota_win < r + cnt)
+        if "place=where" in stubs:
+            acc[win] = jnp.where(region, rows, acc[win])
+        else:
+            pltpu.store(acc.at[win], rows,
+                        mask=jnp.broadcast_to(region, rows.shape))
+
+    def place(g, nlk, nrk, block, carry):
+        """Each side placed and, where its window filled, flushed: the
+        first side, then the staged one, as the kernel orders them."""
+        nl, nr, lo_, ro_, lfl, rfl, pl_, pr_ = carry
+        if old:
+            if "rotate" in stubs:
+                placed_l = placed_r = block
+            else:
+                placed_l = pltpu.roll(block, lo_, axis=0)
+                placed_r = pltpu.roll(block, ro_ - nlk + C2, axis=0)
+            sides = (lambda: blend(lacc, placed_l, nlk, lo_, left_value),
+                     lambda: blend(racc, placed_r, nrk, ro_, right_value))
+        elif sides_race:
+            sides = (lambda: put(lacc, lo_, nlk, block[0]),
+                     lambda: put(racc, ro_, nrk, block[1]))
+        else:
+            tile_r = pl.multiple_of(((lo_ & 7) + nlk + 7) & -8, 8)
+            sides = (lambda: put(lacc, lo_, nlk, blk[g, 0:WIN]),
+                     lambda: put(racc, ro_, nrk, blk[g, pl.ds(tile_r, WIN)]))
         fl = ((lo_ + nlk) >= CHUNK).astype(jnp.int32)
-        blend(racc, placed_r, nrk, ro_, right_value)
         fr = ((ro_ + nrk) >= CHUNK).astype(jnp.int32)
+        sides[0]()
+        if "flush" not in stubs:
+            @pl.when(fl > 0)
+            def _flush_l():
+                flush(lacc, payload_out, base + lfl * CHUNK, stage, sem_w,
+                      pl_)
+        sides[1]()
         if "flush" in stubs:
             return (nl + nlk, nr + nrk, lo_ + nlk - fl * CHUNK,
                     ro_ + nrk - fr * CHUNK, lfl + fl, rfl + fr, pl_, pr_)
-
-        @pl.when(fl > 0)
-        def _flush_l():
-            flush(lacc, payload_out, base + lfl * CHUNK, stage, sem_w, pl_)
 
         @pl.when(fr > 0)
         def _flush_r():
@@ -390,15 +422,15 @@ def _pass_a_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm, *rest,
         def _seed():
             lacc[0:CHUNK] = datas[0]
 
-        if body == "old":
-            chunks = [permuted_old(k0 + i, datas[i], windows[i])
-                      for i in range(G)]
-        else:
-            gl, dest = routed(k0, windows)
-            chunks = [permuted_new(i, k0 + i, datas[i], gl, dest)
-                      for i in range(G)]
-        for chunk in chunks:
-            carry = place(*chunk, carry)
+        lo_, ro_ = carry[2], carry[3]
+        gl, dest, dest_r = routed(k0, windows, lo_, ro_)
+        chunks = []
+        for i in range(G):
+            chunks.append(permuted(i, k0 + i, datas[i], gl, dest, dest_r,
+                                   lo_))
+            lo_ = lo_ + chunks[i][0]
+        for i in range(G):
+            carry = place(i, *chunks[i], carry)
         return carry
 
     out = lax.fori_loop(
@@ -424,7 +456,6 @@ def pass_a(payload, aux, route, start, count, pred, left_value, right_value,
     scalars = pseg._acc_scalars(start, count, pred, pred.col - win_lo,
                                 win_lo, B, False)
     fvals = jnp.stack([left_value, right_value]).astype(jnp.float32)
-    bitset = pred.bitset.astype(jnp.int32).reshape(1, B)
     kern = functools.partial(_pass_a_kernel, P=P, B=B, value_col=value_col,
                              stubs=stubs, group=group, body=body,
                              blocks=blocks)
@@ -434,8 +465,7 @@ def pass_a(payload, aux, route, start, count, pred, left_value, right_value,
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(1,),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM), hbm, hbm]
-            + [hbm] * blocks,
+            in_specs=[hbm, hbm] + [hbm] * blocks,
             out_specs=(hbm, hbm, pl.BlockSpec(memory_space=pltpu.SMEM)),
             scratch_shapes=[
                 pltpu.VMEM((depth, CHUNK, P), jnp.float32),
@@ -443,8 +473,9 @@ def pass_a(payload, aux, route, start, count, pred, left_value, right_value,
                 pltpu.VMEM((C2, P), jnp.float32),
                 pltpu.VMEM((CHUNK, P), jnp.float32),
                 pltpu.VMEM((CHUNK, P), jnp.float32),
+                pltpu.VMEM((group, pseg.BLOCK_SCRATCH_ROWS, P),
+                           jnp.float32),
                 pltpu.VMEM((group, 128, CHUNK), jnp.float32),
-                pltpu.VMEM((8, CHUNK), jnp.int32),
                 pltpu.SemaphoreType.DMA((depth,)),
                 pltpu.SemaphoreType.DMA(()),
                 pltpu.SemaphoreType.DMA(()),
@@ -453,24 +484,29 @@ def pass_a(payload, aux, route, start, count, pred, left_value, right_value,
         out_shape=(jax.ShapeDtypeStruct(payload.shape, payload.dtype),
                    jax.ShapeDtypeStruct(aux.shape, aux.dtype),
                    jax.ShapeDtypeStruct((1,), jnp.int32)),
-        input_output_aliases={3: 0, 4: 1},
+        input_output_aliases={2: 0, 3: 1},
         compiler_params=pseg._SIDE_EFFECTS,
         interpret=interpret,
-    )(scalars, fvals, bitset, payload, aux, *([route] * blocks))
+    )(scalars, fvals, payload, aux, *([route] * blocks))
     return payload_new, aux_new, nl[0]
 
 
 def stub_sets(body, race):
-    """(label, stubs, group) of every kernel timed for `body`."""
-    names = OLD_STUBS if body == "old" else NEW_STUBS
-    sets = [("full", (), 1)] + [(s, (s,), 1) for s in names] + [
+    """(label, stubs, group) of every kernel timed for `body`: each stub
+    at one chunk a trip, the placement's also at two and four, and the
+    loop's shapes."""
+    placement = PLACEMENT[body]
+    sets = [("full", (), 1)] + [(s, (s,), 1)
+                                for s in SHARED_STUBS + placement] + [
         ("matmul2+onehot+rank", ("matmul2", "onehot", "rank"), 1),
-        ("blend+rotate", ("blend", "rotate"), 1),
+        ("placement", placement, 1),
         ("index chain", ("onehot", "rank", "route"), 1),
-        ("group2", (), 2), ("group4", (), 4),
-        ("group2+route", ("route",), 2),
-        ("group2+index chain", ("onehot", "rank", "route"), 2),
-        ("group2+body", ("body",), 2)]
+        ("group2", (), 2), ("group4", (), 4)]
+    for g in (2, 4):
+        sets += [("group%d+%s" % (g, label), stubs, g) for label, stubs in (
+            ("placement", placement), ("flush", ("flush",)),
+            ("index chain", ("onehot", "rank", "route")),
+            ("body", ("body",)))]
     if body == "new" and race:
         for choice in RACES:
             sets += [(choice, (choice,), 1), ("group2+" + choice, (choice,), 2)]

@@ -180,8 +180,10 @@ def partition_rmw():
 
 
 def partition_acc():
-    """partition_segment_acc (placement is a traced sublane pltpu.roll of
-    a [2C, P] concatenate; the index arithmetic has rows in lanes), under
+    """partition_segment_acc (pass A's placement is a masked store of a
+    [C + 8, P] window of the permuted block's scratch, both at traced
+    tile-aligned starts, pass B's a traced sublane pltpu.roll of a [2C, P]
+    concatenate; the index arithmetic has rows in lanes), under
     a numerical predicate, a categorical one whose set bits sit at the
     packed bitset's word edges, and a missing-value one over an
     EFB-decoded column: no cell has a categorical column, so the vector

@@ -17,8 +17,8 @@ kernels keep every one-hot in VMEM:
 - `partition_segment_acc`: the three compact passes of
   `ops.segment.partition_segment` fused into one kernel (`_acc_kernel`);
   each chunk's stable partition is a one-hot permutation matmul in VMEM,
-  rotated into per-side accumulator windows that flush whole aligned
-  chunks.  `partition_segment_acc_blocks` runs the same kernel once a
+  put at the cursors of per-side accumulator windows that flush whole
+  aligned chunks.  `partition_segment_acc_blocks` runs the same kernel once a
   512-lane window of a payload too wide for one pass, routing every pass
   from a snapshot of the split column (`_snap_window_kernel`);
   `partition_segment` (`_partition_kernel`) is the older read-modify-write
@@ -152,8 +152,11 @@ def _acc_plan_bytes(payload_width: int, num_bins: int, group: int) -> int:
     """VMEM plan of the accumulator-window partition kernel with pass A
     taking `group` chunks a loop trip: the read ring (`_RING_DEPTH` groups
     of chunks), two [2C, P] accumulators, stage/blend buffers, the P-wide
-    placement intermediates of each chunk in flight (parts + permuted +
-    doubled + rolled buffers, ~10C rows), the [C, C] machinery (`tri_t`
+    placement intermediates of each chunk in flight (10C rows: the parts,
+    the permuted block's [2C + 24, P] scratch and the windows read from
+    it take some 7C since PR 36; the doubled and the two rotated blocks
+    the plan was written for are gone, and the plan keeps their room, so
+    that the gates admit what they admitted), the [C, C] machinery (`tri_t`
     and the row iota once, a one-hot as a mask and as f32 a chunk in
     flight, and the four more the plan has carried since the widths it
     admits were proven on the chip) and the masked split window of each
@@ -814,6 +817,16 @@ def partition_segment(payload, aux, start, count, pred, left_value,
 
 C2 = 2 * CHUNK
 
+#: rows of a chunk's permuted block (pass A): the first side's rows from
+#: row `cursor & 7` of the block, the staged side's from the same part of
+#: ITS cursor in the first tile behind them: at most 7 + 7 + 7 rows more
+#: than the chunk's own
+BLOCK_ROWS = CHUNK + 24
+#: rows of the block's scratch: the staged side's [C + 8, P] window starts
+#: at a tile up to row C + 8; the rows past the block's own are never
+#: written, and the placement's mask keeps them out
+BLOCK_SCRATCH_ROWS = BLOCK_ROWS + CHUNK
+
 
 def _acc_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
                 P, B, value_col, group=1, lane_lo=None):
@@ -839,11 +852,18 @@ def _acc_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
 
     Pass A places a chunk's rows with ONE permutation, not one compaction
     per side: lefts in order, then rights in order, is a stable partition
-    of the chunk's valid rows, so one destination vector and one [C, C]
-    one-hot applied to the three parts give a [C, P] block with the lefts
-    at rows [0, nl_k) and the rights at [nl_k, nl_k + nr_k).  Each side's
-    placement is then a rotate of the SAME doubled block to its
-    accumulator's cursor (exact data movement).
+    of the chunk's valid rows, so one destination vector and one one-hot
+    applied to the three parts give a block with each side's rows in one
+    run.  The one-hot puts a row where it is told at no cost, so it is
+    told the part of each accumulator's cursor that is no multiple of a
+    sublane tile: with a cursor written 8 q + r, the lefts go to rows
+    [r_l, r_l + nl_k) of a [C + 24, P] block and the rights to
+    [s, s + nr_k), s the first row behind the lefts that is r_r modulo 8.
+    Each side's placement is then ONE masked store into the tile-aligned
+    [C + 8, P] window of its accumulator from tile q, of the window of
+    the block that starts at the side's own tile (`put`): no rotate,
+    nothing of the accumulators' [2C, P] computed or read (PERF.md §6,
+    PR 36; pass B, whose rows arrive contiguous, keeps its one rotate).
 
     Where the 256 rows of a chunk go is arithmetic on 256 numbers, and it
     is done with ROWS IN LANES, the chunks of a trip as the rows of one
@@ -861,17 +881,21 @@ def _acc_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
     quantity born from a lane reduction lives one row a SUBLANE, one lane
     in 128 at work: that was 640 of a chunk's 1,600 ns.)  Per chunk: 4
     MXU contractions (the column, the 3 parts) and a share of the trip's
-    rank product, one [C, C] matrix built on the VPU, two rotates, two
-    blends; what does not depend on the chunk (`tri_t`, the index planes,
-    the column's one-hot row) is built once before the loop.
+    rank product, one [C + 24, C] matrix built on the VPU, one store of
+    the block, two masked stores of its windows; what does not depend on
+    the chunk
+    (`tri_t`, the index planes, the column's one-hot row) is built once
+    before the loop.
 
     That body is one dependent chain (routing -> rank -> one-hot ->
-    matmuls -> rotate -> blend) and the chip runs it at the chain's
+    matmuls -> block -> windows) and the chip runs it at the chain's
     latency, not at any unit's rate (PERF.md §6, PR 25), so pass A takes
     `group` chunks a loop trip: every wait and load first, then the
-    trip's index arithmetic, then each chunk's permuted block, which
-    depends on no cursor, then the blocks placed in order.  The chains of
-    a trip share a basic block and interleave.  The ring holds its depth
+    trip's index arithmetic (the cursors' parts under 8 at each chunk are
+    the carried cursors plus the counts of the trip's earlier chunks: no
+    accumulator is read), then each chunk's permuted block into a scratch
+    of its own, then the blocks placed in order.  The chains of a trip
+    share a basic block and interleave.  The ring holds its depth
     in groups; a trip's chunks past the segment's last are not read and
     count as empty.
 
@@ -890,7 +914,7 @@ def _acc_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
     if blocks:
         route_hbm, *rest = rest
     payload_out, aux_out, nl_out, *rest = rest
-    ring, lacc, racc, stage, rbuf, sem_ring, sem_w, sem_r, *rest = rest
+    ring, lacc, racc, stage, rbuf, blk, sem_ring, sem_w, sem_r, *rest = rest
     if blocks:
         route_ring, sem_route = rest
     start = scalars[0]
@@ -903,6 +927,7 @@ def _acc_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
     nch = jnp.where(count > 0, (shift + count + CHUNK - 1) // CHUNK, 0)
     iota_rows = _row_iota()
     iota_c2 = lax.broadcasted_iota(jnp.int32, (C2, 1), 0)[:, 0]
+    iota_win = lax.broadcasted_iota(jnp.int32, (WIN, 1), 0)
     iota_p = lax.broadcasted_iota(jnp.int32, (1, P), 1)
     # the split column's 128-lane window of a chunk (in ring slot `slot`,
     # loaded as `data`) and the column's place in it: a column block's
@@ -952,6 +977,8 @@ def _acc_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
     # more vregs than the replicated layout holds (hardware-bisected,
     # round 4).
     iota_ci = lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 0)
+    iota_bi = lax.broadcasted_iota(jnp.int32, (BLOCK_ROWS, CHUNK), 0)
+    iota_b = lax.broadcasted_iota(jnp.int32, (BLOCK_ROWS, 1), 0)
     # tri_t[j, i] = 1 where row j comes before row i: gl x tri_t is the
     # exclusive prefix count of the lefts (<= C, exact in one bf16 pass)
     tri_t = (iota_ci < lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 1)
@@ -968,26 +995,29 @@ def _acc_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
                  route_col).astype(jnp.float32)
 
     def blend(acc, placed, cnt, off, value):
-        """Write the child's tree output into the value column of the
-        placed rows and blend region [off, off+cnt) into the accumulator.
-        where, NOT an arithmetic blend: rows outside the region may hold
-        uninitialized accumulator memory, and 0 * NaN poisons a multiply."""
+        """Pass B's: write the child's tree output into the value column
+        of the placed rows and blend region [off, off+cnt) into the
+        accumulator.  where, NOT an arithmetic blend: rows outside the
+        region may hold uninitialized accumulator memory, and 0 * NaN
+        poisons a multiply."""
         placed = jnp.where(iota_p == value_col, value, placed)
         region = ((iota_c2 >= off) & (iota_c2 < off + cnt))[:, None]
         acc[:] = jnp.where(region, placed, acc[:])
 
-    def permute_doubled(parts, dest):
-        """[2C, P], twice the [C, P] block in which source row j sits at
-        row dest[0, j] (-1: nowhere): one 0/1 one-hot, the lane-major
-        destination broadcast along sublanes, applied to the exact parts
-        (three one-pass matmuls).  Doubled so that a rotate by any cursor
-        difference places a run of it without wrap-around."""
-        mat = (iota_ci == dest).astype(jnp.float32)              # [C, C]
-        hi, mid, lo = parts
-        perm = (jnp.dot(mat, hi, preferred_element_type=jnp.float32) +
-                jnp.dot(mat, mid, preferred_element_type=jnp.float32) +
-                jnp.dot(mat, lo, preferred_element_type=jnp.float32))
-        return jnp.concatenate([perm, perm], axis=0)
+    def put(acc, cursor, cnt, rows):
+        """Rows [r, r + cnt) of `rows` ([C + 8, P]; r = cursor & 7, where
+        the one-hot put them) to the accumulator's rows [cursor,
+        cursor + cnt): ONE masked store of the TILE-ALIGNED window that
+        holds them, no rotate and no read of the accumulator (a select
+        against the window read back took 1% longer at 128 lanes and 3%
+        in a 512-lane block, PERF.md §6, PR 36).  Nothing arithmetic
+        touches a row outside the region: it may hold uninitialized
+        memory on either side."""
+        r = cursor & 7
+        win = pl.ds(pl.multiple_of(cursor - r, 8), WIN)
+        region = (iota_win >= r) & (iota_win < r + cnt)
+        pltpu.store(acc.at[win], rows,
+                    mask=jnp.broadcast_to(region, rows.shape))
 
     def drain(dst_ref, stage_buf, sem, pend):
         """Wait a still-flying flush before its staging buffer/semaphore
@@ -1027,13 +1057,26 @@ def _acc_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
 
     # ---- pass A: one read of the segment; lefts accumulate toward payload
     # windows, rights accumulate toward aux staging windows -------------
-    def routed(k0, windows):
+    def above(x):
+        """[8, C]: under each chunk of a trip, the sum of `x`'s rows of
+        the trip's earlier chunks (a sublane broadcast each; none at
+        G = 1)."""
+        out = jnp.zeros_like(x)
+        for g in range(G - 1):
+            out = out + jnp.where(chunk_of_row > g, x[g:g + 1, :], 0)
+        return out
+
+    def routed(k0, windows, lo_, ro_):
         """(gl, dest) of the trip's chunks k0 .. k0 + G - 1, [8, C] i32
         with a chunk a row and the chunk's rows in lanes: the routing
         under the validity mask, and where the chunk's ONE stable
-        partition puts each row (lefts to [0, nl_k), rights to
-        [nl_k, nl_k + nr_k), both in original order; a row outside the
-        segment to -1, which is no row).  `windows` hold the split
+        partition puts each row of its block: the lefts from row
+        r_l = (the left cursor at that chunk) & 7, the rights from row
+        r_r = (the right cursor) & 7 of the first tile behind them, both
+        in original order; a row outside the segment to -1, which is no
+        row.  The cursors at a chunk are the carried ones (`lo_`, `ro_`)
+        plus the counts of the trip's earlier chunks; a flush moves a
+        cursor by CHUNK, which is 0 modulo 8.  `windows` hold the split
         column: [C, 128] of the chunk, or of a column block's snapshot."""
         raw = None
         for g, window in enumerate(windows):
@@ -1059,34 +1102,59 @@ def _acc_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
         # are those that are not lefts: no second prefix count
         rank_r = jnp.maximum(row_of_lane - jnp.maximum(first, 0), 0) - rank_l
         nl = jnp.sum(gl, axis=1, keepdims=True)
-        dest = jnp.where(gl > 0, rank_l, nl + rank_r)
+        # the trip's earlier chunks: their lefts by a second lane sum,
+        # their rows from where the segment lies in the stream
+        nl_above = jnp.sum(above(gl), axis=1, keepdims=True)
+        span = chunk_of_row * CHUNK
+        n_above = (jnp.clip(shift + count - k0 * CHUNK, 0, span) -
+                   jnp.clip(shift - k0 * CHUNK, 0, span))
+        r_l = (lo_ + nl_above) & 7
+        r_r = (ro_ + n_above - nl_above) & 7
+        dest = jnp.where(gl > 0, r_l + rank_l,
+                         ((r_l + nl + 7) & -8) + r_r + rank_r)
         return gl, jnp.where(valid > 0, dest, -1)
 
-    def permuted(g, k, data, gl, dest):
-        """(nlk, nrk, block) of chunk k, row g of the trip's index
-        vectors: what of its placement depends on no cursor and no
-        accumulator, so that the chunks of a trip are independent up to
-        here."""
+    def permuted(g, k, data, gl, dest, lo_):
+        """(nlk, nrk) of chunk k, row g of the trip's index vectors, and
+        its [C + 24, P] block into its scratch: source row j at row
+        dest[g, j] (-1: nowhere) by one 0/1 one-hot, the lane-major
+        destination broadcast along sublanes, applied to the exact parts
+        (three one-pass matmuls); the value column takes the first
+        child's output in the rows under the first side's end and the
+        staged child's from there on.  This is what of a chunk's
+        placement reads no accumulator, so that the chunks of a trip are
+        independent up to here.  `lo_` is the left cursor at this chunk
+        up to flushes."""
         nlk = jnp.sum(jnp.where(chunk_of_row == g, gl, 0))
-        lo = jnp.maximum(shift - k * CHUNK, 0)
-        hi = jnp.minimum(shift + count - k * CHUNK, CHUNK)
-        return nlk, jnp.maximum(hi - lo, 0) - nlk, permute_doubled(
-            _bf16_parts(data), dest[g:g + 1, :])
+        j0 = jnp.maximum(shift - k * CHUNK, 0)
+        j1 = jnp.minimum(shift + count - k * CHUNK, CHUNK)
+        mat = (iota_bi == dest[g:g + 1, :]).astype(jnp.float32)
+        hi, mid, lo = _bf16_parts(data)
+        perm = (jnp.dot(mat, hi, preferred_element_type=jnp.float32) +
+                jnp.dot(mat, mid, preferred_element_type=jnp.float32) +
+                jnp.dot(mat, lo, preferred_element_type=jnp.float32))
+        if value_col >= 0:
+            perm = jnp.where(
+                iota_p == value_col,
+                jnp.where(iota_b < (lo_ & 7) + nlk, left_value, right_value),
+                perm)
+        blk[g, 0:BLOCK_ROWS] = perm
+        return nlk, jnp.maximum(j1 - j0, 0) - nlk
 
-    def place(nlk, nrk, block, carry):
+    def place(g, nlk, nrk, carry):
         nl, nr, lo_, ro_, lfl, rfl, pl_, pr_ = carry
-        # each side is a rotate of the same doubled block to its cursor
-        # (the rotate-by-a-difference of pass B)
-        placed_l = pltpu.roll(block, lo_, axis=0)
-        placed_r = pltpu.roll(block, ro_ - nlk + C2, axis=0)
-        blend(lacc, placed_l, nlk, lo_, left_value)
+        # each side is a tile-aligned window of the block, put at the
+        # tile of its cursor: the first side's the block's head, the
+        # staged side's from the first tile behind the first side's rows
+        tile_r = pl.multiple_of(((lo_ & 7) + nlk + 7) & -8, 8)
+        put(lacc, lo_, nlk, blk[g, 0:WIN])
         fl = ((lo_ + nlk) >= CHUNK).astype(jnp.int32)
 
         @pl.when(fl > 0)
         def _flush_l():
             flush(lacc, payload_out, base + lfl * CHUNK, stage, sem_w, pl_)
 
-        blend(racc, placed_r, nrk, ro_, right_value)
+        put(racc, ro_, nrk, blk[g, pl.ds(tile_r, WIN)])
         fr = ((ro_ + nrk) >= CHUNK).astype(jnp.int32)
 
         @pl.when(fr > 0)
@@ -1128,12 +1196,16 @@ def _acc_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
             # full-window write
             lacc[0:CHUNK] = datas[0]
 
+        lo_, ro_ = carry[2], carry[3]
         gl, dest = routed(k0, [split_window(slot, data)
-                               for slot, data in zip(slots, datas)])
-        permuted_chunks = [permuted(i, k0 + i, datas[i], gl, dest)
-                           for i in range(G)]
-        for chunk in permuted_chunks:
-            carry = place(*chunk, carry)
+                               for slot, data in zip(slots, datas)],
+                          lo_, ro_)
+        counts = []
+        for i in range(G):
+            counts.append(permuted(i, k0 + i, datas[i], gl, dest, lo_))
+            lo_ = lo_ + counts[i][0]
+        for i in range(G):
+            carry = place(i, *counts[i], carry)
         return carry
 
     (num_left, num_right, lo_, ro_, lfl, rfl, pl_, pr_) = lax.fori_loop(
@@ -1247,6 +1319,8 @@ def _partition_segment_acc(payload, aux, start, count, pred, left_value,
                 pltpu.VMEM((C2, P), jnp.float32),         # right accumulator
                 pltpu.VMEM((CHUNK, P), jnp.float32),      # flush stage
                 pltpu.VMEM((CHUNK, P), jnp.float32),      # final blend read
+                pltpu.VMEM((group, BLOCK_SCRATCH_ROWS, P),
+                           jnp.float32),                  # permuted blocks
                 pltpu.SemaphoreType.DMA((_RING_DEPTH * group,)),
                 pltpu.SemaphoreType.DMA(()),
                 pltpu.SemaphoreType.DMA(()),
@@ -1376,6 +1450,8 @@ def _partition_segment_acc_blocks(payload, aux, start, count, pred,
                     pltpu.VMEM((C2, bw), jnp.float32),    # right accumulator
                     pltpu.VMEM((CHUNK, bw), jnp.float32),  # flush stage
                     pltpu.VMEM((CHUNK, bw), jnp.float32),  # final blend read
+                    pltpu.VMEM((group, BLOCK_SCRATCH_ROWS, bw),
+                               jnp.float32),              # permuted blocks
                     pltpu.SemaphoreType.DMA((slots,)),
                     pltpu.SemaphoreType.DMA(()),
                     pltpu.SemaphoreType.DMA(()),

@@ -230,23 +230,30 @@ def _pred(feature=1, threshold=None, default_left=False, is_cat=False,
         identity=jnp.bool_(identity))
 
 
-def _check_exact(kernel, pay, start, count, pred, value_col, bins):
-    """Payload, the rights staged in aux and num_left of a Pallas
-    partition, bit for bit against the portable one."""
+#: the portable partition under jit: called bare it dispatches operation
+#: by operation, 0.4 s a call where the interpreted kernel takes 0.01
+_portable = jax.jit(seg.partition_segment, static_argnums=(7,))
+
+
+def _check_exact(kernel, pay, start, count, pred, value_col, bins,
+                 right_first=False):
+    """Payload, the second child's rows staged in aux and num_left of a
+    Pallas partition, bit for bit against the portable one."""
     aux = jnp.zeros_like(pay)
     lv, rv = jnp.float32(1.5), jnp.float32(-2.5)
-    ref_pay, _, ref_nl = seg.partition_segment(
+    ref_pay, _, ref_nl = _portable(
         pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv,
-        value_col)
+        value_col, jnp.bool_(right_first))
     nl = int(ref_nl)
+    n_first = count - nl if right_first else nl
     got_pay, got_aux, got_nl = getattr(pseg, kernel)(
         pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv,
-        value_col, bins, interpret=True)
+        value_col, bins, jnp.bool_(right_first), interpret=True)
     assert int(got_nl) == nl
     np.testing.assert_array_equal(np.asarray(got_pay), np.asarray(ref_pay))
     np.testing.assert_array_equal(
-        np.asarray(got_aux)[start:start + count - nl],
-        np.asarray(ref_pay)[start + nl:start + count])
+        np.asarray(got_aux)[start:start + count - n_first],
+        np.asarray(ref_pay)[start + n_first:start + count])
     return nl
 
 
@@ -278,7 +285,7 @@ def test_partition_matches(start, count, predkw, kernel, width):
     pred = _pred(**predkw)
     lv, rv = jnp.float32(-0.25), jnp.float32(0.75)
 
-    ref_pay, _, ref_nl = seg.partition_segment(
+    ref_pay, _, ref_nl = _portable(
         pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv, VALUE_COL)
     got_pay, _, got_nl = impl(
         pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv,
@@ -350,10 +357,20 @@ def _eqns(jaxpr):
             yield from _eqns(sub)
 
 
-def _while_dots(jaxpr):
-    """dot_generals inside each `while` of a jaxpr, in program order."""
-    return [sum(eqn.primitive.name == "dot_general" for eqn in _eqns(body))
+def _while_count(jaxpr, primitive):
+    """Equations of one primitive inside each `while` of a jaxpr, in
+    program order."""
+    return [sum(eqn.primitive.name == primitive for eqn in _eqns(body))
             for body in _whiles(jaxpr)]
+
+
+def _while_dots(jaxpr):
+    return _while_count(jaxpr, "dot_general")
+
+
+def _while_rotates(jaxpr):
+    """`pltpu.roll`s inside each `while`."""
+    return _while_count(jaxpr, "roll")
 
 
 def _while_shapes(jaxpr):
@@ -370,7 +387,11 @@ def test_pass_a_is_one_permutation():
     before PR 25) must not come back quietly, nor a rank mat-vec a chunk;
     and the index arithmetic has rows in lanes: nothing [C, B]-shaped (the
     categorical bitset's one-hot, before PR 29) and no per-row [C] vector
-    is computed in the loop.  Traced only, nothing runs."""
+    is computed in the loop.  Since PR 36 the block goes to the
+    accumulators by tile-aligned windows: pass A's loop holds no rotate
+    and computes no value of the accumulators' [2C, P] (the doubled block,
+    its two rotated copies and the selects over them, before); pass B
+    keeps its one rotate of a doubled window.  Traced only, nothing runs."""
     pay = _payload(1024)
     closed = jax.make_jaxpr(
         lambda p, a: pseg._partition_segment_acc(
@@ -378,9 +399,12 @@ def test_pass_a_is_one_permutation():
             jnp.float32(-1.0), VALUE_COL, B))(
         pay, jnp.zeros_like(pay))
     assert _while_dots(closed.jaxpr) == [4 * pseg._pass_a_group(P, B) + 1, 0]
-    pass_a = _while_shapes(closed.jaxpr)[0]
+    pass_a, pass_b = _while_shapes(closed.jaxpr)
     assert (seg.CHUNK, B) not in pass_a and (seg.CHUNK,) not in pass_a
     assert (8, seg.CHUNK) in pass_a
+    assert _while_rotates(closed.jaxpr) == [0, 1]
+    assert (pseg.C2, P) not in pass_a and (pseg.C2, P) in pass_b
+    assert (pseg.BLOCK_ROWS, P) in pass_a and (pseg.WIN, P) in pass_a
 
 
 @pytest.mark.parametrize("width,group", [(P, 2), (256, 2), (384, 1)])
@@ -396,13 +420,99 @@ def test_partition_acc_groups(width, group, start, count):
     aux = jnp.zeros_like(pay)
     pred = _pred(feature=2, threshold=B // 3)
     lv, rv = jnp.float32(-0.25), jnp.float32(0.75)
-    ref_pay, _, ref_nl = seg.partition_segment(
+    ref_pay, _, ref_nl = _portable(
         pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv, VALUE_COL)
     got_pay, _, got_nl = pseg.partition_segment_acc(
         pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv,
         VALUE_COL, B, interpret=True)
     assert int(got_nl) == int(ref_nl)
     np.testing.assert_array_equal(np.asarray(got_pay), np.asarray(ref_pay))
+
+
+def _sided_payload(first_rows, start, count, width, right_first, seed):
+    """A payload whose split column (feature 1, threshold B // 2) sends
+    `first_rows(k, nv)` of the `nv` segment rows of chunk k of the
+    kernel's aligned read stream to the FIRST side (the left child, or
+    the right one with `right_first`), at random places; and the counts
+    it made, a (first, second) pair a chunk."""
+    pay = np.array(_payload(1536, seed=seed))
+    rng = np.random.default_rng(seed)
+    first_bin, second_bin = (B - 1, 0) if right_first else (0, B - 1)
+    base = start - start % 8
+    counts = []
+    for k in range(-(-(start + count - base) // seg.CHUNK)):
+        rows = np.arange(max(start, base + k * seg.CHUNK),
+                         min(start + count, base + (k + 1) * seg.CHUNK))
+        n_first = int(np.clip(first_rows(k, len(rows)), 0, len(rows)))
+        pay[rows, 1] = second_bin
+        pay[rng.choice(rows, n_first, replace=False), 1] = first_bin
+        counts.append((n_first, len(rows) - n_first))
+    return _widened(jnp.asarray(pay), width), counts
+
+
+#: how many of a chunk's `nv` segment rows go to the first side, by chunk
+#: k of the stream: the ends of the one-hot's block (a side empty, a whole
+#: chunk one side), a side's count on a tile edge and one past it, and
+#: counts that walk both cursors through the residues
+BLOCK_EDGES = {
+    "no_first": lambda k, nv: 0,
+    "no_staged": lambda k, nv: nv,
+    "whole_chunks": lambda k, nv: nv if k % 2 == 0 else 0,
+    "first_on_tile": lambda k, nv: 64,
+    "first_off_tile": lambda k, nv: 65,
+    "staged_on_tile": lambda k, nv: nv - 64,
+    "staged_off_tile": lambda k, nv: nv - 65,
+    "walk": lambda k, nv: (3 * k + 5) % (nv + 1),
+}
+
+
+@pytest.mark.parametrize("edge", list(BLOCK_EDGES))
+@pytest.mark.parametrize("start", range(8))
+@pytest.mark.parametrize("kernel,width", PLANS[1:])
+def test_pass_a_block_edges(kernel, width, start, edge):
+    """Pass A puts a chunk's rows at the accumulators' cursors by the
+    one-hot's destination (the part of each cursor under 8) and a
+    tile-aligned window a side (the rest): every start modulo 8, the edges
+    of the [C + 24, P] block, segments of 1 to 5 chunks whose last chunk
+    is whole, a row short or nearly empty (two chunks a trip at 128 and
+    256 lanes: an odd count leaves the last trip half empty), the left
+    child first and the right one; payload, staged rows and count bit for
+    bit the portable partition's."""
+    at = start + list(BLOCK_EDGES).index(edge)
+    chunks, cut = 1 + at % 5, (0, 1, 100, 250)[at % 4]
+    count = chunks * seg.CHUNK - start - cut
+    for right_first in (False, True):
+        pay, counts = _sided_payload(BLOCK_EDGES[edge], start, count, width,
+                                     right_first, seed=at)
+        assert len(counts) == chunks
+        nl = _check_exact(kernel, pay, start, count, _pred(), VALUE_COL, B,
+                          right_first)
+        n_first = sum(first for first, _ in counts)
+        assert nl == (count - n_first if right_first else n_first)
+
+
+@pytest.mark.parametrize("r_staged", range(8))
+@pytest.mark.parametrize("r_first", range(8))
+@pytest.mark.parametrize("kernel,width", [PLANS[1], PLANS[4]])
+def test_pass_a_cursor_residues(kernel, width, r_first, r_staged):
+    """Every pair of the two cursors' parts under 8 meets a FULL chunk
+    whose first side ends two rows past a tile edge: the block's longest
+    reach (row C + 20 where both parts are 7), in one pass at two chunks a
+    trip and a column block at a time; then a short chunk on the cursors
+    that chunk left.  With the left child first and with the right one."""
+    start = (r_first + r_staged) % 8
+    lead = 40 + (-r_staged) % 8
+    count = 2 * seg.CHUNK + 37 - start
+    for right_first in (False, True):
+        pay, counts = _sided_payload(
+            lambda k, nv: (lead, 130, 1)[k], start, count, width,
+            right_first, seed=8 * r_first + r_staged)
+        # both cursors start at `start % 8`; where chunk 0 leaves them
+        assert ((start + counts[0][0]) % 8, (start + counts[0][1]) % 8) \
+            == (r_first, r_staged)
+        assert counts[1] == (130, seg.CHUNK - 130) and len(counts) == 3
+        _check_exact(kernel, pay, start, count, _pred(), VALUE_COL, B,
+                     right_first)
 
 
 #: the accumulator kernel in one pass (`higgs-train`'s plan) and a 512-lane
@@ -681,7 +791,7 @@ def test_partition_blocks_matches(start, count, predkw, block_w):
     vcol = Fw + 3
     pred = _pred(**predkw)
     lv, rv = jnp.float32(-0.25), jnp.float32(0.75)
-    ref_pay, _, ref_nl = seg.partition_segment(
+    ref_pay, _, ref_nl = _portable(
         pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv, vcol)
     got_pay, _, got_nl = pseg.partition_segment_acc_blocks(
         pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv,
@@ -709,7 +819,7 @@ def test_partition_blocks_epsilon_shape(start, count, feature):
         num_bin=jnp.int32(Bw), default_bin=jnp.int32(0),
         offset=jnp.int32(0), identity=jnp.bool_(True))
     lv, rv = jnp.float32(-0.25), jnp.float32(0.75)
-    ref_pay, _, ref_nl = seg.partition_segment(
+    ref_pay, _, ref_nl = _portable(
         pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv, Fw + 3)
     got_pay, _, got_nl = pseg.partition_segment_acc_blocks(
         pay, aux, jnp.int32(start), jnp.int32(count), pred, lv, rv,
@@ -730,8 +840,14 @@ def test_partition_blocks_pass_a_is_one_permutation():
             jnp.float32(-1.0), 1203, B))(
         pay, jnp.zeros_like(pay))
     assert _while_dots(closed.jaxpr) == [0, 5, 0, 5, 0, 9, 0]
-    assert not any((seg.CHUNK, B) in shapes
-                   for shapes in _while_shapes(closed.jaxpr))
+    shapes = _while_shapes(closed.jaxpr)
+    assert not any((seg.CHUNK, B) in loop for loop in shapes)
+    # the snapshot, then pass A and pass B of each block: a rotate in
+    # pass B alone, nothing of an accumulator's shape in pass A
+    assert _while_rotates(closed.jaxpr) == [0, 0, 1, 0, 1, 0, 1]
+    for pass_a, width in zip(shapes[1::2], (512, 512, 256)):
+        assert (pseg.C2, width) not in pass_a
+        assert (pseg.BLOCK_ROWS, width) in pass_a
 
 
 def test_partition_blocks_narrow_pin():

@@ -71,17 +71,20 @@ def _partition_args(sharding, rows=ROWS, lanes=LANES, features=FEATURES,
 
 @pytest.mark.parametrize("lanes,bins", [(LANES, BINS), (LANES, 255),
                                         (256, 255), (384, 64),
-                                        (LANES, 1024)])
+                                        (LANES, 1024), (512, 255)])
 def test_partition_acc_compiles_for_v5e(one_chip, lanes, bins):
     """The accumulator partition at the Higgs cell's shape, at its 255
     bins (eight words of packed bitset, the last short of a bit) and past
     128 lanes, where the split column's window is a lane slice of the ring
-    at a traced offset (two chunks a trip at 256 lanes, one at 384; as
-    many rows as fill the chip the same), and at 1,024 bins, where the
-    column is read out at HIGHEST.
+    at a traced offset (two chunks a trip at 256 lanes, one at 384 and at
+    512, the widest one pass takes; as many rows as fill the chip the
+    same), and at 1,024 bins, where the column is read out at HIGHEST.
     Pass A's index arithmetic has rows in lanes: the NT product that
     reads the column out, the vector shifts of the categorical test and
-    the sublane broadcast into the one-hot are Mosaic's to accept."""
+    the sublane broadcast into the one-hot are Mosaic's to accept; and so
+    is its placement: a [C + 24, C] one-hot's product stored to a scratch,
+    and loads and stores of [C + 8, P] windows of the scratch and of the
+    accumulators at traced starts that are multiples of 8."""
     assert pseg.partition_acc_fits_vmem(lanes, bins)
     lowered = pseg._partition_segment_acc.lower(
         *_partition_args(one_chip, ROWS * LANES // lanes, lanes, bins=bins))
