@@ -24,6 +24,14 @@ them), then one JSON line with the fits, also written to
 chiprun_out/fit_kernel_calls.<workload>.s<seed>.json.
 
     python exp/fit_kernel_calls.py --workload higgs-train --seed 11
+
+A partition that is several kernel calls a split (the column-block
+engine: the split window's snapshot and a pass a 512-lane block) is
+fitted on the sum of a split's calls.  `--partition-engine E` makes the
+run on engine E whatever `grower2.partition_engine` would choose for the
+shape (an experiment's override, for racing two engines in one cell:
+PERF.md section 6, PR 37), and `--seconds S` makes an UNTRACED run of S
+seconds instead (the cell's `train_s_per_iter` on that engine; no fit).
 """
 import argparse
 import json
@@ -64,9 +72,17 @@ def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", default="higgs-train")
     ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--partition-engine", default=None,
+                    choices=("pallas-rmw", "pallas-blocks", "pallas-acc",
+                             "lax"))
+    ap.add_argument("--seconds", type=float, default=0.0,
+                    help="an untraced window of this many seconds, no fit")
     args = ap.parse_args(argv)
 
     from benchmarks import run as brun
+    if args.partition_engine:
+        from lightgbm_tpu.boosting import grower2
+        grower2.partition_engine = lambda *shape: args.partition_engine
 
     # keep the harness's Run as it is made (no subclass: the train driver
     # finds the harness by the run's own module)
@@ -75,11 +91,16 @@ def main(argv=None):
 
     def keep(self, *a, **kw):
         make_run(self, *a, **kw)
+        if args.partition_engine:
+            self.config["engines"]["partition"] = args.partition_engine
         runs.append(self)
 
     brun.Run.__init__ = keep
-    result = brun.run_cell(args.workload, args.seed, 0.0, True)
+    result = brun.run_cell(args.workload, args.seed, args.seconds,
+                           not args.seconds)
     print(json.dumps(result), flush=True)
+    if args.seconds:
+        return 0
     run = runs[0]
     chips = run.cell["chips"]
     lo, hi = run.xtrace.window_ns()
@@ -93,6 +114,10 @@ def main(argv=None):
     part_rows = np.concatenate([r[0] for r in rows]) / chips
     part_staged = np.concatenate([r[1] for r in rows]) / chips
     hist_rows = np.concatenate([r[2] for r in rows]) / chips
+    if len(part_rows) and len(part_s) > len(part_rows) \
+            and len(part_s) % len(part_rows) == 0:
+        # several kernel calls a split, in time order: their sum
+        part_s = part_s.reshape(len(part_rows), -1).sum(axis=1)
     if len(part_s) != len(part_rows) or len(hist_s) != len(hist_rows):
         sys.exit("fit_kernel_calls: %d partition and %d histogram events "
                  "for %d splits and %d histograms of the window's trees"
@@ -100,7 +125,13 @@ def main(argv=None):
                     len(hist_rows)))
 
     out = {"workload": args.workload, "seed": args.seed, "chips": chips,
-           "trees": len(run.trees), "device": result["device"]}
+           "trees": len(run.trees), "device": result["device"],
+           "engines": run.state["bst"]._engine.engines,
+           # the growth loop's rounds a tree beside the trees' leaves: a
+           # round a split, none after the last leaf that can split
+           "leaves": [int(t.num_leaves) for t in run.trees],
+           "split_rounds_per_tree":
+               run.state["bst"]._engine.split_rounds_per_tree()}
     (call, per_row, per_staged), r2 = fit([part_rows, part_staged], part_s)
     out["partition"] = {
         "calls": len(part_s), "seconds": float(part_s.sum()),

@@ -3,7 +3,8 @@ the chip and compared with the portable lax engine (or with an already
 checked sibling kernel).
 
 One verdict per kernel (histogram, RMW / accumulator / column-block
-partition, precision), each with a fetch-forced time printed as
+partition, the two engines of a 1,024-lane payload under a NaN-routing
+split, precision), each with a fetch-forced time printed as
 information.  A section that raises records its error and the run carries
 on to the next kernel, so one call to the chip answers for all of them;
 the exit code is non-zero if any section failed.  The last stdout line is
@@ -13,8 +14,9 @@ On the chip:   python exp/smoke_tpu_kernels.py [section ...]
 CPU rehearsal: JAX_PLATFORMS=cpu python exp/smoke_tpu_kernels.py --interpret
 (the Pallas interpreter at a reduced row count; proves the script, says
 nothing about Mosaic).  Section names (`partition_acc blocks precision`
-are the three that run `_acc_kernel`; `state_cols` is the three kernels
-of `ops/state_columns.py`) keep the run to those.
+are the three that run `_acc_kernel`; `missing_wide` is the Bosch cell's
+1,024 lanes on both its engines; `state_cols` is the three kernels of
+`ops/state_columns.py`) keep the run to those.
 """
 import json
 import os
@@ -302,6 +304,45 @@ def blocks():
     return info
 
 
+def missing_wide():
+    """The Bosch cell's shape, 968 columns x 64 bins in 1,024 lanes, on
+    BOTH engines its band has (the read-modify-write kernel in one pass,
+    the accumulator kernel in two 512-lane blocks), under the split that
+    cell makes at every node: the column has a NaN bin that four rows in
+    five sit in, and they go where `default_left` says.  The split column
+    in block 0 and in block 1 (the block pass that does not hold it routes
+    from the snapshot alone), the missing rows sent left and sent right,
+    each segment with the left child first and with the right one."""
+    Fw, Bw, Pw = 968, 64, 1024
+    nan_bin = Bw - 1
+    host = np.array(make_payload(N, Fw, Bw, width=Pw))
+    cols = (100, 700)
+    for col in cols:
+        host[:N, col] = np.where(rng.random(N) < 0.81, nan_bin,
+                                 rng.integers(0, nan_bin, N))
+    pay = jnp.asarray(host)
+    engines = {"rmw": pseg.partition_segment,
+               "blocks": pseg.partition_segment_acc_blocks}
+    info = {}
+    for name, kernel in engines.items():
+        for col in cols:
+            for default_left in (True, False):
+                pred = make_pred(col, 20, Bw)._replace(
+                    missing_type=jnp.int32(seg.MISSING_NAN),
+                    default_left=jnp.bool_(default_left))
+                check_partition(
+                    lambda p, a, s, c, rf: kernel(
+                        p, a, s, c, pred, LV, RV, Fw + 3, Bw, rf, **IK),
+                    pay, pred, Fw + 3,
+                    segs((128, 3000), (7, 8000), (513, 256), (0, N)))
+        info[name + "_ms"] = median_ms(lambda: int(kernel(
+            pay, jnp.zeros_like(pay), jnp.int32(0), jnp.int32(N), pred, LV,
+            RV, Fw + 3, Bw, **IK)[2]))
+    info["cases"] = "2 engines x column %s x default left/right x 4 " \
+        "segments x child order" % (cols,)
+    return info
+
+
 def precision():
     """The MXU's default f32 matmul is one bf16 pass: the partition must
     still permute payload values and radix-4096 index columns exactly, and
@@ -430,8 +471,8 @@ def state_cols():
     return info
 
 
-SECTIONS = (histogram, partition_rmw, partition_acc, blocks, precision,
-            state_cols)
+SECTIONS = (histogram, partition_rmw, partition_acc, blocks, missing_wide,
+            precision, state_cols)
 
 
 def main():

@@ -27,7 +27,7 @@ from ..io.binning import BIN_TYPE_CATEGORICAL
 from ..io.dataset import BinnedDataset
 from ..models.gbdt_model import GBDTModel
 from ..models.tree import Tree
-from ..ops.split import FeatureMeta
+from ..ops.split import FeatureMeta, MISSING_NONE
 from ..runtime import resilience, syncs, telemetry, tracing, xla_obs
 from ..utils.log import Log
 from ..utils.random import Random, partition_seed
@@ -205,6 +205,7 @@ def _cached_pgrower(meta_dev: FeatureMeta, cfg, max_num_bin: int,
             # and so do each block's row counters, a (high, low) pair each
             tree_specs["rows_partitioned"] = P(ax)
             tree_specs["rows_staged"] = P(ax)
+            tree_specs["rows_missing"] = P(ax)
             # quantized growers take the replicated [2] scale pair as a
             # fourth argument (scales are global maxima, so every shard
             # holds the same values)
@@ -769,10 +770,16 @@ class _FastState:
         #: lay second in their parents' ranges, which the Pallas kernels
         #: stage and move once more), a tree an entry in the order they
         #: were finished: read off the tree's own fetch by
-        #: `_finish_tree_host`, so it costs no dispatch and no transfer
+        #: `_finish_tree_host`, so it costs no dispatch and no transfer.
+        #: `missing_splits`: the numerical splits on a column whose mapper
+        #: has a NaN or zero-as-missing bin; `default_left_splits`: those
+        #: of them that send the missing rows left; `rows_missing`: the
+        #: rows such splits routed by that direction and not by the
+        #: threshold (the split leaf's histogram count at the missing bin)
         self.counters: Dict[str, List[int]] = {
             "splits": [], "categorical_splits": [],
-            "rows_partitioned": [], "rows_staged": []}
+            "missing_splits": [], "default_left_splits": [],
+            "rows_partitioned": [], "rows_staged": [], "rows_missing": []}
 
     def window_program(self, J: int, with_bag: bool):
         """One jitted, donated device program for a whole boosting window:
@@ -2371,8 +2378,17 @@ class GBDT:
             self._fast.counters["splits"].append(nl - 1)
             self._fast.counters["categorical_splits"].append(
                 int(host["split_is_cat"][:nl - 1].sum()))
-            for name in ("rows_partitioned", "rows_staged"):
+            for name in ("rows_partitioned", "rows_staged", "rows_missing"):
                 self._fast.counters[name].append(wide_count(host[name]))
+            ni = max(nl - 1, 0)
+            mappers = self.train_set.bin_mappers
+            aware = np.asarray(
+                [mappers[int(f)].missing_type != MISSING_NONE
+                 for f in host["split_feature"][:ni]], bool) \
+                & ~host["split_is_cat"][:ni].astype(bool)
+            self._fast.counters["missing_splits"].append(int(aware.sum()))
+            self._fast.counters["default_left_splits"].append(
+                int((aware & host["default_left"][:ni].astype(bool)).sum()))
         L = self.grower_cfg.num_leaves
         tree = Tree(max(L, 2))
         tree.num_leaves = nl

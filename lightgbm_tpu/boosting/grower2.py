@@ -33,8 +33,8 @@ from ..runtime import xla_obs
 from ..utils.log import Log
 
 from ..ops.bundle import BundleMap, expand_histogram, identity_bundle_map
-from ..ops.split import (FeatureMeta, K_MIN_SCORE, SplitResult,
-                         dequantize_hist, find_best_split,
+from ..ops.split import (FeatureMeta, K_MIN_SCORE, MISSING_NAN, MISSING_NONE,
+                         SplitResult, dequantize_hist, find_best_split,
                          find_best_split_batched, leaf_output,
                          pad_feature_meta, per_feature_best_gains)
 from ..ops import segment as seg
@@ -73,28 +73,33 @@ def phase(name: str):
 def partition_engine(hist_impl: str, payload_width: int,
                      num_bins: int) -> str:
     """The partition implementation for a [N, payload_width] payload,
-    chosen from the platform and the shape: the accumulator kernel where
-    its VMEM plan fits, then the read-modify-write kernel, then, for a
-    payload neither single-pass plan holds (1,920 lanes and up), the
-    accumulator kernel a 512-lane column block at a time, else the
-    portable lax partition.  Gated separately from the histogram: the
-    partition is exact at any bin count but spans the full payload width,
-    so a wide payload can overflow it while the histogram kernel still
-    fits."""
+    chosen from the platform and the shape: the accumulator kernel in one
+    pass where its VMEM plan fits (to 512 lanes); past that, for a
+    lane-padded payload, the accumulator kernel a 512-lane column block
+    at a time; else the portable lax partition.  The read-modify-write
+    kernel (`pallas_segment.partition_segment`) is chosen for no shape
+    since the band of 640 to 1,664 lanes, where its plan and the block
+    plan both fit, was raced on the chip (PERF.md section 6, PR 37): the
+    block kernel takes 25 to 51 ns a row there where the read-modify-write
+    kernel takes 157 to 255, 0.21 s a tree against 1.57 at the Bosch
+    cell's 1,024 lanes, and at 1,664 lanes Mosaic refuses the
+    read-modify-write kernel for want of VMEM though its plan admits it.
+    Gated separately from the histogram: the partition is exact at any
+    bin count but spans the full payload width, so a wide payload can
+    overflow it while the histogram kernel still fits."""
     if hist_impl == "lax" or jax.default_backend() != "tpu":
         return "lax"
     from ..ops import pallas_segment as pseg
     if pseg.partition_acc_fits_vmem(payload_width, num_bins):
         return "pallas-acc"
-    if pseg.partition_fits_vmem(payload_width, num_bins):
-        return "pallas-rmw"
     if (payload_width % 128 == 0
             and pseg.partition_blocks_fits_vmem(payload_width, num_bins)):
         return "pallas-blocks"
     return "lax"
 
 
-#: a tree's row counters (`rows_partitioned`, `rows_staged`) are sums of
+#: a tree's row counters (`rows_partitioned`, `rows_staged`,
+#: `rows_missing`) are sums of
 #: up to num_leaves - 1 segment lengths: past int32 on a deep tree over
 #: 10^7 rows, and the tree's fetch carries float32, exact to 2^24.  They
 #: ride as [2] int32, (count >> WIDE_BITS, count & (2^WIDE_BITS - 1)),
@@ -282,6 +287,8 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
             return seg.partition_segment(payload, aux, start, count, pred,
                                          lv, rv, cols.value, right_first)
         from ..ops import pallas_segment as pseg
+        # ("pallas-rmw": no shape resolves to it; the scripts under exp/
+        # that race it name it themselves)
         kernel = {"pallas-acc": pseg.partition_segment_acc,
                   "pallas-rmw": pseg.partition_segment,
                   "pallas-blocks": pseg.partition_segment_acc_blocks}[part]
@@ -304,6 +311,34 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
             return hist_g
         return expand_histogram(hist_g, bmap, meta.num_bin, meta.default_bin,
                                 B)
+
+    def missing_rows(hist_leaf, f, column):
+        """Rows of a leaf that its split on feature `f` routes by the
+        default direction and not by the threshold: the count channel of
+        the leaf's own histogram at the feature's missing bin (the NaN bin
+        or, with zero as missing, the default bin; none where the mapper
+        has neither; a categorical split routes by its set alone and
+        counts none), so in-bag rows under bagging.  `column` is where the
+        feature's storage column lies in `hist_leaf`; a bundle's default
+        bin is its total less the member's own bins, as
+        `expand_histogram` has it.  int32."""
+        kind, nb, d = (meta.missing_type[f], meta.num_bin[f],
+                       meta.default_bin[f])
+        m = jnp.where(kind == MISSING_NAN, nb - 1, d)
+        if not bundled:
+            at = hist_leaf[column, m, 2].astype(jnp.float32)
+        else:
+            counts = hist_leaf[column, :, 2].astype(jnp.float32)
+            off = bmap.f_offset[f]
+            b = jnp.arange(counts.shape[0], dtype=jnp.int32)
+            own = jnp.sum(jnp.where((b >= off) & (b < off + nb - 1), counts,
+                                    0.0))
+            stored = jnp.where(bmap.f_identity[f], m, off + m - (m > d))
+            at = counts[jnp.clip(stored, 0, counts.shape[0] - 1)]
+            at = jnp.where(bmap.f_identity[f] | (m != d), at,
+                           jnp.sum(counts) - own)
+        return jnp.where(kind != MISSING_NONE, jnp.round(at),
+                         0.0).astype(jnp.int32)
 
     # histogram pool (reference HistogramPool, feature_histogram.hpp:655-826):
     # POOL < L caches per-leaf histograms with LRU eviction; a split whose
@@ -473,6 +508,22 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
                 return find(hist_view(deq(h)), sg, sh, cnt, feature_mask,
                             **constraints)
 
+        # a split's missing rows, counted once over a mesh: by the shard
+        # that owns the column's (global) histogram, by every shard from
+        # its local one (voting), by shard 0 where all hold the same
+        if scatter_mode or feature_mode:
+            def own_missing(hist_leaf, f, gcol):
+                local = gcol - f_offset if scatter_mode else gcol
+                owned = (local >= 0) & (local < Gloc)
+                return jnp.where(owned, missing_rows(
+                    hist_leaf, f, jnp.clip(local, 0, Gloc - 1)), 0)
+        elif replicated:
+            def own_missing(hist_leaf, f, gcol):
+                return jnp.where(lax.axis_index(axis_name) == 0,
+                                 missing_rows(hist_leaf, f, gcol), 0)
+        else:
+            own_missing = missing_rows
+
         if stacked_find:
             def find_split_batched(hists, sgs, shs, cnts):
                 """Fused search over a [Q, Gh, B, 3] stack of children."""
@@ -573,6 +624,9 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
             # the Pallas kernels): raw counts, as the kernels returned them
             "rows_partitioned": jnp.zeros(2, jnp.int32),
             "rows_staged": jnp.zeros(2, jnp.int32),
+            # of the rows partitioned, those their split's column had no
+            # value for: routed by `default_left`, not by the threshold
+            "rows_missing": jnp.zeros(2, jnp.int32),
         }
         # per-leaf (or pooled) histogram state for the subtraction trick.
         # int32 in quantized mode (the narrow-dtype plumbing: LRU slots,
@@ -788,6 +842,10 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
             st_new["rows_partitioned"] = _wide_add(st["rows_partitioned"],
                                                    count)
             st_new["rows_staged"] = _wide_add(st["rows_staged"], h_count)
+            st_new["rows_missing"] = _wide_add(
+                st["rows_missing"],
+                jnp.where(st["bcat"][best_leaf], 0,
+                          own_missing(hist_parent, f, gcol)))
             st_new["sum_g"] = set2(st["sum_g"], lg, rg)
             st_new["sum_h"] = set2(st["sum_h"], lh, rh)
             st_new["cnt"] = set2(st["cnt"], lcnt, rcnt)
@@ -961,6 +1019,8 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
             gain_r = jnp.where(depth_ok, res2.gain[KB:], K_MIN_SCORE)
             lval_c = st["leaf_val"][cand]
             gain_stored = st["bgain"][cand]
+            miss_c = jnp.where(bcat_c, 0, jax.vmap(missing_rows)(
+                st["hist"][cand], feat_c, bmap.f_group[feat_c]))
 
             # commit phase: replay the sequential argmax order against the
             # evaluated window.  Small-state bookkeeping only (payload and
@@ -1001,6 +1061,8 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
                     st2["rows_partitioned"], done * cnt_c[j])
                 st2["rows_staged"] = _wide_add(
                     st2["rows_staged"], done * h_count[j])
+                st2["rows_missing"] = _wide_add(
+                    st2["rows_missing"], done * miss_c[j])
                 st2["sum_g"] = set2(st2["sum_g"], lg_c[j], rg_c[j])
                 st2["sum_h"] = set2(st2["sum_h"], lh_c[j], rh_c[j])
                 st2["cnt"] = set2(st2["cnt"], lc_c[j], rc_c[j])
@@ -1121,6 +1183,7 @@ def make_partitioned_grower(meta: FeatureMeta, cfg: GrowerConfig,
             "internal_count": st["internal_count"],
             "rows_partitioned": st["rows_partitioned"],
             "rows_staged": st["rows_staged"],
+            "rows_missing": st["rows_missing"],
         }
         return tree, st["payload"], st["aux"]
 

@@ -41,7 +41,7 @@ def _load():
                 ctypes.POINTER(ctypes.c_double)]
             lib.LGBMT_EncodeBins.restype = ctypes.c_int
             lib.LGBMT_EncodeBins.argtypes = [
-                ctypes.POINTER(ctypes.c_double), ctypes.c_longlong,
+                ctypes.c_void_p, ctypes.c_int, ctypes.c_longlong,
                 ctypes.c_int, ctypes.POINTER(ctypes.c_double),
                 ctypes.POINTER(ctypes.c_longlong),
                 ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_int),
@@ -171,13 +171,16 @@ def encode_bins(X: np.ndarray, mappers: List,
     else:
         passes = [(triv, int(is_cat.sum()) or None)]
 
-    # chunk the f64 conversion: a whole-matrix ascontiguousarray of a
-    # float32 Higgs-scale X would be a multi-GB transient
-    already = (X.dtype == np.float64 and X.flags.c_contiguous)
-    block = n if already else max(1, (1 << 24) // max(F, 1))
+    # a C-contiguous float32 or float64 matrix is coded where it lies
+    # (the library widens a float32 value as it reads it); anything else
+    # is converted a block of rows at a time: a whole-matrix
+    # ascontiguousarray of a Higgs-scale X would be a multi-GB transient
+    in_place = X.dtype in (np.float32, np.float64) and X.flags.c_contiguous
+    block = n if in_place else max(1, (1 << 24) // max(F, 1))
     for b0 in range(0, n, block):
         b1 = min(b0 + block, n)
-        Xc = np.ascontiguousarray(X[b0:b1], dtype=np.float64)
+        Xc = X if in_place else np.ascontiguousarray(X[b0:b1],
+                                                     dtype=np.float64)
         for skip, n_cat in passes:
             skip = np.ascontiguousarray(skip, dtype=np.int32)
             span = (tracing.span("dataset/encode_categorical",
@@ -185,7 +188,9 @@ def encode_bins(X: np.ndarray, mappers: List,
                     if n_cat else contextlib.nullcontext())
             with span:
                 rc = lib.LGBMT_EncodeBins(
-                    ptr(Xc, ctypes.c_double), ctypes.c_longlong(b1 - b0), F,
+                    ctypes.c_void_p(Xc.ctypes.data),
+                    int(Xc.dtype == np.float32), ctypes.c_longlong(b1 - b0),
+                    F,
                     ptr(bounds, ctypes.c_double), ptr(offs, ctypes.c_longlong),
                     ptr(cnts, ctypes.c_int), ptr(miss, ctypes.c_int),
                     ptr(nbin, ctypes.c_int), ptr(skip, ctypes.c_int),

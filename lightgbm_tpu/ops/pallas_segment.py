@@ -22,7 +22,10 @@ kernels keep every one-hot in VMEM:
   512-lane window of a payload too wide for one pass, routing every pass
   from a snapshot of the split column (`_snap_window_kernel`);
   `partition_segment` (`_partition_kernel`) is the older read-modify-write
-  kernel, the only one whose plan fits between the two (640-1,792 lanes).
+  kernel, whose plan fits between the two (640-1,664 lanes): raced there
+  on the chip it is five to six times slower than the block kernel
+  (PERF.md section 6, PR 37), and `partition_engine` chooses it for no
+  shape any more (the scripts under exp/ that race it name it themselves).
   All three take `right_first`, data like the predicate: which child lies
   FIRST in the parent's range (`ops.segment.partition_segment`).  The
   first side is the one written in place, the other is staged in `aux`
@@ -763,12 +766,15 @@ def _partition_kernel(scalars, fvals, bitset_ref, payload_hbm, aux_hbm,
     lax.fori_loop(0, nrch, body_b, 0)
 
 
-@functools.partial(xla_obs.jit, site="pallas.partition_segment", static_argnames=("value_col", "num_bins",
-                                             "interpret"))
-def partition_segment(payload, aux, start, count, pred, left_value,
-                      right_value, value_col, num_bins, right_first=False,
-                      interpret=False):
-    """Same contract as ops.segment.partition_segment, fused on-chip."""
+@functools.partial(xla_obs.jit, site="pallas.partition_segment",
+                   static_argnames=("value_col", "num_bins", "interpret"))
+def _partition_segment(payload, aux, start, count, pred, left_value,
+                       right_value, value_col, num_bins, right_first=False,
+                       interpret=False):
+    """Same contract as ops.segment.partition_segment, fused on-chip.
+    (Named as its siblings are: a trace calls the kernel's custom call
+    after this wrapper, and the benchmark's `kernel.partition_s_per_iter`
+    matches `_partition_segment*`.)"""
     P = payload.shape[1]
     B = num_bins
     scalars = jnp.stack([
@@ -809,6 +815,9 @@ def partition_segment(payload, aux, start, count, pred, left_value,
         interpret=interpret,
     )(scalars, fvals, bitset, payload, aux)
     return payload_new, aux_new, jnp.where(right_first, count - nl[0], nl[0])
+
+
+partition_segment = _partition_segment
 
 
 # ---------------------------------------------------------------------------

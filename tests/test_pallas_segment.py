@@ -170,9 +170,12 @@ def test_histogram_keeps_gradient_bits(f, b):
 
 
 def test_partition_vmem_gate():
-    """The partition kernel has no feature tiling: Bosch-wide payloads
-    (P ~ 1024) fit, Epsilon-wide (P ~ 2048) fall back to the portable
-    partition while the histogram stays on the Pallas kernel."""
+    """The read-modify-write kernel has no feature tiling: its plan holds
+    a Bosch-wide payload (1,024 lanes) and not an Epsilon-wide one
+    (2,048).  Both take the column-block kernel all the same
+    (`test_partition_engine_by_shape`): at 1,024 lanes it won the race on
+    the chip by 7.3 times (PERF.md section 6, PR 37), and the histogram
+    stays on its own Pallas kernel at either width."""
     if seg.CHUNK != 256:
         pytest.skip("VMEM gate expectations assume the default CHUNK")
     assert pseg.partition_fits_vmem(128, 256)   # Higgs-shaped payload
@@ -205,10 +208,15 @@ def test_vmem_gate_admits_benchmark_shapes():
 #: the plans `grower2.partition_engine` chooses between, by kernel and
 #: payload lanes: the read-modify-write kernel; the accumulator kernel with
 #: pass A two chunks a trip (128, 256 lanes) and one (384); the
-#: column-block kernel over two 512-lane blocks
+#: column-block kernel over two 512-lane blocks (the Bosch cell's 1,024
+#: lanes) and at the edges of the band it took from the read-modify-write
+#: kernel: 640 lanes (a block of 512 and one of 128) and 1,664 (three and
+#: one of 128)
 PLANS = [("partition_segment", 128), ("partition_segment_acc", 128),
          ("partition_segment_acc", 256), ("partition_segment_acc", 384),
-         ("partition_segment_acc_blocks", 1024)]
+         ("partition_segment_acc_blocks", 1024),
+         ("partition_segment_acc_blocks", 640),
+         ("partition_segment_acc_blocks", 1664)]
 
 
 def _widened(pay, width):
@@ -687,10 +695,23 @@ def _wide_payload(n_pad, F_wide, B_wide, seed=0):
 # column-block partition (ultra-wide payloads)
 # ---------------------------------------------------------------------------
 
+#: the engine of a lane-padded payload of 640 to 1,664 lanes, where both
+#: the read-modify-write kernel's plan and the column-block kernel's fit:
+#: the one that won the race on the chip (`exp/race_partition_band.py`,
+#: PERF.md section 6, PR 37)
+BAND_ENGINE = "pallas-blocks"
+
+
 @pytest.mark.parametrize("hist_impl,backend,width,bins,engine", [
     ("auto", "tpu", 128, 256, "pallas-acc"),      # higgs-train
     ("auto", "tpu", 256, 256, "pallas-acc"),
-    ("auto", "tpu", 1024, 64, "pallas-rmw"),      # a single-pass plan fits
+    ("auto", "tpu", 512, 64, "pallas-acc"),       # the widest single pass
+    ("auto", "tpu", 640, 64, BAND_ENGINE),        # the band's lower edge
+    ("auto", "tpu", 1024, 64, BAND_ENGINE),       # bosch-train
+    ("auto", "tpu", 1024, 256, BAND_ENGINE),
+    ("auto", "tpu", 1664, 64, BAND_ENGINE),       # the band's upper edge
+    ("auto", "tpu", 1792, 64, "pallas-blocks"),   # no single-pass plan fits
+    ("auto", "tpu", 1000, 64, "lax"),             # in the band, not padded
     ("auto", "tpu", 2048, 64, "pallas-blocks"),   # epsilon-train
     ("auto", "tpu", 4352, 256, "pallas-blocks"),  # raw Allstate
     ("auto", "tpu", 2000, 64, "lax"),             # not lane-padded
@@ -707,6 +728,37 @@ def test_partition_engine_by_shape(monkeypatch, hist_impl, backend, width,
     from lightgbm_tpu.boosting import grower2
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     assert grower2.partition_engine(hist_impl, width, bins) == engine
+
+
+@pytest.mark.parametrize("bins", [16, 64, 255, 256, 1024])
+def test_partition_engine_outside_the_band_is_the_plans_order(monkeypatch,
+                                                              bins):
+    """At 128 to 4,480 lanes: outside the band the engine is the first
+    plan that fits in the order accumulator, read-modify-write, column
+    blocks (the rule before the band was raced); inside it, the race's
+    winner where the payload is lane-padded."""
+    if seg.CHUNK != 256:
+        pytest.skip("VMEM gate expectations assume the default CHUNK")
+    from lightgbm_tpu.boosting import grower2
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    band = []
+    for width in range(128, 4481, 128):
+        by_order = ("pallas-acc" if pseg.partition_acc_fits_vmem(width, bins)
+                    else "pallas-rmw" if pseg.partition_fits_vmem(width, bins)
+                    else "pallas-blocks"
+                    if pseg.partition_blocks_fits_vmem(width, bins)
+                    else "lax")
+        got = grower2.partition_engine("auto", width, bins)
+        if by_order == "pallas-rmw":
+            band.append(width)
+            assert got == BAND_ENGINE, width
+            # not lane-padded, in the band: no kernel, as past it
+            assert grower2.partition_engine("auto", width + 8,
+                                            bins) == "lax", width
+        else:
+            assert got == by_order, width
+    assert band[0] == 640 and band[-1] == (1664 if bins < 1024 else 1536)
+    assert band == list(range(band[0], band[-1] + 1, 128))
 
 
 @pytest.mark.parametrize("backend,features,bins,width,kw,engine", [

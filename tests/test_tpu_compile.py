@@ -1,7 +1,8 @@
 """The segment kernels compile for a TPU v5e that is described, not
 attached: Mosaic's verdict on each kernel as it stands, at the real
-shapes of the Higgs cell (10.5M x 128 lanes) and of the Epsilon cell
-(409,600 x 2,048 lanes, 2,000 columns x 64 bins), with no chip.  `test_pallas_segment.py` runs
+shapes of the Higgs cell (10.5M x 128 lanes), of the Epsilon cell
+(409,600 x 2,048 lanes, 2,000 columns x 64 bins) and of the Bosch cell
+(1,015,808 x 1,024 lanes, 968 columns x 64 bins), with no chip.  `test_pallas_segment.py` runs
 the same kernels in interpret mode, which says nothing about what Mosaic
 accepts (an unaligned slice, a layout it cannot apply, too much VMEM).
 Nothing runs here, so nothing is said about results or times.
@@ -99,6 +100,41 @@ def test_partition_blocks_compiles_for_v5e_at_epsilon(one_chip):
         *_partition_args(one_chip, WIDE_ROWS, WIDE_LANES, WIDE_FEATURES,
                          WIDE_BINS))
     assert lowered.compile().as_text().count("tpu_custom_call") >= 5
+
+
+#: the Bosch cell: 1,000,000 rows padded to whole 16,384-row blocks, 968
+#: columns and 10 value columns in 1,024 lanes
+BOSCH_ROWS, BOSCH_LANES, BOSCH_FEATURES, BOSCH_BINS = 1_015_808, 1024, 968, 64
+
+
+@pytest.mark.parametrize("engine,calls", [("_partition_segment_acc_blocks", 3),
+                                          ("_partition_segment", 1)])
+def test_band_partitions_compile_for_v5e_at_bosch(one_chip, engine, calls):
+    """Both engines of the 640-1,664-lane band at the Bosch cell's shape:
+    two 512-lane passes of the accumulator kernel and the split-window
+    snapshot, which `partition_engine` picks there, and the
+    read-modify-write kernel's one pass over 1,024 lanes, which the race
+    of `exp/race_partition_band.py` runs beside it."""
+    assert pseg.partition_blocks_fits_vmem(BOSCH_LANES, BOSCH_BINS)
+    assert pseg.partition_fits_vmem(BOSCH_LANES, BOSCH_BINS)
+    assert not pseg.partition_acc_fits_vmem(BOSCH_LANES, BOSCH_BINS)
+    lowered = getattr(pseg, engine).lower(
+        *_partition_args(one_chip, BOSCH_ROWS, BOSCH_LANES, BOSCH_FEATURES,
+                         BOSCH_BINS))
+    assert lowered.compile().as_text().count("tpu_custom_call") >= calls
+
+
+def test_histogram_compiles_for_v5e_at_bosch(one_chip):
+    """The histogram at 968 columns x 64 bins (eight column tiles, the
+    last of 72 columns) over full 1,024-lane rows."""
+    assert pseg.fits_vmem(BOSCH_FEATURES, BOSCH_BINS, BOSCH_LANES)
+    payload, _, i32 = _partition_args(
+        one_chip, BOSCH_ROWS, BOSCH_LANES, BOSCH_FEATURES, BOSCH_BINS)[:3]
+    lowered = pseg._segment_histogram.lower(
+        payload, i32, i32, num_features=BOSCH_FEATURES, num_bins=BOSCH_BINS,
+        grad_col=BOSCH_FEATURES, hess_col=BOSCH_FEATURES + 1,
+        cnt_col=BOSCH_FEATURES + 2, interpret=False)
+    assert "tpu_custom_call" in lowered.compile().as_text()
 
 
 def test_histogram_compiles_for_v5e_at_epsilon(one_chip):
