@@ -6,24 +6,33 @@ Pallas kernel" is not seen), so the body is timed by leaving pieces out.
 `_pass_a_kernel` below is pass A of `pallas_segment._acc_kernel` (ring
 read, index arithmetic with rows in lanes, one destination one-hot, three
 part matmuls, the placement into the two accumulators, the flushes),
-without pass B and the final blend read, in two bodies that differ in the
-placement alone:
+without pass B and the final blend read, in two bodies that differ in how
+a full window of an accumulator reaches HBM, and in nothing else:
 
-    new   as the kernel stands since PR 36: the sub-tile part of each
-          cursor rides in the one-hot's destination, the [C + 24, P] block
-          is stored once to a scratch and each side is ONE masked store
-          into the tile-aligned [C + 8, P] window of its accumulator
-    old   as it stood from PR 29 to PR 35: a [C, P] block doubled to
-          [2C, P], rotated by a dynamic amount once a side and selected
-          into the whole [2C, P] accumulator, the value column's select
-          over [2C, P] twice; kept runnable so that both tables come from
-          one instrument
+    new   as the kernel stands since PR 38: each accumulator a ring of
+          `_ACC_WINDOWS` windows and a chunk's tail; a flush is ONE DMA
+          out of the ring's own window, the cursor runs on and wraps, the
+          put that fills a window first waits for the flush of the window
+          it runs on into, and a put that crosses the ring's end is two
+          aligned masked stores
+    old   as it stood from PR 36 to PR 37: a [2C, P] accumulator whose
+          first window is copied to a stage, sent from there, and whose
+          second half is then slid onto the first; each flush first waits
+          for the side's last one (the stage is one buffer); kept runnable
+          so that both tables come from one instrument
 
 and with a static set of stubs.  Both bodies:
 
     matmul2  one part matmul of the three (the two others' cost)
     parts    no bf16 hi/mid/lo split (the chunk stands in for each part)
-    flush    no write of a full accumulator window to HBM
+    flush    no write of a full accumulator window to HBM; its parts:
+               wait       (both) no wait inside the loop: every flush's
+                          wait is paid at the kernel's end instead, so
+                          the semaphores end balanced
+               stage      (old) no copy of the window to the stage
+               slide      (old) no slide of the accumulator
+               wrapstore  (new) no second store where a put crosses the
+                          ring's end
     body     nothing but the ring read and one add of the chunk (the DMA
              floor)
     rank     the lefts' ranks are the row number (no product)
@@ -35,26 +44,24 @@ and with a static set of stubs.  Both bodies:
                           the product
                predicate  the Bin::Split arithmetic is a parity of the bin
                catword    no word select chain of the categorical bitset
-
-the placement's own, by body:
-
-    place       (new) the accumulators take 8 rows of the block, not a
-                masked store of an aligned [C + 8, P] window a side
-    blockstore  (new) the block is not stored to its scratch (the value
+    place       the accumulators take 8 rows of the block, not a masked
+                store of an aligned [C + 8, P] window a side
+    blockstore  the block is not stored to its scratch (the value
                 column's select goes with it)
-    rotate      (old) no dynamic rotate of the doubled block
-    blend       (old) the accumulators take 8 rows, not a [2C, P] select
 
 the choices the new body was made from, by race (`--race`):
 
-    place=where   each side a select of the block's window against the
-                  accumulator's window read back, not a masked store
-    onehot=sides  a [C + 8, C] one-hot a side, each applied to the three
-                  parts (six products a chunk), no scratch and no dynamic
-                  slice of the block
-    above=roll    the lefts of a trip's earlier chunks by static sublane
-                  rotates of the lane-reduced counts, not by a second lane
-                  sum over the earlier chunks' rows broadcast down
+    windows=2    a ring of two windows: the put that fills one waits for
+                 the flush one chunk of rows earlier, as the stage did
+    wrap=always  the second store of a put unconditional, its mask empty
+                 where the put does not cross the ring's end: a store
+                 more a put for a region less
+    wrap=flush   the second store inside the flush's own region (a put
+                 that crosses the ring's end fills a window): a region
+                 less a put and no store more
+    above=roll  the lefts of a trip's earlier chunks by static sublane
+                rotates of the lane-reduced counts, not by a second lane
+                sum over the earlier chunks' rows broadcast down
     rank=roll  the exclusive prefix count by log-step roll-and-add along
                lanes, not `[8, C] x tri_t` on the MXU
     col=xpose  the split column by one XLU transposition of its window
@@ -68,8 +75,15 @@ the choices the new body was made from, by race (`--race`):
 and the loop's shape, `group2` / `group4`: that many chunks a loop trip on
 a ring twice as deep.  The product takes 2 at 128 lanes (`_pass_a_group`)
 and 1 in a 512-lane block; the stubs are read at one chunk a trip, where a
-piece's cost is not hidden behind another chunk's, and the placement's
-also at the shipped trip.
+piece's cost is not hidden behind another chunk's, and the flush's and
+the placement's also at the shipped trip.
+
+`--first S` sends S% of the rows to the first side (50, 90, 100; the
+bins are uniform, so it is a threshold): a tree's splits are lopsided,
+the larger child lies first, and the side that takes nearly every row
+flushes nearly every chunk, so the flush is read at 90 and 100, not at
+the even split the other pieces are read at (39% where nothing is asked
+for, as the tables of PR 29 and PR 36 were).
 
 The argument `512` (beside `128`, the default being both) times one
 512-lane column block: the kernel moves a 512-lane payload and routes from
@@ -78,13 +92,14 @@ a [N, 128] copy of the split window, read into a ring of its own, as
 
 A stubbed kernel computes nonsense; only its time is read.  With no stub
 the lefts it writes are checked against the portable partition, for a
-numerical and a categorical predicate.  Times are wall clock round a call
-whose payload is donated (no copy in the program) and whose scalar result
-is fetched; the cost of a piece is full minus stubbed, per chunk of CHUNK
-rows.  Pieces overlap in the kernel's schedule, so the costs need not add
-up to the body.
+numerical and a categorical predicate and under the timed one.  Times are
+wall clock round a call whose payload is donated (no copy in the program)
+and whose scalar result is fetched; the cost of a piece is full minus
+stubbed, per chunk of CHUNK rows.  Pieces overlap in the kernel's
+schedule, so the costs need not add up to the body.
 
 On the chip:   python exp/ablate_partition_body.py [--race] [128] [512]
+               [--first 50|90|100] [--body old|new]
                [--match A,B]   (only `full` and the labels that hold A or B)
 CPU rehearsal: JAX_PLATFORMS=cpu python exp/ablate_partition_body.py --interpret
 """
@@ -108,11 +123,14 @@ from lightgbm_tpu.ops import segment as seg
 from lightgbm_tpu.ops import pallas_segment as pseg
 
 CHUNK, C2, WIN, BLOCK_ROWS = pseg.CHUNK, pseg.C2, pseg.WIN, pseg.BLOCK_ROWS
-SHARED_STUBS = ("rank", "onehot", "matmul2", "parts", "flush", "body",
-                "route", "colselect", "mask", "predicate", "catword")
-#: the stubs of a body's placement, together its ceiling
-PLACEMENT = {"old": ("rotate", "blend"), "new": ("place", "blockstore")}
-RACES = ("place=where", "onehot=sides", "above=roll", "rank=roll",
+SHARED_STUBS = ("rank", "onehot", "matmul2", "parts", "flush", "wait", "body",
+                "route", "colselect", "mask", "predicate", "catword",
+                "place", "blockstore")
+#: the placement's stubs, together what is left of it
+PLACEMENT = ("place", "blockstore")
+#: the parts of a body's flush beside the wait
+FLUSH_PARTS = {"old": ("stage", "slide"), "new": ("wrapstore",)}
+RACES = ("windows=2", "wrap=always", "wrap=flush", "above=roll", "rank=roll",
          "col=xpose", "col=nt", "col=nthigh", "nl=scalar")
 
 
@@ -121,17 +139,19 @@ def _pass_a_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
     if blocks:
         route_hbm, *rest = rest
     payload_out, aux_out, nl_out, *rest = rest
-    (ring, lacc, racc, stage, rbuf, blk, win_t, sem_ring, sem_w, sem_r,
-     *rest) = rest
+    old = body == "old"
+    if old:
+        ring, lacc, racc, stage, rbuf, blk, win_t, *rest = rest
+    else:
+        ring, lacc, racc, blk, win_t, *rest = rest
+    sem_ring, sem_w, sem_r, *rest = rest
     if blocks:
         route_ring, sem_route = rest
-    old, sides_race = body == "old", "onehot=sides" in stubs
     start, count = scalars[0], scalars[1]
     left_value, right_value = fvals[0], fvals[1]
     shift = lax.rem(start, 8)
     base = start - shift
     nch = jnp.where(count > 0, (shift + count + CHUNK - 1) // CHUNK, 0)
-    iota_c2 = lax.broadcasted_iota(jnp.int32, (C2, 1), 0)[:, 0]
     iota_win = lax.broadcasted_iota(jnp.int32, (WIN, 1), 0)
     iota_p = lax.broadcasted_iota(jnp.int32, (1, P), 1)
     # the lanes of the split window: the 128-lane chunk's own, or those of
@@ -140,19 +160,23 @@ def _pass_a_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
     iota_ci = lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 0)
     iota_cj = lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 1)
     tri_t = (iota_ci < iota_cj).astype(jnp.float32)
-    # the one-hot's rows: the block's (new), a side's window, the chunk's
-    hot_rows = CHUNK if old else WIN if sides_race else BLOCK_ROWS
-    iota_hot = iota_ci if old else lax.broadcasted_iota(
-        jnp.int32, (hot_rows, CHUNK), 0)
+    iota_hot = lax.broadcasted_iota(jnp.int32, (BLOCK_ROWS, CHUNK), 0)
     iota_b = lax.broadcasted_iota(jnp.int32, (BLOCK_ROWS, 1), 0)
     tri_hot = (iota_hot < lax.broadcasted_iota(
-        jnp.int32, (hot_rows, CHUNK), 1)).astype(jnp.float32)
+        jnp.int32, (BLOCK_ROWS, CHUNK), 1)).astype(jnp.float32)
     chunk_of_row = lax.broadcasted_iota(jnp.int32, (8, CHUNK), 0)
     row_of_lane = lax.broadcasted_iota(jnp.int32, (8, CHUNK), 1)
     R, G = ring.shape[0], group
+    # the new body's ring: its rows and windows (the old accumulator is
+    # [2C, P], a window and the room a put overruns it by)
+    RS = lacc.shape[0] - CHUNK
+    NW = RS // CHUNK
     route_col = scalars[2]
     route_sel = (lax.broadcasted_iota(jnp.int32, (8, 128), 1) ==
                  route_col).astype(jnp.float32)
+
+    def hbm_window(dst_ref, row0):
+        return dst_ref.at[pl.ds(row0, CHUNK), :]
 
     def read_a(k, slot):
         rows = pl.ds(pl.multiple_of(base + k * CHUNK, 8), CHUNK)
@@ -164,19 +188,42 @@ def _pass_a_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
                 sem_route.at[slot]))
         return dmas
 
-    def drain(dst_ref, stage_buf, sem, pend):
-        @pl.when(pend > 0)
-        def _():
-            pltpu.make_async_copy(
-                stage_buf, dst_ref.at[pl.ds(0, CHUNK), :], sem).wait()
+    # ---- the old flush: wait, copy to a stage, send, slide ------------------
+    def old_landed(dst_ref, stage_buf, sem):
+        pltpu.make_async_copy(stage_buf, hbm_window(dst_ref, 0), sem).wait()
 
-    def flush(acc, dst_ref, wbase, stage_buf, sem, pend):
-        drain(dst_ref, stage_buf, sem, pend)
-        stage_buf[:] = acc[0:CHUNK]
+    def old_flush(acc, dst_ref, wbase, stage_buf, sem, pend):
+        if "wait" not in stubs:
+            @pl.when(pend > 0)
+            def _():
+                old_landed(dst_ref, stage_buf, sem)
+        if "stage" not in stubs:
+            stage_buf[:] = acc[0:CHUNK]
         pltpu.make_async_copy(
-            stage_buf, dst_ref.at[pl.ds(pl.multiple_of(wbase, 8), CHUNK), :],
+            stage_buf, hbm_window(dst_ref, pl.multiple_of(wbase, 8)),
             sem).start()
-        acc[0:CHUNK] = acc[CHUNK:C2]
+        if "slide" not in stubs:
+            acc[0:CHUNK] = acc[CHUNK:C2]
+
+    # ---- the new flush: reserve, (put), one DMA out of the ring -------------
+    def landed(acc, dst_ref, sem, f):
+        pltpu.make_async_copy(acc.at[pl.ds(0, CHUNK)], hbm_window(dst_ref, 0),
+                              sem.at[lax.rem(f, NW)]).wait()
+
+    def reserve(acc, dst_ref, sem, f, fl):
+        if "wait" in stubs or "flush" in stubs:
+            return
+
+        @pl.when((fl > 0) & (f >= NW - 1))
+        def _():
+            landed(acc, dst_ref, sem, f + 1 - NW)
+
+    def flush(acc, dst_ref, sem, f):
+        h = lax.rem(f, NW)
+        pltpu.make_async_copy(
+            acc.at[pl.ds(pl.multiple_of(h * CHUNK, CHUNK), CHUNK)],
+            hbm_window(dst_ref, pl.multiple_of(base + f * CHUNK, 8)),
+            sem.at[h]).start()
 
     @pl.when(nch > 0)
     def _prefetch_first():
@@ -186,7 +233,7 @@ def _pass_a_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
                 for dma in read_a(i, i):
                     dma.start()
 
-    # ---- the index arithmetic, rows in lanes: both bodies' ------------------
+    # ---- the index arithmetic, rows in lanes --------------------------------
     def go_left_lanes(raw):
         """`pseg._go_left_lanes` with its pieces stubbable."""
         if "predicate" in stubs:
@@ -205,10 +252,8 @@ def _pass_a_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
         return out
 
     def routed(k0, windows, lo_, ro_):
-        """(gl, dest, dest_r): the routing and the one-hot's destination,
-        the parent's (lefts from row 0, rights behind them) or the new
-        body's (each side from its cursor's part under 8); `dest_r` the
-        second one-hot's under `onehot=sides`."""
+        """(gl, dest): the routing and the one-hot's destination, each
+        side from its cursor's part under 8."""
         if "colselect" in stubs:
             raw = row_of_lane
         elif "col=xpose" in stubs:
@@ -263,9 +308,6 @@ def _pass_a_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
                                nl)
         else:
             nl = jnp.sum(gl, axis=1, keepdims=True)
-        if old:
-            dest = jnp.where(gl > 0, rank_l, nl + rank_r)
-            return gl, jnp.where(valid > 0, dest, -1), None
         # the trip's earlier chunks: their lefts by a second lane sum,
         # their rows from where the segment lies in the stream
         if "above=roll" in stubs:
@@ -280,12 +322,9 @@ def _pass_a_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
                    jnp.clip(shift - k0 * CHUNK, 0, span))
         r_l = (lo_ + nl_above) & 7
         r_r = (ro_ + n_above - nl_above) & 7
-        if sides_race:
-            return (gl, jnp.where(gl > 0, r_l + rank_l, -1),
-                    jnp.where(valid > gl, r_r + rank_r, -1))
         dest = jnp.where(gl > 0, r_l + rank_l,
                          ((r_l + nl + 7) & -8) + r_r + rank_r)
-        return gl, jnp.where(valid > 0, dest, -1), None
+        return gl, jnp.where(valid > 0, dest, -1)
 
     def product(mat, data):
         hi, mid, lo = (data, data, data) if "parts" in stubs \
@@ -293,32 +332,19 @@ def _pass_a_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
         perm = jnp.dot(mat, hi, preferred_element_type=jnp.float32)
         if "matmul2" in stubs:
             rest = mid + lo
-            if perm.shape[0] > CHUNK:
-                rest = jnp.pad(rest, ((0, perm.shape[0] - CHUNK), (0, 0)))
-            return perm + rest
+            return perm + jnp.pad(rest, ((0, perm.shape[0] - CHUNK), (0, 0)))
         return (perm + jnp.dot(mat, mid, preferred_element_type=jnp.float32)
                 + jnp.dot(mat, lo, preferred_element_type=jnp.float32))
 
-    def permuted(g, k, data, gl, dest, dest_r, lo_):
-        """(nlk, nrk, block) of chunk k: the parent's doubled block, the
-        two sides' windows, or None with the block in its scratch."""
+    def permuted(g, k, data, gl, dest, lo_):
+        """(nlk, nrk) of chunk k, its block in its scratch."""
         nlk = jnp.sum(jnp.where(chunk_of_row == g, gl, 0))
         lo = jnp.maximum(shift - k * CHUNK, 0)
         hi = jnp.minimum(shift + count - k * CHUNK, CHUNK)
         nrk = jnp.maximum(hi - lo, 0) - nlk
-
-        def hot(dest):
-            return tri_hot if "onehot" in stubs \
-                else (iota_hot == dest[g:g + 1, :]).astype(jnp.float32)
-
-        if old:
-            perm = product(hot(dest), data)
-            return nlk, nrk, jnp.concatenate([perm, perm], axis=0)
-        if sides_race:
-            return nlk, nrk, tuple(
-                jnp.where(iota_p == value_col, value, product(hot(d), data))
-                for d, value in ((dest, left_value), (dest_r, right_value)))
-        perm = product(hot(dest), data)
+        hot = tri_hot if "onehot" in stubs \
+            else (iota_hot == dest[g:g + 1, :]).astype(jnp.float32)
+        perm = product(hot, data)
         if "blockstore" not in stubs:
             blk[g, 0:BLOCK_ROWS] = jnp.where(
                 iota_p == value_col,
@@ -326,70 +352,92 @@ def _pass_a_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
                 perm)
         else:
             blk[g, 0:8] = perm[0:8]
-        return nlk, nrk, None
+        return nlk, nrk
 
-    # ---- the parent's placement: rotate the doubled block, blend ------------
-    def blend(acc, placed, cnt, off, value):
-        if "blend" in stubs:
-            acc[0:8] = placed[0:8]
-            return
-        placed = jnp.where(iota_p == value_col, value, placed)
-        region = ((iota_c2 >= off) & (iota_c2 < off + cnt))[:, None]
-        acc[:] = jnp.where(region, placed, acc[:])
-
-    # ---- the new placement: a tile-aligned window a side --------------------
-    def put(acc, cursor, cnt, rows):
+    # ---- the placement: a tile-aligned window a side ------------------------
+    def put(acc, cursor, cnt, g, tile_src, second=False):
+        """The block's window from `tile_src` to the accumulator's window
+        at the cursor's tile; the new body's second store where the rows
+        cross the ring's end (with `wrap=flush` the caller asks for it
+        apart, `second`, from inside the flush's region)."""
         if "place" in stubs:
-            acc[0:8] = rows[0:8]
+            if not second:
+                acc[0:8] = blk[g, 0:8]
             return
         r = cursor & 7
-        win = pl.ds(pl.multiple_of(cursor - r, 8), WIN)
-        region = (iota_win >= r) & (iota_win < r + cnt)
-        if "place=where" in stubs:
-            acc[win] = jnp.where(region, rows, acc[win])
-        else:
-            pltpu.store(acc.at[win], rows,
+        tile = pl.multiple_of(cursor - r, 8)
+        rows = blk[g, pl.ds(tile_src, WIN)]
+        if not second:
+            region = (iota_win >= r) & (iota_win < r + cnt)
+            pltpu.store(acc.at[pl.ds(tile, WIN)], rows,
                         mask=jnp.broadcast_to(region, rows.shape))
+        if old or "wrapstore" in stubs or (
+                "wrap=flush" in stubs and not second):
+            return
+        wraps = cursor + cnt > RS
+        if "wrap=always" in stubs:
+            over = pl.multiple_of(jnp.where(wraps, RS - tile, 0), 8)
+            spill = blk[g, pl.ds(pl.multiple_of(tile_src + over, 8), WIN)]
+            pltpu.store(acc.at[pl.ds(0, WIN)], spill, mask=jnp.broadcast_to(
+                iota_win + over < jnp.where(wraps, r + cnt, 0), rows.shape))
+            return
 
-    def place(g, nlk, nrk, block, carry):
+        @pl.when(wraps)
+        def _wrap():
+            over = pl.multiple_of(RS - tile, 8)
+            spill = blk[g, pl.ds(pl.multiple_of(tile_src + over, 8), WIN)]
+            pltpu.store(acc.at[pl.ds(0, WIN)], spill, mask=jnp.broadcast_to(
+                iota_win + over < r + cnt, rows.shape))
+
+    def fills(cursor, cnt):
+        return (lax.rem(cursor, CHUNK) + cnt >= CHUNK).astype(jnp.int32)
+
+    def moved(cursor, cnt):
+        return jnp.where(cursor + cnt >= RS, cursor + cnt - RS, cursor + cnt)
+
+    def place(g, nlk, nrk, carry):
         """Each side placed and, where its window filled, flushed: the
         first side, then the staged one, as the kernel orders them."""
         nl, nr, lo_, ro_, lfl, rfl, pl_, pr_ = carry
+        tile_r = pl.multiple_of(((lo_ & 7) + nlk + 7) & -8, 8)
         if old:
-            if "rotate" in stubs:
-                placed_l = placed_r = block
-            else:
-                placed_l = pltpu.roll(block, lo_, axis=0)
-                placed_r = pltpu.roll(block, ro_ - nlk + C2, axis=0)
-            sides = (lambda: blend(lacc, placed_l, nlk, lo_, left_value),
-                     lambda: blend(racc, placed_r, nrk, ro_, right_value))
-        elif sides_race:
-            sides = (lambda: put(lacc, lo_, nlk, block[0]),
-                     lambda: put(racc, ro_, nrk, block[1]))
-        else:
-            tile_r = pl.multiple_of(((lo_ & 7) + nlk + 7) & -8, 8)
-            sides = (lambda: put(lacc, lo_, nlk, blk[g, 0:WIN]),
-                     lambda: put(racc, ro_, nrk, blk[g, pl.ds(tile_r, WIN)]))
-        fl = ((lo_ + nlk) >= CHUNK).astype(jnp.int32)
-        fr = ((ro_ + nrk) >= CHUNK).astype(jnp.int32)
-        sides[0]()
-        if "flush" not in stubs:
-            @pl.when(fl > 0)
-            def _flush_l():
-                flush(lacc, payload_out, base + lfl * CHUNK, stage, sem_w,
-                      pl_)
-        sides[1]()
-        if "flush" in stubs:
+            fl = ((lo_ + nlk) >= CHUNK).astype(jnp.int32)
+            fr = ((ro_ + nrk) >= CHUNK).astype(jnp.int32)
+            put(lacc, lo_, nlk, g, 0)
+            if "flush" not in stubs:
+                @pl.when(fl > 0)
+                def _flush_l():
+                    old_flush(lacc, payload_out, base + lfl * CHUNK, stage,
+                              sem_w, pl_)
+            put(racc, ro_, nrk, g, tile_r)
+            if "flush" not in stubs:
+                @pl.when(fr > 0)
+                def _flush_r():
+                    old_flush(racc, aux_out, base + rfl * CHUNK, rbuf, sem_r,
+                              pr_)
+                pl_, pr_ = jnp.maximum(pl_, fl), jnp.maximum(pr_, fr)
             return (nl + nlk, nr + nrk, lo_ + nlk - fl * CHUNK,
                     ro_ + nrk - fr * CHUNK, lfl + fl, rfl + fr, pl_, pr_)
 
-        @pl.when(fr > 0)
-        def _flush_r():
-            flush(racc, aux_out, base + rfl * CHUNK, rbuf, sem_r, pr_)
-
-        return (nl + nlk, nr + nrk, lo_ + nlk - fl * CHUNK,
-                ro_ + nrk - fr * CHUNK, lfl + fl, rfl + fr,
-                jnp.maximum(pl_, fl), jnp.maximum(pr_, fr))
+        fl, fr = fills(lo_, nlk), fills(ro_, nrk)
+        reserve(lacc, payload_out, sem_w, lfl, fl)
+        put(lacc, lo_, nlk, g, 0)
+        if "flush" not in stubs:
+            @pl.when(fl > 0)
+            def _flush_l():
+                if "wrap=flush" in stubs:
+                    put(lacc, lo_, nlk, g, 0, second=True)
+                flush(lacc, payload_out, sem_w, lfl)
+        reserve(racc, aux_out, sem_r, rfl, fr)
+        put(racc, ro_, nrk, g, tile_r)
+        if "flush" not in stubs:
+            @pl.when(fr > 0)
+            def _flush_r():
+                if "wrap=flush" in stubs:
+                    put(racc, ro_, nrk, g, tile_r, second=True)
+                flush(racc, aux_out, sem_r, rfl)
+        return (nl + nlk, nr + nrk, moved(lo_, nlk), moved(ro_, nrk),
+                lfl + fl, rfl + fr, pl_, pr_)
 
     def body_trip(t, carry):
         """Chunks G t .. G t + G - 1 (the caller's segment has a multiple
@@ -420,14 +468,16 @@ def _pass_a_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
 
         @pl.when(t == 0)
         def _seed():
-            lacc[0:CHUNK] = datas[0]
+            if old:
+                lacc[0:CHUNK] = datas[0]
+            else:
+                lacc[0:8] = ring[slots[0], 0:8]
 
         lo_, ro_ = carry[2], carry[3]
-        gl, dest, dest_r = routed(k0, windows, lo_, ro_)
+        gl, dest = routed(k0, windows, lo_, ro_)
         chunks = []
         for i in range(G):
-            chunks.append(permuted(i, k0 + i, datas[i], gl, dest, dest_r,
-                                   lo_))
+            chunks.append(permuted(i, k0 + i, datas[i], gl, dest, lo_))
             lo_ = lo_ + chunks[i][0]
         for i in range(G):
             carry = place(i, *chunks[i], carry)
@@ -438,8 +488,31 @@ def _pass_a_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
         (jnp.int32(0), jnp.int32(0), shift, shift,
          jnp.int32(0), jnp.int32(0), jnp.int32(0), jnp.int32(0)))
     nl_out[0] = out[0]
-    drain(payload_out, stage, sem_w, out[6])
-    drain(aux_out, rbuf, sem_r, out[7])
+    if "flush" in stubs or "body" in stubs:
+        return
+    def settle(acc, dst_ref, sem, stage_buf, flushes, pend):
+        """What the loop has not waited for: with `wait` stubbed every
+        flush, else the old body's last one, the new body's last NW - 1."""
+        if "wait" in stubs:
+            def one(f, c):
+                if old:
+                    old_landed(dst_ref, stage_buf, sem)
+                else:
+                    landed(acc, dst_ref, sem, f)
+                return c
+            lax.fori_loop(0, flushes, one, 0)
+        elif old:
+            @pl.when(pend > 0)
+            def _():
+                old_landed(dst_ref, stage_buf, sem)
+        else:
+            for j in range(1, NW):
+                @pl.when(flushes >= j)
+                def _(j=j):
+                    landed(acc, dst_ref, sem, flushes - j)
+
+    settle(lacc, payload_out, sem_w, stage if old else None, out[4], out[6])
+    settle(racc, aux_out, sem_r, rbuf if old else None, out[5], out[7])
 
 
 @functools.partial(jax.jit, static_argnames=("value_col", "num_bins",
@@ -460,6 +533,15 @@ def pass_a(payload, aux, route, start, count, pred, left_value, right_value,
                              stubs=stubs, group=group, body=body,
                              blocks=blocks)
     depth = 2 * group
+    windows = 2 if "windows=2" in stubs else pseg._ACC_WINDOWS
+    f32 = jnp.float32
+    if body == "old":
+        accs = [pltpu.VMEM((C2, P), f32), pltpu.VMEM((C2, P), f32),
+                pltpu.VMEM((CHUNK, P), f32), pltpu.VMEM((CHUNK, P), f32)]
+        flush_sems = [pltpu.SemaphoreType.DMA(())] * 2
+    else:
+        accs = [pltpu.VMEM(((windows + 1) * CHUNK, P), f32)] * 2
+        flush_sems = [pltpu.SemaphoreType.DMA((windows,))] * 2
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     payload_new, aux_new, nl = pl.pallas_call(
         kern,
@@ -467,20 +549,12 @@ def pass_a(payload, aux, route, start, count, pred, left_value, right_value,
             num_scalar_prefetch=2, grid=(1,),
             in_specs=[hbm, hbm] + [hbm] * blocks,
             out_specs=(hbm, hbm, pl.BlockSpec(memory_space=pltpu.SMEM)),
-            scratch_shapes=[
-                pltpu.VMEM((depth, CHUNK, P), jnp.float32),
-                pltpu.VMEM((C2, P), jnp.float32),
-                pltpu.VMEM((C2, P), jnp.float32),
-                pltpu.VMEM((CHUNK, P), jnp.float32),
-                pltpu.VMEM((CHUNK, P), jnp.float32),
-                pltpu.VMEM((group, pseg.BLOCK_SCRATCH_ROWS, P),
-                           jnp.float32),
-                pltpu.VMEM((group, 128, CHUNK), jnp.float32),
-                pltpu.SemaphoreType.DMA((depth,)),
-                pltpu.SemaphoreType.DMA(()),
-                pltpu.SemaphoreType.DMA(()),
-            ] + [pltpu.VMEM((depth, CHUNK, 128), jnp.float32),
-                 pltpu.SemaphoreType.DMA((depth,))] * blocks),
+            scratch_shapes=[pltpu.VMEM((depth, CHUNK, P), f32)] + accs + [
+                pltpu.VMEM((group, pseg.BLOCK_SCRATCH_ROWS, P), f32),
+                pltpu.VMEM((group, 128, CHUNK), f32),
+                pltpu.SemaphoreType.DMA((depth,))] + flush_sems + [
+                pltpu.VMEM((depth, CHUNK, 128), f32),
+                pltpu.SemaphoreType.DMA((depth,))] * blocks),
         out_shape=(jax.ShapeDtypeStruct(payload.shape, payload.dtype),
                    jax.ShapeDtypeStruct(aux.shape, aux.dtype),
                    jax.ShapeDtypeStruct((1,), jnp.int32)),
@@ -493,18 +567,20 @@ def pass_a(payload, aux, route, start, count, pred, left_value, right_value,
 
 def stub_sets(body, race):
     """(label, stubs, group) of every kernel timed for `body`: each stub
-    at one chunk a trip, the placement's also at two and four, and the
-    loop's shapes."""
-    placement = PLACEMENT[body]
+    at one chunk a trip, the flush's and the placement's also at two and
+    four, and the loop's shapes."""
+    parts = FLUSH_PARTS[body]
     sets = [("full", (), 1)] + [(s, (s,), 1)
-                                for s in SHARED_STUBS + placement] + [
+                                for s in SHARED_STUBS + parts] + [
         ("matmul2+onehot+rank", ("matmul2", "onehot", "rank"), 1),
-        ("placement", placement, 1),
+        ("placement", PLACEMENT, 1),
         ("index chain", ("onehot", "rank", "route"), 1),
         ("group2", (), 2), ("group4", (), 4)]
     for g in (2, 4):
         sets += [("group%d+%s" % (g, label), stubs, g) for label, stubs in (
-            ("placement", placement), ("flush", ("flush",)),
+            ("placement", PLACEMENT), ("flush", ("flush",)),
+            ("wait", ("wait",)), *((part, (part,)) for part in parts),
+            ("wait+" + "+".join(parts), ("wait",) + parts),
             ("index chain", ("onehot", "rank", "route")),
             ("body", ("body",)))]
     if body == "new" and race:
@@ -513,7 +589,8 @@ def stub_sets(body, race):
     return sets
 
 
-def ablate(P, interpret, race, times, match=None):
+def ablate(P, interpret, race, times, match=None, first=None,
+           bodies=("old", "new")):
     assert P in (128, 512), P
     n = 2048 if interpret else (1 << 22 if P == 128 else 1 << 20)
     F, B = 28, 256
@@ -529,6 +606,10 @@ def ablate(P, interpret, race, times, match=None):
     lv, rv = jnp.float32(1.5), jnp.float32(-2.5)
     start, count = jnp.int32(0), jnp.int32(n)
     fresh = jax.jit(lambda x: x + 0.0)
+
+    # the share of the rows the timed predicate sends to the first side
+    # (bins are uniform over 256): 100 of 256 where none is asked for
+    timed_threshold = 100 if first is None else round(B * first / 100) - 1
 
     def pred(**kw):
         base = dict(
@@ -554,10 +635,11 @@ def ablate(P, interpret, race, times, match=None):
     # the unstubbed copies write the lefts the portable partition writes
     cat = pred(is_cat=jnp.bool_(True), bitset=jnp.asarray(
         np.isin(np.arange(B), (0, 31, 32, 63, 64, 100, B - 1)), jnp.int32))
-    for pr in (pred(), cat):
+    timed = pred(threshold=jnp.int32(timed_threshold))
+    for pr in (pred(), cat) + ((timed,) if first is not None else ()):
         ref, _, ref_nl = seg.partition_segment(
             payload, jnp.zeros_like(payload), start, count, pr, lv, rv, F + 3)
-        for body in ("old", "new"):
+        for body in bodies:
             variants = [((), g) for g in ((1,) if P > 128 else (1, 2, 4))]
             if body == "new" and race:
                 variants += [((c,), 1) for c in RACES]
@@ -570,24 +652,27 @@ def ablate(P, interpret, race, times, match=None):
         del out, ref
 
     chunks = n // CHUNK
-    for body in ("old", "new"):
+    for body in bodies:
         for label, stubs, group in stub_sets(body, race):
             if P > 128 and group > 2:
                 continue        # four chunks of 512 lanes pass the VMEM plan
-            if match and label != "full" and not any(
+            if match and label not in ("full", "group2") and not any(
                     m in label for m in match.split(",")):
                 continue
             name = "%d %s %s" % (P, body, label)
             if interpret:
-                call(pred(), body, stubs, group)
+                call(timed, body, stubs, group)
                 times[name] = None
                 continue
-            call(pred(), body, stubs, group)
-            ts = sorted(call(pred(), body, stubs, group)[0] for _ in range(5))
+            call(timed, body, stubs, group)
+            ts = sorted(call(timed, body, stubs, group)[0] for _ in range(5))
             times[name] = ts[2]
-            full = times["%d %s %s" % (P, body, "full")]
-            print("%-44s %8.3f ms  %7.1f ns/chunk  (full - this: %7.1f)"
-                  % (name, ts[2] * 1e3, ts[2] / chunks * 1e9,
+            # a piece costs what its own trip's unstubbed body takes more
+            whole = label.split("+")[0] if label.startswith("group") \
+                else "full"
+            full = times.get("%d %s %s" % (P, body, whole), ts[2])
+            print("%-44s %8.3f ms  %7.1f ns/chunk  (%s - this: %7.1f)"
+                  % (name, ts[2] * 1e3, ts[2] / chunks * 1e9, whole,
                      (full - ts[2]) / chunks * 1e9), flush=True)
     return n
 
@@ -599,15 +684,20 @@ def main():
     if not interpret and jax.default_backend() != "tpu":
         sys.exit("ablate_partition_body: platform is %r, not tpu"
                  % jax.default_backend())
-    lanes = [int(a) for a in argv if a.isdigit()] or [128, 512]
+    lanes = [int(a) for a in argv if a in ("128", "512")] or [128, 512]
     match = argv[argv.index("--match") + 1] if "--match" in argv else None
+    first = int(argv[argv.index("--first") + 1]) if "--first" in argv else None
+    bodies = (argv[argv.index("--body") + 1],) if "--body" in argv \
+        else ("old", "new")
     times, rows = {}, {}
     for P in lanes:
-        rows[P] = ablate(P, interpret, race, times, match)
+        rows[P] = ablate(P, interpret, race, times, match, first, bodies)
     line = json.dumps({"rows": rows, "chunk": CHUNK, "interpret": interpret,
-                       "seconds": times})
+                       "first": first, "seconds": times})
     os.makedirs(os.path.join(REPO, "chiprun_out"), exist_ok=True)
-    with open(os.path.join(REPO, "chiprun_out", "ablate_partition_body.json"),
+    with open(os.path.join(REPO, "chiprun_out",
+                           "ablate_partition_body%s.json"
+                           % ("" if first is None else "_first%d" % first)),
               "w") as fh:
         fh.write(line + "\n")
     print(line, flush=True)

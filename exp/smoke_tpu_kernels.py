@@ -189,7 +189,10 @@ def partition_acc():
     a numerical predicate, a categorical one whose set bits sit at the
     packed bitset's word edges, and a missing-value one over an
     EFB-decoded column: no cell has a categorical column, so the vector
-    shifts and the word select are seen by Mosaic here or nowhere."""
+    shifts and the word select are seen by Mosaic here or nowhere; and
+    under lopsided splits (nine rows in ten and all of them on one side)
+    over segments of some hundred chunks, where one accumulator's ring
+    flushes every chunk and goes round with its DMAs in flight."""
     edges = np.isin(np.arange(B), (0, 31, 32, 63, 64, 100, 191, 192, B - 1))
     preds = {
         "numerical": PRED,
@@ -208,6 +211,12 @@ def partition_acc():
                 p, a, s, c, pred, LV, RV, VAL, B, rf, **IK),
             PAY, pred, VAL,
             segs((128, 3000), (7, 8000), (513, 256), (0, 8192)))
+    for threshold in (229, B - 1):
+        pred = PRED._replace(threshold=jnp.int32(threshold))
+        check_partition(
+            lambda p, a, s, c, rf: pseg.partition_segment_acc(
+                p, a, s, c, pred, LV, RV, VAL, B, rf, **IK),
+            PAY, pred, VAL, segs((7, 8000), (5, 100_000)))
     # bins past 256: the column is read out at HIGHEST, 32 words of bitset
     wide_b = 1000
     wide = make_payload(N, F, wide_b, width=128)
@@ -218,7 +227,7 @@ def partition_acc():
             lambda p, a, s, c, rf: pseg.partition_segment_acc(
                 p, a, s, c, pred, LV, RV, VAL, wide_b, rf, **IK),
             wide, pred, VAL, segs((7, 8000), (513, 256)))
-    return {"predicates": sorted(preds) + ["1000_bins"],
+    return {"predicates": sorted(preds) + ["lopsided", "1000_bins"],
             "ms": median_ms(lambda: int(pseg.partition_segment_acc(
                 PAY, jnp.zeros_like(PAY), jnp.int32(0), jnp.int32(N), PRED,
                 LV, RV, VAL, B, **IK)[2]))}
@@ -271,7 +280,9 @@ def blocks():
 
     kernel = run()
     cases = [(0, rows, 1300, 30), (7, 256, 3, 20), (128, 3000, 511, 40),
-             (513, 100_000, 512, 31), (300_001, 65_536, 1999, 10)]
+             (513, 100_000, 512, 31), (300_001, 65_536, 1999, 10),
+             # lopsided: 19 rows in 20 on one side, and all of them
+             (9, 50_000, 77, 60), (3, 50_000, 1400, 63)]
     # the right child first in every other case
     for i, (s0, c0, col, thr) in enumerate(cases):
         if s0 + c0 > rows:

@@ -17,10 +17,11 @@ kernels keep every one-hot in VMEM:
 - `partition_segment_acc`: the three compact passes of
   `ops.segment.partition_segment` fused into one kernel (`_acc_kernel`);
   each chunk's stable partition is a one-hot permutation matmul in VMEM,
-  put at the cursors of per-side accumulator windows that flush whole
-  aligned chunks.  `partition_segment_acc_blocks` runs the same kernel once a
-  512-lane window of a payload too wide for one pass, routing every pass
-  from a snapshot of the split column (`_snap_window_kernel`);
+  put at the cursors of per-side accumulator rings that send each full,
+  aligned window to HBM by one DMA.  `partition_segment_acc_blocks` runs
+  the same kernel once a 512-lane window of a payload too wide for one
+  pass, routing every pass from a snapshot of the split column
+  (`_snap_window_kernel`);
   `partition_segment` (`_partition_kernel`) is the older read-modify-write
   kernel, whose plan fits between the two (640-1,664 lanes): raced there
   on the chip it is five to six times slower than the block kernel
@@ -151,25 +152,44 @@ _RING_DEPTH = 2
 _BLOCK_WIDTH = 512
 
 
+#: windows of CHUNK rows in an accumulator's ring.  A flush of a full
+#: window is a DMA out of the ring itself, and the cursor may not run on
+#: into the window behind before THAT window's last flush has landed: with
+#: two windows that is the flush one chunk of rows earlier (the wait the
+#: stage buffer cost before PR 38, 114-146 ns of a 128-lane chunk's 800
+#: on a lopsided split, which pays it every chunk), with three the one
+#: before it, a trip of the body away.  In a 512-lane block the wait does
+#: not show either way, and the third window fits every plan the gates
+#: admit (PERF.md section 6, PR 38): one length for every shape.
+_ACC_WINDOWS = 3
+
+
 def _acc_plan_bytes(payload_width: int, num_bins: int, group: int) -> int:
-    """VMEM plan of the accumulator-window partition kernel with pass A
-    taking `group` chunks a loop trip: the read ring (`_RING_DEPTH` groups
-    of chunks), two [2C, P] accumulators, stage/blend buffers, the P-wide
-    placement intermediates of each chunk in flight (10C rows: the parts,
-    the permuted block's [2C + 24, P] scratch and the windows read from
-    it take some 7C since PR 36; the doubled and the two rotated blocks
-    the plan was written for are gone, and the plan keeps their room, so
-    that the gates admit what they admitted), the [C, C] machinery (`tri_t`
+    """VMEM plan of the accumulator partition kernel with pass A taking
+    `group` chunks a loop trip: the read ring (`_RING_DEPTH` groups of
+    chunks), the two accumulators (each a ring of `_ACC_WINDOWS` chunks
+    and a chunk's tail behind it, which takes the part of a placement's
+    aligned window that lies past the ring's end: 8C rows; and nothing
+    beside them since PR 38: a flush is a DMA out of the ring itself and
+    the final blend reads into a slot of the read ring, so the flush
+    stage and the blend buffer are gone), the P-wide placement
+    intermediates of each chunk in flight (10C rows: the parts, the
+    permuted block's [2C + 24, P] scratch and the windows read from it
+    take some 7C since PR 36; the plan keeps the room of the doubled and
+    rotated blocks it was written for), the [C, C] machinery (`tri_t`
     and the row iota once, a one-hot as a mask and as f32 a chunk in
     flight, and the four more the plan has carried since the widths it
     admits were proven on the chip) and the masked split window of each
     chunk of a trip.  The index arithmetic itself is a few [8, C]
     vectors, and the categorical bitset is `num_bins` bits of scalar
-    memory: neither is planned for, and `num_bins` no longer enters."""
+    memory: neither is planned for, and `num_bins` no longer enters.
+    (Before PR 38 the accumulators and their two buffers were 6C rows;
+    the gates answer at 8C as they did at 6C at every lane-padded width
+    to 4,480 lanes: tests/test_pallas_segment.py holds the table.)"""
     P, C = payload_width, CHUNK
-    return (4 * P * C * (_RING_DEPTH * group   # ring
-                         + 6                   # accs(4C) + stage/rbuf(2C)
-                         + 10 * group)         # placement intermediates
+    return (4 * P * C * (_RING_DEPTH * group          # ring
+                         + 2 * (_ACC_WINDOWS + 1)     # two rings, their tails
+                         + 10 * group)                # placement intermediates
             + 4 * C * C * (6 + 2 * group)
             + 4 * 128 * C * group)
 
@@ -835,20 +855,37 @@ BLOCK_ROWS = CHUNK + 24
 #: at a tile up to row C + 8; the rows past the block's own are never
 #: written, and the placement's mask keeps them out
 BLOCK_SCRATCH_ROWS = BLOCK_ROWS + CHUNK
+#: rows of an accumulator: a ring of `_ACC_WINDOWS` windows of CHUNK rows
+#: and a chunk's tail, so that the [C + 8, P] window at any tile of the
+#: ring lies inside the buffer
+ACC_ROWS = (_ACC_WINDOWS + 1) * CHUNK
 
 
 def _acc_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
                 P, B, value_col, group=1, lane_lo=None):
     """Accumulator-window partition: same contract as `_partition_kernel`,
     restructured around the measured bottleneck (per-chunk latency, not
-    bandwidth).  Lefts and rights accumulate in VMEM windows [2C, P] that
-    flush ALIGNED, FULL chunks to HBM only when a window fills — so the
-    per-chunk read-modify-write round trips of the RMW kernel collapse to
-    one amortized direct write per side, reads prefetch on a
-    double-buffered ring, and exactness costs three ONE-pass matmuls on a
-    bf16-exact hi/mid/lo decomposition instead of a 6-pass HIGHEST.
-    Only the LAST window of a segment needs a blend read (its tail crosses
-    into the next leaf's rows).
+    bandwidth).  Lefts and rights accumulate in VMEM, each side in a RING
+    of `_ACC_WINDOWS` windows of CHUNK rows (`lacc`, `racc`: [ACC_ROWS, P],
+    the ring and a chunk's tail behind it), and a window goes to HBM
+    ALIGNED and FULL when the side's cursor leaves it — so the per-chunk
+    read-modify-write round trips of the RMW kernel collapse to one
+    amortized direct write per side, reads prefetch on a double-buffered
+    ring, and exactness costs three ONE-pass matmuls on a bf16-exact
+    hi/mid/lo decomposition instead of a 6-pass HIGHEST.  Only the LAST
+    window of a segment needs a blend read (its tail crosses into the
+    next leaf's rows).
+
+    A flush is ONE DMA out of the ring's own window (`flush`), nothing
+    copied: the cursor runs on into the next window and, past the last,
+    comes back into the first.  The one wait it costs (`reserve`) is for
+    the flush `_ACC_WINDOWS - 1` earlier, whose window the cursor is
+    about to enter, and only the put that fills a window pays it; a put
+    that crosses the ring's end is two aligned masked stores (`put`).
+    Until PR 38 a flush waited for the one before it, copied the window
+    to a stage and slid the accumulator's second half onto its first:
+    two [C, P] copies and a wait a chunk of rows, which a lopsided split
+    pays every chunk on one side (PERF.md §6, PR 38).
 
     "Lefts" below are the rows of the FIRST side, which pass A writes in
     place, and "rights" those of the STAGED side, which it parks in `aux`
@@ -871,8 +908,10 @@ def _acc_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
     Each side's placement is then ONE masked store into the tile-aligned
     [C + 8, P] window of its accumulator from tile q, of the window of
     the block that starts at the side's own tile (`put`): no rotate,
-    nothing of the accumulators' [2C, P] computed or read (PERF.md §6,
-    PR 36; pass B, whose rows arrive contiguous, keeps its one rotate).
+    nothing of an accumulator computed or read (PERF.md §6, PR 36; pass
+    B, whose rows arrive contiguous, keeps its one rotate of the doubled
+    chunk, to the cursor's part under 8, and places the head of it by
+    the same `put`).
 
     Where the 256 rows of a chunk go is arithmetic on 256 numbers, and it
     is done with ROWS IN LANES, the chunks of a trip as the rows of one
@@ -923,7 +962,7 @@ def _acc_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
     if blocks:
         route_hbm, *rest = rest
     payload_out, aux_out, nl_out, *rest = rest
-    ring, lacc, racc, stage, rbuf, blk, sem_ring, sem_w, sem_r, *rest = rest
+    ring, lacc, racc, blk, sem_ring, sem_w, sem_r, *rest = rest
     if blocks:
         route_ring, sem_route = rest
     start = scalars[0]
@@ -935,9 +974,12 @@ def _acc_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
     base = start - shift
     nch = jnp.where(count > 0, (shift + count + CHUNK - 1) // CHUNK, 0)
     iota_rows = _row_iota()
-    iota_c2 = lax.broadcasted_iota(jnp.int32, (C2, 1), 0)[:, 0]
     iota_win = lax.broadcasted_iota(jnp.int32, (WIN, 1), 0)
     iota_p = lax.broadcasted_iota(jnp.int32, (1, P), 1)
+    # an accumulator is a ring of NW windows of CHUNK rows and a chunk's
+    # tail behind it; a cursor is a row of the ring, [0, RS)
+    NW = _ACC_WINDOWS
+    RS = NW * CHUNK
     # the split column's 128-lane window of a chunk (in ring slot `slot`,
     # loaded as `data`) and the column's place in it: a column block's
     # frozen copy, the chunk itself (at 128 lanes, and under the
@@ -1003,52 +1045,78 @@ def _acc_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
     route_sel = (lax.broadcasted_iota(jnp.int32, (8, route_w), 1) ==
                  route_col).astype(jnp.float32)
 
-    def blend(acc, placed, cnt, off, value):
-        """Pass B's: write the child's tree output into the value column
-        of the placed rows and blend region [off, off+cnt) into the
-        accumulator.  where, NOT an arithmetic blend: rows outside the
-        region may hold uninitialized accumulator memory, and 0 * NaN
-        poisons a multiply."""
-        placed = jnp.where(iota_p == value_col, value, placed)
-        region = ((iota_c2 >= off) & (iota_c2 < off + cnt))[:, None]
-        acc[:] = jnp.where(region, placed, acc[:])
-
-    def put(acc, cursor, cnt, rows):
+    def put(acc, cursor, cnt, rows, spill):
         """Rows [r, r + cnt) of `rows` ([C + 8, P]; r = cursor & 7, where
-        the one-hot put them) to the accumulator's rows [cursor,
-        cursor + cnt): ONE masked store of the TILE-ALIGNED window that
-        holds them, no rotate and no read of the accumulator (a select
-        against the window read back took 1% longer at 128 lanes and 3%
-        in a 512-lane block, PERF.md §6, PR 36).  Nothing arithmetic
-        touches a row outside the region: it may hold uninitialized
-        memory on either side."""
+        the one-hot or pass B's rotate put them) to the ring's rows
+        [cursor, cursor + cnt): ONE masked store of the TILE-ALIGNED
+        window that holds them, no rotate and no read of the accumulator
+        (a select against the window read back took 1% longer at 128
+        lanes and 3% in a 512-lane block, PERF.md §6, PR 36).  Where the
+        rows run past the ring's end, that store leaves them in the tail
+        behind it and a second one, of the block's window `spill(over)`
+        that starts `over` rows further on, puts them at the ring's head:
+        the ring, a window and a chunk are multiples of a sublane tile,
+        so both stores are aligned.  A masked-out sublane is not written:
+        neither store touches a row outside [cursor, cursor + cnt), which
+        may hold uninitialized memory or lie in a window whose flush is
+        still flying."""
         r = cursor & 7
-        win = pl.ds(pl.multiple_of(cursor - r, 8), WIN)
+        tile = pl.multiple_of(cursor - r, 8)
         region = (iota_win >= r) & (iota_win < r + cnt)
-        pltpu.store(acc.at[win], rows,
+        pltpu.store(acc.at[pl.ds(tile, WIN)], rows,
                     mask=jnp.broadcast_to(region, rows.shape))
 
-    def drain(dst_ref, stage_buf, sem, pend):
-        """Wait a still-flying flush before its staging buffer/semaphore
-        is reused or the kernel exits (the descriptor's address only
-        sizes the semaphore wait; the in-flight copy's target differs)."""
-        @pl.when(pend > 0)
-        def _():
-            pltpu.make_async_copy(stage_buf, window(dst_ref, 0), sem).wait()
+        @pl.when(cursor + cnt > RS)
+        def _wrap():
+            over = pl.multiple_of(RS - tile, 8)
+            pltpu.store(acc.at[pl.ds(0, WIN)], spill(over),
+                        mask=jnp.broadcast_to(iota_win + over < r + cnt,
+                                              rows.shape))
 
-    def flush(acc, dst_ref, wbase, stage_buf, sem, pend):
-        """Write the full first window of the accumulator and slide.
-        The DMA is NOT waited here: it flies while the next chunks
-        compute, and the NEXT flush (which needs the staging buffer)
-        waits it — flush windows are disjoint from every later access
-        until then.  The slide is safe immediately: the DMA reads the
-        staging copy, not the accumulator."""
-        drain(dst_ref, stage_buf, sem, pend)
-        stage_buf[:] = acc[0:CHUNK]
+    def fills(cursor, cnt):
+        """1 where `cnt` more rows fill the cursor's window."""
+        return (lax.rem(cursor, CHUNK) + cnt >= CHUNK).astype(jnp.int32)
+
+    def moved(cursor, cnt):
+        """The cursor `cnt` rows on, round the ring."""
+        return jnp.where(cursor + cnt >= RS, cursor + cnt - RS, cursor + cnt)
+
+    def landed(acc, dst_ref, sem, f):
+        """Wait for flush `f` of an accumulator (the descriptor's
+        addresses only size the semaphore wait)."""
+        pltpu.make_async_copy(acc.at[pl.ds(0, CHUNK)], window(dst_ref, 0),
+                              sem.at[lax.rem(f, NW)]).wait()
+
+    def reserve(acc, dst_ref, sem, f, fl):
+        """Before the put that fills the window of flush `f` (`fl` says
+        it does): that put may run on into the window behind, whose last
+        flush was number f + 1 - NW and may still fly.  It is the ONE
+        wait a flush costs; every other store of the kernel stays inside
+        windows no flush is reading."""
+        @pl.when((fl > 0) & (f >= NW - 1))
+        def _():
+            landed(acc, dst_ref, sem, f + 1 - NW)
+
+    def flush(acc, dst_ref, sem, f):
+        """Flush number `f` of an accumulator: its window f % NW, full,
+        to the f-th window of the segment's aligned stream, by ONE DMA
+        out of the ring itself: no copy to a stage, no slide (PR 38).
+        Not waited here: it flies while the cursor fills the next
+        window, and `reserve` waits for it NW - 1 flushes on."""
+        h = lax.rem(f, NW)
         pltpu.make_async_copy(
-            stage_buf, window(dst_ref, pl.multiple_of(wbase, 8)),
-            sem).start()
-        acc[0:CHUNK] = acc[CHUNK:C2]
+            acc.at[pl.ds(pl.multiple_of(h * CHUNK, CHUNK), CHUNK)],
+            window(dst_ref, pl.multiple_of(base + f * CHUNK, 8)),
+            sem.at[h]).start()
+
+    def drain(acc, dst_ref, sem, flushes):
+        """Wait for what `reserve` has not: the last NW - 1 of an
+        accumulator's `flushes`, before their HBM rows are read or the
+        kernel exits."""
+        for j in range(1, NW):
+            @pl.when(flushes >= j)
+            def _(j=j):
+                landed(acc, dst_ref, sem, flushes - j)
 
     # the ring holds its depth in GROUPS of chunks for pass A, in chunks
     # for pass B
@@ -1084,9 +1152,10 @@ def _acc_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
         r_r = (the right cursor) & 7 of the first tile behind them, both
         in original order; a row outside the segment to -1, which is no
         row.  The cursors at a chunk are the carried ones (`lo_`, `ro_`)
-        plus the counts of the trip's earlier chunks; a flush moves a
-        cursor by CHUNK, which is 0 modulo 8.  `windows` hold the split
-        column: [C, 128] of the chunk, or of a column block's snapshot."""
+        plus the counts of the trip's earlier chunks; the ring's length
+        is 0 modulo 8, so a cursor that wraps keeps its part.  `windows`
+        hold the split column: [C, 128] of the chunk, or of a column
+        block's snapshot."""
         raw = None
         for g, window in enumerate(windows):
             column = lax.dot_general(
@@ -1151,28 +1220,30 @@ def _acc_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
         return nlk, jnp.maximum(j1 - j0, 0) - nlk
 
     def place(g, nlk, nrk, carry):
-        nl, nr, lo_, ro_, lfl, rfl, pl_, pr_ = carry
+        nl, nr, lo_, ro_, lfl, rfl = carry
         # each side is a tile-aligned window of the block, put at the
         # tile of its cursor: the first side's the block's head, the
         # staged side's from the first tile behind the first side's rows
         tile_r = pl.multiple_of(((lo_ & 7) + nlk + 7) & -8, 8)
-        put(lacc, lo_, nlk, blk[g, 0:WIN])
-        fl = ((lo_ + nlk) >= CHUNK).astype(jnp.int32)
+        fl, fr = fills(lo_, nlk), fills(ro_, nrk)
+        reserve(lacc, payload_out, sem_w, lfl, fl)
+        put(lacc, lo_, nlk, blk[g, 0:WIN],
+            lambda over: blk[g, pl.ds(over, WIN)])
 
         @pl.when(fl > 0)
         def _flush_l():
-            flush(lacc, payload_out, base + lfl * CHUNK, stage, sem_w, pl_)
+            flush(lacc, payload_out, sem_w, lfl)
 
-        put(racc, ro_, nrk, blk[g, pl.ds(tile_r, WIN)])
-        fr = ((ro_ + nrk) >= CHUNK).astype(jnp.int32)
+        reserve(racc, aux_out, sem_r, rfl, fr)
+        put(racc, ro_, nrk, blk[g, pl.ds(tile_r, WIN)],
+            lambda over: blk[g, pl.ds(pl.multiple_of(tile_r + over, 8), WIN)])
 
         @pl.when(fr > 0)
         def _flush_r():
-            flush(racc, aux_out, base + rfl * CHUNK, rbuf, sem_r, pr_)
+            flush(racc, aux_out, sem_r, rfl)
 
-        return (nl + nlk, nr + nrk, lo_ + nlk - fl * CHUNK,
-                ro_ + nrk - fr * CHUNK, lfl + fl, rfl + fr,
-                jnp.maximum(pl_, fl), jnp.maximum(pr_, fr))
+        return (nl + nlk, nr + nrk, moved(lo_, nlk), moved(ro_, nrk),
+                lfl + fl, rfl + fr)
 
     def body_a(t, carry):
         k0 = t * G
@@ -1200,10 +1271,10 @@ def _acc_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
             for i in range(1, G)]
         @pl.when(t == 0)
         def _seed():
-            # the first window's prologue rows belong to the previous
-            # leaf; seeding from chunk 0 makes every later flush a plain
-            # full-window write
-            lacc[0:CHUNK] = datas[0]
+            # the first window's prologue rows (under `shift`, so in its
+            # first tile) belong to the previous leaf; seeding them from
+            # chunk 0 makes every later flush a plain full-window write
+            lacc[0:8] = ring[slots[0], 0:8]
 
         lo_, ro_ = carry[2], carry[3]
         gl, dest = routed(k0, [split_window(slot, data)
@@ -1217,20 +1288,27 @@ def _acc_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
             carry = place(i, *counts[i], carry)
         return carry
 
-    (num_left, num_right, lo_, ro_, lfl, rfl, pl_, pr_) = lax.fori_loop(
+    num_left, num_right, lo_, ro_, lfl, rfl = lax.fori_loop(
         0, (nch + G - 1) // G, body_a,
         (jnp.int32(0), jnp.int32(0), shift, shift,
-         jnp.int32(0), jnp.int32(0), jnp.int32(0), jnp.int32(0)))
+         jnp.int32(0), jnp.int32(0)))
     nl_out[0] = num_left
 
-    # rights not yet flushed go out as one final aux window (junk tails in
-    # the scratch buffer are harmless); pass B reads aux, so drain the
-    # right-flush pipeline before it starts
-    @pl.when(ro_ > 0)
-    def _flush_r_tail():
-        flush(racc, aux_out, base + rfl * CHUNK, rbuf, sem_r, pr_)
+    # rights not yet flushed go out as one final aux window, from where
+    # they lie in the ring (junk tails in the scratch buffer are
+    # harmless), a flush like any other: `reserve` keeps the count of
+    # flushes in flight, which `drain` relies on (the chip halts a kernel
+    # that exits with a semaphore not at zero); pass B reads aux, so
+    # drain the right-flush pipeline before it starts
+    tail = (lax.rem(ro_, CHUNK) > 0).astype(jnp.int32)
 
-    drain(aux_out, rbuf, sem_r, jnp.maximum(pr_, (ro_ > 0).astype(jnp.int32)))
+    reserve(racc, aux_out, sem_r, rfl, tail)
+
+    @pl.when(tail > 0)
+    def _flush_r_tail():
+        flush(racc, aux_out, sem_r, rfl)
+
+    drain(racc, aux_out, sem_r, rfl + tail)
 
     # ---- pass B: append the staged rights behind the lefts, continuing
     # in the SAME left accumulator (rights start exactly at the left
@@ -1246,7 +1324,7 @@ def _acc_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
                 ring_dma(aux_out, i, i).start()
 
     def body_b(k, carry):
-        lo_, lfl, pl_ = carry
+        lo_, lfl = carry
         slot = lax.rem(k, R)
 
         @pl.when(k + R - 1 < nchb)
@@ -1262,37 +1340,54 @@ def _acc_kernel(scalars, fvals, payload_hbm, aux_hbm, *rest,
         # memory; zero them BEFORE placement
         data = jnp.where(member[:, None] > 0, ring[slot], 0.0)
         # staged rights are already contiguous: placement is a pure rotate
-        # of the doubled window — no decomposition, no matmul
+        # of the doubled window that brings row j0 to the cursor's part
+        # under 8, then pass A's aligned masked store of the [C + 8, P]
+        # head: no decomposition, no matmul, no read of the accumulator
         placed = pltpu.roll(jnp.concatenate([data, data], axis=0),
-                            lo_ - j0 + C2, axis=0)
-        blend(lacc, placed, cnt, lo_, right_value)
-        fl = ((lo_ + cnt) >= CHUNK).astype(jnp.int32)
+                            (lo_ & 7) - j0 + C2, axis=0)
+        rows = jnp.where(iota_p == value_col, right_value, placed[0:WIN])
+        fl = fills(lo_, cnt)
+        reserve(lacc, payload_out, sem_w, lfl, fl)
+
+        def spill(over):
+            # a value has no dynamic slice: through the block's scratch,
+            # once a turn of the ring
+            blk[0, 0:WIN] = rows
+            return blk[0, pl.ds(over, WIN)]
+
+        put(lacc, lo_, cnt, rows, spill)
 
         @pl.when(fl > 0)
         def _flush_l():
-            flush(lacc, payload_out, base + lfl * CHUNK, stage, sem_w, pl_)
+            flush(lacc, payload_out, sem_w, lfl)
 
-        return (lo_ + cnt - fl * CHUNK, lfl + fl, jnp.maximum(pl_, fl))
+        return moved(lo_, cnt), lfl + fl
 
-    lo_, lfl, pl_ = lax.fori_loop(0, nchb, body_b, (lo_, lfl, pl_))
+    lo_, lfl = lax.fori_loop(0, nchb, body_b, (lo_, lfl))
 
-    # the final RMW below reuses the left staging buffer and the kernel
-    # must not exit with a flying DMA — drain the left-flush pipeline
-    drain(payload_out, stage, sem_w, pl_)
+    # the final RMW below rewrites HBM rows behind the last flush and the
+    # kernel must not exit with a flying DMA — drain the left-flush
+    # pipeline
+    drain(lacc, payload_out, sem_w, lfl)
 
     # ---- final window: its tail crosses into the next leaf's rows — the
-    # one place the kernel pays a blend read ----------------------------
-    @pl.when((count > 0) & (lo_ > 0))
+    # one place the kernel pays a blend read, into a slot of the read ring
+    # (every read of either pass has been waited for) ---------------------
+    off = lax.rem(lo_, CHUNK)
+
+    @pl.when((count > 0) & (off > 0))
     def _final():
         wbase = pl.multiple_of(base + lfl * CHUNK, 8)
         dma_r = pltpu.make_async_copy(
-            window(payload_out, wbase), rbuf, sem_r)
+            window(payload_out, wbase), ring.at[0], sem_ring.at[0])
         dma_r.start()
         dma_r.wait()
-        region = (iota_rows < lo_)[:, None]
-        stage[:] = jnp.where(region, lacc[0:CHUNK], rbuf[:])
+        region = (iota_rows < off)[:, None]
+        ring[0] = jnp.where(
+            region, lacc[pl.ds(pl.multiple_of(lo_ - off, CHUNK), CHUNK)],
+            ring[0])
         dma_w = pltpu.make_async_copy(
-            stage, window(payload_out, wbase), sem_w)
+            ring.at[0], window(payload_out, wbase), sem_ring.at[0])
         dma_w.start()
         dma_w.wait()
 
@@ -1324,15 +1419,13 @@ def _partition_segment_acc(payload, aux, start, count, pred, left_value,
             scratch_shapes=[
                 pltpu.VMEM((_RING_DEPTH * group, CHUNK, P),
                            jnp.float32),                  # read ring
-                pltpu.VMEM((C2, P), jnp.float32),         # left accumulator
-                pltpu.VMEM((C2, P), jnp.float32),         # right accumulator
-                pltpu.VMEM((CHUNK, P), jnp.float32),      # flush stage
-                pltpu.VMEM((CHUNK, P), jnp.float32),      # final blend read
+                pltpu.VMEM((ACC_ROWS, P), jnp.float32),   # left accumulator
+                pltpu.VMEM((ACC_ROWS, P), jnp.float32),   # right accumulator
                 pltpu.VMEM((group, BLOCK_SCRATCH_ROWS, P),
                            jnp.float32),                  # permuted blocks
                 pltpu.SemaphoreType.DMA((_RING_DEPTH * group,)),
-                pltpu.SemaphoreType.DMA(()),
-                pltpu.SemaphoreType.DMA(()),
+                pltpu.SemaphoreType.DMA((_ACC_WINDOWS,)),  # left flushes
+                pltpu.SemaphoreType.DMA((_ACC_WINDOWS,)),  # right flushes
             ],
         ),
         out_shape=(jax.ShapeDtypeStruct(payload.shape, payload.dtype),
@@ -1455,15 +1548,13 @@ def _partition_segment_acc_blocks(payload, aux, start, count, pred,
                            pl.BlockSpec(memory_space=pltpu.SMEM)),
                 scratch_shapes=[
                     pltpu.VMEM((slots, CHUNK, bw), jnp.float32),  # read ring
-                    pltpu.VMEM((C2, bw), jnp.float32),    # left accumulator
-                    pltpu.VMEM((C2, bw), jnp.float32),    # right accumulator
-                    pltpu.VMEM((CHUNK, bw), jnp.float32),  # flush stage
-                    pltpu.VMEM((CHUNK, bw), jnp.float32),  # final blend read
+                    pltpu.VMEM((ACC_ROWS, bw), jnp.float32),  # left acc.
+                    pltpu.VMEM((ACC_ROWS, bw), jnp.float32),  # right acc.
                     pltpu.VMEM((group, BLOCK_SCRATCH_ROWS, bw),
                                jnp.float32),              # permuted blocks
                     pltpu.SemaphoreType.DMA((slots,)),
-                    pltpu.SemaphoreType.DMA(()),
-                    pltpu.SemaphoreType.DMA(()),
+                    pltpu.SemaphoreType.DMA((_ACC_WINDOWS,)),
+                    pltpu.SemaphoreType.DMA((_ACC_WINDOWS,)),
                     pltpu.VMEM((slots, CHUNK, 128),
                                jnp.float32),              # split-window ring
                     pltpu.SemaphoreType.DMA((slots,)),

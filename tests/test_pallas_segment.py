@@ -387,6 +387,26 @@ def _while_shapes(jaxpr):
              if hasattr(v.aval, "shape")} for body in _whiles(jaxpr)]
 
 
+def _assert_flush_is_one_dma(jaxpr, width, group, loops=slice(None)):
+    """Since PR 38 a full window goes to HBM by ONE DMA out of the
+    accumulator's ring: neither loop of `_acc_kernel` stores a [C, P]
+    value (the copy to a flush stage, the slide of the accumulator's
+    second half onto its first, before), and the DMAs it starts out of an
+    accumulator ([(windows + 1) C, P]) are one a side and chunk in pass A
+    (`group` chunks a trip) and one a chunk in pass B."""
+    acc = ((pseg._ACC_WINDOWS + 1) * seg.CHUNK, width)
+    bodies = _whiles(jaxpr)[loops]
+    assert len(bodies) == 2
+    for body, flushes in zip(bodies, (2 * group, 1)):
+        stores = [eqn.invars[1].aval.shape for eqn in _eqns(body)
+                  if eqn.primitive.name in ("swap", "masked_swap")]
+        assert stores and (seg.CHUNK, width) not in stores
+        from_acc = [eqn for eqn in _eqns(body)
+                    if eqn.primitive.name == "dma_start"
+                    and eqn.invars[0].aval.shape == acc]
+        assert len(from_acc) == flushes
+
+
 def test_pass_a_is_one_permutation():
     """Pass A of `_acc_kernel` places a chunk with ONE permutation: its
     loop body holds 4 MXU contractions a chunk (the split column read out
@@ -399,7 +419,9 @@ def test_pass_a_is_one_permutation():
     accumulators by tile-aligned windows: pass A's loop holds no rotate
     and computes no value of the accumulators' [2C, P] (the doubled block,
     its two rotated copies and the selects over them, before); pass B
-    keeps its one rotate of a doubled window.  Traced only, nothing runs."""
+    keeps its one rotate of a doubled window.  Since PR 38 neither loop
+    copies a window to flush it (`_assert_flush_is_one_dma`).  Traced
+    only, nothing runs."""
     pay = _payload(1024)
     closed = jax.make_jaxpr(
         lambda p, a: pseg._partition_segment_acc(
@@ -413,6 +435,7 @@ def test_pass_a_is_one_permutation():
     assert _while_rotates(closed.jaxpr) == [0, 1]
     assert (pseg.C2, P) not in pass_a and (pseg.C2, P) in pass_b
     assert (pseg.BLOCK_ROWS, P) in pass_a and (pseg.WIN, P) in pass_a
+    _assert_flush_is_one_dma(closed.jaxpr, P, pseg._pass_a_group(P, B))
 
 
 @pytest.mark.parametrize("width,group", [(P, 2), (256, 2), (384, 1)])
@@ -437,13 +460,14 @@ def test_partition_acc_groups(width, group, start, count):
     np.testing.assert_array_equal(np.asarray(got_pay), np.asarray(ref_pay))
 
 
-def _sided_payload(first_rows, start, count, width, right_first, seed):
+def _sided_payload(first_rows, start, count, width, right_first, seed,
+                   n_pad=1536):
     """A payload whose split column (feature 1, threshold B // 2) sends
     `first_rows(k, nv)` of the `nv` segment rows of chunk k of the
     kernel's aligned read stream to the FIRST side (the left child, or
     the right one with `right_first`), at random places; and the counts
     it made, a (first, second) pair a chunk."""
-    pay = np.array(_payload(1536, seed=seed))
+    pay = np.array(_payload(n_pad, seed=seed))
     rng = np.random.default_rng(seed)
     first_bin, second_bin = (B - 1, 0) if right_first else (0, B - 1)
     base = start - start % 8
@@ -519,6 +543,92 @@ def test_pass_a_cursor_residues(kernel, width, r_first, r_staged):
         assert ((start + counts[0][0]) % 8, (start + counts[0][1]) % 8) \
             == (r_first, r_staged)
         assert counts[1] == (130, seg.CHUNK - 130) and len(counts) == 3
+        _check_exact(kernel, pay, start, count, _pred(), VALUE_COL, B,
+                     right_first)
+
+
+#: windows of an accumulator's ring (PR 38); a cursor that leaves the last
+#: one comes back into the first
+NW = pseg._ACC_WINDOWS
+
+
+@pytest.mark.parametrize("share", [100, 90, 0])
+@pytest.mark.parametrize("start", [0, 5])
+@pytest.mark.parametrize("kernel,width", [PLANS[1], PLANS[4]])
+def test_acc_ring_goes_round(kernel, width, start, share):
+    """Segments long enough for an accumulator's ring to go round three
+    times: all of the rows, nine in ten or none on the first side, so
+    that the first side's ring (pass A), the staged side's (pass A) and
+    the first side's again (pass B, appending the staged rows) each wrap
+    and every put past the ring's end lands in the window flushed NW - 1
+    chunks earlier.  One pass and a column block at a time, each child
+    order, bit for bit."""
+    chunks = 3 * NW + 2
+    count = chunks * seg.CHUNK - start - 3
+    for right_first in (False, True):
+        pay, counts = _sided_payload(
+            lambda k, nv: nv * share // 100, start, count, width, right_first,
+            seed=share + start, n_pad=(chunks + 1) * seg.CHUNK)
+        assert len(counts) == chunks
+        _check_exact(kernel, pay, start, count, _pred(), VALUE_COL, B,
+                     right_first)
+
+
+@pytest.mark.parametrize("r", range(8))
+@pytest.mark.parametrize("window", range(NW))
+@pytest.mark.parametrize("side", ["first", "staged"])
+@pytest.mark.parametrize("kernel,width", [PLANS[1], PLANS[4]])
+def test_acc_ring_window_ends(kernel, width, side, window, r):
+    """A side's cursor in each window of its ring, at each part under 8,
+    meets a chunk whose rows cross that window's end: the last window's
+    is the ring's, where the put is two stores, the second at the ring's
+    head.  Whole chunks walk the cursor to the window, one chunk sets its
+    part (row 200 + r of the window), the next brings 130 rows; the other
+    side takes what is left."""
+    start = (3 * r + window) % 8
+    lead = [seg.CHUNK] * window + [200 + r - (start if window == 0 else 0),
+                                   130, 9]
+    count = len(lead) * seg.CHUNK - start - 100
+    for right_first in (False, True):
+        pay, counts = _sided_payload(
+            lambda k, nv: lead[k] if side == "first" else nv - lead[k],
+            start, count, width, right_first, seed=8 * window + r,
+            n_pad=(len(lead) + 1) * seg.CHUNK)
+        mine = [c[side == "staged"] for c in counts]
+        # both cursors start at `start % 8`: where the side's stands when
+        # the chunk of 130 comes
+        at = start + sum(mine[:window + 1])
+        assert (at // seg.CHUNK, at % seg.CHUNK) == (window, 200 + r)
+        assert mine[window + 1] == 130
+        _check_exact(kernel, pay, start, count, _pred(), VALUE_COL, B,
+                     right_first)
+
+
+@pytest.mark.parametrize("r", range(8))
+@pytest.mark.parametrize("window", range(NW))
+@pytest.mark.parametrize("kernel,width", [PLANS[1], PLANS[4]])
+def test_acc_ring_pass_b_hand_off(kernel, width, window, r):
+    """Pass B takes the first side's accumulator over where pass A left
+    its cursor (in each window of the ring, at each part under 8) and
+    appends a ring's length of staged rows and more behind it: its puts
+    cross every window's end and the ring's, from whichever window the
+    hand-off fell in."""
+    start = (5 * r + window) % 8
+    first = window * seg.CHUNK + 100 + r - start      # rows pass A keeps
+    chunks = window + NW + 3
+    count = chunks * seg.CHUNK - start - 50
+
+    def first_rows(k, nv):
+        # spread over the leading chunks, 200 rows at a time
+        return int(np.clip(first - 200 * k, 0, 200))
+
+    for right_first in (False, True):
+        pay, counts = _sided_payload(
+            first_rows, start, count, width, right_first,
+            seed=8 * window + r, n_pad=(chunks + 1) * seg.CHUNK)
+        at = start + sum(c[0] for c in counts)
+        assert (at // seg.CHUNK, at % 8) == (window, (100 + r) % 8)
+        assert sum(c[1] for c in counts) > (NW + 1) * seg.CHUNK
         _check_exact(kernel, pay, start, count, _pred(), VALUE_COL, B,
                      right_first)
 
@@ -761,6 +871,49 @@ def test_partition_engine_outside_the_band_is_the_plans_order(monkeypatch,
     assert band == list(range(band[0], band[-1] + 1, 128))
 
 
+#: what the four gates answered at PR 37, before the accumulators became
+#: rings (taken from that commit, the same at 16, 64, 255, 256 and 1,024
+#: bins): by lane-padded width from .. to, the partition engine, pass A's
+#: chunks a trip in one pass and in a column block of that width (512 at
+#: most) beside its split-window ring, whether the one-pass plan fits,
+#: whether the column-block plan fits
+GATES_AT_PR37 = [
+    ((128, 256), ("pallas-acc", 2, 2, True, True)),
+    ((384, 512), ("pallas-acc", 1, 1, True, True)),
+    ((640, 4480), ("pallas-blocks", 1, 1, False, True)),
+]
+
+
+@pytest.mark.parametrize("bins", [16, 64, 255, 256, 1024])
+def test_gates_answer_as_before_the_rings(monkeypatch, bins):
+    """`_acc_plan_bytes` counts what the body holds since PR 38 (two
+    rings of three windows and a tail each, 8C rows, where two [2C, P]
+    accumulators, a flush stage and a blend buffer were 6C), and the
+    gates that read it answer as they did: `partition_engine`,
+    `_pass_a_group`, `partition_acc_fits_vmem` and
+    `partition_blocks_fits_vmem` at every lane-padded width from 128 to
+    4,480 lanes, 175 shapes with the five bin counts.  (Whether the chip
+    agrees with the plan is Mosaic's verdict, tests/test_tpu_compile.py.)"""
+    if seg.CHUNK != 256:
+        pytest.skip("VMEM gate expectations assume the default CHUNK")
+    from lightgbm_tpu.boosting import grower2
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    widths = []
+    for (lo, hi), want in GATES_AT_PR37:
+        for width in range(lo, hi + 1, 128):
+            widths.append(width)
+            got = (grower2.partition_engine("auto", width, bins),
+                   pseg._pass_a_group(width, bins),
+                   pseg._pass_a_group(
+                       min(pseg._BLOCK_WIDTH, width), bins,
+                       pseg._route_ring_bytes(pseg._PASS_A_GROUP)),
+                   pseg.partition_acc_fits_vmem(width, bins),
+                   pseg.partition_blocks_fits_vmem(width, bins))
+            assert got == want, width
+    assert widths == list(range(128, 4481, 128))
+    assert pseg.ACC_ROWS == (pseg._ACC_WINDOWS + 1) * seg.CHUNK
+
+
 @pytest.mark.parametrize("backend,features,bins,width,kw,engine", [
     ("tpu", 28, 256, 128, {}, "pallas"),          # higgs-train
     ("tpu", 67, 256, 128, {}, "pallas"),          # criteo-dp4-train, a shard
@@ -897,9 +1050,11 @@ def test_partition_blocks_pass_a_is_one_permutation():
     # the snapshot, then pass A and pass B of each block: a rotate in
     # pass B alone, nothing of an accumulator's shape in pass A
     assert _while_rotates(closed.jaxpr) == [0, 0, 1, 0, 1, 0, 1]
-    for pass_a, width in zip(shapes[1::2], (512, 512, 256)):
+    for i, (pass_a, width) in enumerate(zip(shapes[1::2], (512, 512, 256))):
         assert (pseg.C2, width) not in pass_a
         assert (pseg.BLOCK_ROWS, width) in pass_a
+        _assert_flush_is_one_dma(closed.jaxpr, width, 1 + (width == 256),
+                                 slice(1 + 2 * i, 3 + 2 * i))
 
 
 def test_partition_blocks_narrow_pin():

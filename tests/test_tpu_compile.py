@@ -85,7 +85,15 @@ def test_partition_acc_compiles_for_v5e(one_chip, lanes, bins):
     the sublane broadcast into the one-hot are Mosaic's to accept; and so
     is its placement: a [C + 24, C] one-hot's product stored to a scratch,
     and loads and stores of [C + 8, P] windows of the scratch and of the
-    accumulators at traced starts that are multiples of 8."""
+    accumulators at traced starts that are multiples of 8.  Since PR 38
+    each accumulator is a ring of three windows and a chunk's tail that
+    a flush's DMA reads at a traced window, a put that crosses the ring's
+    end stores a second window of the scratch at the ring's head, and
+    pass B places the head of its rotated chunk the same way; the scratch
+    list holds no flush stage and no blend buffer.  Mosaic's verdict on
+    the VMEM asked for is the check of `_acc_plan_bytes`, at the widest
+    shapes its gates admit (512 lanes here, a 512-lane block beside its
+    split-window ring below)."""
     assert pseg.partition_acc_fits_vmem(lanes, bins)
     lowered = pseg._partition_segment_acc.lower(
         *_partition_args(one_chip, ROWS * LANES // lanes, lanes, bins=bins))
