@@ -25,6 +25,9 @@ the raw data (row counts equal -- past 2^24 rows, within what float32
 counting loses -- and leaf values within the configuration's
 `leaf_value_atol`); and the held-out measure of the model cut at
 `quality_at_iter` lies in the band recorded when the cell was defined.
+A task's `first_tree` may name further numbers it compared, under
+`compared` ({name: [value, limit]}): they follow the driver's own in
+the result's `compared` and never replace one.
 """
 import os
 import sys
@@ -68,7 +71,7 @@ def setup(run):
         bst.current_iteration()             # drains the dispatch pipeline
     run.state.update(bst=bst, task=task, data=data, held=held,
                      binning=train_set.binned.binning)
-    run.say("train", rows=len(data["X"]), features=data["X"].shape[1],
+    run.say("train", rows=data["X"].shape[0], features=data["X"].shape[1],
             binning=run.state["binning"], **run.setup)
 
 
@@ -132,6 +135,7 @@ def verify(run):
     atol = cfg.get("leaf_value_atol", LEAF_VALUE_ATOL)
     tree0 = dict(task.first_tree(trees[0], run.state["data"], cfg),
                  leaf_value_atol=atol)
+    task_compared = tree0.pop("compared", {})
     checks["tree0"] = tree0
     ok = ok and tree0["counts_ok"] and tree0["max_value_diff"] <= atol
 
@@ -154,5 +158,7 @@ def verify(run):
     if "heldout" in checks:
         compared["heldout_in_band"] = [checks["heldout"]["value"],
                                        checks["heldout"]["band"]]
+    for name, pair in task_compared.items():    # never over the driver's
+        compared.setdefault(name, pair)
     return {"correct": bool(ok), "attempted": iters, "failed": int(failed),
             "checks": checks, "compared": compared}

@@ -18,8 +18,19 @@ produced.  Three uses:
 * `leaf_index` itself, for the held-out metric.
 
 Rows are walked in chunks across threads (lib/parallel.py).
+
+`X` is a dense [n, F] array or a scipy.sparse table.  A sparse one is
+made CSR once, where a walk enters, and is never densified: a walk reads
+one value a row and a level, the stored value or 0.0 where the row
+stores none, which is what LightGBM means by an absent entry.  That 0.0
+then meets the node's missing type as a stored 0.0 does (zero-as-missing
+routes it by `default_left`, otherwise it is compared with the
+threshold), and a stored NaN is a NaN.  A chunk is a row slice, which of
+CSR is views of its arrays, so a level costs O(rows) and never O(rows x
+columns).
 """
 import numpy as np
+from scipy import sparse
 
 from benchmarks.lib import parallel
 
@@ -63,13 +74,28 @@ def _depths(left, right, ni):
     return depth
 
 
+def _walkable(X):
+    """X as a walk reads it: a dense array as it is, a sparse table as
+    CSR (a CSR table itself, not a copy)."""
+    return X.tocsr() if sparse.issparse(X) else X
+
+
+def _at(X, rows, cols):
+    """X[rows[i], cols[i]] for every i: of a CSR table the stored value,
+    or 0.0 where the row stores none (scipy's own sampling, in X's
+    dtype, as a dense read is)."""
+    if sparse.issparse(X):
+        return np.asarray(X[rows, cols]).reshape(-1)
+    return X[rows, cols]
+
+
 def _walk(table, depth, X):
     """The leaf of each row of X, down at most `depth` levels."""
     feat, thr, miss, dleft, left, right, ni = table
-    node = np.zeros(len(X), np.int64)
-    rows = np.arange(len(X))
+    node = np.zeros(X.shape[0], np.int64)
+    rows = np.arange(X.shape[0])
     for _ in range(depth):
-        x = X[rows, feat[node]]
+        x = _at(X, rows, feat[node])
         m = miss[node]
         nan = np.isnan(x)
         x = np.where(nan & (m != MISSING_NAN), 0.0, x)
@@ -88,22 +114,24 @@ def _walker(tree):
 
 def leaf_index(tree, X):
     """The leaf each row of X [n, F] falls into."""
+    X = _walkable(X)
     table, depth = _walker(tree)
-    out = np.empty(len(X), np.int64)
+    out = np.empty(X.shape[0], np.int64)
 
     def part(_, lo, hi):
         out[lo:hi] = _walk(table, depth, X[lo:hi])
 
-    parallel.for_chunks(parallel.even_bounds(len(X)), part)
+    parallel.for_chunks(parallel.even_bounds(X.shape[0]), part)
     return out
 
 
 def predict_raw(trees, X):
     """Raw score of each row: the sum of its leaves' values, float64,
     tree after tree."""
+    X = _walkable(X)
     walkers = [_walker(t) + (np.asarray(t.leaf_value, np.float64),)
                for t in trees]
-    out = np.empty(len(X), np.float64)
+    out = np.empty(X.shape[0], np.float64)
 
     def part(_, lo, hi):
         acc = np.zeros(hi - lo)
@@ -111,7 +139,7 @@ def predict_raw(trees, X):
             acc += values[_walk(table, depth, X[lo:hi])]
         out[lo:hi] = acc
 
-    parallel.for_chunks(parallel.even_bounds(len(X)), part)
+    parallel.for_chunks(parallel.even_bounds(X.shape[0]), part)
     return out
 
 
@@ -168,7 +196,7 @@ def tree0_check(tree, X, y, learning_rate, lambda_l2=0.0):
     off = np.abs(count - np.asarray(tree.leaf_count[:nl], np.int64))
     slack = count_slack(tree, count)
     return {
-        "leaves": nl, "rows": int(len(X)),
+        "leaves": nl, "rows": int(X.shape[0]),
         "counts_ok": bool((off <= slack).all()),
         "max_count_diff": int(off.max()), "leaves_off": int((off > 0).sum()),
         "count_slack_max": int(slack.max()),

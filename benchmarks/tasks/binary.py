@@ -11,7 +11,21 @@ What drivers/train.py asks of a task, and all it knows of one:
     heldout(trees, data, cfg)     the held-out measure, one float
 
 `data` is the task's own dictionary; the driver reads `X` and `y` of it
-and hands the rest back untouched.
+and hands the rest back untouched.  `X` is a dense [n, F] array or a
+scipy.sparse CSR table (`csr_matrix` or `csr_array`, float32 or
+float64) in which an absent entry means 0.0; the driver reads only its
+`shape` and hands it to `lgb.Dataset` as it is.  `lib/reference.py`
+walks either form: a dense array by `X[rows, cols]`, a CSR table by the
+value each row stores, 0.0 where it stores none, a row slice a chunk,
+never densified (another sparse format is made CSR once, where a walk
+enters).
+
+`first_tree` returns `counts_ok`, `max_value_diff` and detail, and may
+return `compared`, {name: [value, limit]}: numbers of the task's own
+that the result's `compared` shows after the driver's (a name the
+driver uses stays the driver's).  What decides `correct` is still
+`counts_ok` and `max_value_diff`: fold into `counts_ok` whatever of
+them must hold.
 """
 from benchmarks.lib import quality, reference, synth
 

@@ -503,7 +503,8 @@ def first_tree(tree, data, cfg):
     categorical column here has more than max_cat_to_onehot values).
     And the root's split held to the plain search: its gain, recomputed
     in float64 from the rows it sends left, within ROOT_GAIN_RTOL of the
-    best the search above finds in any column.  `counts_ok` is both.
+    best the search above finds in any column.  `counts_ok` is both; the
+    root's gain is also an entry of the task's own `compared`.
 
     `max_value_diff` is NOT the largest difference over the leaves but
     their THIRD QUARTILE, or a `LONE_LEAF_ROOM`-th of the largest where
@@ -584,6 +585,8 @@ def first_tree(tree, data, cfg):
         "root_feature_plain": int(plain["feature"]),
         "root_gain_by_column": {COLUMNS[c]: v
                                 for c, v in plain["columns"].items()},
+        "compared": {"tree0_root_gain_rel_diff": [float(gain_off),
+                                                  ROOT_GAIN_RTOL]},
     }
 
 

@@ -494,8 +494,9 @@ def first_tree(tree, data, cfg):
     to the plain search: its gain, recomputed in float64 from the rows its
     feature, threshold and direction send left, within ROOT_GAIN_RTOL of
     the best the search finds in any of the 968 columns in either
-    direction.  `counts_ok` is both (drivers/train.py fixes the keys of
-    `compared`, so the root's numbers stand on standard error).
+    direction.  `counts_ok` is both; the root's gain and direction are
+    also entries of the task's own `compared`, beside their lines on
+    standard error (the direction is shown, not held).
 
     Tree 0's values are held to TWO limits.  `max_value_diff`, which
     drivers/train.py holds to `leaf_value_atol`, is the THIRD QUARTILE of
@@ -504,8 +505,8 @@ def first_tree(tree, data, cfg):
     and the hessian, 0.0058 and 0.00577, to neighbouring or equal steps),
     so the quartile tells it from a sound run by the widest margin.  The
     LARGEST difference, which `reference.tree0_check` gives, is held here
-    to the configuration's `leaf_value_largest_atol`, printed as a
-    `compared` line and folded into `counts_ok` as the root's gain is: the
+    to the configuration's `leaf_value_largest_atol`, a `compared` entry
+    and line, and folded into `counts_ok` as the root's gain is: the
     leaf that holds most of the positives reads several times the others
     in sound runs (its gradient sum is a difference of two large float32
     sums), so this limit is the wider of the two, and it is what catches
@@ -563,6 +564,11 @@ def first_tree(tree, data, cfg):
         "root_feature_plain": int(plain["feature"]),
         "root_value_plain": plain["value"],
         "root_default_left_plain": bool(plain["default_left"]),
+        "compared": {
+            "tree0_root_gain_rel_diff": [float(gain_off), ROOT_GAIN_RTOL],
+            "tree0_largest_value_diff": [largest, largest_atol],
+            "tree0_root_default_left": [default_left,
+                                        bool(plain["default_left"])]},
     })
     return out
 

@@ -8,7 +8,7 @@ import sys
 
 import pytest
 
-from conftest import BENCH, ROOT, metric_entry, run_tiny
+from conftest import BENCH, DATA, ROOT, metric_entry, run_tiny
 
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
                "compared"}
@@ -226,6 +226,56 @@ def test_new_task_is_found_as_new_files(bench_tree):
     assert result["compared"]["tree0_max_count_diff"][0] == 0
     for path, content in before.items():
         assert open(path, "rb").read() == content
+
+
+#: `binary` with numbers of its own in `compared`, one of them under a
+#: name the driver uses
+BINARY_COMPARED = '''"""`binary`, and two entries of its own in `compared`."""
+import os
+
+from benchmarks.run import load_module
+
+binary = load_module(os.path.join(os.path.dirname(__file__), "binary.py"))
+make, dataset_args, heldout = binary.make, binary.dataset_args, binary.heldout
+
+
+def first_tree(tree, data, cfg):
+    out = binary.first_tree(tree, data, cfg)
+    out["compared"] = {"tree0_leaves": [out["leaves"], 15],
+                       "trees_failed": [7, 0]}
+    return out
+'''
+
+
+def test_a_tasks_own_compared_entries_follow_the_drivers(bench_tree):
+    """`first_tree` may return `compared`: its entries come after the
+    driver's, a name the driver uses keeps the driver's value, and what
+    decides `correct` does not change."""
+    root, bench_dir = bench_tree["root"], bench_tree["bench_dir"]
+    with open(os.path.join(bench_dir, "tasks", "binary_compared.py"),
+              "w") as fh:
+        fh.write(BINARY_COMPARED)
+    with open(os.path.join(DATA, "tiny-higgs.json")) as fh:
+        config = dict(json.load(fh), task="binary_compared")
+    with open(os.path.join(root, "tiny-compared.json"), "w") as fh:
+        json.dump(config, fh)
+    manifest = bench_tree["manifest"]
+    manifest["configs"].append({
+        "name": "tiny-compared",
+        "file": os.path.join(root, "tiny-compared.json")})
+    manifest["workloads"].append({"name": "tiny-compared-train",
+                                  "config": "tiny-compared",
+                                  "traffic": "train", "chips": 1})
+    with open(bench_tree["manifest_path"], "w") as fh:
+        json.dump(manifest, fh)
+    result = run_tiny(bench_tree, "tiny-compared-train", seconds=1.0)
+    check_result(result, trace=False)
+    assert list(result["compared"]) == [
+        "trees_failed", "payload_devices", "tree0_max_count_diff",
+        "tree0_max_value_diff", "heldout_in_band", "tree0_leaves",
+        "compiled_in_window"]
+    assert result["compared"]["trees_failed"] == [0, 0]
+    assert result["compared"]["tree0_leaves"] == [15, 15]
 
 
 @pytest.mark.parametrize("seed", [3, 2**31 + 7])

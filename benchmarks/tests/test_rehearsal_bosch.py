@@ -12,6 +12,7 @@ import pytest
 
 from conftest import BENCH, ROOT, run_tiny
 from test_rehearsal import check_result
+from test_rehearsal_expo_cat import DRIVER_COMPARED
 
 MISSING_READERS = {"split.missing_share", "split.default_left_share",
                    "partition.missing_row_share"}
@@ -111,6 +112,13 @@ def test_bosch_cell_untraced(bosch_tree, capfd):
     assert tree0["root_feature"] == tree0["root_feature_plain"] \
         or value <= 1e-9
     assert tree0["root_default_left"] == tree0["root_default_left_plain"]
+    # the task's own three entries, after the driver's
+    assert list(result["compared"])[:5] == DRIVER_COMPARED
+    assert result["compared"]["tree0_root_gain_rel_diff"] == [value, limit]
+    assert result["compared"]["tree0_largest_value_diff"] \
+        == tree0["largest_value_diff"]
+    assert result["compared"]["tree0_root_default_left"] \
+        == [tree0["root_default_left"], tree0["root_default_left_plain"]]
     # the root is cut on a reading of the final test, which a third of the
     # parts skip
     assert 0.30 < tree0["root_nan_rows"] / 20000 < 0.35

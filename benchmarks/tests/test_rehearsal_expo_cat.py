@@ -16,6 +16,9 @@ CAT_READERS = {"split.categorical_share", "ingest.encode_cat_s"}
 #: a device-trace reader: a CPU trace has no device plane, so it finds
 #: nothing here and is left out; the chip's reading is in PERF.md
 CAT_DEVICE_READERS = {"grower.cat_search_s_per_iter"}
+#: what drivers/train.py compares of every train cell, in its order
+DRIVER_COMPARED = ["trees_failed", "payload_devices", "tree0_max_count_diff",
+                   "tree0_max_value_diff", "heldout_in_band"]
 
 
 def load_task():
@@ -95,6 +98,9 @@ def test_expo_cat_cell_untraced(expo_tree):
     assert tree0["root_feature_plain"] == 5
     value, limit = tree0["root_gain_rel_diff"]
     assert value <= 1e-6 and limit == 1e-4
+    # the task's own entry, after the driver's
+    assert list(result["compared"])[:5] == DRIVER_COMPARED
+    assert result["compared"]["tree0_root_gain_rel_diff"] == [value, limit]
     assert 2 <= tree0["largest_left_set"] <= 32
     # every column within 256 bins, coded by the native library
     assert detail["train"][0]["binning"]["path"] == "native"

@@ -25,7 +25,7 @@ def _run(counters, iters=2, K=1):
 
 def test_manifest_entry_is_the_readers():
     real = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    entry = real["per_layer"][-1]
+    [entry] = [m for m in real["per_layer"] if m["name"] == NAME]
     reader = _reader()
     assert entry == {"name": NAME, "unit": reader.UNIT, "better": "lower",
                      "source": reader.SOURCE, "layer": reader.LAYER,
